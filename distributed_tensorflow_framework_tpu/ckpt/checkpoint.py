@@ -351,6 +351,23 @@ class CheckpointManager:
             saved_topo, template, allow_reshard=self.config.allow_reshard,
             directory=self._path, step=step)
 
+        saved_layout = (saved_topo or {}).get("opt_layout")
+        if saved_layout is not None and saved_layout != \
+                reshard.opt_layout_digest(template.opt_state):
+            # Compared from the manifest's own record, before Orbax sees
+            # the template: its shape/rank errors carry no tree path and
+            # change wording between releases.
+            raise ValueError(
+                f"checkpoint step {step} in {self._path} stores an "
+                f"optimizer state whose slot layout does not match this "
+                f"run's: toggling optimizer.zero_sharding between "
+                f"'shard_map' and another mode (or precision.fused_update, "
+                f"which regroups the slots per ZeRO bucket) across a resume "
+                f"is unsupported (replicated, ZeRO-stacked and per-bucket "
+                f"slot layouts are incompatible) — restore with the "
+                f"settings the checkpoint was saved under"
+            )
+
         want_ema = bool(jax.tree.leaves(template.ema_params))
         want_res = bool(jax.tree.leaves(template.collective_residual))
         n_want = (jax.tree.leaves(template.collective_residual)[0].shape[0]
@@ -506,25 +523,6 @@ class CheckpointManager:
                     stored_res = ("empty" if stored_res == "shaped"
                                   else "shaped")
                     continue
-                if "opt_state" in msg or "Ranks do not match" in msg:
-                    # A slot-shape (or tensorstore rank — the stacked
-                    # (n, chunk) layout differs in RANK from the param
-                    # shape, and that error carries no tree path)
-                    # mismatch here is the ZeRO layout
-                    # toggled (or re-gridded without a reshard plan)
-                    # across a resume — name the knob instead of leaking
-                    # an orbax tree error.
-                    raise ValueError(
-                        f"checkpoint step {step} in {self._path} stores an "
-                        f"optimizer state whose slot layout does not match "
-                        f"this run's: toggling optimizer.zero_sharding "
-                        f"between 'shard_map' and another mode (or "
-                        f"precision.fused_update, which regroups the slots "
-                        f"per ZeRO bucket) across a resume is unsupported "
-                        f"(replicated, ZeRO-stacked and per-bucket slot "
-                        f"layouts are incompatible) — restore with the "
-                        f"settings the checkpoint was saved under ({e})"
-                    ) from e
                 raise
         if reshard_plan is not None:
             # Cross-mesh load succeeded mechanically; confirm it moved

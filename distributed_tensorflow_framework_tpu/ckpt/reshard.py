@@ -146,6 +146,22 @@ def spec_digest(state: Any) -> str:
     return h.hexdigest()
 
 
+def opt_layout_digest(opt_state: Any) -> str:
+    """sha256 over every optimizer-slot leaf's (tree path, rank) — which
+    slot LAYOUT the state uses, independent of the mesh's size: slots
+    shaped like their params (replicated / jit-sharded), ZeRO-stacked
+    ``(n, chunk)`` rows (every slot rank 2), or per-bucket states
+    (precision.fused_update — another tree). A reshard keeps it (8 rows
+    refold to 4); toggling the layout across a resume changes it, which
+    the restore refuses by name before Orbax sees the template."""
+    h = hashlib.sha256()
+    leaves, _ = jax.tree_util.tree_flatten_with_path(opt_state)
+    for path, leaf in leaves:
+        h.update(f"{jax.tree_util.keystr(path)}:"
+                 f"{len(getattr(leaf, 'shape', ()))}\n".encode())
+    return h.hexdigest()
+
+
 def state_topology(state: Any, *, mesh: Mesh | None = None,
                    process_count: int | None = None) -> dict | None:
     """The manifest topology record for a (sharded) state, or None when
@@ -159,6 +175,7 @@ def state_topology(state: Any, *, mesh: Mesh | None = None,
         "process_count": int(
             jax.process_count() if process_count is None else process_count),
         "spec_digest": spec_digest(state),
+        "opt_layout": opt_layout_digest(state.opt_state),
     }
 
 

@@ -17,9 +17,7 @@ import argparse
 import logging
 import sys
 
-from distributed_tensorflow_framework_tpu.cli.train import (
-    _honor_platform_env,
-)
+from distributed_tensorflow_framework_tpu.core import platform
 from distributed_tensorflow_framework_tpu.core.config import load_config
 from distributed_tensorflow_framework_tpu.core.metrics import setup_logging
 
@@ -41,9 +39,10 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     setup_logging()
-    _honor_platform_env()
+    platform.apply_cpu_collective_timeouts()
     args = parse_args(argv)
     config = load_config(args.config, overrides=list(args.overrides))
+    platform.resolve_compilation_cache()
     from distributed_tensorflow_framework_tpu.serve.export import (
         export_checkpoint,
     )

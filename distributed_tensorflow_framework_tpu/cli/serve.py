@@ -23,9 +23,7 @@ import logging
 import os
 import sys
 
-from distributed_tensorflow_framework_tpu.cli.train import (
-    _honor_platform_env,
-)
+from distributed_tensorflow_framework_tpu.core import platform
 from distributed_tensorflow_framework_tpu.core.config import load_config
 from distributed_tensorflow_framework_tpu.core.metrics import setup_logging
 
@@ -47,7 +45,7 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     setup_logging()
-    _honor_platform_env()
+    platform.apply_cpu_collective_timeouts()
     args = parse_args(argv)
     config = load_config(args.config, overrides=list(args.overrides))
     srv = config.serve
@@ -55,8 +53,10 @@ def main(argv=None) -> int:
     if not artifact_dir:
         log.error("no artifact: pass --artifact or set serve.artifact_dir")
         return 2
+    platform.resolve_compilation_cache()
 
     from distributed_tensorflow_framework_tpu.core import telemetry, tracing
+    from distributed_tensorflow_framework_tpu.core.mesh import device_record
     from distributed_tensorflow_framework_tpu.serve.engine import (
         InferenceEngine,
     )
@@ -75,7 +75,8 @@ def main(argv=None) -> int:
     writer.emit_run_meta(
         argv=list(argv if argv is not None else sys.argv),
         config=config.name, role="serve", artifact=artifact_dir,
-        model=artifact.model_config.name, step=artifact.step)
+        model=artifact.model_config.name, step=artifact.step,
+        **device_record())
     engine = InferenceEngine(artifact, srv, telemetry_writer=writer,
                              trace_enabled=config.trace.enabled)
     decode_engine = None

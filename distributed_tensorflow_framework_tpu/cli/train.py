@@ -18,36 +18,9 @@ import logging
 import os
 import sys
 
-from distributed_tensorflow_framework_tpu.core import supervision
+from distributed_tensorflow_framework_tpu.core import platform, supervision
 from distributed_tensorflow_framework_tpu.core.config import load_config
 from distributed_tensorflow_framework_tpu.core.metrics import setup_logging
-
-
-def _honor_platform_env() -> None:
-    """Restore stock JAX semantics for the JAX_PLATFORMS env var.
-
-    Some images pin the platform via ``jax.config`` in sitecustomize,
-    which silently beats the env var — a launcher that sets
-    ``JAX_PLATFORMS=cpu`` (e.g. scripts/launch_local_cluster.py spawning
-    virtual-CPU workers) would otherwise end up on the pinned backend
-    with the wrong device count. Re-assert the env var through
-    jax.config BEFORE any backend query; unset/empty leaves the default
-    untouched.
-    """
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-    if plat and plat.split(",")[0] == "cpu":
-        # Rendezvous-timeout defaults for virtual-device CPU runs — see
-        # core/platform.py (tests/conftest.py applies the same policy).
-        from distributed_tensorflow_framework_tpu.core.platform import (
-            with_cpu_collective_timeouts,
-        )
-
-        os.environ["XLA_FLAGS"] = with_cpu_collective_timeouts(
-            os.environ.get("XLA_FLAGS", ""))
 
 
 def parse_args(argv=None):
@@ -73,7 +46,7 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     setup_logging()
-    _honor_platform_env()
+    platform.apply_cpu_collective_timeouts()
     args = parse_args(argv)
     overrides = list(args.overrides)
     # Elastic refit (core/supervision.py): the supervisor passes the
@@ -95,24 +68,9 @@ def main(argv=None) -> int:
 
         print(yaml.safe_dump(config.to_dict(), sort_keys=False))
         return 0
-    if config.train.compilation_cache_dir:
-        # Before the Trainer touches a backend: cached executables from the
-        # previous attempt turn the relaunch recompile into a disk read
-        # (the startup telemetry event shows the delta).
-        from distributed_tensorflow_framework_tpu.core.platform import (
-            enable_compilation_cache,
-        )
-
-        if enable_compilation_cache(config.train.compilation_cache_dir):
-            logging.getLogger(__name__).info(
-                "persistent XLA compilation cache: %s",
-                config.train.compilation_cache_dir,
-            )
-        else:
-            logging.getLogger(__name__).warning(
-                "this jax build lacks the persistent compilation cache — "
-                "continuing uncached"
-            )
+    # Before the Trainer touches a backend: cached executables from the
+    # previous attempt turn the relaunch recompile into a disk read.
+    platform.resolve_compilation_cache()
     from distributed_tensorflow_framework_tpu.core.mesh import MeshSizeError
     from distributed_tensorflow_framework_tpu.train import Trainer
 

@@ -64,7 +64,7 @@ def initialize_distributed() -> None:
     """
     # NOTE: must not touch jax.process_count()/devices() here — any backend
     # query initializes XLA, after which jax.distributed.initialize raises.
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return
     coord = os.environ.get("JAX_COORDINATOR_ADDRESS")
     num_procs = os.environ.get("JAX_NUM_PROCESSES")
@@ -74,21 +74,6 @@ def initialize_distributed() -> None:
             num_processes=int(num_procs),
             process_id=int(os.environ.get("JAX_PROCESS_ID", "0")),
         )
-
-
-def _distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized`` without requiring it to exist —
-    jax < 0.5 has no public probe, but the private global_state.client is
-    the exact value the public API later wrapped."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:  # pragma: no cover - private API moved
-        return False
 
 
 def _resolve_axis_sizes(config: MeshConfig, n: int) -> dict[str, int]:
@@ -237,11 +222,25 @@ class MeshRuntime:
         )
 
     def describe(self) -> str:
+        dev = device_record()
         return (
             f"process {self.process_index}/{self.process_count}, "
+            f"platform {dev['platform']} ({dev['device_kind']}), "
             f"{self.local_device_count} local / {self.global_device_count} "
             f"global devices, mesh {dict(self.mesh.shape)}"
         )
+
+
+def device_record() -> dict:
+    """Where this process runs, as JAX reports it. Every run-meta record
+    (trainer, serve, bench) and the mesh log line carry these fields, so
+    an ``events.jsonl`` opens with the device its numbers belong to."""
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+    }
 
 
 def initialize_runtime(
@@ -259,4 +258,11 @@ def initialize_runtime(
         global_device_count=jax.device_count(),
     )
     log.info("Mesh runtime: %s", rt.describe())
+    # Mesh position -> physical placement, once: create_mesh lays devices
+    # out in enumeration order, and this is the record that shows which
+    # chip (TPU coords; process elsewhere) each mesh index landed on.
+    log.info("Mesh devices, row-major over %s: %s", ",".join(MESH_AXES),
+             " ".join(
+                 f"id{d.id}@{getattr(d, 'coords', None) or f'p{d.process_index}'}"
+                 for d in mesh.devices.flat))
     return rt

@@ -7,6 +7,8 @@ callers mutate os.environ with these helpers first).
 
 from __future__ import annotations
 
+import os
+
 # XLA:CPU aborts a collective whose participants don't all reach the
 # rendezvous within ~40 s (`rendezvous.cc` termination timeout). On small
 # hosts running N virtual devices (N threads time-sharing few cores) the
@@ -34,115 +36,58 @@ FAST_FAIL_COLLECTIVE_FLAGS: tuple[tuple[str, int], ...] = (
 )
 
 
-def xla_flag_supported(name: str) -> bool:
-    """Whether this jaxlib's XLA knows flag ``name``.
+# JAX reads this variable itself (it is the ``jax_compilation_cache_dir``
+# config flag's environment form), so when it is set the program sets no
+# directory in code.
+COMPILATION_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    XLA *hard-aborts the process* at first backend init on any unknown
-    flag in XLA_FLAGS (``parse_flags_from_env.cc``) — observed killing
-    every test in the suite when a jaxlib upgrade dropped the
-    ``xla_cpu_collective_call_*`` timeout flags. Registered flag names
-    are compiled into the xla_extension binary as plain strings, so a
-    substring probe of the shared object is a reliable, cheap (mmap'd)
-    check that never needs to initialize a backend. Unknown layouts
-    (no .so found) fail open: the flag is assumed supported, matching
-    the old unconditional behavior.
+# The fixed fallback: ``<checkout>/.jax_cache``, derived from this
+# package's own location. The directory is part of every cache key, so
+# it must be the same in every process of every run — never a tempfile,
+# a pid or a timestamp. Git-ignored.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-    The scan MUST be ``mmap.find`` (C memmem over the mapping): ``in``
-    against an mmap falls back to byte-wise sequence iteration — ~10 s
-    of interpreter time per probe on a 264 MiB binary, and never a
-    match for a multi-byte needle. Results are memoized per process;
-    supervisor relaunch loops call this on every start.
+
+def resolve_compilation_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Called by every entry point (cli/train.py, cli/serve.py,
+    cli/export.py, bench.py, chip_smoke.py's children) before the first
+    backend use, so a relaunched or sibling process reloads the compiled
+    step instead of recompiling it — the dominant share of start-up
+    (the ``startup`` telemetry event reports time-to-first-step and the
+    directory). JAX's own thresholds decide what is worth caching.
     """
-    cached = _FLAG_SUPPORTED.get(name)
-    if cached is None:
-        blob = _xla_binary_flag_blob()
-        if len(blob) == 0:  # no .so located: fail open
-            cached = True
-        else:
-            cached = blob.find(name.encode()) >= 0
-        _FLAG_SUPPORTED[name] = cached
-    return cached
-
-
-_FLAG_SUPPORTED: dict[str, bool] = {}
-
-
-_XLA_BINARY_BLOB = None  # bytes | mmap.mmap once probed
-
-
-def _xla_binary_flag_blob():
-    global _XLA_BINARY_BLOB
-    if _XLA_BINARY_BLOB is None:
-        import mmap
-        import pathlib
-
-        blob = b""
-        try:
-            import jaxlib
-
-            root = pathlib.Path(jaxlib.__file__).parent
-            so = next(root.glob("**/xla_extension*.so"), None)
-            if so is not None:
-                with open(so, "rb") as fh:
-                    # mmap: the binary is hundreds of MB; don't copy it.
-                    blob = mmap.mmap(fh.fileno(), 0, access=mmap.ACCESS_READ)
-        except Exception:
-            blob = b""
-        _XLA_BINARY_BLOB = blob
-    return _XLA_BINARY_BLOB
-
-
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so a
-    relaunched process reuses the previous run's compiled executables
-    instead of re-lowering + re-compiling the train step — the dominant
-    share of restart → first-step latency (the ``startup`` telemetry
-    event measures it; docs/PERFORMANCE.md has numbers).
-
-    Must run before the first backend use (jax.config updates after
-    compilation has started don't retroactively cache). Returns True when
-    the cache was enabled, False when this jax build lacks the knobs (old
-    releases) — callers log and continue uncached rather than fail.
-
-    CAVEAT (why the config knob defaults off): executables that embed
-    host callbacks — pallas INTERPRET-mode kernels on the CPU backend —
-    SIGABRT when reloaded from cache in a fresh process (the serialized
-    executable holds dead callback pointers; see pytest.ini). Real TPU
-    backends compile pallas to Mosaic, which caches fine.
-    """
-    if not cache_dir:
-        return False
-    import os as _os
-
+    from_env = os.environ.get(COMPILATION_CACHE_ENV)
+    if from_env:
+        return from_env
     import jax
 
-    _os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except AttributeError:
-        return False
-    # Cache everything, immediately: the defaults skip "fast" compiles
-    # (min time 1 s) and small programs, which on the CPU test backend is
-    # most of them — useless for measuring the restart win.
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except AttributeError:
-            pass  # older jax: keep its defaults
-    return True
+    jax.config.update("jax_compilation_cache_dir",
+                      DEFAULT_COMPILATION_CACHE_DIR)
+    return DEFAULT_COMPILATION_CACHE_DIR
 
 
 def with_cpu_collective_timeouts(flags: str, table=None) -> str:
     """Append rendezvous-timeout flags to an XLA_FLAGS string, skipping
-    any flag the caller already set and any flag this jaxlib's XLA does
-    not register (an unknown flag aborts the process — see
-    ``xla_flag_supported``). ``table`` defaults to the
+    any flag the caller already set. ``table`` defaults to the
     long-run-tolerant values; pass FAST_FAIL_COLLECTIVE_FLAGS for the
     relaunch-loop tuning."""
     for name, value in (table or CPU_COLLECTIVE_TIMEOUT_FLAGS):
-        if name not in flags and xla_flag_supported(name):
+        if name not in flags:
             flags += f" --{name}={value}"
     return flags.strip()
+
+
+def apply_cpu_collective_timeouts() -> None:
+    """When the process was pointed at the CPU backend
+    (``JAX_PLATFORMS=cpu...``), add the rendezvous-timeout defaults to
+    ``XLA_FLAGS``. XLA reads the variable at first backend init, so entry
+    points call this before touching JAX."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        os.environ["XLA_FLAGS"] = with_cpu_collective_timeouts(
+            os.environ.get("XLA_FLAGS", ""))

@@ -24,7 +24,6 @@ temp bytes), the CollectiveTally's wire bytes, and
 from __future__ import annotations
 
 import dataclasses
-import os
 
 GIB = 1024 ** 3
 
@@ -41,38 +40,12 @@ CHIP_PEAKS: dict[str, tuple[float, float, float]] = {
     "TPU v6e": (918e12, 1640e9, 32 * GIB),
 }
 
-# Ridge-point fallback for backends absent from CHIP_PEAKS (the CPU
-# harness): the bound verdict is about the PROGRAM's position relative
-# to a roofline, and the v5e ridge (peak_flops/hbm_bw ≈ 240 flops/byte,
-# the fleet's deploy target) is the reference every row is read against
-# — tagged with bound_ridge_source so a fallback verdict is never
-# mistaken for a measured-chip one.
-RIDGE_FALLBACK_CHIP = "TPU v5e"
-
 
 def chip_hbm_capacity(chip: str) -> float | None:
-    """Per-chip HBM capacity, or host RAM when the chip isn't in the
-    table (the CPU backend: headroom against physical memory is still a
-    meaningful ceiling for the compiled step's working set)."""
+    """Per-chip HBM capacity, or None for a device that is not in
+    CHIP_PEAKS (host RAM is not HBM)."""
     peak = CHIP_PEAKS.get(chip)
-    if peak:
-        return peak[2]
-    try:
-        return float(os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE"))
-    except (ValueError, OSError, AttributeError):
-        return None
-
-
-def ridge_point(chip: str) -> tuple[float, str] | None:
-    """(ridge FLOP/byte, source chip) for ``chip``, falling back to the
-    RIDGE_FALLBACK_CHIP reference when the chip isn't in CHIP_PEAKS.
-    Returns None only if the fallback itself were removed from the table."""
-    source = chip if chip in CHIP_PEAKS else RIDGE_FALLBACK_CHIP
-    peak = CHIP_PEAKS.get(source)
-    if not peak:
-        return None
-    peak_flops, hbm_bw = peak[:2]
-    return peak_flops / hbm_bw, source
+    return peak[2] if peak else None
 
 
 def traffic_bytes(memory_analysis: dict | None, wire_bytes: float = 0.0,
@@ -100,9 +73,7 @@ class RooflinePrediction:
 
     ``sec_per_step`` is ``max(sec_compute, sec_hbm)`` — the roofline
     says the step can't beat the slower resource. ``bound`` names that
-    resource; ``ridge_source`` records which chip's ridge judged it
-    (``"<chip> (fallback)"`` when CHIP_PEAKS had no entry for the chip,
-    mirroring bench.py's bound_ridge_source tag).
+    resource, judged against ``chip``'s own ridge.
     """
 
     chip: str
@@ -110,7 +81,6 @@ class RooflinePrediction:
     bytes_per_step: float
     intensity: float | None
     ridge: float
-    ridge_source: str
     sec_compute: float
     sec_hbm: float
     sec_per_step: float
@@ -123,12 +93,15 @@ def predict(chip: str, flops_per_step: float, bytes_per_step: float,
 
     Inputs are WHOLE-program flops and bytes (use :func:`traffic_bytes`
     to assemble bytes from footprint + wire + opt state); the work is
-    assumed evenly divided across ``n_chips``. Unknown chips are judged
-    against the RIDGE_FALLBACK_CHIP roofline and tagged.
+    assumed evenly divided across ``n_chips``. A chip that is not in
+    CHIP_PEAKS raises: there is no roofline to predict against.
     """
     n = max(1, int(n_chips))
-    source = chip if chip in CHIP_PEAKS else RIDGE_FALLBACK_CHIP
-    peak_flops, hbm_bw = CHIP_PEAKS[source][:2]
+    if chip not in CHIP_PEAKS:
+        raise ValueError(
+            f"no roofline for device {chip!r}: not in CHIP_PEAKS "
+            f"({sorted(CHIP_PEAKS)})")
+    peak_flops, hbm_bw = CHIP_PEAKS[chip][:2]
     ridge = peak_flops / hbm_bw
     sec_compute = flops_per_step / n / peak_flops
     sec_hbm = bytes_per_step / n / hbm_bw
@@ -137,11 +110,10 @@ def predict(chip: str, flops_per_step: float, bytes_per_step: float,
         bound = "hbm_bandwidth" if intensity < ridge else "compute"
     else:
         bound = "compute"
-    ridge_source = source if source == chip else f"{source} (fallback)"
     return RooflinePrediction(
         chip=chip, flops_per_step=float(flops_per_step),
         bytes_per_step=float(bytes_per_step), intensity=intensity,
-        ridge=ridge, ridge_source=ridge_source, sec_compute=sec_compute,
+        ridge=ridge, sec_compute=sec_compute,
         sec_hbm=sec_hbm, sec_per_step=max(sec_compute, sec_hbm),
         bound=bound)
 
@@ -151,7 +123,10 @@ def annotate_roofline(out: dict, result: dict, chip: str, n_chips: int,
     """Achieved TFLOP/s, MFU, arithmetic intensity and the bottleneck
     verdict from the XLA cost model + public chip peaks (the bench row
     annotator, moved here from bench.py so the tuner's predictor and the
-    bench's measured verdict share one ridge).
+    bench's measured verdict share one ridge). A device that is not in
+    CHIP_PEAKS gets the program's own numbers (TFLOP/s, intensities) and
+    no ``mfu`` / ``bound`` / ``hbm_bw_util``: those are statements about
+    a chip, and there is none to make them about.
 
     Two intensity numbers ride every row that can compute them:
     ``arith_intensity`` (cost-model flops / cost-model bytes accessed —
@@ -198,15 +173,3 @@ def annotate_roofline(out: dict, result: dict, chip: str, n_chips: int,
                 result["bytes_per_step"] / result["sec_per_step"]
                 / n_chips / hbm_bw, 4,
             )
-    if "bound" not in out:
-        # Every row carries a verdict: on unknown backends (or when the
-        # cost model's byte count is absent) fall back to the reference
-        # ridge and the best intensity available, tagged as a fallback.
-        best = intensity if intensity is not None else ai
-        if best is not None:
-            ref = ridge_point("")  # forces the fallback reference
-            if ref is not None:
-                ridge, source = ref
-                out["bound"] = ("hbm_bandwidth" if best < ridge
-                                else "compute")
-                out["bound_ridge_source"] = f"{source} (fallback)"

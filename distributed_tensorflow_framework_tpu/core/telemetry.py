@@ -50,7 +50,6 @@ RESERVED_FIELDS = (
 KIND_TRAIN_STEP = "train_step"
 KIND_EVAL = "eval"
 KIND_BENCH = "bench_result"
-KIND_BENCH_PROBE = "backend_probe"
 KIND_TRACE_SUMMARY = "trace_summary"
 KIND_HEALTH = "health"
 KIND_FAILURE = "failure"
@@ -180,7 +179,7 @@ KIND_DATA_PACKING = "data_packing"
 KIND_DATA_STATE = "data_state"
 # Goodput-driven autotuner (scripts/autotune.py, tools/autotune,
 # docs/PERFORMANCE.md "Autotuning"): one event per trial decision.
-# ``extra.status`` is started|done|skipped|failed|window_abort, keyed by
+# ``extra.status`` is started|done|skipped|failed, keyed by
 # ``extra.trial`` (the candidate's config digest in space mode,
 # §section:label in plan mode), carrying the roofline prediction for
 # pruned candidates and the goodput-weighted score for completed ones —
@@ -467,7 +466,6 @@ def summarize_events(path: str) -> dict:
     meta: dict | None = None
     evals = {"count": 0, "last_step": None}
     bench = {"count": 0, "workloads": []}
-    bench_probes = 0
     trace_summaries = 0
     health_events: dict[str, int] = {}
     mesh_resizes: list[dict] = []
@@ -530,8 +528,7 @@ def summarize_events(path: str) -> dict:
     # KIND_AUTOTUNE_TRIAL ledger: trial decisions by status plus the
     # best goodput-weighted score the window produced.
     autotune = {
-        "events": 0, "ran": 0, "pruned": 0, "failed": 0,
-        "window_aborts": 0, "best": None,
+        "events": 0, "ran": 0, "pruned": 0, "failed": 0, "best": None,
     }
     for ev in read_events(path, strict=False):
         kind = ev["kind"]
@@ -608,8 +605,6 @@ def summarize_events(path: str) -> dict:
             wl = extra.get("workload")
             if wl and wl not in bench["workloads"]:
                 bench["workloads"].append(wl)
-        elif kind == KIND_BENCH_PROBE:
-            bench_probes += 1
         elif kind == KIND_TRACE_SUMMARY:
             trace_summaries += 1
         elif kind == KIND_HEALTH:
@@ -784,8 +779,6 @@ def summarize_events(path: str) -> dict:
                 autotune["pruned"] += 1
             elif status == "failed":
                 autotune["failed"] += 1
-            elif status == "window_abort":
-                autotune["window_aborts"] += 1
         elif kind == KIND_GOODPUT:
             m = ev.get("metrics") or {}
             snap = {
@@ -902,7 +895,6 @@ def summarize_events(path: str) -> dict:
         "meta": meta,
         "evals": evals,
         "bench": bench,
-        "bench_probes": bench_probes,
         "trace_summaries": trace_summaries,
         "health_events": health_events,
         "collectives": collectives,
@@ -997,8 +989,6 @@ def format_run_summary(summary: dict) -> str:
     if bench.get("count"):  # KIND_BENCH rollup
         wl = ", ".join(bench.get("workloads") or []) or "?"
         lines.append(f"  bench results: {bench['count']} ({wl})")
-    if summary.get("bench_probes"):  # KIND_BENCH_PROBE rollup
-        lines.append(f"  backend probes: {summary['bench_probes']}")
     if summary.get("trace_summaries"):  # KIND_TRACE_SUMMARY rollup
         lines.append(f"  trace summaries: {summary['trace_summaries']}")
     colls = summary.get("collectives")
@@ -1214,8 +1204,6 @@ def format_run_summary(summary: dict) -> str:
         lines.append(
             f"  autotune: {at['ran']} ran / {at['pruned']} pruned / "
             f"{at['failed']} failed"
-            + (f", {at['window_aborts']} window abort(s)"
-               if at.get("window_aborts") else "")
         )
         best = at.get("best")
         if best:

@@ -33,12 +33,14 @@ _lib = None
 
 
 def _build() -> str:
+    """Compile native/record_reader.cc into the (git-ignored) shared
+    library next to it, unless a build of this exact source is already
+    there."""
     lib = os.path.abspath(_LIB_CACHE)
     src = os.path.abspath(_SRC)
-    # Cache validity = source CONTENT hash (sidecar file), not mtimes: a
-    # fresh clone gives lib and source the same checkout mtime, so an
-    # mtime gate would silently load a stale committed .so after a source
-    # change (ADVICE r3).
+    # Validity = source CONTENT hash (sidecar file), not mtimes: a copy
+    # of the tree gives lib and source the same mtime, so an mtime gate
+    # would load a stale build after a source change.
     import hashlib
 
     with open(src, "rb") as f:
@@ -47,20 +49,20 @@ def _build() -> str:
     if os.path.exists(lib) and os.path.exists(sidecar):
         with open(sidecar) as f:
             if f.read().strip() == src_hash:
-                # Hash match isn't enough: a committed .so built against a
-                # newer glibc/libjpeg fails dlopen on this host (observed:
-                # GLIBC_2.34 symbols on a 2.31 image). Probe before trusting.
-                try:
-                    ctypes.CDLL(lib)
-                    return lib
-                except OSError as e:
-                    log.warning(
-                        "cached native reader unloadable (%s); rebuilding", e
-                    )
+                return lib
+    # Build under a per-process name and rename into place: several
+    # processes (launcher-spawned workers) may reach first use together,
+    # and none may dlopen a half-written file.
+    tmp = f"{lib}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           src, "-ljpeg", "-o", lib]
+           src, "-ljpeg", "-o", tmp]
     log.info("building native record reader: %s", " ".join(cmd))
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, lib)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     with open(sidecar, "w") as f:
         f.write(src_hash + "\n")
     return lib

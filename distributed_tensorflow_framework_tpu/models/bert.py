@@ -56,7 +56,10 @@ class MultiHeadAttention(nn.Module):
     num_heads: int
     dtype: Any = jnp.bfloat16
     attention_impl: str = "xla"
-    mesh: Any = None  # required for attention_impl="ring"
+    # The physical mesh: required for attention_impl="ring", and what
+    # lets "pallas" split its kernel across devices under a global-view
+    # jit (ops/flash_attention.flash_attention).
+    mesh: Any = None
     # One (H, 3·H) projection GEMM instead of three (H, H) — fewer,
     # fatter MXU calls on a step whose measured limit is GEMM
     # fragmentation, not a roofline (PERF_NOTES.md BERT analysis).
@@ -98,7 +101,7 @@ class MultiHeadAttention(nn.Module):
             )
 
             out = flash_attention(q, k, v, mask=mask,
-                                  segment_ids=segment_ids)
+                                  segment_ids=segment_ids, mesh=self.mesh)
         elif self.attention_impl == "ring":
             from distributed_tensorflow_framework_tpu.parallel.ring import (
                 ring_attention_sharded,

@@ -64,7 +64,7 @@ BLOCK_K_KB = int(os.environ.get("FLASH_BLOCK_K_KB", "1024"))
 #     fallback to the O(S²)-materializing XLA chain exists above the
 #     threshold — long chunks stay fused (tests/test_attention.py pins
 #     8192), and the chain is not even COMPILABLE there: at seq 8192 the
-#     XLA impl fails remote compilation outright (PERF_NOTES.md round 4).
+#     XLA impl failed compilation outright (PERF_NOTES.md round 4).
 # Env-tunable so the whole-K vs K-blocked crossover can be re-measured
 # without an edit (FLASH_MAX_SEQ_VMEM=0 forces the streaming kernels
 # everywhere).
@@ -79,22 +79,25 @@ MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
 # the two-pass kernels remain the only path).
 #
 # Tri-state default: ``None`` (env unset) = auto — ON only on backends
-# where scripts/verify_fused_bwd.py results are RECORDED (the
-# 2026-08-01 v5e window: EXACT on-device agreement with the two-pass
-# kernels at seq 8192, worst rel diff 0.0, and the step A/B measured
-# 36,150 vs 33,526 tok/s, +7.8%, at seq 8192 bs 4 — PERF_NOTES round
-# 5). On any other real TPU generation the fused dk/dv/dbias flush
-# ordering is UNVERIFIED silicon behavior (ADVICE r5): auto keeps the
-# two-pass backward and says so once. FLASH_FUSED_BWD=1/0 forces either
-# way (env read at import time like the other FLASH_* knobs); tests and
-# scripts/verify_fused_bwd.py assign the module global directly — the
-# backward closures consult it at call time through fused_bwd_enabled().
+# where scripts/verify_flash_kernels.py results are RECORDED: v5e, where
+# chip_smoke.py re-runs that check on every smoke (on jax 0.9.0 /
+# libtpu 0.0.34 the fused backward agrees with the two-pass kernels and
+# with the float32 reference at seq 8192, 4096 and 2048 — PERF.md,
+# PR 21; the +7.8% step A/B at seq 8192 is a 2026-08-01 number from an
+# earlier tree and toolchain, PERF_NOTES round 5). On any other real TPU
+# generation the fused dk/dv/dbias flush ordering is UNVERIFIED silicon
+# behavior (ADVICE r5): auto keeps the two-pass backward and says so
+# once. FLASH_FUSED_BWD=1/0 forces either way (env read at import time
+# like the other FLASH_* knobs); tests and
+# scripts/verify_flash_kernels.py assign the module global directly —
+# the backward closures consult it at call time through
+# fused_bwd_enabled().
 _FUSED_BWD_ENV = os.environ.get("FLASH_FUSED_BWD")
 FUSED_BWD: bool | None = (
     None if _FUSED_BWD_ENV is None else _FUSED_BWD_ENV not in ("", "0"))
 FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
 # Backend substrings (matched against device_kind, lowercased) with
-# recorded verify_fused_bwd.py + step-A/B results.
+# recorded verify_flash_kernels.py results.
 FUSED_BWD_VERIFIED_PLATFORMS = ("v5 lite", "v5e")
 # The fused one-pass backward can also REPLACE the whole-K two-pass
 # backward for mid-length sequences (FUSED_WHOLE_K_MIN ≤ s ≤
@@ -501,6 +504,13 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def kernel_mode() -> str:
+    """How this process compiles the kernels: ``"mosaic"`` on a TPU
+    backend, ``"interpret"`` anywhere else (the CPU test mesh). Run-meta
+    records carry it so an interpreted run never reads as a chip run."""
+    return "interpret" if _interpret() else "mosaic"
+
+
 _fused_bwd_auto: bool | None = None  # memoized auto-resolution
 
 
@@ -508,7 +518,7 @@ def fused_bwd_enabled() -> bool:
     """Resolve the FUSED_BWD tri-state at backward-dispatch time.
 
     A bool in the module global (env knob, test monkeypatch, or
-    scripts/verify_fused_bwd.py's direct assignment) always wins. ``None``
+    scripts/verify_flash_kernels.py's direct assignment) always wins. ``None``
     = auto: ON only when the default backend is a TPU whose device_kind
     matches a FUSED_BWD_VERIFIED_PLATFORMS entry; any OTHER real TPU gets
     the two-pass backward plus a one-line warning (once) — the fused
@@ -530,9 +540,9 @@ def fused_bwd_enabled() -> bool:
             if not _fused_bwd_auto:
                 log.warning(
                     "fused flash-attention backward disabled: no recorded "
-                    "verify_fused_bwd.py results for TPU %r — run "
-                    "scripts/verify_fused_bwd.py and set FLASH_FUSED_BWD=1 "
-                    "to enable", kind,
+                    "verify_flash_kernels.py results for TPU %r — run "
+                    "scripts/verify_flash_kernels.py and set "
+                    "FLASH_FUSED_BWD=1 to enable", kind,
                 )
     return _fused_bwd_auto
 
@@ -1055,15 +1065,30 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     return dq, dk, dv, dbias
 
 
-def flash_attention(q, k, v, *, mask=None, segment_ids=None):
+def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None):
     """Fused attention. q,k,v: (B, S, H, D); mask: (B,1,1,S) bool or None;
     segment_ids: (B, S) int packed-sequence ids or None — tokens attend
     only within equal ids (block-diagonal mask computed INSIDE the kernel
     from O(S) ids, so packing never materializes an S×S mask).
 
+    ``mesh``: the physical mesh when the caller is global-view (``jit``)
+    code over more than one device. A Mosaic kernel has no partitioning
+    rule — on TPU a bare ``pallas_call`` under a multi-device ``jit``
+    does not lower at all ("Mosaic kernels cannot be automatically
+    partitioned") — so the call is wrapped in a ``shard_map`` over every
+    mesh axis: batch split over the data axes, heads over ``model`` when
+    they divide, each device running the kernel on its own shard. Inside
+    an enclosing ``shard_map`` (the explicit-collective train step, a
+    pipeline stage) the axes are already manual and the kernel is called
+    as is. Interpret mode (CPU tests) takes the same wrap, so the CPU
+    mesh compiles the structure the chips run.
+
     Returns (B, S, H, D) in q's dtype. Differentiable end to end with
     Pallas forward AND backward kernels (module docstring).
     """
+    if (mesh is not None and mesh.size > 1
+            and not jax.sharding.get_abstract_mesh().manual_axes):
+        return _flash_attention_sharded(q, k, v, mask, segment_ids, mesh)
     b, s, hh, d = q.shape
     if s % min(BLOCK_Q, s):
         raise ValueError(f"seq len {s} must be a multiple of {BLOCK_Q}")
@@ -1081,3 +1106,31 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None):
         seg = _seg_f32(segment_ids)
         out = _FUSED[(True, False)](qt, kt, vt, bias, seg, seg)
     return out.transpose(0, 2, 1, 3)
+
+
+def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh):
+    """``flash_attention`` per device under a ``shard_map`` over all of
+    ``mesh`` (see its ``mesh`` argument)."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_tensorflow_framework_tpu.core.mesh import batch_spec
+
+    batch_axes = tuple(a for a in batch_spec(mesh)[0] if a in mesh.shape)
+    heads = ("model" if mesh.shape.get("model", 1) > 1
+             and q.shape[2] % mesh.shape["model"] == 0 else None)
+    qkv_spec = P(batch_axes, None, heads, None)
+    optional = {
+        "mask": (mask, P(batch_axes, None, None, None)),
+        "segment_ids": (segment_ids, P(batch_axes, None)),
+    }
+    present = {name: pair for name, pair in optional.items()
+               if pair[0] is not None}
+
+    def per_device(q, k, v, *rest):
+        return flash_attention(q, k, v, **dict(zip(present, rest)))
+
+    return jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(qkv_spec,) * 3 + tuple(s for _, s in present.values()),
+        out_specs=qkv_spec, check_vma=False,
+    )(q, k, v, *(a for a, _ in present.values()))

@@ -45,16 +45,8 @@ TALLY_TOTAL_FIELDS = ("total_bytes", "total_logical_bytes")
 
 
 def axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions.
-
-    ``lax.axis_size`` is newer than 0.4; ``lax.psum`` of a Python literal
-    has always constant-folded to ``size * x`` at trace time, so it
-    yields the same static int on old jaxlibs.
-    """
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+    """Static mesh-axis size inside shard_map."""
+    return lax.axis_size(axis_name)
 
 
 def _axes_tuple(axis_names) -> tuple:
@@ -77,24 +69,6 @@ def linear_axis_index(axis_names) -> jax.Array:
     for a in _axes_tuple(axis_names):
         idx = idx * axis_size(a) + lax.axis_index(a)
     return idx
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    The public ``jax.shard_map`` (with ``check_vma``) landed after 0.4;
-    earlier jaxlibs only have ``jax.experimental.shard_map.shard_map``
-    whose equivalent knob is ``check_rep``. All in-repo call sites go
-    through this wrapper so the version split lives in one place.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
 
 
 class CollectiveTally:

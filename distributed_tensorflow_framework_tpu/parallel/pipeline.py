@@ -280,7 +280,7 @@ def _pipeline_apply_1f1b(layer, stacked_params, x, mask, rng, *, mesh,
     recompute+backward slot table (see module docstring)."""
     s_stages, m = num_stages, num_microbatches
     layers_per_stage = num_layers // s_stages
-    fwd_mapped = coll.shard_map(
+    fwd_mapped = jax.shard_map(
         _circular_fwd_fn(layer, s_stages, m, num_layers, train, axis_name,
                          ckpt_policy),
         mesh=mesh, in_specs=in_specs, out_specs=out_spec, check_vma=False,
@@ -380,7 +380,7 @@ def _pipeline_apply_1f1b(layer, stacked_params, x, mask, rng, *, mesh,
         return dp_sum, dx
 
     dx_out_spec = P(data_axes, *([None] * (x.ndim - 1)))
-    bwd_mapped = coll.shard_map(
+    bwd_mapped = jax.shard_map(
         bwd_fn, mesh=mesh,
         in_specs=in_specs + (x_spec,),
         out_specs=(stack_spec, dx_out_spec),
@@ -472,7 +472,7 @@ def pipeline_apply(
     else:
         fn = _circular_fwd_fn(layer, s_stages, m, num_layers, train,
                               axis_name, ckpt_policy)
-    mapped = coll.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                             out_specs=out_spec, check_vma=False)
     # Stacked out over pipe: every stage emits its slot trace; only the
     # last stage's row is the real output (selected outside shard_map so

@@ -174,7 +174,7 @@ def ring_attention_sharded(q, k, v, *, mesh, mask=None, segment_ids=None,
     else:
         in_specs = (spec, spec, spec, bias_spec, bias_spec)
         args = (q, k, v, bias, segment_ids)
-    fn = coll.shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name),
         mesh=mesh,
         in_specs=in_specs,

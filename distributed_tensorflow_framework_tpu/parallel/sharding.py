@@ -25,6 +25,11 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+# The ``with mesh:`` context the step paths enter is only readable through
+# this private name on the installed JAX (0.9.0). Imported at module scope
+# on purpose: a JAX that moves it fails this import loudly, where a lazy
+# try/except would quietly turn every activation sharding hint off.
+from jax._src.mesh import thread_resources
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Megatron-style TP rules for the transformer models: column-parallel QKV and
@@ -194,9 +199,6 @@ def tree_map_specs(fn: Callable[[P], P], specs: Any) -> Any:
     return jax.tree.map(fn, specs, is_leaf=lambda x: isinstance(x, P))
 
 
-_WARNED_NO_THREAD_RESOURCES = False
-
-
 def constrain_activation(x: jax.Array, *axes: Any) -> jax.Array:
     """Best-effort ``with_sharding_constraint`` for model-internal
     activations (e.g. the MoE (B, E, C, H) expert tensors, whose backward
@@ -213,20 +215,7 @@ def constrain_activation(x: jax.Array, *axes: Any) -> jax.Array:
     no unconstrained marker for named specs) — only pin dims whose
     layout you know; a wrong ``None`` forces an all-gather.
     """
-    try:  # private API (jax 0.9): best-effort must stay best-effort
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-    except Exception:
-        global _WARNED_NO_THREAD_RESOURCES
-        if not _WARNED_NO_THREAD_RESOURCES:
-            _WARNED_NO_THREAD_RESOURCES = True
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "jax._src.mesh.thread_resources unavailable on this jax "
-                "version — activation sharding hints are disabled"
-            )
-        return x
+    m = thread_resources.env.physical_mesh
     if m.empty:
         return x
     names = set(m.axis_names)

@@ -855,7 +855,7 @@ class StepBuilder:
         # pmean would double-count. The explicit-collective mode exists to
         # mirror the reference's SyncReplicasOptimizer pipeline, so we keep
         # the collectives visible and own them.
-        mapped = coll.shard_map(
+        mapped = jax.shard_map(
             self._train_step_replica,
             mesh=self.mesh,
             in_specs=(state_P, batch_P),

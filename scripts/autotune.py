@@ -16,16 +16,15 @@ Two modes, one journal/runner/scoring machinery:
                         configs/best_<workload>.yaml (bench.py reads the
                         pin back and flags regressions).
   --plan chip_window    the compiled scripts/chip_window_queue.sh
-                        backlog (§0/§0b preflights, BENCH_r02
+                        backlog (§0 preflight, BENCH_r02
                         revalidation first, then the §13 precision
                         ladder, then §7–§17 and the round-5 tail) run
                         through the same journal. --dry-run prints the
                         prioritized trial list without spending anything.
 
-Exit codes follow the queue's taxonomy: 0 done, 1 real failure (a §0/§0b
-preflight failing refuses the window), 3 probe hang — the WINDOW is
-aborted but the dtf-autotune-journal/1 journal keeps every settled trial,
-so re-landing the same command continues where it stopped.
+Exit codes: 0 done, 1 real failure (the §0 preflight failing refuses the
+window). The dtf-autotune-journal/1 journal keeps every settled trial, so
+re-landing the same command after a kill continues where it stopped.
 
 SPEC.json: {"workload": ..., "incumbent": {chip, n_chips, flops_per_step,
 hbm_bytes_per_step, wire_bytes_per_step, opt_state_bytes,
@@ -119,8 +118,7 @@ def main(argv: list[str] | None = None) -> int:
         runner = tune_lib.FakeRunner.from_file(args.fake_runner)
     else:
         runner = tune_lib.SubprocessRunner(
-            str(_ROOT), bench_wait_min=tune.bench_wait_min,
-            timeout_s=args.timeout_s)
+            str(_ROOT), timeout_s=args.timeout_s)
 
     journal = tune_lib.TrialJournal(journal_path)
     events_path = os.path.join(
@@ -136,9 +134,7 @@ def main(argv: list[str] | None = None) -> int:
                 space, profile, runner, journal,
                 prune_margin=tune.prune_margin,
                 max_trials=tune.max_trials, writer=writer)
-            # Pin only a COMPLETED window's winner — an aborted window
-            # resumes from the journal and pins when it finishes.
-            if result.get("best") and not result.get("aborted"):
+            if result.get("best"):
                 tune_lib.pin_winner(
                     result,
                     leaderboard_path=os.path.join(out_dir,
@@ -152,8 +148,6 @@ def main(argv: list[str] | None = None) -> int:
     finally:
         writer.close()
     print(json.dumps(dict(result)))
-    if result.get("aborted"):
-        return 3
     if result.get("preflight_failed"):
         return 1
     return 0

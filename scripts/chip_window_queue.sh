@@ -4,16 +4,15 @@
 # tools/autotune/plan.py). The queue recipes themselves live in the plan
 # compiler; this script only preserves the operator entry point:
 #
-#   setsid nohup bash scripts/chip_window_queue.sh > /tmp/chipq.log 2>&1 &
+#   bash scripts/chip_window_queue.sh > /tmp/chipq.log 2>&1
 #
-# Contract carried over from the shell queue (verify skill): serial runs,
-# nothing else on the host, never killed mid-run. §0 (graftcheck) and
-# §0b (chip probe) still run FIRST and still refuse the window — exec
-# passes the autotuner's exit codes straight through: 0 done, 1 a
-# preflight failed (window refused), 3 probe hang (chip access down,
-# window aborted; the dtf-autotune-journal/1 journal keeps every settled
-# trial, so re-landing this same command resumes where it stopped
-# instead of re-spending the budget).
+# Serial runs, one child at a time under a parent that never touches JAX
+# (one process per chip), nothing else on the host. §0 (graftcheck) still
+# runs FIRST and still refuses the window — exec passes the autotuner's
+# exit codes straight through: 0 done, 1 the preflight failed (window
+# refused). The dtf-autotune-journal/1 journal keeps every settled
+# trial, so re-landing this same command after a kill resumes where it
+# stopped instead of re-spending the budget.
 #
 # The plan-manifest lines below are the machine-readable section→label
 # map; tests/test_autotune.py asserts every label appears in
@@ -21,7 +20,6 @@
 # compiler cannot drift apart silently.
 #
 # plan-manifest §0: graftcheck
-# plan-manifest §0b: probe
 # plan-manifest §1: resnet
 # plan-manifest §13: prec-f32 prec-bf16 prec-bf16-fused prec-bf16-int8
 # plan-manifest §7: wk-verify-2048 wk2048-fused wk2048-two wk-verify-4096 wk4096-fused wk4096-two

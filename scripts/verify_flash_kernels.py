@@ -1,0 +1,210 @@
+"""On-DEVICE compile-and-agree check for every flash-attention kernel.
+
+ops/flash_attention.py has seven Pallas kernels in three regimes, chosen
+by sequence length; a training step reaches only the ones its length
+selects. This drives ``value_and_grad`` through all of them, segmented
+and unsegmented, at bf16:
+
+  whole_k_short   whole-K fwd/dq/dkv at MAX_SEQ_VMEM/8        (512)
+  whole_k_max     whole-K fwd/dq/dkv at MAX_SEQ_VMEM          (4096)
+  kblocked        K-blocked fwd/dq/dkv at 2*MAX_SEQ_VMEM,     (8192)
+                  fused backward forced off
+  fused           fused one-pass backward at 2*MAX_SEQ_VMEM   (8192)
+  fused_takeover  fused backward at both ends of the bf16     (2048)
+  fused_takeover_max  whole-K takeover band:                  (4096)
+                  fused_whole_k_min(bf16) and MAX_SEQ_VMEM
+
+Every case is held to a float32 ``jax.numpy`` reference computed one
+head at a time (so it fits at any length), and the fused backward is
+additionally held to the two-pass backward at the same length: its
+dk/dv/dbias rest on in-order HBM flushes of revisited output blocks — a
+Mosaic behaviour that interpret mode, which walks the grid sequentially
+by construction, cannot exercise. Run THIS before trusting the kernels on
+a new backend or toolchain. A kernel the compiler refuses raises here;
+nothing is routed around it.
+
+    python scripts/verify_flash_kernels.py [case ...]
+
+The last line of stdout is one JSON object: ``ok``, the ``platform``,
+``device_kind`` and ``kernel_mode`` ("mosaic" | "interpret") it ran in,
+which backward the default dispatch picks at the streaming length
+(``streaming_backward_default``), and each case's statistics. Exit 0
+when every case agrees, 1 otherwise. The lengths follow the module's own
+thresholds, so the FLASH_* variables shrink the matrix for a CPU
+plumbing run (timings and flush order mean nothing there).
+"""
+
+import json
+import os
+import pathlib
+import sys
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributed_tensorflow_framework_tpu.core import platform
+from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+B = int(os.environ.get("VFB_B", "1"))
+H = int(os.environ.get("VFB_H", "12"))
+D = int(os.environ.get("VFB_D", "64"))
+
+# Relative-L2 gates. Against the f32 reference the kernels differ by bf16
+# rounding of p/ds and of the outputs (1e-2 class at worst); a wrong tile,
+# mask or flush order moves the error to the 1e0 class. Fused and two-pass
+# backward share one accumulation order when both stream, so they agree
+# far inside the same gate.
+GATE_VS_REFERENCE = 3e-2
+GATE_FUSED_VS_TWO_PASS = 3e-2
+
+
+def _cases() -> dict:
+    """name -> (seq, FUSED_BWD setting for the case)."""
+    vmem = fa.MAX_SEQ_VMEM
+    return {
+        "whole_k_short": (max(vmem // 8, fa.BLOCK_Q), False),
+        "whole_k_max": (vmem, False),
+        "kblocked": (2 * vmem, False),
+        "fused": (2 * vmem, True),
+        "fused_takeover": (fa.fused_whole_k_min(jnp.bfloat16), True),
+        "fused_takeover_max": (vmem, True),
+    }
+
+
+def _inputs(seq: int):
+    kq, kk, kv = jax.random.split(jax.random.key(seq), 3)
+    shape = (B, seq, H, D)
+    q, k, v = (jax.random.normal(r, shape, jnp.bfloat16)
+               for r in (kq, kk, kv))
+    # Four packed documents of unequal length per row.
+    cuts = np.array([0.15, 0.4, 0.8]) * seq
+    seg = np.searchsorted(cuts, np.arange(seq), side="right") + 1
+    return q, k, v, jnp.asarray(np.tile(seg, (B, 1)), jnp.int32)
+
+
+def _loss_and_out(out):
+    return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+
+def _kernel_fn(segmented: bool):
+    def loss(q, k, v, seg):
+        return _loss_and_out(fa.flash_attention(
+            q, k, v, segment_ids=seg if segmented else None))
+    return loss
+
+
+def _reference_fn(segmented: bool):
+    """float32 attention, one (batch, head) at a time under ``lax.map`` —
+    an (S, S) score block per step, never (B, H, S, S)."""
+    def one_head(args):
+        q, k, v, seg = args                       # (S, D) f32, (S,) int
+        s = (q @ k.T) / jnp.sqrt(jnp.float32(q.shape[-1]))
+        if segmented:
+            s = jnp.where(seg[:, None] == seg[None, :], s, fa.NEG_INF)
+        return jax.nn.softmax(s, axis=-1) @ v
+
+    def loss(q, k, v, seg):
+        b, s, h, d = q.shape
+        qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
+                      .reshape(b * h, s, d) for t in (q, k, v))
+        segf = jnp.repeat(seg, h, axis=0)         # (B*H, S)
+        out = jax.lax.map(one_head, (qf, kf, vf, segf))
+        # bf16 output like the kernels', so the loss sees the same values.
+        out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return _loss_and_out(out.astype(jnp.bfloat16))
+    return loss
+
+
+def _run(loss_fn, args) -> tuple[list, int]:
+    """([out, dq, dk, dv] as float32 numpy arrays, Mosaic custom calls in
+    the lowered program — 0 in interpret mode)."""
+    lowered = jax.jit(jax.value_and_grad(
+        loss_fn, argnums=(0, 1, 2), has_aux=True)).lower(*args)
+    (_, out), grads = lowered.compile()(*args)
+    jax.block_until_ready((out, grads))
+    return ([np.asarray(t, np.float32) for t in (out, *grads)],
+            lowered.as_text().count("tpu_custom_call"))
+
+
+def _rel_l2(a, b) -> float:
+    return float(np.linalg.norm(a - b) / max(float(np.linalg.norm(b)), 1e-30))
+
+
+def run_case(name: str, seq: int, fused: bool, two_pass_cache: dict) -> dict:
+    args = _inputs(seq)
+    rec = {"case": name, "seq": seq, "fused_bwd": fused, "variants": {}}
+    ok = True
+    for segmented in (False, True):
+        fa.FUSED_BWD = fused
+        # Fresh outer trace per setting: the fused decision is read at
+        # the custom_vjp layer, outside the inner jit's cache.
+        got, mosaic_calls = _run(_kernel_fn(segmented), args)
+        want, _ = _run(_reference_fn(segmented), args)
+        stats = {
+            "mosaic_calls": mosaic_calls,
+            "finite": bool(all(np.isfinite(t).all() for t in got)),
+        }
+        for n, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+            stats[f"{n}_rel_l2_vs_reference"] = _rel_l2(g, w)
+        good = stats["finite"] and max(
+            v for k, v in stats.items() if k.endswith("_vs_reference")
+        ) <= GATE_VS_REFERENCE
+        if fused:
+            two_pass = two_pass_cache.get((seq, segmented))
+            if two_pass is None:
+                fa.FUSED_BWD = False
+                two_pass, _ = _run(_kernel_fn(segmented), args)
+            diff = max(_rel_l2(g, t) for g, t in zip(got, two_pass))
+            stats["rel_l2_vs_two_pass"] = diff
+            good = good and diff <= GATE_FUSED_VS_TWO_PASS
+        else:
+            two_pass_cache[(seq, segmented)] = got
+        stats["ok"] = bool(good)
+        ok = ok and good
+        rec["variants"]["segmented" if segmented else "unsegmented"] = stats
+        print(f"{name} seq {seq} {'seg' if segmented else 'unseg'}: "
+              + " ".join(f"{k}={v:.3e}" if isinstance(v, float) else f"{k}={v}"
+                         for k, v in stats.items()), flush=True)
+    rec["ok"] = ok
+    return rec
+
+
+def main(argv) -> int:
+    cases = _cases()
+    unknown = [a for a in argv if a not in cases]
+    if unknown:
+        print(f"unknown case(s) {unknown}; known: {sorted(cases)}",
+              file=sys.stderr)
+        return 2
+    selected = argv or list(cases)
+    platform.resolve_compilation_cache()
+    dev = jax.devices()[0]
+    fa.FUSED_BWD = None
+    stream_seq = 2 * fa.MAX_SEQ_VMEM
+    streaming_default = ("fused" if fa.fused_bwd_enabled()
+                         and stream_seq <= fa.FUSED_BWD_MAX else "two_pass")
+    print(f"flash kernels on {dev.platform} ({dev.device_kind}), "
+          f"{fa.kernel_mode()} mode, B={B} H={H} D={D} bf16; default "
+          f"backward at seq {stream_seq}: {streaming_default}", flush=True)
+    two_pass_cache: dict = {}
+    results = [run_case(name, *cases[name], two_pass_cache)
+               for name in selected]
+    ok = all(r["ok"] for r in results)
+    if not ok:
+        print("FLASH KERNEL MISMATCH — do not trust these kernels on this "
+              "backend/toolchain; for the fused backward "
+              "(FLASH_FUSED_BWD=0 keeps the two-pass) the flush ordering "
+              "is suspect", flush=True)
+    print(json.dumps({
+        "ok": ok, "platform": dev.platform, "device_kind": dev.device_kind,
+        "kernel_mode": fa.kernel_mode(),
+        "streaming_backward_default": streaming_default,
+        "cases": results}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

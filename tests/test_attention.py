@@ -638,3 +638,85 @@ def test_ring_chunk_8192_kblocked(devices):
     gq_ref = jax.grad(loss_ref)(q)
     np.testing.assert_allclose(np.asarray(gq), np.asarray(gq_ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# -- several devices: the kernel runs under a shard_map (chip bring-up) ----
+# On TPU a Pallas (Mosaic) kernel under a multi-device jit does not lower
+# at all; flash_attention(mesh=) splits itself across the mesh instead.
+# Interpret mode takes the same wrap, so the CPU mesh pins its numerics.
+
+
+def _mesh_case(devices):
+    from distributed_tensorflow_framework_tpu.core.config import MeshConfig
+    from distributed_tensorflow_framework_tpu.core.mesh import create_mesh
+
+    mesh = create_mesh(MeshConfig(data=4, model=2), devices=devices)
+    q, k, v = _rand_qkv(jax.random.key(7), b=8, s=128, h=4, d=16)
+    lengths = jnp.array([128, 96, 128, 64, 128, 128, 32, 128])
+    mask = (jnp.arange(128)[None, :] < lengths[:, None])[:, None, None, :]
+    seg = (jnp.arange(128)[None, :] >= 48).astype(jnp.int32) + jnp.zeros(
+        (8, 1), jnp.int32)
+    return mesh, (q, k, v), mask, seg
+
+
+def test_flash_attention_on_a_mesh_matches_one_device(devices):
+    from distributed_tensorflow_framework_tpu.ops.flash_attention import (
+        flash_attention,
+    )
+
+    mesh, qkv, mask, seg = _mesh_case(devices)
+
+    def loss(q, k, v, m):
+        out = flash_attention(q, k, v, mask=mask, segment_ids=seg, mesh=m)
+        return jnp.sum(jnp.sin(out))
+
+    # Batch over data (4), heads over model (2): both splits at once.
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2))(*qkv, None)
+    got = jax.jit(jax.value_and_grad(
+        lambda q, k, v: loss(q, k, v, mesh), argnums=(0, 1, 2)))(*qkv)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+
+def test_flash_attention_inside_shard_map_is_called_as_is(devices):
+    """Where the axes are already manual (the explicit-collective step,
+    a pipeline stage) a second wrap would be an error; mesh= is inert."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_tensorflow_framework_tpu.ops.flash_attention import (
+        flash_attention,
+    )
+
+    mesh, (q, k, v), _, _ = _mesh_case(devices)
+    spec = P(("data", "fsdp", "expert"), None, "model", None)
+    mapped = jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, mesh=mesh), mesh=mesh,
+        in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
+    np.testing.assert_allclose(
+        jax.jit(mapped)(q, k, v), flash_attention(q, k, v),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_mode_off_the_chip_is_interpret(devices):
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    assert fa.kernel_mode() == "interpret"
+
+
+def test_kernel_check_matrix_follows_the_module_thresholds():
+    """scripts/verify_flash_kernels.py (the smoke's kernel leg) reaches
+    every regime at the shipped thresholds, and the autotune plan's
+    verify trials name cases it has."""
+    from scripts import verify_flash_kernels as vfk
+    from tools.autotune.plan import compile_chip_window_plan
+
+    cases = vfk._cases()
+    assert {name: seq for name, (seq, _) in cases.items()} == {
+        "whole_k_short": 512, "whole_k_max": 4096, "kblocked": 8192,
+        "fused": 8192, "fused_takeover": 2048, "fused_takeover_max": 4096}
+    assert [f for _, f in cases.values()] == [False] * 3 + [True] * 3
+    named = {a for t in compile_chip_window_plan()
+             if "scripts/verify_flash_kernels.py" in t.argv
+             for a in t.argv[2:]}
+    assert named and named <= set(cases)

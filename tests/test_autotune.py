@@ -125,7 +125,6 @@ class TestJournal:
         j.record("t1", "started")
         j.record("t1", "done", score=1.0)
         j.record("t2", "started")          # interrupted — must re-run
-        j.record("t3", "window_abort")     # aborted — must re-run
         j.record("t4", "skipped", reason="pruned")
         settled = tune.TrialJournal(str(tmp_path / "j.jsonl")).settled()
         assert set(settled) == {"t1", "t4"}
@@ -227,25 +226,6 @@ class TestSmokeDrill:
         text = yaml_path.read_text()
         assert result["best"]["trial"] in text  # the digest, traceable
         assert "activation_dtype: bf16" in text
-
-    def test_probe_hang_aborts_window_resumably(self, tmp_path):
-        space, profile = _space_and_profile()
-        journal_path = str(tmp_path / "j.jsonl")
-        hang = tune.FakeRunner({"*": {"exit_code": 3}})
-        result = tune.run_space_search(
-            space, profile, hang, tune.TrialJournal(journal_path),
-            prune_margin=0.05, log=lambda *_: None)
-        assert result["aborted"] and result["ran"] == 0
-        assert len(hang.calls) == 1  # the window stopped at the hang
-        # window_abort is non-terminal: the resumed window re-runs it.
-        ok = tune.FakeRunner({"*": {"exit_code": 0,
-                                    "payload": GOOD_PAYLOAD,
-                                    "summary": GOOD_SUMMARY}})
-        resumed = tune.run_space_search(
-            space, profile, ok, tune.TrialJournal(journal_path),
-            prune_margin=0.05, log=lambda *_: None)
-        assert not resumed["aborted"] and resumed["ran"] == 2
-        assert resumed["best"] is not None
 
 
 class TestKillResume:
@@ -403,12 +383,11 @@ class TestChipWindowPlan:
 
     def test_priority_order(self, dry_run):
         lines = dry_run.splitlines()
-        # §0/§0b preflights first, then the BENCH_r02 revalidation,
-        # then the §13 precision ladder before everything else.
+        # The §0 preflight first, then the BENCH_r02 revalidation, then
+        # the §13 precision ladder before everything else.
         assert "§0 graftcheck [preflight]" in lines[0]
-        assert "§0b probe [preflight]" in lines[1]
-        assert "§1 resnet" in lines[2]
-        assert "§13" in lines[3]
+        assert "§1 resnet" in lines[1]
+        assert "§13" in lines[2]
 
     def test_wrapper_is_thin(self):
         script = (REPO / "scripts" / "chip_window_queue.sh").read_text()
@@ -424,10 +403,10 @@ class TestChipWindowPlan:
         assert by_label["fused-bwd"].gate == "fused-bwd-verify"
         assert by_label["serve-batched"].gate == "serve-export"
         # A failed preflight refuses the window (§0 contract).
-        hang_free_fail = tune.FakeRunner({"s0:graftcheck": {"exit_code": 1},
+        preflight_fail = tune.FakeRunner({"s0:graftcheck": {"exit_code": 1},
                                           "*": {"exit_code": 0}})
         result = tune.run_plan(
-            trials, hang_free_fail,
+            trials, preflight_fail,
             tune.TrialJournal(str(tmp_path / "j.jsonl")),
             log=lambda *_: None)
         assert result["preflight_failed"] and result["ran"] == 0
@@ -443,13 +422,11 @@ class TestTelemetryRollup:
                status="skipped", reason="pruned")
         w.emit(telemetry.KIND_AUTOTUNE_TRIAL, trial="sha256:cc",
                status="failed", error="exit 1")
-        w.emit(telemetry.KIND_AUTOTUNE_TRIAL, trial="sha256:dd",
-               status="window_abort", error="probe hang")
         w.close()
         summary = telemetry.summarize_events(path)
         at = summary["autotune"]
         assert at["ran"] == 1 and at["pruned"] == 1
-        assert at["failed"] == 1 and at["window_aborts"] == 1
+        assert at["failed"] == 1
         assert at["best"] == {"trial": "sha256:aa", "score": 2418.0,
                               "unit": "images/sec/chip"}
         rendered = telemetry.format_run_summary(summary)

@@ -26,7 +26,7 @@ def _shard_map_allreduce(mesh, accumulate_f32):
     from distributed_tensorflow_framework_tpu.parallel import collectives as coll
 
     @functools.partial(
-        coll.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+        jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
     def fn(x):
         return coll.allreduce_gradients(
             {"g": x}, ("data",), compute_dtype=jnp.bfloat16,
@@ -191,7 +191,7 @@ def test_int8_single_step_error_bound(devices):
          ).astype(np.float32)
     exact = x.mean(axis=0)
 
-    @functools.partial(coll.shard_map, mesh=mesh, in_specs=P("data"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                        out_specs=P("data"), check_vma=False)
     def fn(v):
         m, _ = coll.allreduce_gradients_ef({"g": v}, None, ("data",),
@@ -215,7 +215,7 @@ def test_linear_axis_index_matches_gather_order(devices):
 
     mesh = create_mesh(MeshConfig(data=4, fsdp=2))
 
-    @functools.partial(coll.shard_map, mesh=mesh, in_specs=(),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(),
                        out_specs=P(), check_vma=False)
     def fn():
         idx = coll.linear_axis_index(("data", "fsdp"))

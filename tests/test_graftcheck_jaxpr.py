@@ -147,7 +147,7 @@ def _census_of(fn, devices):
     from jax.sharding import PartitionSpec as P
     # check_vma=False to match the trainer's shard_map usage — with vma
     # tracking on, jax rewrites psum to a different primitive family.
-    mapped = coll.shard_map(fn, mesh=_mesh_1d(devices),
+    mapped = jax.shard_map(fn, mesh=_mesh_1d(devices),
                             in_specs=(P("data"),), out_specs=P(),
                             check_vma=False)
     with coll.tally() as t:

@@ -64,3 +64,34 @@ def test_runtime(devices):
     assert rt.data_parallel_size == 8
     sh = batch_sharding(rt.mesh)
     assert sh.spec == sh.spec  # constructible
+
+
+def test_device_record_is_what_jax_reports(devices):
+    """The three fields every run-meta record and bench row carry."""
+    from distributed_tensorflow_framework_tpu.core.mesh import device_record
+
+    assert device_record() == {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
+def test_mosaic_call_shapes_are_read_from_compiled_hlo():
+    """scripts/multichip_check.py's reading of a per-device HLO, on a
+    line recorded from an ahead-of-time v5e 2x2 compile of the BERT step
+    at global batch 32: every rank-4 shape leads with 32/4 = 8 rows."""
+    from scripts.multichip_check import mosaic_batch_dims
+
+    line = (
+        '  %_flash_fwd.12 = (bf16[8,12,512,64]{3,2,1,0:T(8,128)(2,1)S(1)}, '
+        'f32[8,12,512,1]{3,2,1,0:T(8,128)}) custom-call(%bitcast.1296, '
+        '%bitcast.1293, %bitcast.1290, %select_n.1057), '
+        'custom_call_target="tpu_custom_call", operand_layout_constraints='
+        '{bf16[8,12,512,64]{3,2,1,0}, bf16[8,12,512,64]{3,2,1,0}, '
+        'bf16[8,12,512,64]{3,2,1,0}, f32[8,1,512]{2,1,0}}, '
+        'metadata={op_name="jit(_train_step_jit)/jvp(BertForMLM)/layer0/'
+        'attn/shard_map/jit(_flash_fwd)/pallas_call"}, '
+        'backend_config={"custom_call_config":{"body":"f32[99,1,1,1]"}}')
+    other = '  %fusion.1 = f32[32,512,768]{2,1,0} fusion(%p0), kind=kLoop'
+    assert mosaic_batch_dims("\n".join([other, line, other])) == [[8]]

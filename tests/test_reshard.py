@@ -514,6 +514,20 @@ def test_zero_toggle_across_resume_is_rejected(devices, tmp_path):
     mgr.close()
 
 
+def test_opt_layout_digest_tracks_the_layout_not_the_mesh_size():
+    """What the manifest records to refuse a slot-layout toggle: stacked
+    rows refold across a reshard (same digest), but replicated vs stacked
+    differ — without asking Orbax, whose shape errors name no leaf."""
+    def slots(w, b):
+        return {"mu": {"fc": {"kernel": np.zeros(w), "bias": np.zeros(b)}},
+                "count": np.zeros(())}
+
+    replicated = reshard.opt_layout_digest(slots((400, 120), (120,)))
+    stacked8 = reshard.opt_layout_digest(slots((8, 6000), (8, 15)))
+    stacked4 = reshard.opt_layout_digest(slots((4, 12000), (4, 30)))
+    assert stacked8 == stacked4 != replicated
+
+
 # -- cross-mesh parity matrix on genuinely sharded states -----------------
 @pytest.mark.slow
 class TestCrossMeshParityMatrix:

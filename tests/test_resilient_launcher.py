@@ -64,20 +64,11 @@ def test_checkpoint_warning():
 
 
 def test_cpu_fast_fail_flags_env():
-    from distributed_tensorflow_framework_tpu.core.platform import (
-        xla_flag_supported,
-    )
     from scripts.train_resilient import build_env
 
     env = build_env({"JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
-    if xla_flag_supported("xla_cpu_collective_call_terminate_timeout_seconds"):
-        assert "terminate_timeout_seconds=240" in env["XLA_FLAGS"]
-    else:
-        # This jaxlib's XLA doesn't register the flag; injecting it would
-        # hard-abort every child at backend init, so it must be absent.
-        assert "terminate_timeout_seconds" not in env["XLA_FLAGS"]
-    # user-set value wins (and must survive even when unsupported-by-probe:
-    # explicit user flags are never stripped)
+    assert "terminate_timeout_seconds=240" in env["XLA_FLAGS"]
+    # user-set value wins
     env = build_env({
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_cpu_collective_call_terminate_timeout_seconds=9",

@@ -23,34 +23,20 @@ R02_BYTES_PER_STEP = R02_FLOPS_PER_STEP / R02_INTENSITY
 V5E_PEAK_FLOPS, V5E_HBM_BW, _ = roofline.CHIP_PEAKS["TPU v5e"]
 
 
-class TestRidgePoint:
-    def test_v5e_ridge_is_240(self):
-        ridge, source = roofline.ridge_point(R02_CHIP)
-        assert source == R02_CHIP
-        assert ridge == pytest.approx(240.5, abs=0.1)
-        assert ridge == pytest.approx(V5E_PEAK_FLOPS / V5E_HBM_BW)
-
-    def test_unknown_chip_falls_back_to_v5e_reference(self):
-        ridge, source = roofline.ridge_point("cpu")
-        assert source == roofline.RIDGE_FALLBACK_CHIP
-        assert ridge == pytest.approx(240.5, abs=0.1)
-
-    def test_aliases_agree(self):
-        # v5e is listed under both its device_kind and marketing names.
-        assert (roofline.CHIP_PEAKS["TPU v5 lite"]
-                == roofline.CHIP_PEAKS["TPU v5e"])
-        assert (roofline.CHIP_PEAKS["TPU v6 lite"]
-                == roofline.CHIP_PEAKS["TPU v6e"])
+def test_v5e_aliases_agree():
+    # v5e is listed under both its device_kind and marketing names.
+    assert (roofline.CHIP_PEAKS["TPU v5 lite"]
+            == roofline.CHIP_PEAKS["TPU v5e"])
+    assert (roofline.CHIP_PEAKS["TPU v6 lite"]
+            == roofline.CHIP_PEAKS["TPU v6e"])
 
 
 class TestChipHbmCapacity:
     def test_known_chip_uses_spec_sheet(self):
         assert roofline.chip_hbm_capacity("TPU v4") == 32 * roofline.GIB
 
-    def test_unknown_chip_falls_back_to_host_ram(self):
-        cap = roofline.chip_hbm_capacity("cpu")
-        # Host RAM: positive and at least tens of MiB on any real box.
-        assert cap is None or cap > 64 * 1024 * 1024
+    def test_unknown_device_has_no_hbm(self):
+        assert roofline.chip_hbm_capacity("cpu") is None
 
 
 class TestTrafficBytes:
@@ -72,7 +58,7 @@ class TestPredict:
         assert p.bound == "hbm_bandwidth"
         assert p.intensity == pytest.approx(78.7)
         assert p.ridge == pytest.approx(240.5, abs=0.1)
-        assert p.ridge_source == R02_CHIP  # measured chip, no fallback tag
+        assert p.ridge == pytest.approx(V5E_PEAK_FLOPS / V5E_HBM_BW)
         # HBM term binds: bytes/bw > flops/peak.
         assert p.sec_per_step == p.sec_hbm > p.sec_compute
         assert p.sec_hbm == pytest.approx(R02_BYTES_PER_STEP / V5E_HBM_BW)
@@ -93,10 +79,9 @@ class TestPredict:
         assert p.bound == "compute"
         assert p.sec_per_step == p.sec_compute
 
-    def test_unknown_chip_tagged_fallback(self):
-        p = roofline.predict("cpu", 1e12, 1e11)
-        assert p.ridge_source == "TPU v5e (fallback)"
-        assert p.bound == "hbm_bandwidth"  # intensity 10 < 240
+    def test_unknown_device_raises(self):
+        with pytest.raises(ValueError, match="no roofline for device 'cpu'"):
+            roofline.predict("cpu", 1e12, 1e11)
 
     def test_n_chips_divides_work(self):
         one = roofline.predict(R02_CHIP, R02_FLOPS_PER_STEP,
@@ -134,7 +119,6 @@ class TestAnnotateRoofline:
         assert out["bound"] == "hbm_bandwidth"
         assert out["mfu"] == pytest.approx(61.2 / 197.0, abs=1e-3)
         assert 0.9 < out["hbm_bw_util"] <= 1.0
-        assert "bound_ridge_source" not in out  # known chip, no fallback
 
     def test_bench_reexports_the_shared_model(self):
         # bench.py must serve the same names it always exported, now
@@ -147,12 +131,13 @@ class TestAnnotateRoofline:
         assert bench.chip_hbm_capacity is roofline.chip_hbm_capacity
         assert bench._annotate_roofline is roofline.annotate_roofline
 
-    def test_unknown_chip_gets_fallback_verdict(self):
+    def test_unknown_device_gets_no_verdict(self):
         out = {}
         roofline.annotate_roofline(out, self._r02_result(), "cpu", 1)
-        assert out["bound"] == "hbm_bandwidth"
-        assert out["bound_ridge_source"] == "TPU v5e (fallback)"
-        assert "mfu" not in out  # no peak table entry for cpu
+        # The program's own numbers stay; nothing is said about a chip.
+        assert out["arith_intensity"] == pytest.approx(78.7)
+        for key in ("bound", "mfu", "hbm_bw_util", "bound_ridge_source"):
+            assert key not in out
 
     def test_no_flops_no_annotation(self):
         out = {}

@@ -126,7 +126,7 @@ def test_collective_tally_2dev_shard_map(devices):
         z = coll.all_gather(x, "data")       # local shard: 4 f32 = 16 B
         return y, z
 
-    mapped = jax.jit(coll.shard_map(
+    mapped = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"), out_specs=(P(None), P(None)),
         check_vma=False))
     with coll.tally() as t:
@@ -155,7 +155,7 @@ def test_collective_tally_allreduce_gradients(devices):
     sharding = jax.sharding.NamedSharding(mesh, P())
     grads = jax.device_put(grads, sharding)
 
-    mapped = jax.jit(coll.shard_map(
+    mapped = jax.jit(jax.shard_map(
         lambda g: coll.allreduce_gradients(g, ("data",)),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     with coll.tally() as t:
@@ -175,7 +175,7 @@ def test_collective_tally_int8_wire_vs_logical(devices):
     grads = {"w": np.ones((256,), np.float32)}
     grads = jax.device_put(grads, jax.sharding.NamedSharding(mesh, P()))
 
-    mapped = jax.jit(coll.shard_map(
+    mapped = jax.jit(jax.shard_map(
         lambda g: coll.allreduce_gradients(
             g, ("data",), compute_dtype="int8", block_size=64),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
@@ -464,7 +464,6 @@ def test_summarize_rolls_up_every_kind(tmp_path):
     w.emit(telemetry.KIND_EVAL, step=2, metrics={"eval_loss": 1.0})
     w.emit(telemetry.KIND_BENCH, metrics={"value": 1.0},
            workload="resnet50")
-    w.emit(telemetry.KIND_BENCH_PROBE, platform="cpu")
     w.emit(telemetry.KIND_TRACE_SUMMARY, trace_dir="/tmp/t")
     w.emit(telemetry.KIND_HEALTH, step=3,
            health={"event": "moe_collapse"})
@@ -565,7 +564,6 @@ def test_summarize_rolls_up_every_kind(tmp_path):
     assert s["meta"]["config_name"] == "lenet"
     assert s["evals"] == {"count": 1, "last_step": 2}
     assert s["bench"] == {"count": 1, "workloads": ["resnet50"]}
-    assert s["bench_probes"] == 1
     assert s["trace_summaries"] == 1
     assert s["health_events"] == {"moe_collapse": 1}
     assert s["serve"]["requests"] == 1 and s["serve"]["batches"] == 1
@@ -597,7 +595,6 @@ def test_summarize_rolls_up_every_kind(tmp_path):
     assert "run: config_name=lenet" in text
     assert "evals: 1 (last at step 2)" in text
     assert "bench results: 1 (resnet50)" in text
-    assert "backend probes: 1" in text
     assert "trace summaries: 1" in text
     assert "health events: moe_collapse=1" in text
     assert "serving: 1 requests (2 rows) in 1 batches" in text

@@ -200,6 +200,12 @@ def test_trainer_run_emits_joined_telemetry(devices, tmp_path):
     evs = list(telemetry.read_events(os.path.join(run_dir, "events.jsonl")))
     kinds = [e["kind"] for e in evs]
     assert kinds[0] == telemetry.KIND_RUN_META
+    # Every events.jsonl opens with where the run landed and how its
+    # Pallas kernels compile there: a CPU run never reads as a chip run.
+    meta = evs[0]["extra"]
+    assert meta["platform"] == "cpu" and meta["device_kind"] == "cpu"
+    assert meta["device_count"] == 8
+    assert meta["pallas_kernels"] == "interpret"
     assert telemetry.KIND_TRAIN_STEP in kinds
     assert all(e["run_id"] == trainer.run_id for e in evs)
     step_ev = next(e for e in evs if e["kind"] == telemetry.KIND_TRAIN_STEP)

@@ -123,11 +123,18 @@ def test_f32_parity_zero_vs_replicated(devices):
     np.testing.assert_allclose(
         float(m_off["grad_norm"]), float(m_zero["grad_norm"]), rtol=1e-5)
     # Same data, same mesh, f32 wire: the sharded update must reproduce
-    # the replicated trajectory to reduction-order noise (observed
-    # ~1e-8 after 3 adam steps).
+    # the replicated trajectory to reduction-order noise. That noise is
+    # ~1e-8 in a gradient, but Adam divides by sqrt(v): where a gradient
+    # element is itself near zero the two reduction orders can disagree
+    # on m/sqrt(v) by a visible fraction, and the parameter then moves by
+    # that fraction of the learning rate per step. So the bound is
+    # absolute and scaled by what three steps at lr 1e-2 can move: 1e-3
+    # of it (observed on jax 0.9.0: 2 of 48,000 elements of one leaf off
+    # by 5.8e-6, every other element inside 1e-6). A wrong shard, bucket
+    # or mask moves whole leaves by O(lr) = 1e-2, a thousand times more.
     for a, b in zip(jax.tree.leaves(jax.device_get(s_off.params)),
                     jax.tree.leaves(jax.device_get(s_zero.params))):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=3e-5)
 
 
 def test_zero_slots_stacked_and_sharded_one_over_n(devices):

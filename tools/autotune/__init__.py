@@ -4,8 +4,8 @@ The library behind ``scripts/autotune.py`` (stdlib-only, same layout
 discipline as tools/graftcheck): typed SearchSpace specs over the real
 config dataclasses (space), an analytic roofline/traffic pruner that
 skips configs predicted worse than the incumbent on the binding resource
-(model, backed by core/roofline), supervised subprocess trials honoring
-the BENCH_WAIT budget and the exit-3 probe_hang taxonomy (runner), the
+(model, backed by core/roofline), supervised subprocess trials, one
+child at a time under a stdlib-only parent (runner), the
 resumable dtf-autotune-journal/1 trial journal (journal), goodput-
 weighted scoring off dtf-run-summary/1 (scoring), the dtf-leaderboard/1
 regression pin bench.py reads back (leaderboard), the chip_window plan
@@ -39,7 +39,6 @@ from tools.autotune.plan import (  # noqa: F401
 )
 from tools.autotune.runner import (  # noqa: F401
     FakeRunner,
-    ProbeHangError,
     SubprocessRunner,
     TrialResult,
     TrialRunError,

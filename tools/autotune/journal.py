@@ -1,13 +1,11 @@
 """dtf-autotune-journal/1 — the resumable trial journal.
 
 Append-only JSONL, one record per trial state change. The journal is why
-a killed chip window (probe hang, preemption, operator ctrl-C) continues
-where it stopped instead of re-spending completed trials: on restart the
-tuner replays the file, treats every trial whose LAST record is terminal
+a killed chip window (preemption, operator ctrl-C) continues where it
+stopped instead of re-spending completed trials: on restart the tuner
+replays the file, treats every trial whose LAST record is terminal
 (``done`` / ``skipped`` / ``failed``) as settled, and re-runs only trials
-left ``started`` (killed mid-flight) or never seen. A ``window_abort``
-record marks where a probe hang ended the window — the trial it
-interrupted stays non-terminal so the next window retries it.
+left ``started`` (killed mid-flight) or never seen.
 """
 
 from __future__ import annotations
@@ -19,7 +17,7 @@ import time
 JOURNAL_SCHEMA = "dtf-autotune-journal/1"
 
 # Terminal statuses: the trial consumed its decision and must not re-run
-# on resume. "started" and "window_abort" are non-terminal by design.
+# on resume. "started" is non-terminal by design.
 TERMINAL_STATUSES = ("done", "skipped", "failed")
 
 

@@ -145,7 +145,7 @@ def prune_decision(profile: TrafficProfile, overrides: dict[str, object],
         "predicted_rate": round(cand_rate, 2),
         "incumbent_rate": round(inc_rate, 2),
         "bound": cand.bound,
-        "ridge_source": cand.ridge_source,
+        "chip": cand.chip,
         "sec_compute": cand.sec_compute,
         "sec_hbm": cand.sec_hbm,
     }

@@ -5,8 +5,8 @@
 the search loop can journal, resume and supervise like any other trial
 set. Priorities, per the queue's own rules:
 
-  1. §0/§0b preflights — a graftcheck finding or a probe hang refuses
-     to spend the window at all (exit 1 / exit 3 respectively);
+  1. §0 preflight — a graftcheck finding refuses to spend the window
+     at all (exit 1);
   2. §1 — re-validate BENCH_r02 (the last good chip number, 2513
      img/s/chip) before anything else, so a silent regression is caught
      while the whole window is still ahead;
@@ -35,7 +35,7 @@ class PlannedTrial:
     """One queue arm: ``section`` is the chip_window_queue § it came
     from, ``gate`` names a trial that must succeed first (numerics
     verifies, exports), ``kind`` separates preflights (whose failure
-    aborts the window) from ordinary trials."""
+    refuses the window) from ordinary trials."""
 
     section: str
     label: str
@@ -217,14 +217,11 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
     """The full prioritized window (see module docstring for the order)."""
     trials: list[PlannedTrial] = []
 
-    # §0/§0b preflights: refuse to spend the window on a tree graftcheck
-    # rejects or a chip whose probe hangs (exit 3 → window abort).
+    # §0 preflight: refuse to spend the window on a tree graftcheck
+    # rejects.
     trials.append(PlannedTrial(
         "0", "graftcheck", (PY, "scripts/graftcheck.py"),
         (("JAX_PLATFORMS", "cpu"),), kind="preflight"))
-    trials.append(PlannedTrial(
-        "0b", "probe", (PY, "bench.py"), (("BENCH_PROBE_ONLY", "1"),),
-        kind="preflight"))
 
     # §1: re-validate BENCH_r02 (the last good number) FIRST.
     trials.append(_bench("1", "resnet"))
@@ -238,10 +235,11 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
                          BENCH_PRECISION="bf16_int8"))
 
     # §7 whole-K takeover bands: numerics verify gates each pair.
-    for seq, bs in ((2048, 16), (4096, 8)):
+    for seq, bs, case in ((2048, 16, "fused_takeover"),
+                          (4096, 8, "fused_takeover_max")):
         verify = f"wk-verify-{seq}"
         trials.append(_script(
-            "7", verify, (PY, "scripts/verify_fused_bwd.py", str(seq))))
+            "7", verify, (PY, "scripts/verify_flash_kernels.py", case)))
         trials.append(_bench(
             "7", f"wk{seq}-fused", gate=verify, BENCH_WORKLOAD="bert",
             BENCH_ATTN="pallas", BENCH_SEQ=seq, BENCH_BS=bs))
@@ -250,7 +248,7 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
             BENCH_ATTN="pallas", BENCH_SEQ=seq, BENCH_BS=bs,
             FLASH_FUSED_WHOLE_K_MIN=1000000000))
 
-    # §8 pipeline-schedule A/B (pp-sanity re-probes the tunnel cheap).
+    # §8 pipeline-schedule A/B (pp-sanity: one cheap default run first).
     trials.append(_bench("8", "pp-sanity"))
     for sched in ("gpipe", "1f1b", "interleaved"):
         trials.append(_bench(
@@ -354,8 +352,8 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
         (PY, "scripts/bench_chunk_crossover.py", "256", "512", "1024",
          "2048", "4096")))
     trials.append(_script(
-        "4b", "fused-bwd-verify", (PY, "scripts/verify_fused_bwd.py",
-                                   "8192")))
+        "4b", "fused-bwd-verify", (PY, "scripts/verify_flash_kernels.py",
+                                   "kblocked", "fused")))
     trials.append(_bench(
         "4b", "fused-bwd", gate="fused-bwd-verify", BENCH_WORKLOAD="bert",
         BENCH_ATTN="pallas", BENCH_SEQ=8192, BENCH_BS=4,

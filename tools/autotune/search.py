@@ -9,12 +9,10 @@ configs/leaderboard.json + configs/best_<workload>.yaml. Plan mode runs
 a compiled PlannedTrial list (tools/autotune/plan) through the same
 journal/runner machinery — no pruning, the queue arms are all wanted.
 
-Window-vs-search taxonomy: ProbeHangError (the runner's exit-3 class)
-aborts the WINDOW — a ``window_abort`` journal record is written, the
-loop stops, and the search resumes from the journal next window.
-TrialRunError fails only its trial. Every decision (ran / pruned /
-failed / aborted) is journaled (dtf-autotune-journal/1) and emitted as
-KIND_AUTOTUNE_TRIAL telemetry when a writer is attached.
+A TrialRunError fails only its trial. Every decision (ran / pruned /
+failed) is journaled (dtf-autotune-journal/1) and emitted as
+KIND_AUTOTUNE_TRIAL telemetry when a writer is attached; a killed
+window resumes from the journal.
 """
 
 from __future__ import annotations
@@ -22,7 +20,7 @@ from __future__ import annotations
 from tools.autotune import model as traffic_model
 from tools.autotune import scoring
 from tools.autotune.journal import TrialJournal
-from tools.autotune.runner import ProbeHangError, TrialRunError
+from tools.autotune.runner import TrialRunError
 
 
 def trial_id_for(overrides: dict) -> str:
@@ -48,7 +46,7 @@ def run_space_search(space, profile, runner, journal: TrialJournal, *,
                      prune_margin: float = 0.05, max_trials: int = 0,
                      writer=None, log=print) -> SearchResult:
     """Search ``space`` for ``space.workload``; returns the tally dict
-    {"workload", "ran", "pruned", "resumed", "failed", "aborted",
+    {"workload", "ran", "pruned", "resumed", "failed",
     "best": {trial, overrides, score...}|None}."""
     baseline = space.baseline()
     settled = journal.settled()
@@ -64,7 +62,7 @@ def run_space_search(space, profile, runner, journal: TrialJournal, *,
                         "unit": rec.get("unit"),
                         "payload": rec.get("payload")}
     tally = {"workload": space.workload, "ran": 0, "pruned": 0,
-             "resumed": 0, "failed": 0, "aborted": False}
+             "resumed": 0, "failed": 0}
     for overrides in space.enumerate():
         if max_trials and tally["ran"] >= max_trials:
             log(f"autotune: max_trials={max_trials} reached — stopping")
@@ -92,13 +90,6 @@ def run_space_search(space, profile, runner, journal: TrialJournal, *,
         try:
             result = runner.run(tid, ["python", "bench.py"],
                                 space.trial_env(overrides))
-        except ProbeHangError as e:
-            tally["aborted"] = True
-            journal.record(tid, "window_abort", overrides=overrides,
-                           error=str(e))
-            _emit(writer, trial=tid, status="window_abort", error=str(e))
-            log(f"autotune: WINDOW ABORT at {tid}: {e}")
-            break
         except TrialRunError as e:
             tally["failed"] += 1
             journal.record(tid, "failed", overrides=overrides,
@@ -153,14 +144,13 @@ def pin_winner(result: SearchResult, *, leaderboard_path: str,
 
 def run_plan(trials, runner, journal: TrialJournal, *, writer=None,
              log=print) -> SearchResult:
-    """Execute a compiled PlannedTrial list (plan mode). Preflight
-    failures and probe hangs abort the window (the §0/§0b contract);
-    gated trials are skipped when their gate didn't succeed; everything
-    is journaled under the trial's §section/label id for resume."""
+    """Execute a compiled PlannedTrial list (plan mode). A preflight
+    failure refuses the window (the §0 contract); gated trials are
+    skipped when their gate didn't succeed; everything is journaled
+    under the trial's §section/label id for resume."""
     settled = journal.settled()
     tally = {"workload": "chip_window", "ran": 0, "pruned": 0,
-             "resumed": 0, "failed": 0, "aborted": False,
-             "preflight_failed": False}
+             "resumed": 0, "failed": 0, "preflight_failed": False}
     succeeded: set[str] = {
         rec.get("label") or tid for tid, rec in settled.items()
         if rec.get("status") == "done"}
@@ -186,20 +176,13 @@ def run_plan(trials, runner, journal: TrialJournal, *, writer=None,
         _emit(writer, trial=tid, status="started", section=trial.section)
         try:
             result = runner.run(tid, list(trial.argv), trial.env_dict())
-        except ProbeHangError as e:
-            tally["aborted"] = True
-            journal.record(tid, "window_abort", label=trial.label,
-                           error=str(e))
-            _emit(writer, trial=tid, status="window_abort", error=str(e))
-            log(f"autotune: WINDOW ABORT at {tid}: {e}")
-            break
         except TrialRunError as e:
             tally["failed"] += 1
             journal.record(tid, "failed", label=trial.label, error=str(e))
             _emit(writer, trial=tid, status="failed", error=str(e))
             log(f"autotune: FAILED {tid}: {e}")
             if trial.kind == "preflight":
-                # §0/§0b: a failed preflight refuses the window.
+                # §0: a failed preflight refuses the window.
                 tally["preflight_failed"] = True
                 log(f"autotune: preflight {tid} failed — refusing to "
                     f"spend the window")
