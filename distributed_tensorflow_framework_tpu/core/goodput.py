@@ -38,10 +38,22 @@ Bucket definitions (seconds of host wall time; docs/OBSERVABILITY.md):
                  ``ckpt_save`` events' ``ckpt_save_blocked_ms``)
   rollback       anomaly handling: snapshot restore + LR-rewarmup
                  rebuild inside ``_maybe_recover``
+  snapshot       the recovery ladder's device→host copy of the train
+                 state (loop-entry baseline, then every
+                 ``resilience.snapshot_interval_steps`` at a fetch)
+  bookkeeping    the rest of a metrics-fetch iteration: the slow-step
+                 check, phase means, anomaly classification, this
+                 ledger, memory sampling, the packing census, the
+                 ``train.steps`` span
+  hooks          every hook's ``after_step`` (``hook:<Class>`` phases),
+                 eval included; not a hook whose wall an event of its
+                 own already charges (the checkpoint hook's ``save()``
+                 is ``ckpt_blocked``'s): the loop leaves that one out
   startup        trainer construction → first loop iteration (restore +
                  input build; the first compile lands in ``recompile``)
   other          residual: wall since ledger start minus every bucket
-                 above (hooks, logging, eval, exit barrier)
+                 above (hooks' ``on_end``, final eval, exit barrier, the
+                 loop's own statements between spans)
   restart_gap    stitch-time only: wall between one attempt's last
                  ledger event and the next attempt's start
 """
@@ -63,15 +75,30 @@ PHASE_BUCKETS = {
     "compile": "recompile",
     "infeed": "infeed_wait",
     "metrics_fetch": "metrics_fetch",
+    "rollback": "rollback",
+    "snapshot": "snapshot",
+    "bookkeeping": "bookkeeping",
 }
+# Every ``hook:<Class>`` phase folds into one bucket.
+HOOK_PHASE_PREFIX, HOOKS_BUCKET = "hook:", "hooks"
 
 PRODUCTIVE_BUCKETS = ("step_compute",)
 
 # Display order for tables; unknown buckets append after these.
 BUCKET_ORDER = (
     "step_compute", "recompile", "infeed_wait", "metrics_fetch",
-    "ckpt_blocked", "rollback", "startup", "other", "restart_gap",
+    "ckpt_blocked", "rollback", "snapshot", "bookkeeping", "hooks",
+    "startup", "other", "restart_gap",
 )
+
+
+def phase_bucket(phase: str) -> str:
+    """The bucket a ``StepTimer`` phase is charged to. An unknown phase
+    keeps its own name: a new phase must never silently vanish from the
+    accounting."""
+    if phase.startswith(HOOK_PHASE_PREFIX):
+        return HOOKS_BUCKET
+    return PHASE_BUCKETS.get(phase, phase)
 
 
 class GoodputLedger:
@@ -131,14 +158,10 @@ class GoodputLedger:
             self.add(bucket, time.perf_counter() - t0)
 
     def absorb_phases(self, totals: Mapping[str, float]) -> None:
-        """Fold a ``StepTimer.totals`` dict in (call BEFORE its reset).
-
-        Unknown phase names land in their own bucket rather than being
-        dropped — a new phase must never silently vanish from the
-        accounting.
-        """
+        """Fold a ``StepTimer.totals`` dict in (call BEFORE its reset),
+        each phase into ``phase_bucket`` of its name."""
         for phase, seconds in totals.items():
-            self.add(PHASE_BUCKETS.get(phase, phase), float(seconds))
+            self.add(phase_bucket(phase), float(seconds))
 
     def _observe(self, ev: Mapping[str, Any]) -> None:
         """TelemetryWriter listener: join sibling streams in-process."""

@@ -345,22 +345,24 @@ class FlightRecorder:
             self._writer = writer
         return self
 
-    def default_path(self) -> str:
-        """Dump location: explicit dump_dir → DTF_TRACE_DIR → the
-        attached writer's log directory → the system temp dir. The
-        writer fallback is what keeps `flightrec-*.json` out of the
-        repo root when tests (or ad-hoc runs) never set the env var —
-        the dump lands next to the run's own telemetry instead. A
-        recorder with no directory clue at all (stderr-only writer,
-        e.g. a supervisor run without checkpoint.directory) dumps to
-        tempfile.gettempdir(): never the process cwd, which under
-        pytest is the repo root."""
+    def directory(self) -> str | None:
+        """Where this run's forensic files go: explicit dump_dir →
+        DTF_TRACE_DIR → the attached writer's log directory; None with
+        no directory clue at all (stderr-only writer, e.g. a supervisor
+        run without checkpoint.directory). The writer fallback is what
+        keeps dumps next to the run's own telemetry when tests (or
+        ad-hoc runs) never set the env var."""
         base = self.dump_dir or os.environ.get(TRACE_DIR_ENV)
         if not base and self._writer is not None:
             writer_path = getattr(self._writer, "path", None)
             if writer_path:
                 base = os.path.dirname(os.path.abspath(writer_path))
-        return os.path.join(base or tempfile.gettempdir(),
+        return base or None
+
+    def default_path(self) -> str:
+        """Dump location: ``directory()``, else the system temp dir —
+        never the process cwd, which under pytest is the repo root."""
+        return os.path.join(self.directory() or tempfile.gettempdir(),
                             f"flightrec-{os.getpid()}.json")
 
     def dump(self, reason: str, *, path: str | None = None,

@@ -195,6 +195,7 @@ class Snapshot:
     host: Any
     shardings: Any
     data_state: dict | None = None
+    nbytes: int = 0  # the host copy's size
 
 
 class SnapshotRing:
@@ -263,14 +264,19 @@ class RecoveryManager:
             )
 
     # -- snapshots --------------------------------------------------------
+    def snapshot_due(self, step: int, force: bool = False) -> bool:
+        """Whether ``take_snapshot`` at ``step`` would copy the state
+        (so the loop puts its ``snapshot`` span around copies only)."""
+        if not self.armed:
+            return False
+        return (force or self._last_snapshot_step is None
+                or step - self._last_snapshot_step
+                >= max(1, self.cfg.snapshot_interval_steps))
+
     def take_snapshot(self, step: int, state: Any,
                       data_state: dict | None = None,
                       force: bool = False) -> bool:
-        if not self.armed:
-            return False
-        if (not force and self._last_snapshot_step is not None
-                and step - self._last_snapshot_step
-                < max(1, self.cfg.snapshot_interval_steps)):
+        if not self.snapshot_due(step, force):
             return False
         if not _fully_addressable(state):
             # Multi-host sharded state: the device_get snapshot only sees
@@ -279,8 +285,11 @@ class RecoveryManager:
             self.disable("train state is not fully addressable on this host")
             return False
         host, shardings = snapshot_state(state)
-        self.ring.push(Snapshot(step=step, host=host, shardings=shardings,
-                                data_state=dict(data_state or {})))
+        self.ring.push(Snapshot(
+            step=step, host=host, shardings=shardings,
+            data_state=dict(data_state or {}),
+            nbytes=sum(int(getattr(leaf, "nbytes", 0))
+                       for leaf in jax.tree.leaves(host))))
         self._last_snapshot_step = step
         return True
 

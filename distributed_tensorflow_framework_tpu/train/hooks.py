@@ -29,6 +29,12 @@ class Hook(Protocol):
 
 
 class BaseHook:
+    # True on a hook whose ``after_step`` wall reaches the goodput ledger
+    # through an event of its own: the loop then keeps its ``hook:*``
+    # phase out of the ``hooks`` bucket, or the buckets would pass the
+    # wall. (Read with ``getattr``: a hook need not derive from this.)
+    charges_goodput_itself = False
+
     def on_start(self, trainer) -> None:
         pass
 
@@ -167,6 +173,10 @@ class CheckpointHook(BaseHook):
     force-save plus ``wait_until_finished`` block until every in-flight
     commit is durable, so both normal completion and SIGTERM graceful
     preemption (rc 83) exit with nothing half-written."""
+
+    # ``save()`` is where this hook blocks, and the saver's ``ckpt_save``
+    # event charges that wall to ``ckpt_blocked``.
+    charges_goodput_itself = True
 
     def __init__(self, manager, interval: int):
         self.manager = manager

@@ -119,6 +119,17 @@ class Trainer:
             process_id=(self.runtime.process_index
                         if self.runtime.process_count > 1 else None))
         self._startup_accounted = False
+        # The loop's recorder (core/profiling.py): every stretch of an
+        # iteration runs under one of its phases — the per-phase totals
+        # become ``time_*_ms`` at every log interval and feed the goodput
+        # ledger, each occurrence lands in a ring (the loop timeline,
+        # dumped beside the flight recorder) and in any profile as a
+        # trace annotation. The cheap always-on signal for "is the input
+        # pipeline the wall?" (SURVEY.md §7 hard part 1), and for "which
+        # span of which iteration was long?", without capturing a trace.
+        self.timer = profiling.StepTimer()
+        self._judged = None  # the newest ring entry ``slow_step`` has judged
+        self._self_charged: set[str] = set()  # hook phases goodput skips
         # Periodic HBM sampling (core/memstats.py): device.memory_stats()
         # where the backend has it, host RSS where it doesn't.
         self.memstats = memstats.MemoryMonitor(
@@ -431,17 +442,8 @@ class Trainer:
             # post-mortem "how far ahead was the infeed?" number.
             self._ckpt_manager.set_data_sources(
                 watermark_source=infeed.watermark)
-        if self.recovery is not None:
-            # Baseline snapshot: the ladder must be able to roll back even
-            # if the first anomaly lands before the first clean fetch.
-            self.recovery.take_snapshot(
-                self.host_step, self.state,
-                data_state=self.data_ckpt_state, force=True)
-        # Host-side phase timing (core/profiling.py): infeed vs dispatch vs
-        # metric-fetch wall time, reported at every log interval — the
-        # cheap always-on signal for "is the input pipeline the wall?"
-        # (SURVEY.md §7 hard part 1) without capturing a trace.
-        timer = profiling.StepTimer()
+        timer = self.timer
+        timer.step = self.host_step
         # Bounded dispatch-ahead (train.dispatch_ahead): a deque of each
         # in-flight step's metrics; once full, sync on the OLDEST entry
         # before dispatching another step (a scalar device_get of that
@@ -453,6 +455,15 @@ class Trainer:
             self._startup_accounted = True
             self.goodput.add(
                 "startup", time.perf_counter() - self._init_t)
+        if self.recovery is not None:
+            # Baseline snapshot: the ladder must be able to roll back even
+            # if the first anomaly lands before the first clean fetch.
+            # (After the startup bucket closed: its wall is ``snapshot``'s.)
+            self._snapshot(force=True)
+        hook_phases = [(h, f"hook:{type(h).__name__}") for h in hooks]
+        self._self_charged = {
+            phase for h, phase in hook_phases
+            if getattr(h, "charges_goodput_itself", False)}
         try:
             while self.host_step < cfg.total_steps:
                 if supervision.preemption_requested():
@@ -476,6 +487,7 @@ class Trainer:
                     self.writer.telemetry.flush()
                     self.flightrec.dump("graceful_preemption")
                     break
+                timer.step = self.host_step + 1
                 with timer.phase("infeed"):
                     batch, self.data_ckpt_state = self._next_batch(infeed)
                 # Fault injection (core/faults.py, DTF_FAULTS): crash_at_step
@@ -496,8 +508,8 @@ class Trainer:
                 # after a rollback rebuild) is recompile overhead in the
                 # goodput ledger, not step compute.
                 compiling = first_dispatch or self._recompile_pending
-                with timer.phase("compile" if compiling else "dispatch"), \
-                        profiling.annotate("train_step"):
+                with timer.phase("compile" if compiling else "dispatch",
+                                 span="train_step"):
                     if first_dispatch:
                         # First dispatch traces/compiles the step; the
                         # tally sees every collective the executable will
@@ -511,6 +523,8 @@ class Trainer:
                 if compiling:
                     self._recompile_pending = False
                     self.goodput.count("recompiles")
+                    # An iteration that compiled is no ``slow_step``.
+                    self._judged = timer.spans[-1]
                 if cfg.dispatch_ahead > 0:
                     pending.append(metrics)
                 self.host_step += 1
@@ -548,53 +562,33 @@ class Trainer:
                 if fetch:
                     # Only here does the host fully sync with the device;
                     # off-interval steps dispatch asynchronously (at most
-                    # dispatch_ahead deep).
+                    # dispatch_ahead deep). The slow-step check goes first:
+                    # the device still has every step in flight to run, so
+                    # it costs the sync nothing.
+                    with timer.phase("bookkeeping"):
+                        self._report_slow_steps()
                     with timer.phase("metrics_fetch"):
                         host_metrics = {
                             k: float(v)
                             for k, v in jax.device_get(metrics).items()
                         }
-                    host_metrics.update(timer.means())
-                    self.goodput.absorb_phases(timer.totals)
-                    timer.reset()
-                    pending.clear()
+                    with timer.phase("bookkeeping"):
+                        host_metrics.update(timer.means())
+                        self._absorb_phases()
+                        pending.clear()
                     # Recovery ladder rung (train/anomaly.py): a successful
                     # rollback returns None — the anomalous metrics never
                     # reach the hooks (no NaNGuard abort, no poisoned
                     # LoggingHook record) and host_step has been rewound.
+                    # Its spans are its own (``snapshot``, ``rollback``).
                     host_metrics = self._maybe_recover(host_metrics)
-                    self.goodput.maybe_emit(step=self.host_step)
-                    self.memstats.maybe_sample(step=self.host_step)
-                    # Packing census (data/packing.py counters riding the
-                    # iterator state): goodput per padded token, emitted
-                    # at the same cadence as the metrics fetch. Cumulative
-                    # counters — the last event of an attempt is its total.
-                    real = self.data_ckpt_state.get(packing.REAL_TOKENS_KEY)
-                    if real is not None:
-                        self.writer.telemetry.emit(
-                            telemetry.KIND_DATA_PACKING, step=self.host_step,
-                            metrics=packing.packing_stats(
-                                int(real),
-                                int(self.data_ckpt_state.get(
-                                    packing.PADDED_TOKENS_KEY, 0))))
-                    # One span per log-interval window of steps — coarse
-                    # enough to stay cheap, fine enough that a gang
-                    # restart's dead time shows as a gap between the last
-                    # window of attempt N and startup of attempt N+1.
-                    now_mono = time.monotonic()
-                    self.tracer.emit_span(
-                        "train.steps", self.run_span,
-                        start_mono=getattr(self, "_window_mono", now_mono),
-                        end_mono=now_mono,
-                        start_step=getattr(self, "_window_step",
-                                           self.host_step),
-                        end_step=self.host_step)
-                    self._window_mono = now_mono
-                    self._window_step = self.host_step
+                    with timer.phase("bookkeeping"):
+                        self._fetch_bookkeeping()
                     if host_metrics is not None:
                         last_metrics = host_metrics
-                for h in hooks:
-                    h.after_step(self, self.host_step, host_metrics)
+                for h, phase in hook_phases:
+                    with timer.phase(phase):
+                        h.after_step(self, self.host_step, host_metrics)
                 if self.recovery is not None and self.recovery.exhausted:
                     # Finite-anomaly escalation (loss spike / grad-norm
                     # explosion past max_rollbacks): NaNGuardHook only
@@ -621,8 +615,13 @@ class Trainer:
             # on the escalation path (the final rollup below only runs on
             # clean exit; an escalating or SIGKILLed attempt is covered by
             # its last periodic snapshot).
-            self.goodput.absorb_phases(timer.totals)
-            timer.reset()
+            self._absorb_phases()
+            # The loop timeline goes to disk on every way out of the loop
+            # — right behind the flight recorder's dump on preemption and
+            # escalation, and before the hooks' on_end (a final save can
+            # outlast a supervisor's grace window).
+            self._report_slow_steps(final=True)
+            self._dump_timeline()
         for h in hooks:
             h.on_end(self)
         if self._ckpt_manager is not None:
@@ -654,6 +653,82 @@ class Trainer:
                 status="preempted" if self.preempted else "ok",
                 end_step=self.host_step)
         return last_metrics
+
+    # ------------------------------------------------------- loop timeline --
+    def _fetch_bookkeeping(self) -> None:
+        """What a metrics-fetch iteration owes after the fetch: the
+        goodput and memory cadences, the packing census and the
+        step-window span."""
+        self.goodput.maybe_emit(step=self.host_step)
+        self.memstats.maybe_sample(step=self.host_step)
+        # Packing census (data/packing.py counters riding the iterator
+        # state): goodput per padded token, emitted at the same cadence
+        # as the metrics fetch. Cumulative counters — the last event of
+        # an attempt is its total.
+        real = self.data_ckpt_state.get(packing.REAL_TOKENS_KEY)
+        if real is not None:
+            self.writer.telemetry.emit(
+                telemetry.KIND_DATA_PACKING, step=self.host_step,
+                metrics=packing.packing_stats(
+                    int(real),
+                    int(self.data_ckpt_state.get(
+                        packing.PADDED_TOKENS_KEY, 0))))
+        # One span per log-interval window of steps — coarse enough to
+        # stay cheap, fine enough that a gang restart's dead time shows
+        # as a gap between the last window of attempt N and startup of
+        # attempt N+1.
+        now_mono = time.monotonic()
+        self.tracer.emit_span(
+            "train.steps", self.run_span,
+            start_mono=getattr(self, "_window_mono", now_mono),
+            end_mono=now_mono,
+            start_step=getattr(self, "_window_step", self.host_step),
+            end_step=self.host_step)
+        self._window_mono = now_mono
+        self._window_step = self.host_step
+
+    def _absorb_phases(self) -> None:
+        """Fold the timer's totals into the goodput ledger and start them
+        anew; a hook that charges the ledger itself is left out."""
+        for phase in self._self_charged:
+            self.timer.totals.pop(phase, None)
+        self.goodput.absorb_phases(self.timer.totals)
+        self.timer.reset()
+
+    def _report_slow_steps(self, final: bool = False) -> None:
+        """One ``slow_step`` health event per iteration, among those
+        completed since the last call, that spent far longer on the host
+        than the block's median (``profiling.slow_iterations``): which
+        step lost the time, and under which span. Called ahead
+        of the fetch, while the device is busy. The iteration still
+        running (this fetch's own: its fetch and hooks are yet to come)
+        waits for the next call, unless ``final``."""
+        block = []
+        for span in reversed(self.timer.spans):
+            if span is self._judged:
+                break
+            if final or span[1] != self.timer.step:
+                block.append(span)
+        if not block:
+            return
+        self._judged = block[0]
+        for slow in profiling.slow_iterations(reversed(block)):
+            log.info("slow step %d: %.1f ms on the host (block median "
+                     "%.1f ms): %s", slow["step"], slow["host_ms"],
+                     slow["block_median_ms"], slow["spans_ms"])
+            self.writer.telemetry.emit(
+                telemetry.KIND_HEALTH, step=slow["step"],
+                health={"event": "slow_step", **slow})
+
+    def _dump_timeline(self) -> str | None:
+        """``loop_timeline-<pid>.json`` beside the flight recorder's
+        dump (same directory resolution); no directory, no file."""
+        base = self.flightrec.directory()
+        if base is None:
+            return None
+        return self.timer.dump(
+            os.path.join(base, f"loop_timeline-{os.getpid()}.json"),
+            final_step=self.host_step)
 
     # ----------------------------------------------------- recovery ladder --
     def _next_batch(self, infeed):
@@ -692,6 +767,21 @@ class Trainer:
                 )
                 time.sleep(backoff)
 
+    def _snapshot(self, force: bool = False) -> None:
+        """The ladder's device→host copy of the train state, when one is
+        due, under the ``snapshot`` span and the ``snapshots`` /
+        ``snapshot_bytes`` goodput counters."""
+        rec = self.recovery
+        if not rec.snapshot_due(self.host_step, force):
+            return
+        with self.timer.phase("snapshot"):
+            took = rec.take_snapshot(
+                self.host_step, self.state,
+                data_state=self.data_ckpt_state, force=force)
+        if took:
+            self.goodput.count("snapshots")
+            self.goodput.count("snapshot_bytes", rec.ring.latest().nbytes)
+
     def _maybe_recover(self, host_metrics: dict[str, float]) -> dict[str, float] | None:
         """Classify a fetched-metrics step; roll back if anomalous.
 
@@ -705,17 +795,17 @@ class Trainer:
         rec = self.recovery
         if rec is None:
             return host_metrics
-        verdict = rec.classify(self.host_step, host_metrics)
+        with self.timer.phase("bookkeeping"):
+            verdict = rec.classify(self.host_step, host_metrics)
         if verdict is None:
-            rec.take_snapshot(self.host_step, self.state,
-                              data_state=self.data_ckpt_state)
+            self._snapshot()
             return host_metrics
         if not rec.can_rollback():
             rec.exhausted = True
             return host_metrics
         from_step = self.host_step
         t_rb = time.monotonic()
-        with self.goodput.timed("rollback"):
+        with self.timer.phase("rollback"):
             self.state, snap = rec.rollback(self.state, from_step=self.host_step)
             # Skip-batch semantics: host_step rewinds, the data iterator
             # does NOT — the replayed step range consumes fresh batches and
@@ -741,6 +831,9 @@ class Trainer:
             self.host_step = snap.step
             if self.config.resilience.lr_rewarmup_steps > 0:
                 self._rebuild_with_rewarmup(snap.step)
+        # Into the ledger now: this fetch's goodput event, and a crash
+        # before the next fetch, must find the rollback in its bucket.
+        self._absorb_phases()
         self.tracer.emit_span(
             "train.rollback", self.run_span,
             start_mono=t_rb, end_mono=time.monotonic(),
