@@ -2,21 +2,42 @@
 
 Computes softmax(qkᵀ/√d)·v with the S×S score matrix living only in VMEM.
 Forward: one HBM read of q/k/v and one write of o (+ the per-row
-logsumexp) per (batch, head, q-block) program. Backward: two Pallas
-kernels (dq over q-blocks; dk/dv over k-blocks) that RECOMPUTE the
-probability blocks online from the saved (q, k, v, o, lse) — so training
-peak memory is O(S·D) end to end; no O(S²) tensor is ever materialized in
-HBM in either direction. This is the flash-attention recompute pattern
-(PAPERS.md); XLA alone tiles but still round-trips the score tensor for
-the unfused einsum+softmax+einsum chain.
+logsumexp). Backward: Pallas kernels that RECOMPUTE the probability
+blocks from the saved (q, k, v, o, lse) — so training peak memory is
+O(S·D) end to end; no O(S²) tensor is ever materialized in HBM in either
+direction. This is the flash-attention recompute pattern (PAPERS.md);
+XLA alone tiles but still round-trips the score tensor for the unfused
+einsum+softmax+einsum chain.
 
-Shapes: q, k, v are (B, S, H, D). Two kernel regimes, dispatched on
-sequence length (see MAX_SEQ_VMEM): whole-K (each program holds its
-block plus the full opposing sequence in VMEM — the measured-fast path
-to S=4K) and K-blocked streaming (sequential k-axis grid with running
-softmax state in VMEM scratch — any length, VMEM use O(block²)). Ring
-attention over the ``seq`` mesh axis composes on top for sharded
-sequences.
+Shapes: q, k, v are (B, S, H, D). Two kernel families:
+
+  whole-K   a program holds a block of rows plus the FULL opposing
+            sequence in VMEM: no running-softmax state, no init or
+            finalise. Forward, dq and dk/dv kernels.
+  stream    K-blocked: the grid gains a sequential k-axis, running
+            (m, l, acc) state lives in VMEM scratch and K/V stream
+            through in tiles, so VMEM use is O(block²) at any length.
+            Forward, dq, dk/dv, and the fused one-pass backward that
+            computes each probability block ONCE for all four
+            cotangents.
+
+``select_dispatch`` picks family, tile and backward from what a call
+shows: the two sequence lengths, the dtype, and whether the platform is
+one the fused backward is verified on. What it selects today (bf16 on
+v5e; the per-pair kernel times behind each line are in PERF.md §6,
+PR 25):
+
+  s_k ≤ MAX_SEQ_VMEM   whole-K forward whose f32 score block keeps the
+                       area of 128 rows × 4096 keys: 512 rows a program
+                       to S=1024, 256 at 2048, 128 at 4096; fused
+                       one-pass backward from S=128 up
+  s_k > MAX_SEQ_VMEM   streaming forward on 512×1024 tiles, fused
+                       backward to FUSED_BWD_MAX, two-pass beyond
+
+f32 inputs, S < 128, and any TPU generation off the fused backward's
+verified list keep the two-pass backward of the family the forward runs
+in, on the same tile rule. Ring attention over the ``seq`` mesh axis
+composes on top for sharded sequences.
 
 The kernels run in interpret mode off-TPU so the CPU test mesh exercises
 the same code path; tests/test_attention.py pins fwd+bwd numerics against
@@ -28,6 +49,7 @@ from __future__ import annotations
 import functools
 import logging
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,61 +58,53 @@ from jax.experimental import pallas as pl
 log = logging.getLogger(__name__)
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+# The hardware tile floor: every sequence is a multiple of it (or shorter
+# than it), and no kernel tiles finer.
 BLOCK_Q = 128
-BLOCK_K = 128
-# Streaming-kernel tile sizes (the s_k > MAX_SEQ_VMEM regime only). The
-# 128×128 tiles the whole-K path uses are far too fine here: at S=8192
-# they make a (B,H,64,64) grid of ~200k programs whose per-program
-# overhead swamps the 128×64×128 matmuls (measured 3% MFU on v5e,
-# PERF_NOTES.md round 4). Fatter tiles amortize the grid: 8 sequential
-# k-steps instead of 64, and each dot is MXU-sized. Measured ladder at
-# seq 8192 (PERF_NOTES round 4): 128/128 → 7.9k tok/s, 256/1024 → 30k,
-# 512/1024 → 35.2k, 1024/1024 → 35.4k, 512/2048 → 31.9k (VMEM pressure).
-# 512/1024 ships: within noise of the peak at half the q-tile VMEM.
-# Env-tunable for A/Bs, same spirit as the BENCH_* knobs.
+# Streaming tile targets, and the widest row block any kernel here takes.
+# 128×128 tiles make a grid whose fixed per-program cost swamps the
+# matmuls: at S=8192 ~200k programs (3% MFU, PERF_NOTES.md round 4; the
+# ladder there: 128/128 → 7.9k tok/s, 256/1024 → 30k, 512/1024 → 35.2k,
+# 1024/1024 → 35.4k, 512/2048 → 31.9k under VMEM pressure), at S=512
+# 55k programs a step at 10 ps a pair forward and 30 backward where 512
+# rows take 4.7 and 9.4 (PERF.md §6, PR 25). 512/1024 ships: within
+# noise of the ladder's peak at half the q-tile VMEM. The FLASH_*
+# variables here and below exist for trial runs (scripts/, the autotune
+# plan); the shipped selection is taken with all of them unset.
 BLOCK_Q_KB = int(os.environ.get("FLASH_BLOCK_Q_KB", "512"))
 BLOCK_K_KB = int(os.environ.get("FLASH_BLOCK_K_KB", "1024"))
-# VMEM dispatch policy (VERDICT r3 weak #2 — no silent fallback above this):
-#   s_k ≤ MAX_SEQ_VMEM → whole-K kernels: each program holds the full
-#     opposing sequence in VMEM at INPUT dtype (S*D*2B*2 for bf16 K and
-#     V — the round-4 kernels dot in input dtype, no f32 upcast — plus
-#     the BLOCK_Q*S*4B f32 score block) — fits ~16MB with double
-#     buffering, and is the variant whose perf was measured on real TPU
-#     (PERF_NOTES.md).
-#   s_k > MAX_SEQ_VMEM → K-blocked streaming kernels: the grid gains a
-#     sequential k-axis; running (m, l, acc) softmax state lives in VMEM
-#     scratch and K/V stream through in BLOCK_K_KB tiles, so VMEM use is
-#     O(BLOCK_Q_KB·BLOCK_K_KB) regardless of sequence length. No
-#     fallback to the O(S²)-materializing XLA chain exists above the
-#     threshold — long chunks stay fused (tests/test_attention.py pins
-#     8192), and the chain is not even COMPILABLE there: at seq 8192 the
-#     XLA impl failed compilation outright (PERF_NOTES.md round 4).
-# Env-tunable so the whole-K vs K-blocked crossover can be re-measured
-# without an edit (FLASH_MAX_SEQ_VMEM=0 forces the streaming kernels
-# everywhere).
+# Where whole-K ends (no silent fallback above it):
+#   s_k ≤ MAX_SEQ_VMEM → whole-K forward: each program holds the full
+#     opposing sequence in VMEM at INPUT dtype (the kernels dot in input
+#     dtype, no f32 upcast) plus its f32 score block, whose area
+#     select_dispatch holds at BLOCK_Q × MAX_SEQ_VMEM. Measured ahead of
+#     the streaming forward at every bf16 length tried on v5e, 512 to
+#     4096 (3.6–4.7 ps a pair against 4.2–8.2; PERF.md §6, PR 25).
+#   s_k > MAX_SEQ_VMEM → streaming kernels, VMEM use O(BLOCK_Q_KB ·
+#     BLOCK_K_KB) regardless of sequence length. No fallback to the
+#     O(S²)-materializing XLA chain exists above the threshold — long
+#     chunks stay fused (tests/test_attention.py pins 8192), and the
+#     chain is not even COMPILABLE there (PERF_NOTES.md round 4).
+# FLASH_MAX_SEQ_VMEM=0 forces the streaming kernels everywhere.
 MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
-# Fused one-pass streaming backward: one kernel over grid (B,H,nq,nk)
-# produces dq AND dk/dv/dbias, computing each (q-block, k-block)
-# probability block ONCE — the two-pass backward exps every block twice
-# (dq pass + dkv pass). The round-5 PERF_NOTES bound analysis puts the
-# streaming regime's cost in exactly that S² VPU transcendental work,
-# at the price of full-length (S_k, D) f32 dk/dv VMEM accumulators —
-# hence the MAX gate (4 MB at 8192; beyond ~2·8192 it cannot fit and
-# the two-pass kernels remain the only path).
+# Fused one-pass backward: one kernel over grid (B,H,nq,nk) produces dq
+# AND dk/dv/dbias, computing each (q-block, k-block) probability block
+# ONCE — the two-pass backward forms QKᵀ, the mask and the exp in both
+# of its kernels — at the price of full-length (S_k, D) f32 dk/dv VMEM
+# accumulators, hence the MAX gate (4 MB at 8192; beyond ~2·8192 it
+# cannot fit and the two-pass kernels remain the only path).
 #
 # Tri-state default: ``None`` (env unset) = auto — ON only on backends
 # where scripts/verify_flash_kernels.py results are RECORDED: v5e, where
 # chip_smoke.py re-runs that check on every smoke (on jax 0.9.0 /
 # libtpu 0.0.34 the fused backward agrees with the two-pass kernels and
-# with the float32 reference at seq 8192, 4096 and 2048 — PERF.md,
-# PR 21; the +7.8% step A/B at seq 8192 is a 2026-08-01 number from an
-# earlier tree and toolchain, PERF_NOTES round 5). On any other real TPU
-# generation the fused dk/dv/dbias flush ordering is UNVERIFIED silicon
-# behavior (ADVICE r5): auto keeps the two-pass backward and says so
-# once. FLASH_FUSED_BWD=1/0 forces either way (env read at import time
-# like the other FLASH_* knobs); tests and
-# scripts/verify_flash_kernels.py assign the module global directly —
-# the backward closures consult it at call time through
+# with the float32 reference from seq 128 to 8192 — PERF.md, PRs 21 and
+# 25). On any other real TPU generation the fused dk/dv/dbias flush
+# ordering is UNVERIFIED silicon behavior (ADVICE r5): auto keeps the
+# two-pass backward and says so once. FLASH_FUSED_BWD=1/0 forces either
+# way (env read at import time like the other FLASH_* variables); tests
+# and scripts/verify_flash_kernels.py assign the module global directly
+# — select_dispatch consults it at call time through
 # fused_bwd_enabled().
 _FUSED_BWD_ENV = os.environ.get("FLASH_FUSED_BWD")
 FUSED_BWD: bool | None = (
@@ -99,44 +113,32 @@ FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
 # Backend substrings (matched against device_kind, lowercased) with
 # recorded verify_flash_kernels.py results.
 FUSED_BWD_VERIFIED_PLATFORMS = ("v5 lite", "v5e")
-# The fused one-pass backward can also REPLACE the whole-K two-pass
-# backward for mid-length sequences (FUSED_WHOLE_K_MIN ≤ s ≤
-# MAX_SEQ_VMEM): the whole-K dq/dkv kernel pair pays the same three S²
-# exp evaluations the streaming two-pass does, and the round-4 crossover
-# showed the K-blocked kernels already TIE whole-K at 2048 — so the fused
-# kernel's saved exp SHOULD be pure win from there up. But that band's
-# win is EXTRAPOLATED from the 8192 measurement, not measured for f32.
-# The bf16 arm of the §13 precision ladder
-# (scripts/chip_window_queue.sh) re-ran the crossover under the
-# production compute dtype: at bf16 the MXU matmuls halve, leaving the
-# fused kernel's saved S² exp pass as a larger FRACTION of the backward
-# — the takeover is armed by default at 2048 for bf16 inputs only. f32
-# keeps the conservative park above MAX_SEQ_VMEM (where the streaming
-# kernels are the only path anyway and the knob is inert) until the
-# wk2048/wk4096 f32 A/B (scripts/chip_window_queue.sh item 7) lands.
-# FLASH_FUSED_WHOLE_K_MIN=<n> forces one threshold for every dtype
-# (tests and scripts assign the module global directly, same contract);
-# unset leaves the dtype-aware default via fused_whole_k_min(). Forward
-# stays whole-K either way (the streaming backward needs only
-# q/k/v/bias/lse/do, all of which the whole-K forward saves).
+# Below MAX_SEQ_VMEM the fused backward pairs with the whole-K forward
+# (it needs only q/k/v/bias/lse/do, all of which that forward saves).
+# For bf16 it does so from the tile floor up: measured on v5e against
+# the whole-K two-pass pair on its best tile it takes 9.4 ps a pair
+# against 13.7 at S=512, 8.9 against 10.8 at 1024, 8.0 against 13.4 at
+# 2048 (PERF.md §6, PR 25). f32 inputs keep the two-pass pair (the
+# threshold parks above MAX_SEQ_VMEM): no f32 length has been timed on a
+# chip. FLASH_FUSED_WHOLE_K_MIN=<n> forces one threshold for every dtype
+# (tests and scripts assign the module global directly, same contract).
 _FUSED_WHOLE_K_MIN_ENV = os.environ.get("FLASH_FUSED_WHOLE_K_MIN")
 FUSED_WHOLE_K_MIN: int | None = (
     None if _FUSED_WHOLE_K_MIN_ENV is None else int(_FUSED_WHOLE_K_MIN_ENV))
-FUSED_WHOLE_K_MIN_BF16 = 2048
 
 
 def fused_whole_k_min(dtype) -> int:
-    """Minimum sequence length where the fused one-pass backward takes
-    over from the whole-K two-pass pair, resolved per input dtype.
-    An explicit FUSED_WHOLE_K_MIN (env or direct module-global
-    assignment — tests/scripts do the latter) wins for every dtype;
-    otherwise bf16 gets the armed 2048 default and everything else stays
-    parked above MAX_SEQ_VMEM. Reads the module globals at call time so
+    """Shortest sequence at which the fused one-pass backward replaces
+    the whole-K two-pass pair, per input dtype. An explicit
+    FUSED_WHOLE_K_MIN (env or direct module-global assignment —
+    tests/scripts do the latter) wins for every dtype; otherwise bf16
+    takes it from the tile floor and everything else stays parked above
+    MAX_SEQ_VMEM. Reads the module globals at call time so
     monkeypatching keeps working."""
     if FUSED_WHOLE_K_MIN is not None:
         return FUSED_WHOLE_K_MIN
     if jnp.dtype(dtype) == jnp.bfloat16:
-        return FUSED_WHOLE_K_MIN_BF16
+        return BLOCK_Q
     return MAX_SEQ_VMEM + 1
 
 
@@ -259,7 +261,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     """K-blocked forward: grid (B, H, nq, nk) with nk innermost/sequential.
 
     Running-softmax state (m, l, acc) persists in VMEM scratch across the
-    k-blocks of one q-block; K/V stream through in BLOCK_K tiles so no
+    k-blocks of one q-block; K/V stream through in block_k tiles so no
     whole-sequence operand ever sits in VMEM. Finite NEG_INF arithmetic
     gives bit-compatible fully-masked-row semantics with the whole-K
     kernel (garbage o, lse ≈ NEG_INF — the ring merge weights it to 0).
@@ -350,7 +352,7 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                             scale: float, segmented: bool):
     """K-blocked dK/dV/dbias: grid (B, H, nk, nq) with the q-axis
-    innermost/sequential; Q/dO stream through in BLOCK_Q tiles while the
+    innermost/sequential; Q/dO stream through in block_q tiles while the
     (dk, dv, dbias) accumulators for one k-block live in scratch."""
     if segmented:
         (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
@@ -514,6 +516,13 @@ def kernel_mode() -> str:
 _fused_bwd_auto: bool | None = None  # memoized auto-resolution
 
 
+def fused_bwd_verified(device_kind: str) -> bool:
+    """Whether scripts/verify_flash_kernels.py results are recorded for
+    this TPU generation (FUSED_BWD_VERIFIED_PLATFORMS)."""
+    kind = device_kind.lower()
+    return any(p in kind for p in FUSED_BWD_VERIFIED_PLATFORMS)
+
+
 def fused_bwd_enabled() -> bool:
     """Resolve the FUSED_BWD tri-state at backward-dispatch time.
 
@@ -534,9 +543,8 @@ def fused_bwd_enabled() -> bool:
         if jax.default_backend() != "tpu":
             _fused_bwd_auto = False
         else:
-            kind = jax.devices()[0].device_kind.lower()
-            _fused_bwd_auto = any(
-                p in kind for p in FUSED_BWD_VERIFIED_PLATFORMS)
+            kind = jax.devices()[0].device_kind
+            _fused_bwd_auto = fused_bwd_verified(kind)
             if not _fused_bwd_auto:
                 log.warning(
                     "fused flash-attention backward disabled: no recorded "
@@ -545,6 +553,79 @@ def fused_bwd_enabled() -> bool:
                     "FLASH_FUSED_BWD=1 to enable", kind,
                 )
     return _fused_bwd_auto
+
+
+class FlashDispatch(NamedTuple):
+    """What one attention call runs. ``family`` is ``"whole_k"`` (a
+    program holds its rows and ALL of the opposing sequence) or
+    ``"stream"`` (the opposing sequence streams through in tiles under a
+    sequential grid axis); ``backward`` is ``"fused"`` (the one-pass
+    kernel) or ``"two_pass"`` (dq, then dk/dv/dbias). In the whole-K
+    two-pass backward ``bwd_block_q`` is the dq kernel's row block and
+    ``bwd_block_k`` the dk/dv kernel's key block."""
+    family: str
+    block_q: int
+    block_k: int
+    backward: str
+    bwd_family: str
+    bwd_block_q: int
+    bwd_block_k: int
+
+
+def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
+    """The one place a (q length, k length, dtype) becomes kernels and
+    tiles; the platform enters through ``fused_bwd_enabled()``. Called
+    at the custom_vjp layer, outside the jitted wrappers, so the module
+    globals it reads are never frozen into a trace cache: the wrappers
+    take the result as a static argument.
+
+    Whole-K tile: a program's f32 score block keeps the area the family
+    proves at its upper edge, BLOCK_Q rows × MAX_SEQ_VMEM keys, so the
+    rows grow as the keys shrink, up to the streaming q-tile: 512 rows
+    to S=1024, 256 at 2048, 128 at 4096."""
+    stream_tile = (_pick_block(s, BLOCK_Q_KB), _pick_block(s_k, BLOCK_K_KB))
+
+    def whole_k_rows(n: int, held: int) -> int:
+        target = BLOCK_Q * MAX_SEQ_VMEM // held
+        return _pick_block(n, min(max(target, BLOCK_Q), BLOCK_Q_KB))
+
+    if s_k > MAX_SEQ_VMEM:
+        forward = ("stream", *stream_tile)
+    else:
+        forward = ("whole_k", whole_k_rows(s, s_k), s_k)
+    fused = fused_bwd_enabled() and s_k <= FUSED_BWD_MAX
+    if max(s, s_k) > MAX_SEQ_VMEM or (
+            fused and min(s, s_k) >= fused_whole_k_min(dtype)):
+        backward = ("fused" if fused else "two_pass", "stream", *stream_tile)
+    else:
+        backward = ("two_pass", "whole_k",
+                    whole_k_rows(s, s_k), whole_k_rows(s_k, s))
+    return FlashDispatch(*forward, *backward)
+
+
+# (s, s_k, dtype name, segmented) -> FlashDispatch, one entry per
+# distinct call traced in this process: what dispatch_log() reports.
+_dispatch_log: dict = {}
+
+
+def _dispatch(q, k, segmented: bool) -> FlashDispatch:
+    s, s_k = q.shape[2], k.shape[2]
+    dispatch = select_dispatch(s, s_k, q.dtype)
+    _dispatch_log[(s, s_k, jnp.dtype(q.dtype).name, segmented)] = dispatch
+    return dispatch
+
+
+def dispatch_log() -> list[dict]:
+    """Every distinct (s, s_k, dtype, segmented) traced so far with the
+    kernels and tiles it was given — the run-meta record's
+    ``flash_dispatch`` (train/loop.py), so a run says which attention
+    kernels its shapes selected without a trace."""
+    return [
+        dict(s=s, s_k=s_k, dtype=dtype, segmented=segmented,
+             **dispatch._asdict())
+        for (s, s_k, dtype, segmented), dispatch
+        in sorted(_dispatch_log.items())
+    ]
 
 
 def _make_fused(segmented: bool, return_lse: bool):
@@ -562,50 +643,48 @@ def _make_fused(segmented: bool, return_lse: bool):
         @jax.custom_vjp
         def fused(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
-                                segmented=True, interpret=_interpret())
+                                segmented=True, interpret=_interpret(),
+                                dispatch=_dispatch(q, k, True))
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
-                                segmented=True, interpret=_interpret())
+                                segmented=True, interpret=_interpret(),
+                                dispatch=_dispatch(q, k, True))
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, qseg, kseg, o, lse)
 
         def bwd(res, g):
             q, k, v, bias, qseg, kseg, o, lse = res
             do, dlse = g if return_lse else (g, None)
-            use_fused = fused_bwd_enabled() and k.shape[2] <= FUSED_BWD_MAX
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, qseg, kseg, o, lse, do, dlse=dlse,
                 segmented=True, interpret=_interpret(),
-                fused=use_fused,
-                force_stream=use_fused and min(
-                    q.shape[2], k.shape[2]) >= fused_whole_k_min(q.dtype))
+                dispatch=_dispatch(q, k, True))
             return (dq, dk, dv, dbias,
                     jnp.zeros_like(qseg), jnp.zeros_like(kseg))
     else:
         @jax.custom_vjp
         def fused(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
-                                segmented=False, interpret=_interpret())
+                                segmented=False, interpret=_interpret(),
+                                dispatch=_dispatch(q, k, False))
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
-                                segmented=False, interpret=_interpret())
+                                segmented=False, interpret=_interpret(),
+                                dispatch=_dispatch(q, k, False))
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, o, lse)
 
         def bwd(res, g):
             q, k, v, bias, o, lse = res
             do, dlse = g if return_lse else (g, None)
-            use_fused = fused_bwd_enabled() and k.shape[2] <= FUSED_BWD_MAX
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, o, lse, do, dlse=dlse,
                 segmented=False, interpret=_interpret(),
-                fused=use_fused,
-                force_stream=use_fused and min(
-                    q.shape[2], k.shape[2]) >= fused_whole_k_min(q.dtype))
+                dispatch=_dispatch(q, k, False))
             return dq, dk, dv, dbias
 
     fused.defvjp(fwd, bwd)
@@ -669,16 +748,18 @@ def flash_attention_chunk(q, k, v, bias, q_seg=None, kv_seg=None):
     return o.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("segmented", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("segmented", "interpret", "dispatch"))
 def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
-               interpret: bool):
+               interpret: bool, dispatch: FlashDispatch):
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    block_q = min(BLOCK_Q, s)
-    if s_k > MAX_SEQ_VMEM:
+    block_q = dispatch.block_q
+    if dispatch.family == "stream":
         return _flash_fwd_kb(q, k, v, bias, qseg, kseg,
-                             segmented=segmented, interpret=interpret)
+                             segmented=segmented, interpret=interpret,
+                             block_q=block_q, block_k=dispatch.block_k)
     grid = (b, h, s // block_q)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -749,14 +830,12 @@ def _kb_params(interpret: bool, n_parallel: int = 3):
 
 
 def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
-                  interpret: bool):
-    """Streaming forward for s_k > MAX_SEQ_VMEM: sequential k-axis grid +
-    VMEM-scratch running softmax (kernel docstring)."""
+                  interpret: bool, block_q: int, block_k: int):
+    """Streaming forward: sequential k-axis grid + VMEM-scratch running
+    softmax (kernel docstring)."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    block_q = _pick_block(s, BLOCK_Q_KB)
-    block_k = _pick_block(s_k, BLOCK_K_KB)
     grid = (b, h, s // block_q, s_k // block_k)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
@@ -800,11 +879,9 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("segmented", "interpret", "fused",
-                                    "force_stream"))
+                   static_argnames=("segmented", "interpret", "dispatch"))
 def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
-               interpret: bool, dlse=None, fused: bool = False,
-               force_stream: bool = False):
+               interpret: bool, dispatch: FlashDispatch, dlse=None):
     if segmented:
         qseg, kseg, o, lse, do = seg_then_rest
     else:
@@ -825,18 +902,14 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
 
     seg_operands = [qseg, kseg] if segmented else []
 
-    if max(s, s_k) > MAX_SEQ_VMEM or force_stream:
-        # force_stream: mid-length sequences take the FUSED streaming
-        # backward instead of the whole-K two-pass (FUSED_WHOLE_K_MIN
-        # note above). The decision is made at the custom_vjp layer —
-        # this function is jitted, so a module-attr read HERE would
-        # freeze into the first trace's cache (the _flash_bwd_kb
-        # docstring's rule; MAX_SEQ_VMEM predates it and is accepted).
-        return _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta,
-                             segmented=segmented, interpret=interpret,
-                             fused=fused)
+    block_q, block_k = dispatch.bwd_block_q, dispatch.bwd_block_k
+    if dispatch.bwd_family == "stream":
+        stream = (_flash_bwd_fused_kb if dispatch.backward == "fused"
+                  else _flash_bwd_kb)
+        return stream(q, k, v, bias, qseg, kseg, lse, do, delta,
+                      segmented=segmented, interpret=interpret,
+                      block_q=block_q, block_k=block_k)
 
-    block_q = min(BLOCK_Q, s)
     dq_seg_specs = [
         pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi: (bi, 0, qi)),
         pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
@@ -862,7 +935,6 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
         interpret=interpret,
     )(q, k, v, bias, *seg_operands, do, lse, delta)
 
-    block_k = min(BLOCK_K, s_k)
     dkv_seg_specs = [
         pl.BlockSpec((1, 1, s), lambda bi, hi, ki: (bi, 0, 0)),
         pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki: (bi, 0, ki)),
@@ -898,24 +970,14 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
 
 
 def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
-                  segmented: bool, interpret: bool, fused: bool = False):
-    """Streaming backward for sequences > MAX_SEQ_VMEM: dQ accumulates
-    over a sequential k-axis, dK/dV/dbias over a sequential q-axis; no
-    whole-sequence operand in VMEM (kernel docstrings). ``fused`` is the
-    COMPLETE FLASH_FUSED_BWD ∧ s_k ≤ FUSED_BWD_MAX decision, made at the
-    custom_vjp layer OUTSIDE the inner jit — both module attrs are jit-
-    invisible, so reading either here would freeze it into the first
-    trace's cache."""
+                  segmented: bool, interpret: bool, block_q: int,
+                  block_k: int):
+    """Two-pass streaming backward: dQ accumulates over a sequential
+    k-axis, dK/dV/dbias over a sequential q-axis; no whole-sequence
+    operand in VMEM (kernel docstrings)."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    block_q = _pick_block(s, BLOCK_Q_KB)
-    block_k = _pick_block(s_k, BLOCK_K_KB)
-
-    if fused:
-        return _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do,
-                                   delta, segmented=segmented,
-                                   interpret=interpret)
 
     seg_operands = [qseg, kseg] if segmented else []
     dq_seg_specs = [
@@ -1001,15 +1063,14 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
 
 
 def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
-                        segmented: bool, interpret: bool):
-    """One-pass streaming backward (FLASH_FUSED_BWD; kernel docstring):
-    one grid, one exp per (q-block, k-block) pair, full-length dk/dv
-    VMEM accumulators — gated to s_k ≤ FUSED_BWD_MAX by the caller."""
+                        segmented: bool, interpret: bool, block_q: int,
+                        block_k: int):
+    """One-pass streaming backward (kernel docstring): one grid, one exp
+    per (q-block, k-block) pair, full-length dk/dv VMEM accumulators —
+    gated to s_k ≤ FUSED_BWD_MAX by ``select_dispatch``."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    block_q = _pick_block(s, BLOCK_Q_KB)
-    block_k = _pick_block(s_k, BLOCK_K_KB)
 
     seg_operands = [qseg, kseg] if segmented else []
     seg_specs = [

@@ -43,12 +43,13 @@ from distributed_tensorflow_framework_tpu.ops.flash_attention import (
 # 2048 (70.7 vs 69.6) and 4096 (89.8 vs 84.1, +6.8%). 2048 stands as the
 # measured crossover — the round-3 value survived the 2x kernel speedup
 # because XLA's chain got proportionally cheaper at short chunks too.
-# Those flash timings are TWO-PASS backward numbers — the matched
-# regime: the round-5 whole-K fused takeover now ships default-off
-# (ops/flash_attention.py FUSED_WHOLE_K_MIN parks above MAX_SEQ_VMEM
-# until the wk2048/wk4096 chip A/B lands), so chunks in [2048,
-# MAX_SEQ_VMEM] take the measured two-pass path unless the operator
-# re-arms the knob, which would only widen flash's margin here.
+# Those flash timings are of the kernels as they then were: 128-row
+# whole-K tiles and the two-pass backward. Chunks in [FLASH_CHUNK_MIN,
+# MAX_SEQ_VMEM] now take what ops/flash_attention.select_dispatch gives
+# their length — for bf16 on v5e the whole-K forward on 256 or 128 rows
+# and the fused one-pass backward — which only widens flash's margin
+# here; whether the crossover itself has fallen below 2048 is in
+# PERF.md §7.
 # Module-level so tests can force either path.
 FLASH_CHUNK_MIN = 2048
 

@@ -173,22 +173,6 @@ class Trainer:
 
     # -------------------------------------------------------------- setup --
     def build(self) -> None:
-        self.writer.telemetry.emit_run_meta(
-            argv=list(sys.argv),
-            config_name=self.config.name,
-            spmd_mode=self.config.train.spmd_mode,
-            model=self.config.model.name,
-            dataset=self.config.data.name,
-            global_batch_size=self.config.data.global_batch_size,
-            mesh={k: int(v) for k, v in self.mesh.shape.items()},
-            process_count=self.runtime.process_count,
-            process_index=self.runtime.process_index,
-            # Where the run landed and how its Pallas kernels compile
-            # there ("mosaic" on a TPU, "interpret" anywhere else) — a
-            # CPU run must never read as a chip run.
-            **device_record(),
-            pallas_kernels=flash_attention.kernel_mode(),
-        )
         # Shard-assignment record (data/shard.py): validate this host's
         # slice of every global batch against the gang AND the mesh's
         # data-parallel extent before the first batch moves, and put the
@@ -197,13 +181,50 @@ class Trainer:
         mesh_shape = {k: int(v) for k, v in self.mesh.shape.items()}
         data_parallel = (mesh_shape.get("data", 1)
                          * mesh_shape.get("fsdp", 1)) or None
-        shard_layout = data_shard.shard_plan(
-            data_shard.ShardAssignment(
+        try:
+            shard_layout = data_shard.shard_plan(
+                data_shard.ShardAssignment(
+                    process_index=self.runtime.process_index,
+                    process_count=self.runtime.process_count),
+                global_batch=self.config.data.global_batch_size,
+                data_parallel=data_parallel,
+                shard_mode=self.config.data.shard_mode)
+            # Peek one batch for shapes, then restore the stream to the
+            # start.
+            start_state = self.dataset.state()
+            host_batch = next(self.dataset)
+            self.dataset.restore(start_state)
+            sample = to_global(host_batch, self.mesh)
+            # Kept for post-rollback re-jitting (LR re-warmup rebuilds
+            # the optimizer, which needs a recompile against the same
+            # shapes).
+            self._sample = sample
+            self.state = self.builder.init_state(
+                self.config.train.seed, sample)
+        finally:
+            # The run's opening record, written whether or not the init
+            # above succeeded. It waits for the init because that traces
+            # the model's forward, which is when the attention kernels
+            # choose family and tile from the shapes they are given.
+            self.writer.telemetry.emit_run_meta(
+                argv=list(sys.argv),
+                config_name=self.config.name,
+                spmd_mode=self.config.train.spmd_mode,
+                model=self.config.model.name,
+                dataset=self.config.data.name,
+                global_batch_size=self.config.data.global_batch_size,
+                mesh=mesh_shape,
+                process_count=self.runtime.process_count,
                 process_index=self.runtime.process_index,
-                process_count=self.runtime.process_count),
-            global_batch=self.config.data.global_batch_size,
-            data_parallel=data_parallel,
-            shard_mode=self.config.data.shard_mode)
+                # Where the run landed and how its Pallas kernels compile
+                # there ("mosaic" on a TPU, "interpret" anywhere else) —
+                # a CPU run must never read as a chip run — and, per
+                # distinct attention shape traced, the kernel family,
+                # tile and backward it was given.
+                **device_record(),
+                pallas_kernels=flash_attention.kernel_mode(),
+                flash_dispatch=flash_attention.dispatch_log(),
+            )
         self.writer.telemetry.emit(
             telemetry.KIND_DATA_SHARD, step=self.host_step,
             shard=shard_layout)
@@ -230,15 +251,6 @@ class Trainer:
                 peak_inflight=pipe_sched.peak_inflight(
                     name, stages, micro, virtual),
             )
-        # Peek one batch for shapes, then restore the stream to the start.
-        start_state = self.dataset.state()
-        host_batch = next(self.dataset)
-        self.dataset.restore(start_state)
-        sample = to_global(host_batch, self.mesh)
-        # Kept for post-rollback re-jitting (LR re-warmup rebuilds the
-        # optimizer, which needs a recompile against the same shapes).
-        self._sample = sample
-        self.state = self.builder.init_state(self.config.train.seed, sample)
         self.train_step = self.builder.make_train_step(sample)
         if getattr(self.builder, "_zero", False):
             # One record of the static shard/bucket plan so byte and

@@ -1,18 +1,27 @@
 """On-DEVICE compile-and-agree check for every flash-attention kernel.
 
-ops/flash_attention.py has seven Pallas kernels in three regimes, chosen
-by sequence length; a training step reaches only the ones its length
-selects. This drives ``value_and_grad`` through all of them, segmented
-and unsegmented, at bf16:
+ops/flash_attention.py has seven Pallas kernels in two families; which
+ones a call runs, and on what tile, ``select_dispatch`` decides from the
+sequence lengths, the dtype and the platform (the module docstring has
+the rule, PERF.md the measurements). A training step reaches only what
+its length selects. This drives ``value_and_grad`` through all of them,
+segmented and unsegmented, at bf16:
 
-  whole_k_short   whole-K fwd/dq/dkv at MAX_SEQ_VMEM/8        (512)
-  whole_k_max     whole-K fwd/dq/dkv at MAX_SEQ_VMEM          (4096)
-  kblocked        K-blocked fwd/dq/dkv at 2*MAX_SEQ_VMEM,     (8192)
-                  fused backward forced off
-  fused           fused one-pass backward at 2*MAX_SEQ_VMEM   (8192)
-  fused_takeover  fused backward at both ends of the bf16     (2048)
-  fused_takeover_max  whole-K takeover band:                  (4096)
-                  fused_whole_k_min(bf16) and MAX_SEQ_VMEM
+  case                seq   backward   what runs
+  cell_s512           512   as chosen  the default path at ``bert_s512``'s
+                                       shape: whole-K forward on 512 rows,
+                                       fused one-pass backward (v5e)
+  whole_k_short       512   two-pass   whole-K fwd/dq/dkv, 512-row blocks:
+                                       what a TPU off the verified list runs
+  whole_k_max         4096  two-pass   whole-K fwd/dq/dkv at MAX_SEQ_VMEM,
+                                       128-row blocks
+  kblocked            8192  two-pass   K-blocked fwd/dq/dkv, 512x1024 tiles
+  fused               8192  fused      K-blocked fwd + fused backward
+                                       (``bert_s8192``)
+  fused_takeover_min  128   fused      both ends and the middle of the band
+  fused_takeover      2048  fused      in which bf16 pairs the whole-K
+  fused_takeover_max  4096  fused      forward with the fused backward:
+                                       fused_whole_k_min(bf16)..MAX_SEQ_VMEM
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -28,10 +37,11 @@ nothing is routed around it.
 The last line of stdout is one JSON object: ``ok``, the ``platform``,
 ``device_kind`` and ``kernel_mode`` ("mosaic" | "interpret") it ran in,
 which backward the default dispatch picks at the streaming length
-(``streaming_backward_default``), and each case's statistics. Exit 0
-when every case agrees, 1 otherwise. The lengths follow the module's own
-thresholds, so the FLASH_* variables shrink the matrix for a CPU
-plumbing run (timings and flush order mean nothing there).
+(``streaming_backward_default``), and each case's statistics, the
+dispatch it ran under among them. Exit 0 when every case agrees, 1
+otherwise. The lengths follow the module's own thresholds, so the
+FLASH_* variables shrink the matrix for a CPU plumbing run (timings and
+flush order mean nothing there).
 """
 
 import json
@@ -62,14 +72,17 @@ GATE_FUSED_VS_TWO_PASS = 3e-2
 
 
 def _cases() -> dict:
-    """name -> (seq, FUSED_BWD setting for the case)."""
+    """name -> (seq, FUSED_BWD setting for the case; None leaves the
+    choice to the platform, as a training run does)."""
     vmem = fa.MAX_SEQ_VMEM
     return {
+        "cell_s512": (max(vmem // 8, fa.BLOCK_Q), None),
         "whole_k_short": (max(vmem // 8, fa.BLOCK_Q), False),
         "whole_k_max": (vmem, False),
         "kblocked": (2 * vmem, False),
         "fused": (2 * vmem, True),
-        "fused_takeover": (fa.fused_whole_k_min(jnp.bfloat16), True),
+        "fused_takeover_min": (fa.fused_whole_k_min(jnp.bfloat16), True),
+        "fused_takeover": (max(vmem // 2, fa.BLOCK_Q), True),
         "fused_takeover_max": (vmem, True),
     }
 
@@ -133,12 +146,17 @@ def _rel_l2(a, b) -> float:
     return float(np.linalg.norm(a - b) / max(float(np.linalg.norm(b)), 1e-30))
 
 
-def run_case(name: str, seq: int, fused: bool, two_pass_cache: dict) -> dict:
+def run_case(name: str, seq: int, setting: bool | None,
+             two_pass_cache: dict) -> dict:
     args = _inputs(seq)
-    rec = {"case": name, "seq": seq, "fused_bwd": fused, "variants": {}}
+    fa.FUSED_BWD = setting
+    dispatch = fa.select_dispatch(seq, seq, jnp.bfloat16)
+    fused = dispatch.backward == "fused"
+    rec = {"case": name, "seq": seq, "fused_bwd": fused,
+           "dispatch": dispatch._asdict(), "variants": {}}
     ok = True
     for segmented in (False, True):
-        fa.FUSED_BWD = fused
+        fa.FUSED_BWD = setting
         # Fresh outer trace per setting: the fused decision is read at
         # the custom_vjp layer, outside the inner jit's cache.
         got, mosaic_calls = _run(_kernel_fn(segmented), args)

@@ -38,6 +38,28 @@ def devices():
     return devs
 
 
+@pytest.fixture
+def pin_whole_k_rows(monkeypatch):
+    """``pin(rows, s)`` for tests parametrised over ``rows``: ``"128-row"``
+    holds the whole-K flash kernels to the 128-row blocks they shipped
+    with (several blocks a head; the dk/dv kernel over several key
+    blocks), which ``select_dispatch`` no longer picks at short lengths;
+    ``"selected"`` leaves its choice alone. Either way it checks that an
+    f32 sequence of ``s`` then runs whole-K forward and backward."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    def pin(rows: str, s: int) -> None:
+        if rows == "128-row":
+            monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
+        picked = fa.select_dispatch(s, s, "float32")
+        want = 128 if rows == "128-row" else s
+        assert (picked.family, picked.block_q, picked.bwd_family,
+                picked.bwd_block_q, picked.bwd_block_k) == (
+            "whole_k", want, "whole_k", want, want)
+
+    return pin
+
+
 @pytest.fixture(scope="session")
 def gang_capability():
     """Gate for tests that need a REAL multi-process jax.distributed gang.

@@ -19,11 +19,13 @@ def _rand_qkv(key, b=2, s=256, h=4, d=64, dtype=jnp.float32):
     )
 
 
-def test_flash_attention_matches_xla(devices):
+@pytest.mark.parametrize("rows", ["selected", "128-row"])
+def test_flash_attention_matches_xla(devices, pin_whole_k_rows, rows):
     from distributed_tensorflow_framework_tpu.ops.flash_attention import (
         flash_attention,
     )
 
+    pin_whole_k_rows(rows, 256)
     q, k, v = _rand_qkv(jax.random.key(0))
     ref = dot_product_attention(q, k, v)
     out = flash_attention(q, k, v)
@@ -44,13 +46,15 @@ def test_flash_attention_with_mask(devices):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_attention_backward_matches_xla(devices):
+@pytest.mark.parametrize("rows", ["selected", "128-row"])
+def test_flash_attention_backward_matches_xla(devices, pin_whole_k_rows, rows):
     """The Pallas backward kernels (dq + dkv, online recompute) must match
     XLA autodiff through the reference attention — for q, k AND v."""
     from distributed_tensorflow_framework_tpu.ops.flash_attention import (
         flash_attention,
     )
 
+    pin_whole_k_rows(rows, 256)
     q, k, v = _rand_qkv(jax.random.key(3), s=256)
     mask = jnp.ones((2, 1, 1, 256), bool).at[:, :, :, 200:].set(False)
 
@@ -493,6 +497,159 @@ def test_fused_backward_takes_over_whole_k_regime(devices, monkeypatch):
                     err_msg=f"d{name} vs {tag}, seg={seg_ids is not None}")
 
 
+def _selection_case(s, segmented, dtype):
+    """Inputs on the kernels' (B,H,S,D) layout plus the (B,S,S) additive
+    bias that says the same thing to ``_xla_reference``: a key mask over
+    the tail and, when segmented, the block-diagonal document mask."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    b, h, d = 1, 2, 32
+    q, k, v = (t.astype(dtype) for t in _rand_qkv(
+        jax.random.key(s), b=b, s=s, h=h, d=d))
+    keep = jnp.arange(s) < s - s // 8
+    seg = (jnp.searchsorted(jnp.asarray([0.15, 0.4, 0.8]) * s,
+                            jnp.arange(s), side="right") + 1
+           ).astype(jnp.int32)[None, :]
+    full = jnp.broadcast_to(keep[None, None, :], (b, s, s))
+    if segmented:
+        full = full & (seg[:, :, None] == seg[:, None, :])
+    bias = jnp.where(full, 0.0, fa.NEG_INF).astype(jnp.float32)
+    mask = jnp.broadcast_to(keep[None, None, None, :], (b, 1, 1, s))
+    return (q, k, v), mask, (seg if segmented else None), bias
+
+
+def _assert_matches_xla_reference(s, segmented, dtype):
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    (q, k, v), mask, seg, bias = _selection_case(s, segmented, dtype)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, mask=mask, segment_ids=seg)
+
+    def reference(q, k, v):
+        qt, kt, vt = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
+                      for t in (q, k, v))
+        return fa._xla_reference(qt, kt, vt, bias).transpose(0, 2, 1, 3)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+    exact = dtype == jnp.float32
+    out_tol, grad_tol = (2e-5, 2e-4) if exact else (2e-2, 6e-2)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(reference(q, k, v)), rtol=out_tol, atol=out_tol)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (q, k, v)))
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b),
+            rtol=grad_tol, atol=grad_tol, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("segmented", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("s", [64, 128, 384, 512, 1024])
+def test_selected_dispatch_matches_xla_reference(devices, s, segmented,
+                                                 dtype):
+    """What ``select_dispatch`` picks with no module global patched
+    agrees with ``_xla_reference`` forward and backward, from a
+    sub-block sequence to the widest whole-K row block."""
+    _assert_matches_xla_reference(s, segmented, dtype)
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("s", [128, 384, 512, 1024])
+def test_selection_on_a_verified_chip_matches_xla_reference(
+        devices, monkeypatch, s, segmented):
+    """The same check with the fused backward resolved as a platform on
+    the verified list resolves it (off the chip ``fused_bwd_enabled()``
+    is False): bf16 then takes the whole-K forward and the one-pass
+    backward at every length, which is what ``bert_s512`` runs."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "FUSED_BWD", True)
+    picked = fa.select_dispatch(s, s, jnp.bfloat16)
+    assert (picked.family, picked.backward) == ("whole_k", "fused")
+    _assert_matches_xla_reference(s, segmented, jnp.bfloat16)
+    logged = {(e["s"], e["dtype"], e["segmented"]): e
+              for e in fa.dispatch_log()}
+    entry = logged[(s, "bfloat16", segmented)]
+    assert (entry["family"], entry["block_q"], entry["backward"]) == (
+        "whole_k", picked.block_q, "fused")
+
+
+# (s, s_k, dtype, fused backward allowed) -> the dispatch, at the shipped
+# thresholds. ``True`` is what v5e resolves to, ``False`` a TPU
+# generation off the verified list (and every CPU run).
+_DISPATCH_TABLE = [
+    ((64, 64, "bfloat16", True),
+     ("whole_k", 64, 64, "two_pass", "whole_k", 64, 64)),
+    ((128, 128, "bfloat16", True),
+     ("whole_k", 128, 128, "fused", "stream", 128, 128)),
+    ((384, 384, "bfloat16", True),
+     ("whole_k", 384, 384, "fused", "stream", 384, 384)),
+    ((512, 512, "bfloat16", True),        # bert_s512
+     ("whole_k", 512, 512, "fused", "stream", 512, 512)),
+    ((512, 512, "bfloat16", False),
+     ("whole_k", 512, 512, "two_pass", "whole_k", 512, 512)),
+    ((512, 512, "float32", True),
+     ("whole_k", 512, 512, "two_pass", "whole_k", 512, 512)),
+    ((640, 640, "bfloat16", True),        # 5·128: only 128 divides
+     ("whole_k", 128, 640, "fused", "stream", 128, 640)),
+    ((1024, 1024, "bfloat16", True),
+     ("whole_k", 512, 1024, "fused", "stream", 512, 1024)),
+    ((2048, 2048, "bfloat16", True),
+     ("whole_k", 256, 2048, "fused", "stream", 512, 1024)),
+    ((2048, 2048, "float32", True),
+     ("whole_k", 256, 2048, "two_pass", "whole_k", 256, 256)),
+    ((4096, 4096, "bfloat16", True),
+     ("whole_k", 128, 4096, "fused", "stream", 512, 1024)),
+    ((4096, 4096, "bfloat16", False),
+     ("whole_k", 128, 4096, "two_pass", "whole_k", 128, 128)),
+    ((8192, 8192, "bfloat16", True),      # bert_s8192
+     ("stream", 512, 1024, "fused", "stream", 512, 1024)),
+    ((8192, 8192, "bfloat16", False),
+     ("stream", 512, 1024, "two_pass", "stream", 512, 1024)),
+    ((16384, 16384, "bfloat16", True),    # over FUSED_BWD_MAX
+     ("stream", 512, 1024, "two_pass", "stream", 512, 1024)),
+]
+
+
+@pytest.mark.parametrize("case,want", _DISPATCH_TABLE,
+                         ids=[f"{c[0]}-{c[2]}-{'fused' if c[3] else 'twopass'}"
+                              for c, _ in _DISPATCH_TABLE])
+def test_select_dispatch_table(monkeypatch, case, want):
+    """The tile function itself: the table above, every tile a divisor
+    of its sequence, and a whole-K score block never over the area the
+    family proves at its upper edge (BLOCK_Q rows × MAX_SEQ_VMEM keys)."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    s, s_k, dtype, fused_allowed = case
+    monkeypatch.setattr(fa, "FUSED_BWD", fused_allowed)
+    got = fa.select_dispatch(s, s_k, jnp.dtype(dtype))
+    assert tuple(got) == want
+    assert s % got.block_q == 0 and s_k % got.block_k == 0
+    assert s % got.bwd_block_q == 0 and s_k % got.bwd_block_k == 0
+    area = fa.BLOCK_Q * fa.MAX_SEQ_VMEM
+    if got.family == "whole_k":
+        assert got.block_k == s_k and got.block_q * s_k <= area
+    if got.bwd_family == "whole_k":
+        assert got.bwd_block_q * s_k <= area and got.bwd_block_k * s <= area
+
+
+def test_fused_backward_is_on_for_the_verified_platforms_only():
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    assert fa.fused_bwd_verified("TPU v5 lite")
+    assert fa.fused_bwd_verified("TPU v5e")
+    assert not fa.fused_bwd_verified("TPU v4")
+    assert not fa.fused_bwd_verified("TPU v6 lite")
+
+
 def test_pick_block_divisor_policy():
     """Streaming-tile picker: largest 128-multiple ≤ target dividing s;
     sub-128 env targets clamp to 128 instead of dividing by zero; short
@@ -571,7 +728,6 @@ def test_kblocked_segmented_ring_matches_reference(devices, monkeypatch,
     # 16-wide block grid (nq = nk = 4), segments riding along.
     monkeypatch.setattr(fa, "MAX_SEQ_VMEM", 32)
     monkeypatch.setattr(fa, "BLOCK_Q", 16)
-    monkeypatch.setattr(fa, "BLOCK_K", 16)
     monkeypatch.setattr(fa, "BLOCK_Q_KB", 16)
     monkeypatch.setattr(fa, "BLOCK_K_KB", 16)
     monkeypatch.setattr(ring, "FLASH_CHUNK_MIN", 0)
@@ -712,10 +868,11 @@ def test_kernel_check_matrix_follows_the_module_thresholds():
     from tools.autotune.plan import compile_chip_window_plan
 
     cases = vfk._cases()
-    assert {name: seq for name, (seq, _) in cases.items()} == {
-        "whole_k_short": 512, "whole_k_max": 4096, "kblocked": 8192,
-        "fused": 8192, "fused_takeover": 2048, "fused_takeover_max": 4096}
-    assert [f for _, f in cases.values()] == [False] * 3 + [True] * 3
+    assert cases == {
+        "cell_s512": (512, None), "whole_k_short": (512, False),
+        "whole_k_max": (4096, False), "kblocked": (8192, False),
+        "fused": (8192, True), "fused_takeover_min": (128, True),
+        "fused_takeover": (2048, True), "fused_takeover_max": (4096, True)}
     named = {a for t in compile_chip_window_plan()
              if "scripts/verify_flash_kernels.py" in t.argv
              for a in t.argv[2:]}

@@ -66,21 +66,25 @@ def test_xla_segmented_matches_per_segment(data):
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
 
-def test_flash_segmented_matches_per_segment(data):
+@pytest.mark.parametrize("rows", ["selected", "128-row"])
+def test_flash_segmented_matches_per_segment(data, pin_whole_k_rows, rows):
     from distributed_tensorflow_framework_tpu.ops.flash_attention import (
         flash_attention,
     )
 
+    pin_whole_k_rows(rows, S)
     q, k, v, segs, ref = data
     out = flash_attention(q, k, v, segment_ids=segs)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
 
-def test_flash_segmented_gradients_match_xla(data):
+@pytest.mark.parametrize("rows", ["selected", "128-row"])
+def test_flash_segmented_gradients_match_xla(data, pin_whole_k_rows, rows):
     from distributed_tensorflow_framework_tpu.ops.flash_attention import (
         flash_attention,
     )
 
+    pin_whole_k_rows(rows, S)
     q, k, v, segs, ref = data
     # Weight the loss by real-token positions so padding rows (whose
     # outputs legitimately differ in no way that matters) drop out.

@@ -42,6 +42,29 @@ def test_bert_trains(devices, impl):
     assert metrics["loss"] < 6.0, metrics
 
 
+def test_run_meta_lists_the_attention_dispatch(devices, tmp_path):
+    """The run's opening record says which flash kernels the model's
+    shapes selected (family, tile, backward), filled as ``build()``
+    traces the forward — and it still opens the file."""
+    import json
+
+    base = tiny_bert_base(attention_impl="pallas")
+    base["checkpoint"] = {"directory": str(tmp_path / "run")}
+    t = Trainer(load_config(base=base))
+    t.build()
+    with open(tmp_path / "run" / "events.jsonl") as fh:
+        first = json.loads(fh.readline())
+    assert first["kind"] == "run_meta"
+    meta = first["extra"]
+    assert meta["pallas_kernels"] == "interpret"
+    mine = [e for e in meta["flash_dispatch"]
+            if (e["s"], e["s_k"], e["dtype"]) == (128, 128, "float32")]
+    assert mine and all(
+        (e["family"], e["block_q"], e["block_k"], e["backward"],
+         e["bwd_family"]) == ("whole_k", 128, 128, "two_pass", "whole_k")
+        for e in mine)
+
+
 @pytest.mark.slow
 def test_bert_tensor_parallel(devices):
     """model=4 TP: megatron-style sharded QKV/MLP; loss matches DP run."""
