@@ -13,9 +13,16 @@ from a seed, batches synthetic from a seed; no network, no git):
             run, a short eval
   resnet    ``train.py --config configs/resnet50_imagenet.yaml``, per-chip
             batch 128, 8 steps, save, eval
+  lfm2      ``train.py --config configs/lfm2_8b_a1b.yaml`` cut to one
+            chip's share (published widths; 8 of 32 experts, 16384 vocabulary
+            rows, one dense and one attention-expert layer), the
+            ``causal_lm`` task, rows of 8192 packed tokens, 4 steps: the
+            causal grouped-query kernels, the dropless expert layer
+            (``moe_dropped`` must read 0) and the short convolutions
   kernels   ``scripts/verify_flash_kernels.py``: all seven flash-attention
             kernels compiled by Mosaic and held to a float32 reference, the
-            fused backward also to the two-pass backward
+            fused backward also to the two-pass backward, and the causal
+            grouped-query calls in both families
   export    ``cli/export.py`` freezes the checkpoint the bert leg saved
   serve     ``cli/serve.py`` with ``decode.enabled=true`` answers
             ``/predict`` and streamed ``/generate`` requests from
@@ -68,6 +75,15 @@ RESNET_PER_CHIP_BATCH = 128
 # Everything else about the two trained models is the shipped YAML.
 BERT_OVERRIDES = ("data.name=synthetic_mlm",)
 RESNET_OVERRIDES = ("data.name=synthetic_images",)
+# LFM2-8B-A1B at its published widths, cut to what one chip of a four-way
+# expert-parallel group holds of two layers (the dense short-convolution
+# layer and one attention layer with experts): 8 of 32 experts, a quarter
+# of the vocabulary. One row of 8192 packed tokens a chip.
+LFM2_PER_CHIP_BATCH = 1
+LFM2_OVERRIDES = ("model.num_layers=2",
+                  "model.layer_types=[conv,full_attention]",
+                  "model.num_dense_layers=1", "model.expert_groups=4",
+                  "model.vocab_size=16384", "data.vocab_size=16384")
 # /predict answers an MLM request with its full-vocabulary logits as JSON:
 # 30522 floats a token, ~300 MB of text for one 512-token row, and ~20 s
 # of interpreter time to encode it (first chip run, PR 21: 2 of 8 such
@@ -272,6 +288,30 @@ def leg_train(name: str, config: str, overrides: tuple, global_batch: int,
             "cache": _cache_counts(name)}
 
 
+def leg_lfm2(ckpt: pathlib.Path, device: dict) -> dict:
+    """The decoder family through the same trainer leg, then what only it
+    records: no dropped assignment, a quarter of the assignments local,
+    and an attention call that was causal over 8 key/value heads."""
+    out = leg_train("lfm2", "configs/lfm2_8b_a1b.yaml", LFM2_OVERRIDES,
+                    LFM2_PER_CHIP_BATCH * device["count"], ckpt, device)
+    events = _events(LOGS / "lfm2.events.jsonl")
+    steps = [ev["metrics"] for ev in events if ev["kind"] == "train_step"]
+    _check(all(m.get("moe_dropped") == 0.0 for m in steps),
+           f"dropped assignments: {[m.get('moe_dropped') for m in steps]}")
+    share = steps[-1]["moe_local_share"]
+    _check(0.15 < share < 0.35,
+           f"moe_local_share {share}: 8 of 32 experts should see ~0.25")
+    calls = events[0]["extra"]["flash_dispatch"]
+    _check(any(c["causal"] and c["kv_heads"] == 8 and c["heads"] == 32
+               and c["segmented"] for c in calls),
+           f"no causal 32-over-8-head attention call in run_meta: {calls}")
+    experts = events[0]["extra"].get("expert_share") or {}
+    _check(experts.get("held") == list(range(8)),
+           f"run_meta expert_share {experts}")
+    return dict(out, moe_local_share=share,
+                moe_load_max_mean=steps[-1]["moe_load_max_mean"])
+
+
 def leg_kernels(device: dict) -> dict:
     out = run_child("kernels", [PY, "scripts/verify_flash_kernels.py"],
                     timeout=600)
@@ -417,6 +457,7 @@ def main() -> int:
             BERT_OVERRIDES, BERT_PER_CHIP_BATCH * n, bert_ckpt, device)
         leg("resnet", leg_train, "resnet", "configs/resnet50_imagenet.yaml",
             RESNET_OVERRIDES, RESNET_PER_CHIP_BATCH * n, resnet_ckpt, device)
+        leg("lfm2", leg_lfm2, work / "lfm2_ckpt", device)
         leg("kernels", leg_kernels, device)
         artifact = work / "artifact"
         leg("export", leg_export, bert_ckpt, artifact, device)

@@ -249,6 +249,28 @@ class ModelConfig:
     #                  tail — near-zero recompute flops for roughly half
     #                  the activation bytes. ResNet only.
     remat_policy: str = "full"
+    # Decoder family (models/lfm2.py; name "lfm2*"). Knobs it shares with
+    # the BERT family keep their names: vocab_size, hidden_size,
+    # num_layers, num_heads, mlp_dim (the dense SwiGLU width), num_experts
+    # (the router's width), expert_topk, attention_impl, remat.
+    # One mixer kind per layer, "conv" (gated short convolution) or
+    # "full_attention" (causal grouped-query attention); its length must
+    # be num_layers.
+    layer_types: list[str] = field(default_factory=list)
+    # Leading layers with a dense feed-forward; the rest carry experts.
+    num_dense_layers: int = 0
+    num_kv_heads: int = 0       # 0 = as many as num_heads
+    moe_mlp_dim: int = 0        # width of one expert's SwiGLU
+    conv_kernel: int = 3        # taps of the short convolution (conv_L_cache)
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    # The share of an expert-parallel deployment this process computes:
+    # num_experts are divided over expert_groups device groups in
+    # contiguous runs, and this is group expert_group. The layer routes
+    # over all num_experts and adds its own experts' part of the result;
+    # nothing stands in for the other groups. 1 group = the whole layer.
+    expert_groups: int = 1
+    expert_group: int = 0
 
 
 @config_dataclass

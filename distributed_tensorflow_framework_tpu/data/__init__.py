@@ -74,6 +74,8 @@ def get_dataset(config: DataConfig, *, process_index: int = 0,
 
         if "mlm" in name or "text" in name:
             return synthetic.synthetic_mlm(config, process_index, process_count)
+        if name.endswith("_lm"):
+            return synthetic.synthetic_lm(config, process_index, process_count)
         return synthetic.synthetic_images(config, process_index, process_count)
     if name == "mnist":
         from distributed_tensorflow_framework_tpu.data import mnist

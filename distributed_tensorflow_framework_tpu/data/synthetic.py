@@ -111,3 +111,44 @@ def synthetic_mlm(
         # Same refit-safety as synthetic_images: no sample identity.
         repartition=shard.REPARTITION_INVARIANT,
     )
+
+
+def synthetic_lm(
+    config: DataConfig, process_index: int, process_count: int
+) -> HostDataset:
+    """Causal-LM rows (``data.name: synthetic_lm``): two documents packed
+    into each row at a drawn boundary, ids uniform over the vocabulary,
+    ``targets[t]`` the next token of the same document (-1 on each
+    document's last token), ``segment_ids`` 1 and 2 and ``positions``
+    restarting at the boundary."""
+    b = _host_batch(config, process_count)
+    s = config.seq_len
+    vocab = config.vocab_size
+
+    def make_iter(state: dict[str, Any]):
+        state.setdefault("step", 0)
+        idx = np.arange(s, dtype=np.int32)[None, :]
+        while True:
+            rng = prng.host_rng(config.seed, prng.ROLE_DATA,
+                                process_index, state["step"])
+            tokens = rng.integers(0, vocab, size=(b, s),
+                                  dtype=np.int64).astype(np.int32)
+            cut = rng.integers(1, s, size=(b, 1))
+            second = idx >= cut
+            last = (idx == cut - 1) | (idx == s - 1)
+            targets = np.where(last, -1, np.roll(tokens, -1, axis=1))
+            state["step"] += 1
+            yield {
+                "input_ids": tokens,
+                "targets": targets.astype(np.int32),
+                "segment_ids": (1 + second).astype(np.int32),
+                "positions": np.where(second, idx - cut, idx).astype(np.int32),
+            }
+
+    return HostDataset(
+        make_iter,
+        element_spec={key: ((b, s), np.int32) for key in
+                      ("input_ids", "targets", "segment_ids", "positions")},
+        initial_state={"step": 0},
+        repartition=shard.REPARTITION_INVARIANT,
+    )

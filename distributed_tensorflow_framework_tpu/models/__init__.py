@@ -33,8 +33,25 @@ def _is_builtin_model_name(name: str) -> bool:
         name in ("lenet", "lenet5", "lenet-5",
                  "bert", "bert_base", "bert-base",
                  "inception_v3", "inception-v3", "inceptionv3")
+        or _is_lfm2_name(name)
         or re.fullmatch(r"resnet-?(\d+)(_cifar|-cifar)?", name) is not None
     )
+
+
+def _is_lfm2_name(name: str) -> bool:
+    """The causal decoder family of models/lfm2.py: ``lfm2``,
+    ``lfm2_moe``, ``lfm2-8b-a1b`` — the whole ``lfm2`` prefix is
+    reserved. One test for get_model, the task and the decode refusal."""
+    return name.lower().startswith("lfm2")
+
+
+def builtin_task(name: str) -> str:
+    """Task family of a built-in model name (train/step.py picks the
+    loss and the batch wiring from it)."""
+    name = name.lower()
+    if _is_lfm2_name(name):
+        return "causal_lm"
+    return "mlm" if "bert" in name else "classification"
 
 
 def register_model(name: str, *, task: str = "classification"):
@@ -45,9 +62,10 @@ def register_model(name: str, *, task: str = "classification"):
     Flax module. The module's ``__call__`` MUST accept a ``train``
     keyword (the Trainer calls ``init(..., train=False)`` and
     ``apply(..., train=True, rngs={"dropout": ...})``) and its positional
-    inputs must match ``task``: "classification" (images → logits) or
-    "mlm" ((ids, mask[, segment_ids]) → logits) — the task picks the
-    loss and batch wiring (train/step.py). The builder owns the
+    inputs must match ``task``: "classification" (images → logits),
+    "mlm" ((ids, mask[, segment_ids]) → logits) or "causal_lm" ((ids[,
+    segment_ids[, positions]]) → logits, next-token ``targets``) — the
+    task picks the loss and batch wiring (train/step.py). The builder owns the
     interpretation of every other ModelConfig knob (e.g. ``remat``).
     Built-in names cannot be shadowed, and duplicate registrations fail
     loudly.
@@ -63,7 +81,7 @@ def register_model(name: str, *, task: str = "classification"):
                 ...
     """
     key = name.lower()
-    if task not in ("classification", "mlm"):
+    if task not in ("classification", "mlm", "causal_lm"):
         raise ValueError(f"unknown task {task!r} for model {name!r}")
 
     def deco(builder):
@@ -167,15 +185,17 @@ def get_model(config: ModelConfig, *, bn_axis_name=None, mesh=None,
             f"precision.matmul_dtype='int8' is wired for the dense/conv "
             f"image models (lenet, resnet), not {config.name!r}"
         )
-    if config.remat and not (is_bert or name.startswith("resnet")
+    is_lfm2 = _is_lfm2_name(name)
+    if config.remat and not (is_bert or is_lfm2 or name.startswith("resnet")
                              or name.startswith("inception")):
         # Honest failure beats a silently-ignored knob: activation remat is
         # wired for the transformer encoder stack (models/bert.py), the
-        # ResNet residual blocks (models/resnet.py) and the Inception
-        # mixed/reduction blocks (models/inception.py).
+        # decoder blocks (models/lfm2.py), the ResNet residual blocks
+        # (models/resnet.py) and the Inception mixed/reduction blocks
+        # (models/inception.py).
         raise ValueError(
-            f"model.remat is only supported for the transformer (bert), "
-            f"resnet and inception models, not {config.name!r}"
+            f"model.remat is only supported for the transformer (bert, "
+            f"lfm2), resnet and inception models, not {config.name!r}"
         )
     if config.remat_policy != "full" and not (
             config.remat and name.startswith("resnet")):
@@ -194,6 +214,15 @@ def get_model(config: ModelConfig, *, bn_axis_name=None, mesh=None,
             f"model.space_to_depth_stem is a ResNet ImageNet-stem "
             f"optimization, not supported for {config.name!r}"
         )
+    if is_lfm2:
+        if config.pipeline_stages > 1:
+            raise ValueError(
+                "pipeline parallelism is wired for the bert family only, "
+                f"not {config.name!r}")
+        from distributed_tensorflow_framework_tpu.models import lfm2
+
+        return lfm2.build(config, mesh=mesh, dtype=dtype,
+                          ckpt_policy=ckpt_policy)
     if name in ("lenet", "lenet5", "lenet-5"):
         from distributed_tensorflow_framework_tpu.models.lenet import LeNet5
 

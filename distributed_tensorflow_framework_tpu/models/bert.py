@@ -251,7 +251,14 @@ def decode_support_reason(model_config) -> str | None:
     (None = supported). The pure-jnp decode forward walks the dense BERT
     parameter tree by name; trees it does not know must be refused by
     name rather than failing as a KeyError mid-stream."""
-    if model_config.name.lower() not in ("bert", "bert_base", "bert-base"):
+    name = model_config.name.lower()
+    if name.startswith("lfm2"):
+        return (f"model {model_config.name!r} (the lfm2 decoder family) "
+                f"trains only: serving it needs a per-layer cache of two "
+                f"kinds (keys/values for its attention layers, the last "
+                f"conv_kernel-1 gated inputs for its short convolutions) "
+                f"that serve/decode.py does not have")
+    if name not in ("bert", "bert_base", "bert-base"):
         return (f"model {model_config.name!r} has no causal decode head "
                 f"(decode supports the dense bert family)")
     if getattr(model_config, "num_experts", 0):

@@ -1,13 +1,25 @@
-"""Mixture-of-Experts FFN with expert parallelism (GShard/Switch-style).
+"""Mixture-of-Experts feed-forward layers.
 
 The reference framework has no MoE (SURVEY.md §2 parallelism inventory —
 expert parallel: NO); this extends the capability surface the TPU-native
-way: experts live on a dedicated ``expert`` mesh axis, tokens are routed by
-a learned top-k gate, and the dispatch/combine einsums against
-expert-sharded weights make XLA emit ``all_to_all`` collectives over ICI —
-the idiomatic pjit MoE (no hand-written routing RPCs).
+way. Two layers with two semantics live here:
 
-Design points:
+``MoEMlp`` (GShard/Switch-style, the BERT family's): experts live on a
+dedicated ``expert`` mesh axis, tokens are routed by a learned softmax
+top-k gate into static per-group CAPACITY, and the dispatch/combine
+einsums against expert-sharded weights make XLA emit ``all_to_all``
+collectives over ICI — the idiomatic pjit MoE (no hand-written routing
+RPCs). Tokens over capacity are dropped.
+
+``DroplessMoE`` (the decoder family's, models/lfm2.py): sigmoid scores
+with a selection bias, normalised top-k weights, NO capacity and no
+dropped token — assignments are sorted by expert and the experts run as
+one grouped matrix product (``jax.lax.ragged_dot``) over the experts
+this process HOLDS. It is told its share of an expert-parallel
+deployment (``num_experts`` over ``groups``, this is ``group``), routes
+over all experts and computes its own experts' part of the result.
+
+Design points of the capacity layer:
   * **Two dispatchers, one semantics** (parity pinned in tests/test_moe.py):
     the default **sorted** dispatch ranks assignments inside their expert
     with one argsort and gathers/scatters through O(B·E·C) index tables —
@@ -353,3 +365,167 @@ class MoEMlp(nn.Module):
             "zloss": zloss,
             "drop_frac": drop_frac,
         }
+
+
+# ---------------------------------------------------------------------------
+# Dropless layer (the decoder family's).
+# ---------------------------------------------------------------------------
+
+ROUTER_NORM_EPS = 1e-6  # added to the chosen scores' sum before dividing
+# Initial std of the selection bias: non-zero, so that leaving the bias
+# out of the choice changes it. No balancing update moves it afterwards.
+EXPERT_BIAS_INIT_STD = 0.005
+
+
+def held_experts(num_experts: int, groups: int, group: int) -> range:
+    """The experts group ``group`` of ``groups`` holds: a contiguous run
+    of ``num_experts // groups``."""
+    if groups < 1 or num_experts % groups or not 0 <= group < groups:
+        raise ValueError(
+            f"cannot give group {group} of {groups} a whole share of "
+            f"{num_experts} experts")
+    n = num_experts // groups
+    return range(group * n, (group + 1) * n)
+
+
+def route_sigmoid_topk(gate_logits: jax.Array, bias: jax.Array, topk: int
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Bias-routed top-k over sigmoid scores, in float32.
+
+    ``s = sigmoid(logits)``; the ``topk`` experts of a token are the
+    largest of ``s + bias`` (the bias enters the CHOICE only, and carries
+    no gradient); their weights are ``s_e / (sum of the chosen s + 1e-6)``.
+    Returns ``(experts (T, K) int32, weights (T, K) float32)``."""
+    scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), topk)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (chosen.sum(axis=-1, keepdims=True) + ROUTER_NORM_EPS)
+    return experts.astype(jnp.int32), weights
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    """``x[order]`` for a permutation ``order`` with inverse ``inverse``:
+    the cotangent is gathered back (``g[inverse]``) instead of
+    scatter-added, which is what makes dispatch and combine both plain
+    row gathers, forward and backward."""
+    return jnp.take(x, order, axis=0)
+
+
+def _permute_fwd(x, order, inverse):
+    return jnp.take(x, order, axis=0), (order, inverse)
+
+
+def _permute_bwd(res, g):
+    order, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def sort_by_expert(experts: jax.Array, first: int, held: int):
+    """Order the ``T·K`` assignments by held expert, the ones to experts
+    this process does not hold last.
+
+    Returns ``(order, inverse, group_sizes (held,), valid (T·K,) bool)``:
+    ``order[r]`` is the assignment (token-major, ``t·K + k``) that sits at
+    sorted row ``r``, ``inverse`` its inverse permutation,
+    ``group_sizes[e]`` the rows of held expert ``first + e`` and ``valid``
+    marks the sorted rows that belong to a held expert (a prefix)."""
+    flat = experts.reshape(-1)
+    local = (flat >= first) & (flat < first + held)
+    key = jnp.where(local, flat - first, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    valid = jnp.arange(flat.shape[0], dtype=jnp.int32) < group_sizes.sum()
+    return order, inverse, group_sizes, valid
+
+
+class DroplessMoE(nn.Module):
+    """Expert feed-forward without capacity or dropped tokens.
+
+    ``num_experts`` is the router's width; this process holds experts
+    ``held_experts(num_experts, groups, group)`` and adds their part of
+    ``sum_chosen w_e · expert_e(x)``. With one group that is the whole
+    layer; with several, the parts of all groups add up to it (the share
+    test, tests/test_lfm2.py) — on a mesh they would meet through the
+    expert exchange, which this layer does not have: on one chip it runs
+    without it and nothing imitates the other groups.
+
+    Mechanics: every assignment gets a row of a ``T·K``-row buffer (so
+    no routing can overflow it), rows sorted by held expert, the
+    assignments to other groups' experts last; each expert is a SwiGLU
+    of width ``mlp_dim`` and all held experts run as three grouped
+    matrix products (``jax.lax.ragged_dot``: on TPU one Mosaic
+    grouped-matmul kernel each, rows beyond the groups untouched). Rows that belong to no held
+    expert are zeroed on both sides of the products.
+
+    Returns ``(out (B, S, H), counters)``; the counters are explicit
+    outputs so they survive ``nn.remat``: ``local_assignments`` (rows
+    computed here), ``load_max_mean`` (fullest held expert ÷ mean),
+    ``dropped`` (local assignments without a row: 0 by construction) and
+    ``local_share`` (local ÷ all ``T·K`` assignments)."""
+
+    num_experts: int
+    mlp_dim: int
+    topk: int
+    groups: int = 1
+    group: int = 0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> tuple[jax.Array, dict]:
+        b, s, h = x.shape
+        t, k = b * s, self.topk
+        mine = held_experts(self.num_experts, self.groups, self.group)
+        held = len(mine)
+        tokens = x.reshape(t, h)
+        with jax.named_scope("router"):
+            # float32 end to end: the scores decide a discrete choice.
+            gate = self.param("gate", dense_kernel_init,
+                              (h, self.num_experts), jnp.float32)
+            bias = self.param(
+                "expert_bias", nn.initializers.normal(EXPERT_BIAS_INIT_STD),
+                (self.num_experts,), jnp.float32)
+            logits = jnp.dot(tokens.astype(jnp.float32), gate,
+                             precision=jax.lax.Precision.HIGHEST)
+            experts, weights = route_sigmoid_topk(logits, bias, k)
+            # For whoever applies the model with mutable=["intermediates"]
+            # (tests, the router-agreement count of PERF.md); else a no-op.
+            self.sow("intermediates", "experts", experts)
+        w1 = self.param("w1", expert_kernel_init, (held, h, self.mlp_dim),
+                        jnp.float32)
+        w3 = self.param("w3", expert_kernel_init, (held, h, self.mlp_dim),
+                        jnp.float32)
+        w2 = self.param("w2", expert_kernel_init, (held, self.mlp_dim, h),
+                        jnp.float32)
+        with jax.named_scope("dispatch"):
+            order, inverse, group_sizes, valid = sort_by_expert(
+                experts, mine.start, held)
+            per_token = jnp.broadcast_to(
+                tokens.astype(self.dtype)[:, None], (t, k, h)).reshape(t * k, h)
+            xs = jnp.where(valid[:, None],
+                           _permute(per_token, order, inverse), 0)
+        with jax.named_scope("experts"):
+            grouped = lambda lhs, w: jax.lax.ragged_dot(  # noqa: E731
+                lhs, w.astype(self.dtype), group_sizes)
+            hidden = nn.silu(grouped(xs, w1)) * grouped(xs, w3)
+            ys = grouped(hidden, w2)
+        with jax.named_scope("combine"):
+            ys = jnp.where(valid[:, None], ys, 0)
+            back = _permute(ys, inverse, order).reshape(t, k, h)
+            out = jnp.einsum("tkh,tk->th", back.astype(jnp.float32),
+                             weights).astype(self.dtype)
+        local = ((experts >= mine.start) & (experts < mine.stop)).sum()
+        placed = group_sizes.sum()
+        sizes = group_sizes.astype(jnp.float32)
+        counters = {
+            "local_assignments": placed.astype(jnp.float32),
+            "load_max_mean": sizes.max() / jnp.maximum(sizes.mean(), 1.0),
+            "dropped": (local - placed).astype(jnp.float32),
+            "local_share": local.astype(jnp.float32) / (t * k),
+        }
+        return out.reshape(b, s, h), counters

@@ -142,8 +142,30 @@ def fused_whole_k_min(dtype) -> int:
     return MAX_SEQ_VMEM + 1
 
 
+def _causal_mask(s, row0, col0):
+    """Scores of one (rows, cols) block with every key later than its
+    query masked: the mask comes from the block's place in the sequence
+    (``row0``/``col0``: its first row and column), never from HBM."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows >= cols, s, NEG_INF)
+
+
+def _block_needed(qi, ki, block_q: int, block_k: int, qs=None, ks=None):
+    """Whether a causal (q-block, k-block) pair holds any pair to
+    compute: not wholly above the diagonal and, on packed rows, with a
+    document in common (the two blocks' ranges of segment ids overlap; a
+    conservative test that needs no order in the ids). A block that is
+    not needed is skipped, not masked."""
+    needed = ki * block_k <= qi * block_q + (block_q - 1)
+    if qs is not None:
+        needed = needed & (jnp.max(ks) >= jnp.min(qs)) \
+            & (jnp.min(ks) <= jnp.max(qs))
+    return needed
+
+
 def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                     scale: float, segmented: bool):
+                     scale: float, segmented: bool, causal: bool = False):
     # Segment-id refs only exist in the segmented variant — the common
     # unsegmented path carries no extra operands (and no VMEM traffic).
     if segmented:
@@ -169,6 +191,8 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
         qs = qseg_ref[0, 0]                       # (BQ,)
         ks = kseg_ref[0, 0]                       # (S,)
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+    if causal:
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0], 0)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -182,7 +206,7 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                        scale: float, segmented: bool):
+                        scale: float, segmented: bool, causal: bool = False):
     """dQ for one q-block: recompute p from (q, k, lse), no S×S residual."""
     if segmented:
         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref, dq_ref = rest
@@ -202,6 +226,8 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
         qs = qseg_ref[0, 0]
         ks = kseg_ref[0, 0]
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+    if causal:
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0], 0)
     p = jnp.exp(s - lse)                          # recomputed probabilities
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -216,7 +242,8 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                         scale: float, segmented: bool):
+                         scale: float, segmented: bool,
+                         causal: bool = False):
     """dK/dV (+ per-head dbias) for one k-block: full Q/dO in VMEM."""
     if segmented:
         (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
@@ -237,6 +264,8 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
         qs = qseg_ref[0, 0]                       # (S,)
         ks = kseg_ref[0, 0]                       # (BK,)
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+    if causal:
+        s = _causal_mask(s, 0, pl.program_id(2) * k.shape[0])
     p = jnp.exp(s - lse)
     dv = jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -256,8 +285,22 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
     dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)  # (1, BK)
 
 
+def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs):
+    """Run one (q-block, k-block) visit's ``compute``: always without
+    ``causal`` (the trace is then the plain kernel's), under
+    ``_block_needed`` with it."""
+    if not causal:
+        compute()
+        return
+    qs = ks = None
+    if seg_refs:
+        qs, ks = seg_refs[0][0, 0], seg_refs[1][0, 0]
+    pl.when(_block_needed(qi, ki, q_ref.shape[2], k_ref.shape[2],
+                          qs, ks))(compute)
+
+
 def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
-                        scale: float, segmented: bool):
+                        scale: float, segmented: bool, causal: bool = False):
     """K-blocked forward: grid (B, H, nq, nk) with nk innermost/sequential.
 
     Running-softmax state (m, l, acc) persists in VMEM scratch across the
@@ -271,6 +314,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     ki = pl.program_id(3)
+    qi = pl.program_id(2) if causal else None
 
     @pl.when(ki == 0)
     def _init():
@@ -278,27 +322,33 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
         l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
 
-    q = q_ref[0, 0]                               # (BQ, D) input dtype
-    k = k_ref[0, 0]                               # (BK, D)
-    v = v_ref[0, 0]                               # (BK, D)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (BQ, BK) f32
-    if segmented:
-        qs = qseg_ref[0, 0]                       # (BQ,)
-        ks = kseg_ref[0, 0]                       # (BK,)
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    m_prev = m_ref[...]                           # (BQ, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    def _compute():
+        q = q_ref[0, 0]                               # (BQ, D) input dtype
+        k = k_ref[0, 0]                               # (BK, D)
+        v = v_ref[0, 0]                               # (BK, D)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[0]                       # (BQ, BK) f32
+        if segmented:
+            qs = qseg_ref[0, 0]                       # (BQ,)
+            ks = kseg_ref[0, 0]                       # (BK,)
+            s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+        m_prev = m_ref[...]                           # (BQ, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    _guarded(_compute, causal, qi, ki, q_ref, k_ref,
+             (qseg_ref, kseg_ref) if segmented else ())
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -307,42 +357,50 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
-                           scale: float, segmented: bool):
+                           scale: float, segmented: bool,
+                           causal: bool = False):
     """K-blocked dQ: accumulate ds·k over streamed K/V tiles in scratch."""
     if segmented:
         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
     else:
         do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
     ki = pl.program_id(3)
+    qi = pl.program_id(2) if causal else None
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    q = q_ref[0, 0]                               # (BQ, D) input dtype
-    k = k_ref[0, 0]                               # (BK, D)
-    v = v_ref[0, 0]                               # (BK, D)
-    do = do_ref[0, 0]                             # (BQ, D)
-    lse = lse_ref[0, 0]                           # (BQ, 1)
-    delta = delta_ref[0, 0]                       # (BQ, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (BQ, BK) f32
-    if segmented:
-        qs = qseg_ref[0, 0]
-        ks = kseg_ref[0, 0]
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BQ, BK)
-    ds = p * (dp - delta)                         # f32
-    acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    def _compute():
+        q = q_ref[0, 0]                               # (BQ, D) input dtype
+        k = k_ref[0, 0]                               # (BK, D)
+        v = v_ref[0, 0]                               # (BK, D)
+        do = do_ref[0, 0]                             # (BQ, D)
+        lse = lse_ref[0, 0]                           # (BQ, 1)
+        delta = delta_ref[0, 0]                       # (BQ, 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[0]                       # (BQ, BK) f32
+        if segmented:
+            qs = qseg_ref[0, 0]
+            ks = kseg_ref[0, 0]
+            s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # (BQ, BK)
+        ds = p * (dp - delta)                         # f32
+        acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+
+    _guarded(_compute, causal, qi, ki, q_ref, k_ref,
+             (qseg_ref, kseg_ref) if segmented else ())
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -350,7 +408,8 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
-                            scale: float, segmented: bool):
+                            scale: float, segmented: bool,
+                            causal: bool = False):
     """K-blocked dK/dV/dbias: grid (B, H, nk, nq) with the q-axis
     innermost/sequential; Q/dO stream through in block_q tiles while the
     (dk, dv, dbias) accumulators for one k-block live in scratch."""
@@ -361,6 +420,7 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         (do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, db_acc) = rest
     qi = pl.program_id(3)
+    ki = pl.program_id(2) if causal else None
 
     @pl.when(qi == 0)
     def _init():
@@ -368,35 +428,41 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         dv_acc[...] = jnp.zeros(dv_acc.shape, dv_acc.dtype)
         db_acc[...] = jnp.zeros(db_acc.shape, db_acc.dtype)
 
-    q = q_ref[0, 0]                               # (BQ, D) input dtype
-    k = k_ref[0, 0]                               # (BK, D)
-    v = v_ref[0, 0]                               # (BK, D)
-    do = do_ref[0, 0]                             # (BQ, D)
-    lse = lse_ref[0, 0]                           # (BQ, 1)
-    delta = delta_ref[0, 0]                       # (BQ, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (BQ, BK) f32
-    if segmented:
-        qs = qseg_ref[0, 0]
-        ks = kseg_ref[0, 0]
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    p = jnp.exp(s - lse)
-    dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BK, D)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BQ, BK)
-    ds = p * (dp - delta)                         # f32
-    dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                     # (BK, D)
-    db_acc[...] = db_acc[...] + jnp.sum(ds, axis=0, keepdims=True)
+    def _compute():
+        q = q_ref[0, 0]                               # (BQ, D) input dtype
+        k = k_ref[0, 0]                               # (BK, D)
+        v = v_ref[0, 0]                               # (BK, D)
+        do = do_ref[0, 0]                             # (BQ, D)
+        lse = lse_ref[0, 0]                           # (BQ, 1)
+        delta = delta_ref[0, 0]                       # (BQ, 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[0]                       # (BQ, BK) f32
+        if segmented:
+            qs = qseg_ref[0, 0]
+            ks = kseg_ref[0, 0]
+            s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+        p = jnp.exp(s - lse)
+        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # (BK, D)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # (BQ, BK)
+        ds = p * (dp - delta)                         # f32
+        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                     # (BK, D)
+        db_acc[...] = db_acc[...] + jnp.sum(ds, axis=0, keepdims=True)
+
+    _guarded(_compute, causal, qi, ki, q_ref, k_ref,
+             (qseg_ref, kseg_ref) if segmented else ())
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _finalize():
@@ -406,7 +472,8 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
-                              scale: float, segmented: bool):
+                              scale: float, segmented: bool,
+                              causal: bool = False):
     """Fused one-pass streaming backward: grid (B, H, nq, nk), BOTH inner
     axes sequential ("arbitrary"). Each (q-block, k-block) pair is
     visited once; its probability block is exp'd ONCE and feeds all four
@@ -417,7 +484,9 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     order on the core, so the final visit's flush (qi == nq-1) is what
     HBM keeps. Earlier flushes are dead writes: ~(nq-1)·S_k·D·4B extra
     HBM-write traffic per (b,h), orders below the exp savings
-    (PERF_NOTES round-5 analysis)."""
+    (PERF_NOTES round-5 analysis). Under ``causal`` a visit that
+    ``_block_needed`` rules out adds nothing and still flushes, so the
+    last visit of every k-block keeps what HBM holds."""
     if segmented:
         (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref, dbias_ref,
@@ -439,48 +508,69 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         dv_full[...] = jnp.zeros(dv_full.shape, dv_full.dtype)
         db_full[...] = jnp.zeros(db_full.shape, db_full.dtype)
 
-    q = q_ref[0, 0]                               # (BQ, D) input dtype
-    k = k_ref[0, 0]                               # (BK, D)
-    v = v_ref[0, 0]                               # (BK, D)
-    do = do_ref[0, 0]                             # (BQ, D)
-    lse = lse_ref[0, 0]                           # (BQ, 1)
-    delta = delta_ref[0, 0]                       # (BQ, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (BQ, BK) f32
-    if segmented:
-        qs = qseg_ref[0, 0]
-        ks = kseg_ref[0, 0]
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    p = jnp.exp(s - lse)                          # the ONE exp per pair
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BQ, BK)
-    ds = p * (dp - delta)                         # f32
-    dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    bk = k_ref.shape[2]
+    cache: list = []
 
-    bk = k.shape[0]
-    sl = pl.ds(ki * bk, bk)
-    dv_full[sl, :] = dv_full[sl, :] + jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BK, D)
-    dk_full[sl, :] = dk_full[sl, :] + jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                     # (BK, D)
-    db_full[:, sl] = db_full[:, sl] + jnp.sum(ds, axis=0, keepdims=True)
+    def k_rows():
+        """This visit's rows of the full-length accumulators. Formed
+        where the plain kernel forms it (inside ``_compute``, which is
+        then traced inline); under ``causal`` ahead of the guard, so the
+        flush below can use it too."""
+        if not cache:
+            cache.append(pl.ds(ki * bk, bk))
+        return cache[0]
+
+    if causal:
+        k_rows()
+
+    def _compute():
+        q = q_ref[0, 0]                               # (BQ, D) input dtype
+        k = k_ref[0, 0]                               # (BK, D)
+        v = v_ref[0, 0]                               # (BK, D)
+        do = do_ref[0, 0]                             # (BQ, D)
+        lse = lse_ref[0, 0]                           # (BQ, 1)
+        delta = delta_ref[0, 0]                       # (BQ, 1)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[0]                       # (BQ, BK) f32
+        if segmented:
+            qs = qseg_ref[0, 0]
+            ks = kseg_ref[0, 0]
+            s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], ki * bk)
+        p = jnp.exp(s - lse)                          # the ONE exp per pair
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # (BQ, BK)
+        ds = p * (dp - delta)                         # f32
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+
+        sl = k_rows()
+        dv_full[sl, :] = dv_full[sl, :] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # (BK, D)
+        dk_full[sl, :] = dk_full[sl, :] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                     # (BK, D)
+        db_full[:, sl] = db_full[:, sl] + jnp.sum(ds, axis=0, keepdims=True)
+
+    _guarded(_compute, causal, qi, ki, q_ref, k_ref,
+             (qseg_ref, kseg_ref) if segmented else ())
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize_dq():
         dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
     # Store the running partials every visit; the last (qi) visit wins.
+    sl = k_rows()
     dk_ref[0, 0] = dk_full[sl, :].astype(dk_ref.dtype)
     dv_ref[0, 0] = dv_full[sl, :].astype(dv_ref.dtype)
     dbias_ref[0, 0] = db_full[:, sl]
@@ -608,27 +698,29 @@ def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
 _dispatch_log: dict = {}
 
 
-def _dispatch(q, k, segmented: bool) -> FlashDispatch:
+def _dispatch(q, k, segmented: bool, causal: bool = False) -> FlashDispatch:
     s, s_k = q.shape[2], k.shape[2]
     dispatch = select_dispatch(s, s_k, q.dtype)
-    _dispatch_log[(s, s_k, jnp.dtype(q.dtype).name, segmented)] = dispatch
+    _dispatch_log[(s, s_k, jnp.dtype(q.dtype).name, segmented, causal,
+                   q.shape[1], k.shape[1])] = dispatch
     return dispatch
 
 
 def dispatch_log() -> list[dict]:
-    """Every distinct (s, s_k, dtype, segmented) traced so far with the
-    kernels and tiles it was given — the run-meta record's
-    ``flash_dispatch`` (train/loop.py), so a run says which attention
-    kernels its shapes selected without a trace."""
+    """Every distinct (s, s_k, dtype, segmented, causal, heads, kv_heads)
+    traced so far with the kernels and tiles it was given — the run-meta
+    record's ``flash_dispatch`` (train/loop.py), so a run says which
+    attention kernels its shapes selected without a trace."""
     return [
-        dict(s=s, s_k=s_k, dtype=dtype, segmented=segmented,
-             **dispatch._asdict())
-        for (s, s_k, dtype, segmented), dispatch
+        dict(s=s, s_k=s_k, dtype=dtype, segmented=segmented, causal=causal,
+             heads=heads, kv_heads=kv_heads, **dispatch._asdict())
+        for (s, s_k, dtype, segmented, causal, heads, kv_heads), dispatch
         in sorted(_dispatch_log.items())
     ]
 
 
-def _make_fused(segmented: bool, return_lse: bool):
+def _make_fused(segmented: bool, return_lse: bool,
+                causal: bool = False):
     """Build the custom-VJP fused attention for one (segmented, lse)
     variant. Unsegmented signature: (q, k, v, bias) — the common path
     carries NO segment operands or VMEM traffic. Segmented adds
@@ -637,20 +729,25 @@ def _make_fused(segmented: bool, return_lse: bool):
     per-row logsumexp — the chunk primitive for ring attention, whose
     online merge needs lse and therefore flows a cotangent into it.
     Residuals are all O(S·D)/O(S): no score-matrix-shaped tensor is ever
-    saved.
+    saved. ``causal`` masks every key later than its query, from indices
+    inside the kernels; k and v may carry fewer heads than q (a whole
+    divisor: grouped-query attention), reached through the block index
+    maps and never repeated in memory.
     """
     if segmented:
         @jax.custom_vjp
         def fused(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True))
+                                dispatch=_dispatch(q, k, True, causal),
+                                causal=causal)
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True))
+                                dispatch=_dispatch(q, k, True, causal),
+                                causal=causal)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, qseg, kseg, o, lse)
 
@@ -660,7 +757,8 @@ def _make_fused(segmented: bool, return_lse: bool):
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, qseg, kseg, o, lse, do, dlse=dlse,
                 segmented=True, interpret=_interpret(),
-                dispatch=_dispatch(q, k, True))
+                dispatch=_dispatch(q, k, True, causal),
+                                causal=causal)
             return (dq, dk, dv, dbias,
                     jnp.zeros_like(qseg), jnp.zeros_like(kseg))
     else:
@@ -668,13 +766,15 @@ def _make_fused(segmented: bool, return_lse: bool):
         def fused(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False))
+                                dispatch=_dispatch(q, k, False, causal),
+                                causal=causal)
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False))
+                                dispatch=_dispatch(q, k, False, causal),
+                                causal=causal)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, o, lse)
 
@@ -684,7 +784,8 @@ def _make_fused(segmented: bool, return_lse: bool):
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, o, lse, do, dlse=dlse,
                 segmented=False, interpret=_interpret(),
-                dispatch=_dispatch(q, k, False))
+                dispatch=_dispatch(q, k, False, causal),
+                                causal=causal)
             return dq, dk, dv, dbias
 
     fused.defvjp(fwd, bwd)
@@ -693,6 +794,8 @@ def _make_fused(segmented: bool, return_lse: bool):
 
 _FUSED = {(seg, lse): _make_fused(seg, lse)
           for seg in (False, True) for lse in (False, True)}
+_FUSED_CAUSAL = {seg: _make_fused(seg, False, causal=True)
+                 for seg in (False, True)}
 
 
 def chunk_supported(s: int) -> bool:
@@ -749,22 +852,28 @@ def flash_attention_chunk(q, k, v, bias, q_seg=None, kv_seg=None):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("segmented", "interpret", "dispatch"))
+                   static_argnames=("segmented", "interpret", "dispatch",
+                                    "causal"))
 def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
-               interpret: bool, dispatch: FlashDispatch):
+               interpret: bool, dispatch: FlashDispatch,
+               causal: bool = False):
     b, h, s, d = q.shape
+    kv_head = _kv_head_map(h, k.shape[1])
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
     block_q = dispatch.block_q
     if dispatch.family == "stream":
         return _flash_fwd_kb(q, k, v, bias, qseg, kseg,
                              segmented=segmented, interpret=interpret,
-                             block_q=block_q, block_k=dispatch.block_k)
+                             block_q=block_q, block_k=dispatch.block_k,
+                             causal=causal)
     grid = (b, h, s // block_q)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, 1, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
+        pl.BlockSpec((1, 1, s_k, d),
+                     lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
+        pl.BlockSpec((1, 1, s_k, d),
+                     lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
         pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
     ]
     operands = [q, k, v, bias]
@@ -775,7 +884,8 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         ]
         operands += [qseg, kseg]
     return pl.pallas_call(
-        functools.partial(_attn_fwd_kernel, scale=scale, segmented=segmented),
+        functools.partial(_attn_fwd_kernel, scale=scale, segmented=segmented,
+                          causal=causal),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
@@ -788,6 +898,49 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         ],
         interpret=interpret,
     )(*operands)
+
+
+def _kv_head_map(heads: int, kv_heads: int):
+    """Query head -> the key/value head it reads (grouped-query
+    attention): the block index maps send ``heads // kv_heads`` query
+    heads to one key/value block, so k and v are never repeated in
+    memory. With as many key/value heads as query heads it is the
+    identity itself, and the index maps trace as they always did."""
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} key/value "
+                         f"heads: not a whole group size")
+    group = heads // kv_heads
+    return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
+
+
+def _last_k_block(causal: bool, block_q: int, block_k: int):
+    """``(qi, ki) -> k-block to fetch``: under ``causal`` a visit above
+    the diagonal names the last block the row block needs, so the
+    pipeline fetches nothing new for a visit the kernel skips."""
+    if not causal:
+        return lambda qi, ki: ki
+    return lambda qi, ki: jnp.minimum(
+        ki, (qi * block_q + (block_q - 1)) // block_k)
+
+
+def _first_q_block(causal: bool, block_q: int, block_k: int):
+    """``(ki, qi) -> q-block to fetch`` for the dk/dv kernel, whose
+    q-axis is the sequential one: row blocks wholly before a key block
+    are skipped, so they name the first one needed."""
+    if not causal:
+        return lambda ki, qi: qi
+    return lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
+
+
+def _sum_kv_groups(dk, dv, kv_heads: int, dtype):
+    """Per-query-head dk/dv partials (float32 when heads share a
+    key/value head) summed over each group."""
+    b, h, s_k, d = dk.shape
+    if h == kv_heads:
+        return dk, dv
+    fold = lambda t: t.reshape(b, kv_heads, h // kv_heads, s_k, d).sum(  # noqa: E731
+        axis=2).astype(dtype)
+    return fold(dk), fold(dv)
 
 
 def _vmem_scratch(*shapes_dtypes):
@@ -830,32 +983,37 @@ def _kb_params(interpret: bool, n_parallel: int = 3):
 
 
 def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
-                  interpret: bool, block_q: int, block_k: int):
+                  interpret: bool, block_q: int, block_k: int,
+                  causal: bool = False):
     """Streaming forward: sequential k-axis grid + VMEM-scratch running
     softmax (kernel docstring)."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
     grid = (b, h, s // block_q, s_k // block_k)
+    kv_head = _kv_head_map(h, k.shape[1])
+    k_blk = _last_k_block(causal, block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                     lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
         pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-        pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+                     lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
     ]
     operands = [q, k, v, bias]
     if segmented:
         in_specs += [
             pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, block_k),
+                     lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
         ]
         operands += [qseg, kseg]
     return pl.pallas_call(
         functools.partial(_attn_fwd_kernel_kb, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
@@ -879,9 +1037,11 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("segmented", "interpret", "dispatch"))
+                   static_argnames=("segmented", "interpret", "dispatch",
+                                    "causal"))
 def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
-               interpret: bool, dispatch: FlashDispatch, dlse=None):
+               interpret: bool, dispatch: FlashDispatch, dlse=None,
+               causal: bool = False):
     if segmented:
         qseg, kseg, o, lse, do = seg_then_rest
     else:
@@ -889,6 +1049,11 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
         o, lse, do = seg_then_rest
     b, h, s, d = q.shape
     s_k = k.shape[2]
+    kv_heads = k.shape[1]
+    kv_head = _kv_head_map(h, kv_heads)
+    # dk/dv leave the kernels per QUERY head; heads that share a
+    # key/value head are summed after, from float32 partials.
+    dkv_dtype = k.dtype if h == kv_heads else jnp.float32
     scale = 1.0 / (d ** 0.5)
     # delta_i = Σ_d dO_i·O_i — the softmax-jacobian row correction; an
     # O(S·D) elementwise+reduce, cheap in plain XLA.
@@ -908,7 +1073,7 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
                   else _flash_bwd_kb)
         return stream(q, k, v, bias, qseg, kseg, lse, do, delta,
                       segmented=segmented, interpret=interpret,
-                      block_q=block_q, block_k=block_k)
+                      block_q=block_q, block_k=block_k, causal=causal)
 
     dq_seg_specs = [
         pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi: (bi, 0, qi)),
@@ -916,13 +1081,15 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
     ] if segmented else []
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         grid=(b, h, s // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, s_k, d),
+                         lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
+            pl.BlockSpec((1, 1, s_k, d),
+                         lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
             pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
         ] + dq_seg_specs + [
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -941,17 +1108,19 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
     ] if segmented else []
     dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
         grid=(b, h, s_k // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda bi, hi, ki: (bi, kv_head(hi), ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda bi, hi, ki: (bi, kv_head(hi), ki, 0)),
             pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki: (bi, 0, ki)),
         ] + dkv_seg_specs + [
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
@@ -966,37 +1135,45 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
         interpret=interpret,
     )(q, k, v, bias, *seg_operands, do, lse, delta)
     dbias = jnp.sum(dbias_h, axis=1)               # (B, 1, S): Σ over heads
+    dk, dv = _sum_kv_groups(dk, dv, kv_heads, k.dtype)
     return dq, dk, dv, dbias
 
 
 def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                   segmented: bool, interpret: bool, block_q: int,
-                  block_k: int):
+                  block_k: int, causal: bool = False):
     """Two-pass streaming backward: dQ accumulates over a sequential
     k-axis, dK/dV/dbias over a sequential q-axis; no whole-sequence
     operand in VMEM (kernel docstrings)."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
+    kv_heads = k.shape[1]
+    kv_head = _kv_head_map(h, kv_heads)
+    dkv_dtype = k.dtype if h == kv_heads else jnp.float32
+    k_blk = _last_k_block(causal, block_q, block_k)
+    q_blk = _first_q_block(causal, block_q, block_k)
 
     seg_operands = [qseg, kseg] if segmented else []
     dq_seg_specs = [
         pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, 0, qi)),
-        pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
     ] if segmented else []
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel_kb, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         grid=(b, h, s // block_q, s_k // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                         lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+                         lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
+            pl.BlockSpec((1, 1, block_k),
+                     lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
         ] + dq_seg_specs + [
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1014,33 +1191,34 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     )(q, k, v, bias, *seg_operands, do, lse, delta)
 
     dkv_seg_specs = [
-        pl.BlockSpec((1, 1, block_q), lambda bi, hi, ki, qi: (bi, 0, qi)),
+        pl.BlockSpec((1, 1, block_q),
+                     lambda bi, hi, ki, qi: (bi, 0, q_blk(ki, qi))),
         pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki, qi: (bi, 0, ki)),
     ] if segmented else []
     dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel_kb, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
         grid=(b, h, s_k // block_k, s // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+                         lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+                         lambda bi, hi, ki, qi: (bi, kv_head(hi), ki, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+                         lambda bi, hi, ki, qi: (bi, kv_head(hi), ki, 0)),
             pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki, qi: (bi, 0, ki)),
         ] + dkv_seg_specs + [
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+                         lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_q, 1),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+                         lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_q, 1),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+                         lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
@@ -1059,31 +1237,37 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
         **_kb_params(interpret),
     )(q, k, v, bias, *seg_operands, do, lse, delta)
     dbias = jnp.sum(dbias_h, axis=1)               # (B, 1, S): Σ over heads
+    dk, dv = _sum_kv_groups(dk, dv, kv_heads, k.dtype)
     return dq, dk, dv, dbias
 
 
 def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                         segmented: bool, interpret: bool, block_q: int,
-                        block_k: int):
+                        block_k: int, causal: bool = False):
     """One-pass streaming backward (kernel docstring): one grid, one exp
     per (q-block, k-block) pair, full-length dk/dv VMEM accumulators —
     gated to s_k ≤ FUSED_BWD_MAX by ``select_dispatch``."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
+    kv_heads = k.shape[1]
+    kv_head = _kv_head_map(h, kv_heads)
+    dkv_dtype = k.dtype if h == kv_heads else jnp.float32
+    k_blk = _last_k_block(causal, block_q, block_k)
 
     seg_operands = [qseg, kseg] if segmented else []
     seg_specs = [
         pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, 0, qi)),
-        pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+        pl.BlockSpec((1, 1, block_k),
+                     lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
     ] if segmented else []
     dq, dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_fused_kernel_kb, scale=scale,
-                          segmented=segmented),
+                          segmented=segmented, causal=causal),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
         grid=(b, h, s // block_q, s_k // block_k),
@@ -1091,10 +1275,13 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                         lambda bi, hi, qi, ki: (bi, kv_head(hi),
+                                                 k_blk(qi, ki), 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)),
+                         lambda bi, hi, qi, ki: (bi, kv_head(hi),
+                                                 k_blk(qi, ki), 0)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
         ] + seg_specs + [
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1123,14 +1310,21 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
         **_kb_params(interpret, n_parallel=2),
     )(q, k, v, bias, *seg_operands, do, lse, delta)
     dbias = jnp.sum(dbias_h, axis=1)               # (B, 1, S): Σ over heads
+    dk, dv = _sum_kv_groups(dk, dv, kv_heads, k.dtype)
     return dq, dk, dv, dbias
 
 
-def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None):
-    """Fused attention. q,k,v: (B, S, H, D); mask: (B,1,1,S) bool or None;
+def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
+                    causal: bool = False):
+    """Fused attention. q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv a
+    divisor of H (grouped-query attention: each key/value head serves
+    H/H_kv query heads, reached through the kernels' block index maps); mask: (B,1,1,S) bool or None;
     segment_ids: (B, S) int packed-sequence ids or None — tokens attend
     only within equal ids (block-diagonal mask computed INSIDE the kernel
     from O(S) ids, so packing never materializes an S×S mask).
+    ``causal``: a query sees no later key; the mask comes from indices
+    inside the kernels, and the streaming kernels skip (not mask) blocks
+    wholly above the diagonal or, on packed rows, outside the document.
 
     ``mesh``: the physical mesh when the caller is global-view (``jit``)
     code over more than one device. A Mosaic kernel has no partitioning
@@ -1149,7 +1343,8 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None):
     """
     if (mesh is not None and mesh.size > 1
             and not jax.sharding.get_abstract_mesh().manual_axes):
-        return _flash_attention_sharded(q, k, v, mask, segment_ids, mesh)
+        return _flash_attention_sharded(q, k, v, mask, segment_ids, mesh,
+                                        causal)
     b, s, hh, d = q.shape
     if s % min(BLOCK_Q, s):
         raise ValueError(f"seq len {s} must be a multiple of {BLOCK_Q}")
@@ -1161,15 +1356,19 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None):
         bias = jnp.where(mask[:, 0, :, :], 0.0, NEG_INF).astype(jnp.float32)
     else:
         bias = jnp.zeros((b, 1, s), jnp.float32)
-    if segment_ids is None:
-        out = _FUSED[(False, False)](qt, kt, vt, bias)
-    else:
+    segmented = segment_ids is not None
+    fused = (_FUSED_CAUSAL[segmented] if causal
+             else _FUSED[(segmented, False)])
+    if segmented:
         seg = _seg_f32(segment_ids)
-        out = _FUSED[(True, False)](qt, kt, vt, bias, seg, seg)
+        out = fused(qt, kt, vt, bias, seg, seg)
+    else:
+        out = fused(qt, kt, vt, bias)
     return out.transpose(0, 2, 1, 3)
 
 
-def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh):
+def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh,
+                             causal: bool = False):
     """``flash_attention`` per device under a ``shard_map`` over all of
     ``mesh`` (see its ``mesh`` argument)."""
     from jax.sharding import PartitionSpec as P
@@ -1178,7 +1377,8 @@ def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh):
 
     batch_axes = tuple(a for a in batch_spec(mesh)[0] if a in mesh.shape)
     heads = ("model" if mesh.shape.get("model", 1) > 1
-             and q.shape[2] % mesh.shape["model"] == 0 else None)
+             and q.shape[2] % mesh.shape["model"] == 0
+             and k.shape[2] % mesh.shape["model"] == 0 else None)
     qkv_spec = P(batch_axes, None, heads, None)
     optional = {
         "mask": (mask, P(batch_axes, None, None, None)),
@@ -1188,7 +1388,8 @@ def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh):
                if pair[0] is not None}
 
     def per_device(q, k, v, *rest):
-        return flash_attention(q, k, v, **dict(zip(present, rest)))
+        return flash_attention(q, k, v, causal=causal,
+                               **dict(zip(present, rest)))
 
     return jax.shard_map(
         per_device, mesh=mesh,

@@ -45,7 +45,7 @@ TP_RULES: list[tuple[str, tuple[str | None, ...]]] = [
     (r".*qkv/kernel$", (None, None, "model")),
     (r".*(query|key|value|qkv)/kernel$", (None, "model")),
     (r".*attn_out/kernel$", ("model", None)),
-    (r".*mlp_in/kernel$", (None, "model")),
+    (r".*mlp_(in|up)/kernel$", (None, "model")),
     (r".*mlp_out/kernel$", ("model", None)),
     (r".*embed/embedding$", (None, "model")),
 ]

@@ -224,6 +224,10 @@ class Trainer:
                 **device_record(),
                 pallas_kernels=flash_attention.kernel_mode(),
                 flash_dispatch=flash_attention.dispatch_log(),
+                # Which experts this process holds, of how many groups,
+                # as the model says it; None where it has no such layer.
+                expert_share=getattr(self.builder.model, "expert_share",
+                                     lambda: None)(),
             )
         self.writer.telemetry.emit(
             telemetry.KIND_DATA_SHARD, step=self.host_step,

@@ -88,6 +88,18 @@ def mlm_mask(targets: jax.Array) -> jax.Array:
     return (targets >= 0).astype(jnp.float32)
 
 
+def causal_lm_loss(
+    logits: jax.Array, targets: jax.Array
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Next-token CE. ``targets[t]`` holds token ``t+1`` of the same
+    document and -1 where there is none to predict (a document's last
+    token, padding): the mean is over the labelled positions, the same
+    sentinel and arithmetic as the masked-LM loss."""
+    with jax.named_scope("loss"):
+        loss, metrics = mlm_loss(logits, targets)
+    return loss, {"loss": loss, "lm_acc": metrics["mlm_acc"]}
+
+
 def mlm_loss(
     logits: jax.Array, targets: jax.Array
 ) -> tuple[jax.Array, dict[str, jax.Array]]:

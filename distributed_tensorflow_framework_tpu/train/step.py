@@ -49,12 +49,13 @@ def _fsdp_dim(shape, fsdp_n: int) -> int:
 
 
 def task_for_model(name: str) -> str:
-    from distributed_tensorflow_framework_tpu.models import custom_model_task
+    from distributed_tensorflow_framework_tpu.models import (
+        builtin_task, custom_model_task)
 
     custom = custom_model_task(name)
     if custom is not None:
         return custom
-    return "mlm" if "bert" in name.lower() else "classification"
+    return builtin_task(name)
 
 
 def model_inputs(task: str, batch: Any) -> tuple:
@@ -67,6 +68,11 @@ def model_inputs(task: str, batch: Any) -> tuple:
         if "attention_mask" in batch:
             return (batch["input_ids"], batch["attention_mask"])
         return (batch["input_ids"],)
+    if task == "causal_lm":
+        # Packed rows carry their documents' ids and the positions that
+        # restart at each; one document per row needs neither.
+        return (batch["input_ids"], batch.get("segment_ids"),
+                batch.get("positions"))
     return (batch["image"],)
 
 
@@ -181,7 +187,7 @@ class StepBuilder:
                     "gradient SHARDS — use spmd_mode='jit' for clipped "
                     "fsdp training"
                 )
-        if (self.task == "mlm"
+        if (self.task in ("mlm", "causal_lm")
                 and getattr(config.data, "vocab_size", None) is not None
                 and config.data.vocab_size > config.model.vocab_size):
             # Token ids at/above the embedding size clamp silently under
@@ -496,7 +502,18 @@ class StepBuilder:
                 logits, new_model_state = out
             else:
                 logits, new_model_state = out, {}
-            if self.task == "mlm":
+            if self.task == "causal_lm":
+                counters = {}
+                if isinstance(logits, dict):  # expert layers: + counters
+                    counters = {k: v for k, v in logits.items()
+                                if k != "logits"}
+                    logits = logits["logits"]
+                loss, metrics = losses.causal_lm_loss(logits,
+                                                      batch["targets"])
+                # Router counters (models/moe.DroplessMoE) ride the same
+                # fetch as the loss: no sync of their own.
+                metrics.update(counters)
+            elif self.task == "mlm":
                 moe_aux = moe_drop = moe_zloss = None
                 if isinstance(logits, dict):  # MoE model: logits + aux dict
                     moe_aux = logits.get("moe_aux_loss")
@@ -557,7 +574,7 @@ class StepBuilder:
         equal weights); MLM normalizes by the masked-token count, which
         varies per microbatch under dynamic masking — weighting by it makes
         the accumulated gradient exactly the full-batch gradient."""
-        if self.task == "mlm":
+        if self.task in ("mlm", "causal_lm"):
             return losses.mlm_mask(mb["targets"]).sum()
         return jnp.float32(1.0)
 
@@ -893,7 +910,7 @@ class StepBuilder:
             logits = self.model.apply(variables, *inputs, train=False)
         if isinstance(logits, dict):  # MoE aux loss / Inception aux head
             logits = logits["logits"]
-        if self.task == "mlm":
+        if self.task in ("mlm", "causal_lm"):
             weight = batch.get(
                 "weight", jnp.ones(batch["targets"].shape[0], jnp.float32)
             )

@@ -22,6 +22,12 @@ segmented and unsegmented, at bf16:
   fused_takeover      2048  fused      in which bf16 pairs the whole-K
   fused_takeover_max  4096  fused      forward with the fused backward:
                                        fused_whole_k_min(bf16)..MAX_SEQ_VMEM
+  causal_gqa_s512     512   as chosen  ``causal=True`` with one key/value
+  causal_gqa_s8192    8192  as chosen  head per four query heads: the mask
+                                       from indices, k/v through the block
+                                       index maps, blocks above the diagonal
+                                       or outside the document skipped;
+                                       8192 is ``lfm2_moe_s8192``'s call
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -87,11 +93,25 @@ def _cases() -> dict:
     }
 
 
-def _inputs(seq: int):
+# Query heads per key/value head in the causal cases.
+KV_GROUP = 4
+
+
+def _causal_cases() -> dict:
+    """name -> (seq, FUSED_BWD setting): ``causal=True`` over grouped
+    key/value heads, on the path the platform chooses, in both kernel
+    families."""
+    vmem = fa.MAX_SEQ_VMEM
+    return {
+        "causal_gqa_s512": (max(vmem // 8, fa.BLOCK_Q), None),
+        "causal_gqa_s8192": (2 * vmem, None),
+    }
+
+
+def _inputs(seq: int, kv_heads: int = H):
     kq, kk, kv = jax.random.split(jax.random.key(seq), 3)
-    shape = (B, seq, H, D)
-    q, k, v = (jax.random.normal(r, shape, jnp.bfloat16)
-               for r in (kq, kk, kv))
+    q, k, v = (jax.random.normal(r, (B, seq, heads, D), jnp.bfloat16)
+               for r, heads in ((kq, H), (kk, kv_heads), (kv, kv_heads)))
     # Four packed documents of unequal length per row.
     cuts = np.array([0.15, 0.4, 0.8]) * seq
     seg = np.searchsorted(cuts, np.arange(seq), side="right") + 1
@@ -102,25 +122,31 @@ def _loss_and_out(out):
     return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
 
 
-def _kernel_fn(segmented: bool):
+def _kernel_fn(segmented: bool, causal: bool = False):
     def loss(q, k, v, seg):
         return _loss_and_out(fa.flash_attention(
-            q, k, v, segment_ids=seg if segmented else None))
+            q, k, v, segment_ids=seg if segmented else None, causal=causal))
     return loss
 
 
-def _reference_fn(segmented: bool):
+def _reference_fn(segmented: bool, causal: bool = False):
     """float32 attention, one (batch, head) at a time under ``lax.map`` —
-    an (S, S) score block per step, never (B, H, S, S)."""
+    an (S, S) score block per step, never (B, H, S, S). Grouped key/value
+    heads are repeated for their query heads (the gradient sums back)."""
     def one_head(args):
         q, k, v, seg = args                       # (S, D) f32, (S,) int
         s = (q @ k.T) / jnp.sqrt(jnp.float32(q.shape[-1]))
         if segmented:
             s = jnp.where(seg[:, None] == seg[None, :], s, fa.NEG_INF)
+        if causal:
+            at = jnp.arange(s.shape[0])
+            s = jnp.where(at[:, None] >= at[None, :], s, fa.NEG_INF)
         return jax.nn.softmax(s, axis=-1) @ v
 
     def loss(q, k, v, seg):
         b, s, h, d = q.shape
+        group = h // k.shape[2]
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
                       .reshape(b * h, s, d) for t in (q, k, v))
         segf = jnp.repeat(seg, h, axis=0)         # (B*H, S)
@@ -147,20 +173,21 @@ def _rel_l2(a, b) -> float:
 
 
 def run_case(name: str, seq: int, setting: bool | None,
-             two_pass_cache: dict) -> dict:
-    args = _inputs(seq)
+             two_pass_cache: dict, causal: bool = False) -> dict:
+    args = _inputs(seq, H // KV_GROUP if causal else H)
     fa.FUSED_BWD = setting
     dispatch = fa.select_dispatch(seq, seq, jnp.bfloat16)
     fused = dispatch.backward == "fused"
-    rec = {"case": name, "seq": seq, "fused_bwd": fused,
+    rec = {"case": name, "seq": seq, "fused_bwd": fused, "causal": causal,
+           "kv_heads": int(args[1].shape[2]),
            "dispatch": dispatch._asdict(), "variants": {}}
     ok = True
     for segmented in (False, True):
         fa.FUSED_BWD = setting
         # Fresh outer trace per setting: the fused decision is read at
         # the custom_vjp layer, outside the inner jit's cache.
-        got, mosaic_calls = _run(_kernel_fn(segmented), args)
-        want, _ = _run(_reference_fn(segmented), args)
+        got, mosaic_calls = _run(_kernel_fn(segmented, causal), args)
+        want, _ = _run(_reference_fn(segmented, causal), args)
         stats = {
             "mosaic_calls": mosaic_calls,
             "finite": bool(all(np.isfinite(t).all() for t in got)),
@@ -171,15 +198,15 @@ def run_case(name: str, seq: int, setting: bool | None,
             v for k, v in stats.items() if k.endswith("_vs_reference")
         ) <= GATE_VS_REFERENCE
         if fused:
-            two_pass = two_pass_cache.get((seq, segmented))
+            two_pass = two_pass_cache.get((seq, segmented, causal))
             if two_pass is None:
                 fa.FUSED_BWD = False
-                two_pass, _ = _run(_kernel_fn(segmented), args)
+                two_pass, _ = _run(_kernel_fn(segmented, causal), args)
             diff = max(_rel_l2(g, t) for g, t in zip(got, two_pass))
             stats["rel_l2_vs_two_pass"] = diff
             good = good and diff <= GATE_FUSED_VS_TWO_PASS
         else:
-            two_pass_cache[(seq, segmented)] = got
+            two_pass_cache[(seq, segmented, causal)] = got
         stats["ok"] = bool(good)
         ok = ok and good
         rec["variants"]["segmented" if segmented else "unsegmented"] = stats
@@ -191,7 +218,8 @@ def run_case(name: str, seq: int, setting: bool | None,
 
 
 def main(argv) -> int:
-    cases = _cases()
+    causal_cases = _causal_cases()
+    cases = {**_cases(), **causal_cases}
     unknown = [a for a in argv if a not in cases]
     if unknown:
         print(f"unknown case(s) {unknown}; known: {sorted(cases)}",
@@ -208,7 +236,8 @@ def main(argv) -> int:
           f"{fa.kernel_mode()} mode, B={B} H={H} D={D} bf16; default "
           f"backward at seq {stream_seq}: {streaming_default}", flush=True)
     two_pass_cache: dict = {}
-    results = [run_case(name, *cases[name], two_pass_cache)
+    results = [run_case(name, *cases[name], two_pass_cache,
+                        causal=name in causal_cases)
                for name in selected]
     ok = all(r["ok"] for r in results)
     if not ok:

@@ -873,6 +873,9 @@ def test_kernel_check_matrix_follows_the_module_thresholds():
         "whole_k_max": (4096, False), "kblocked": (8192, False),
         "fused": (8192, True), "fused_takeover_min": (128, True),
         "fused_takeover": (2048, True), "fused_takeover_max": (4096, True)}
+    assert vfk._causal_cases() == {
+        "causal_gqa_s512": (512, None), "causal_gqa_s8192": (8192, None)}
+    assert 12 % vfk.KV_GROUP == 0
     named = {a for t in compile_chip_window_plan()
              if "scripts/verify_flash_kernels.py" in t.argv
              for a in t.argv[2:]}

@@ -57,6 +57,7 @@ def test_run_meta_lists_the_attention_dispatch(devices, tmp_path):
     assert first["kind"] == "run_meta"
     meta = first["extra"]
     assert meta["pallas_kernels"] == "interpret"
+    assert meta["expert_share"] is None      # no model of BERT's says one
     mine = [e for e in meta["flash_dispatch"]
             if (e["s"], e["s_k"], e["dtype"]) == (128, 128, "float32")]
     assert mine and all(
