@@ -19,10 +19,11 @@ from a seed, batches synthetic from a seed; no network, no git):
             ``causal_lm`` task, rows of 8192 packed tokens, 4 steps: the
             causal grouped-query kernels, the dropless expert layer
             (``moe_dropped`` must read 0) and the short convolutions
-  kernels   ``scripts/verify_flash_kernels.py``: all seven flash-attention
-            kernels compiled by Mosaic and held to a float32 reference, the
-            fused backward also to the two-pass backward, and the causal
-            grouped-query calls in both families
+  kernels   ``scripts/verify_flash_kernels.py``: all five flash-attention
+            kernels compiled by Mosaic and held to a float32 reference on
+            bf16 and on float32 inputs, from a sequence under one tile up,
+            the fused backward also to the two-pass backward, and the causal
+            grouped-query calls under both forwards
   export    ``cli/export.py`` freezes the checkpoint the bert leg saved
   serve     ``cli/serve.py`` with ``decode.enabled=true`` answers
             ``/predict`` and streamed ``/generate`` requests from
@@ -322,6 +323,9 @@ def leg_kernels(device: dict) -> dict:
     _check(res["platform"] == device["platform"]
            and res["kernel_mode"] == REQUIRED_KERNEL_MODE,
            f"kernels ran in {res['kernel_mode']} mode on {res['platform']}")
+    # select_dispatch names no dtype, so the matrix has to have run both.
+    ran = {case["dtype"] for case in res["cases"]}
+    _check({"bfloat16", "float32"} <= ran, f"kernel cases ran only {ran}")
     for case in res["cases"]:
         for variant, stats in case["variants"].items():
             # Forward plus at least one backward kernel, compiled by

@@ -9,39 +9,44 @@ direction. This is the flash-attention recompute pattern (PAPERS.md);
 XLA alone tiles but still round-trips the score tensor for the unfused
 einsum+softmax+einsum chain.
 
-Shapes: q, k, v are (B, S, H, D). Two kernel families:
+Shapes: q, k, v are (B, S, H, D). Five kernels:
 
-  whole-K   a program holds a block of rows plus the FULL opposing
-            sequence in VMEM: no running-softmax state, no init or
-            finalise. Forward, dq and dk/dv kernels.
-  stream    K-blocked: the grid gains a sequential k-axis, running
-            (m, l, acc) state lives in VMEM scratch and K/V stream
-            through in tiles, so VMEM use is O(block²) at any length.
-            Forward, dq, dk/dv, and the fused one-pass backward that
-            computes each probability block ONCE for all four
-            cotangents.
+  whole-K forward   a program holds a block of rows plus the FULL
+                    opposing sequence in VMEM: no running-softmax state,
+                    no init or finalise.
+  streaming         K-blocked: the grid gains a sequential k-axis,
+                    running (m, l, acc) state lives in VMEM scratch and
+                    K/V stream through in tiles, so VMEM use is
+                    O(block²) at any length. A forward, the fused
+                    one-pass backward that computes each probability
+                    block ONCE for all four cotangents, and the two-pass
+                    pair (dq, then dk/dv/dbias) for where the fused one
+                    may not run.
 
-``select_dispatch`` picks family, tile and backward from what a call
-shows: the two sequence lengths, the dtype, and whether the platform is
-one the fused backward is verified on. What it selects today (bf16 on
-v5e; the per-pair kernel times behind each line are in PERF.md §6,
-PR 25):
+``select_dispatch`` picks forward, tile and backward from what a call
+shows: the two sequence lengths, the bytes of its input dtype, and
+whether the platform is one the fused backward is verified on (the
+per-pair kernel times behind each line are in PERF.md §6, PRs 25 and
+28):
 
   s_k ≤ MAX_SEQ_VMEM   whole-K forward whose f32 score block keeps the
                        area of 128 rows × 4096 keys: 512 rows a program
-                       to S=1024, 256 at 2048, 128 at 4096; fused
-                       one-pass backward from S=128 up
-  s_k > MAX_SEQ_VMEM   streaming forward on 512×1024 tiles, fused
-                       backward to FUSED_BWD_MAX, two-pass beyond
+                       to S=1024, 256 at 2048, 128 at 4096
+  s_k > MAX_SEQ_VMEM   streaming forward on 512×1024 tiles
+  backward, always     streaming tile; fused where it fits VMEM (to
+                       FUSED_BWD_MAX keys of 2 bytes, half as many of 4)
+                       and its key tile is whole lanes, the two-pass
+                       pair beyond that and on a real TPU whose
+                       generation is off FUSED_BWD_VERIFIED_PLATFORMS
 
-f32 inputs, S < 128, and any TPU generation off the fused backward's
-verified list keep the two-pass backward of the family the forward runs
-in, on the same tile rule. Ring attention over the ``seq`` mesh axis
-composes on top for sharded sequences.
+The rule names no dtype: what float32 changes is the bytes a tile holds
+(verified and timed on v5e, PERF.md §6, PR 28). Ring attention over the
+``seq`` mesh axis composes on top for sharded sequences.
 
-The kernels run in interpret mode off-TPU so the CPU test mesh exercises
-the same code path; tests/test_attention.py pins fwd+bwd numerics against
-the plain-XLA reference.
+The kernels run in interpret mode off-TPU, under the selection a
+verified chip makes, so the CPU test mesh differentiates through the
+kernels the chip runs; tests/test_attention.py pins fwd+bwd numerics
+against the plain-XLA reference.
 """
 
 from __future__ import annotations
@@ -63,83 +68,47 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 BLOCK_Q = 128
 # Streaming tile targets, and the widest row block any kernel here takes.
 # 128×128 tiles make a grid whose fixed per-program cost swamps the
-# matmuls: at S=8192 ~200k programs (3% MFU, PERF_NOTES.md round 4; the
-# ladder there: 128/128 → 7.9k tok/s, 256/1024 → 30k, 512/1024 → 35.2k,
-# 1024/1024 → 35.4k, 512/2048 → 31.9k under VMEM pressure), at S=512
-# 55k programs a step at 10 ps a pair forward and 30 backward where 512
-# rows take 4.7 and 9.4 (PERF.md §6, PR 25). 512/1024 ships: within
-# noise of the ladder's peak at half the q-tile VMEM. The FLASH_*
-# variables here and below exist for trial runs (scripts/, the autotune
-# plan); the shipped selection is taken with all of them unset.
+# matmuls: at S=512 55k programs a step at 10 ps a pair forward and 30
+# backward where 512 rows take 4.7 and 9.4 (PERF.md §6, PR 25), and
+# 512×1024 is the tile every attention cell's ledger line was taken on.
+# The four FLASH_* variables here and below exist for trial runs
+# (scripts/bench_flash_tiles.py, the autotune plan); the shipped
+# selection is taken with all of them unset.
 BLOCK_Q_KB = int(os.environ.get("FLASH_BLOCK_Q_KB", "512"))
 BLOCK_K_KB = int(os.environ.get("FLASH_BLOCK_K_KB", "1024"))
-# Where whole-K ends (no silent fallback above it):
-#   s_k ≤ MAX_SEQ_VMEM → whole-K forward: each program holds the full
-#     opposing sequence in VMEM at INPUT dtype (the kernels dot in input
-#     dtype, no f32 upcast) plus its f32 score block, whose area
-#     select_dispatch holds at BLOCK_Q × MAX_SEQ_VMEM. Measured ahead of
-#     the streaming forward at every bf16 length tried on v5e, 512 to
-#     4096 (3.6–4.7 ps a pair against 4.2–8.2; PERF.md §6, PR 25).
-#   s_k > MAX_SEQ_VMEM → streaming kernels, VMEM use O(BLOCK_Q_KB ·
+# Where the whole-K forward ends (no silent fallback above it):
+#   s_k ≤ MAX_SEQ_VMEM → each program holds the full opposing sequence
+#     in VMEM at INPUT dtype (the kernels dot in input dtype, no f32
+#     upcast) plus its f32 score block, whose area select_dispatch holds
+#     at BLOCK_Q × MAX_SEQ_VMEM. Measured ahead of the streaming forward
+#     at every bf16 length tried on v5e, 512 to 4096 (3.6–4.7 ps a pair
+#     against 4.2–8.2; PERF.md §6, PR 25).
+#   s_k > MAX_SEQ_VMEM → streaming forward, VMEM use O(BLOCK_Q_KB ·
 #     BLOCK_K_KB) regardless of sequence length. No fallback to the
 #     O(S²)-materializing XLA chain exists above the threshold — long
-#     chunks stay fused (tests/test_attention.py pins 8192), and the
-#     chain is not even COMPILABLE there (PERF_NOTES.md round 4).
-# FLASH_MAX_SEQ_VMEM=0 forces the streaming kernels everywhere.
+#     chunks stay fused (tests/test_attention.py pins 8192).
+# FLASH_MAX_SEQ_VMEM=0 forces the streaming forward everywhere.
 MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
 # Fused one-pass backward: one kernel over grid (B,H,nq,nk) produces dq
 # AND dk/dv/dbias, computing each (q-block, k-block) probability block
-# ONCE — the two-pass backward forms QKᵀ, the mask and the exp in both
-# of its kernels — at the price of full-length (S_k, D) f32 dk/dv VMEM
+# ONCE — the two-pass pair forms QKᵀ, the mask and the exp in both of
+# its kernels — at the price of full-length (S_k, D) f32 dk/dv VMEM
 # accumulators, hence the MAX gate (4 MB at 8192; beyond ~2·8192 it
-# cannot fit and the two-pass kernels remain the only path).
-#
-# Tri-state default: ``None`` (env unset) = auto — ON only on backends
-# where scripts/verify_flash_kernels.py results are RECORDED: v5e, where
-# chip_smoke.py re-runs that check on every smoke (on jax 0.9.0 /
-# libtpu 0.0.34 the fused backward agrees with the two-pass kernels and
-# with the float32 reference from seq 128 to 8192 — PERF.md, PRs 21 and
-# 25). On any other real TPU generation the fused dk/dv/dbias flush
-# ordering is UNVERIFIED silicon behavior (ADVICE r5): auto keeps the
-# two-pass backward and says so once. FLASH_FUSED_BWD=1/0 forces either
-# way (env read at import time like the other FLASH_* variables); tests
-# and scripts/verify_flash_kernels.py assign the module global directly
-# — select_dispatch consults it at call time through
-# fused_bwd_enabled().
-_FUSED_BWD_ENV = os.environ.get("FLASH_FUSED_BWD")
-FUSED_BWD: bool | None = (
-    None if _FUSED_BWD_ENV is None else _FUSED_BWD_ENV not in ("", "0"))
+# cannot fit and the two-pass pair remains the only path). The gate is
+# the length for 2-byte inputs; select_dispatch holds wider inputs to
+# the same bytes of keys, since the kernel's input and output tiles grow
+# with them: float32 compiles for v5e to 6144 keys and overflows VMEM at
+# 7168, bf16 compiles at 8192 (PERF.md §6, PR 28).
 FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
-# Backend substrings (matched against device_kind, lowercased) with
-# recorded verify_flash_kernels.py results.
+# TPU generations (substrings of device_kind, lowercased) with recorded
+# scripts/verify_flash_kernels.py results: v5e, where chip_smoke.py
+# re-runs that check on every smoke (the fused backward agrees with the
+# two-pass pair and with the float32 reference from seq 128 to 8192 in
+# bf16 and to 4096 in float32 — PERF.md §6, PRs 21, 25 and 28). Its
+# dk/dv/dbias rest on in-order HBM flushes of revisited output blocks,
+# which is silicon behaviour: a real TPU off this list keeps the
+# two-pass pair (fused_bwd_enabled).
 FUSED_BWD_VERIFIED_PLATFORMS = ("v5 lite", "v5e")
-# Below MAX_SEQ_VMEM the fused backward pairs with the whole-K forward
-# (it needs only q/k/v/bias/lse/do, all of which that forward saves).
-# For bf16 it does so from the tile floor up: measured on v5e against
-# the whole-K two-pass pair on its best tile it takes 9.4 ps a pair
-# against 13.7 at S=512, 8.9 against 10.8 at 1024, 8.0 against 13.4 at
-# 2048 (PERF.md §6, PR 25). f32 inputs keep the two-pass pair (the
-# threshold parks above MAX_SEQ_VMEM): no f32 length has been timed on a
-# chip. FLASH_FUSED_WHOLE_K_MIN=<n> forces one threshold for every dtype
-# (tests and scripts assign the module global directly, same contract).
-_FUSED_WHOLE_K_MIN_ENV = os.environ.get("FLASH_FUSED_WHOLE_K_MIN")
-FUSED_WHOLE_K_MIN: int | None = (
-    None if _FUSED_WHOLE_K_MIN_ENV is None else int(_FUSED_WHOLE_K_MIN_ENV))
-
-
-def fused_whole_k_min(dtype) -> int:
-    """Shortest sequence at which the fused one-pass backward replaces
-    the whole-K two-pass pair, per input dtype. An explicit
-    FUSED_WHOLE_K_MIN (env or direct module-global assignment —
-    tests/scripts do the latter) wins for every dtype; otherwise bf16
-    takes it from the tile floor and everything else stays parked above
-    MAX_SEQ_VMEM. Reads the module globals at call time so
-    monkeypatching keeps working."""
-    if FUSED_WHOLE_K_MIN is not None:
-        return FUSED_WHOLE_K_MIN
-    if jnp.dtype(dtype) == jnp.bfloat16:
-        return BLOCK_Q
-    return MAX_SEQ_VMEM + 1
 
 
 def _causal_mask(s, row0, col0):
@@ -203,86 +172,6 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
     o_ref[0, 0] = o.astype(o_ref.dtype)
     # Per-row logsumexp: the only softmax statistic the backward needs.
     lse_ref[0, 0] = (m + jnp.log(l)).astype(jnp.float32)
-
-
-def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                        scale: float, segmented: bool, causal: bool = False):
-    """dQ for one q-block: recompute p from (q, k, lse), no S×S residual."""
-    if segmented:
-        qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref, dq_ref = rest
-    else:
-        do_ref, lse_ref, delta_ref, dq_ref = rest
-    q = q_ref[0, 0]                               # (BQ, D) input dtype
-    k = k_ref[0, 0]                               # (S, D)
-    v = v_ref[0, 0]                               # (S, D)
-    do = do_ref[0, 0]                             # (BQ, D)
-    lse = lse_ref[0, 0]                           # (BQ, 1)
-    delta = delta_ref[0, 0]                       # (BQ, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (BQ, S)
-    if segmented:
-        qs = qseg_ref[0, 0]
-        ks = kseg_ref[0, 0]
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    if causal:
-        s = _causal_mask(s, pl.program_id(2) * q.shape[0], 0)
-    p = jnp.exp(s - lse)                          # recomputed probabilities
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BQ, S)
-    ds = p * (dp - delta)                         # (BQ, S) f32
-    dq = jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-
-
-def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                         scale: float, segmented: bool,
-                         causal: bool = False):
-    """dK/dV (+ per-head dbias) for one k-block: full Q/dO in VMEM."""
-    if segmented:
-        (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dbias_ref) = rest
-    else:
-        do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dbias_ref = rest
-    q = q_ref[0, 0]                               # (S, D) input dtype
-    k = k_ref[0, 0]                               # (BK, D)
-    v = v_ref[0, 0]                               # (BK, D)
-    do = do_ref[0, 0]                             # (S, D)
-    lse = lse_ref[0, 0]                           # (S, 1)
-    delta = delta_ref[0, 0]                       # (S, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bias_ref[0]                       # (S, BK)
-    if segmented:
-        qs = qseg_ref[0, 0]                       # (S,)
-        ks = kseg_ref[0, 0]                       # (BK,)
-        s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
-    if causal:
-        s = _causal_mask(s, 0, pl.program_id(2) * k.shape[0])
-    p = jnp.exp(s - lse)
-    dv = jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (BK, D)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (S, BK)
-    ds = p * (dp - delta)                         # (S, BK) f32
-    dk = jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                     # (BK, D)
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-    dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)  # (1, BK)
 
 
 def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs):
@@ -483,10 +372,10 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     the current partial to the block output — grid steps execute in
     order on the core, so the final visit's flush (qi == nq-1) is what
     HBM keeps. Earlier flushes are dead writes: ~(nq-1)·S_k·D·4B extra
-    HBM-write traffic per (b,h), orders below the exp savings
-    (PERF_NOTES round-5 analysis). Under ``causal`` a visit that
-    ``_block_needed`` rules out adds nothing and still flushes, so the
-    last visit of every k-block keeps what HBM holds."""
+    HBM-write traffic per (b,h), orders below the exp savings. Under
+    ``causal`` a visit that ``_block_needed`` rules out adds nothing and
+    still flushes, so the last visit of every k-block keeps what HBM
+    holds."""
     if segmented:
         (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref, dbias_ref,
@@ -603,9 +492,6 @@ def kernel_mode() -> str:
     return "interpret" if _interpret() else "mosaic"
 
 
-_fused_bwd_auto: bool | None = None  # memoized auto-resolution
-
-
 def fused_bwd_verified(device_kind: str) -> bool:
     """Whether scripts/verify_flash_kernels.py results are recorded for
     this TPU generation (FUSED_BWD_VERIFIED_PLATFORMS)."""
@@ -613,84 +499,75 @@ def fused_bwd_verified(device_kind: str) -> bool:
     return any(p in kind for p in FUSED_BWD_VERIFIED_PLATFORMS)
 
 
+@functools.cache
 def fused_bwd_enabled() -> bool:
-    """Resolve the FUSED_BWD tri-state at backward-dispatch time.
-
-    A bool in the module global (env knob, test monkeypatch, or
-    scripts/verify_flash_kernels.py's direct assignment) always wins. ``None``
-    = auto: ON only when the default backend is a TPU whose device_kind
-    matches a FUSED_BWD_VERIFIED_PLATFORMS entry; any OTHER real TPU gets
-    the two-pass backward plus a one-line warning (once) — the fused
-    flush ordering is verified per-generation, and silently-wrong
-    gradients are the worst possible failure mode. Non-TPU backends run
-    the kernels in interpret mode where perf is moot: auto stays off,
-    quietly (CPU parity for the fused path is pinned by tests that force
-    the flag)."""
-    global _fused_bwd_auto
-    if FUSED_BWD is not None:
-        return FUSED_BWD
-    if _fused_bwd_auto is None:
-        if jax.default_backend() != "tpu":
-            _fused_bwd_auto = False
-        else:
-            kind = jax.devices()[0].device_kind
-            _fused_bwd_auto = fused_bwd_verified(kind)
-            if not _fused_bwd_auto:
-                log.warning(
-                    "fused flash-attention backward disabled: no recorded "
-                    "verify_flash_kernels.py results for TPU %r — run "
-                    "scripts/verify_flash_kernels.py and set "
-                    "FLASH_FUSED_BWD=1 to enable", kind,
-                )
-    return _fused_bwd_auto
+    """Whether the fused one-pass backward may run in this process:
+    everywhere but on a real TPU whose generation has no recorded
+    scripts/verify_flash_kernels.py results. There the fused dk/dv/dbias
+    flush ordering is unverified silicon behaviour and silently wrong
+    gradients are the worst possible failure, so the two-pass pair runs
+    and a warning says so, once. Off the chip the answer is the verified
+    chip's: interpret mode walks the grid in order, so there is no flush
+    ordering to get wrong, the CPU suite differentiates through the
+    kernel the chip runs, and an ahead-of-time compile for a described
+    chip is of the chip's program. The one seam for a test or script
+    that needs the other backward: replace this function."""
+    if jax.default_backend() != "tpu":
+        return True
+    kind = jax.devices()[0].device_kind
+    verified = fused_bwd_verified(kind)
+    if not verified:
+        log.warning(
+            "fused flash-attention backward disabled: TPU %r is not in "
+            "FUSED_BWD_VERIFIED_PLATFORMS — run "
+            "scripts/verify_flash_kernels.py on it and add the generation "
+            "with the results it records", kind)
+    return verified
 
 
 class FlashDispatch(NamedTuple):
-    """What one attention call runs. ``family`` is ``"whole_k"`` (a
-    program holds its rows and ALL of the opposing sequence) or
-    ``"stream"`` (the opposing sequence streams through in tiles under a
-    sequential grid axis); ``backward`` is ``"fused"`` (the one-pass
-    kernel) or ``"two_pass"`` (dq, then dk/dv/dbias). In the whole-K
-    two-pass backward ``bwd_block_q`` is the dq kernel's row block and
-    ``bwd_block_k`` the dk/dv kernel's key block."""
+    """What one attention call runs. ``family`` is the forward's:
+    ``"whole_k"`` (a program holds its rows and ALL of the opposing
+    sequence) or ``"stream"`` (the opposing sequence streams through in
+    tiles under a sequential grid axis). ``backward`` is ``"fused"`` (the
+    one-pass kernel) or ``"two_pass"`` (dq, then dk/dv/dbias); both
+    stream, on the ``bwd_block_q`` × ``bwd_block_k`` tile."""
     family: str
     block_q: int
     block_k: int
     backward: str
-    bwd_family: str
     bwd_block_q: int
     bwd_block_k: int
 
 
 def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
-    """The one place a (q length, k length, dtype) becomes kernels and
-    tiles; the platform enters through ``fused_bwd_enabled()``. Called
-    at the custom_vjp layer, outside the jitted wrappers, so the module
-    globals it reads are never frozen into a trace cache: the wrappers
-    take the result as a static argument.
+    """The one place a (q length, k length, input dtype) becomes kernels
+    and tiles; the platform enters through ``fused_bwd_enabled()``.
+    Called at the custom_vjp layer, outside the jitted wrappers, so the
+    module globals it reads are never frozen into a trace cache: the
+    wrappers take the result as a static argument.
 
-    Whole-K tile: a program's f32 score block keeps the area the family
-    proves at its upper edge, BLOCK_Q rows × MAX_SEQ_VMEM keys, so the
-    rows grow as the keys shrink, up to the streaming q-tile: 512 rows
-    to S=1024, 256 at 2048, 128 at 4096."""
+    Whole-K forward tile: a program's f32 score block keeps the area the
+    family proves at its upper edge, BLOCK_Q rows × MAX_SEQ_VMEM keys, so
+    the rows grow as the keys shrink, up to the streaming q-tile: 512
+    rows to S=1024, 256 at 2048, 128 at 4096."""
     stream_tile = (_pick_block(s, BLOCK_Q_KB), _pick_block(s_k, BLOCK_K_KB))
-
-    def whole_k_rows(n: int, held: int) -> int:
-        target = BLOCK_Q * MAX_SEQ_VMEM // held
-        return _pick_block(n, min(max(target, BLOCK_Q), BLOCK_Q_KB))
-
     if s_k > MAX_SEQ_VMEM:
         forward = ("stream", *stream_tile)
     else:
-        forward = ("whole_k", whole_k_rows(s, s_k), s_k)
-    fused = fused_bwd_enabled() and s_k <= FUSED_BWD_MAX
-    if max(s, s_k) > MAX_SEQ_VMEM or (
-            fused and min(s, s_k) >= fused_whole_k_min(dtype)):
-        backward = ("fused" if fused else "two_pass", "stream", *stream_tile)
-    else:
-        backward = ("two_pass", "whole_k",
-                    whole_k_rows(s, s_k), whole_k_rows(s_k, s))
-    return FlashDispatch(*forward, *backward)
+        rows = BLOCK_Q * MAX_SEQ_VMEM // s_k
+        forward = ("whole_k",
+                   _pick_block(s, min(max(rows, BLOCK_Q), BLOCK_Q_KB)), s_k)
+    # The fused backward where it is allowed, fits VMEM (FUSED_BWD_MAX,
+    # in bytes of keys) and can slice its full-length dbias accumulator
+    # by whole lanes: on a key tile under BLOCK_Q Mosaic refuses the
+    # kernel ("cannot statically prove that index in dimension 1 is a
+    # multiple of 128"), and the two-pass pair is what runs such inputs.
+    fused = (fused_bwd_enabled()
+             and s_k * jnp.dtype(dtype).itemsize <= 2 * FUSED_BWD_MAX
+             and stream_tile[1] % BLOCK_Q == 0)
+    return FlashDispatch(*forward, "fused" if fused else "two_pass",
+                         *stream_tile)
 
 
 # (s, s_k, dtype name, segmented) -> FlashDispatch, one entry per
@@ -1047,14 +924,6 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
     else:
         qseg = kseg = None
         o, lse, do = seg_then_rest
-    b, h, s, d = q.shape
-    s_k = k.shape[2]
-    kv_heads = k.shape[1]
-    kv_head = _kv_head_map(h, kv_heads)
-    # dk/dv leave the kernels per QUERY head; heads that share a
-    # key/value head are summed after, from float32 partials.
-    dkv_dtype = k.dtype if h == kv_heads else jnp.float32
-    scale = 1.0 / (d ** 0.5)
     # delta_i = Σ_d dO_i·O_i — the softmax-jacobian row correction; an
     # O(S·D) elementwise+reduce, cheap in plain XLA.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -1064,79 +933,12 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
         # contribution folds into ds = p·(dp − delta + dlse) — i.e. the
         # kernels run unchanged with delta := delta − dlse.
         delta = delta - dlse.astype(jnp.float32)
-
-    seg_operands = [qseg, kseg] if segmented else []
-
-    block_q, block_k = dispatch.bwd_block_q, dispatch.bwd_block_k
-    if dispatch.bwd_family == "stream":
-        stream = (_flash_bwd_fused_kb if dispatch.backward == "fused"
-                  else _flash_bwd_kb)
-        return stream(q, k, v, bias, qseg, kseg, lse, do, delta,
-                      segmented=segmented, interpret=interpret,
-                      block_q=block_q, block_k=block_k, causal=causal)
-
-    dq_seg_specs = [
-        pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi: (bi, 0, qi)),
-        pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
-    ] if segmented else []
-    dq = pl.pallas_call(
-        functools.partial(_attn_bwd_dq_kernel, scale=scale,
-                          segmented=segmented, causal=causal),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        grid=(b, h, s // block_q),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, s_k, d),
-                         lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
-            pl.BlockSpec((1, 1, s_k, d),
-                         lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
-            pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
-        ] + dq_seg_specs + [
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)
-        ),
-        interpret=interpret,
-    )(q, k, v, bias, *seg_operands, do, lse, delta)
-
-    dkv_seg_specs = [
-        pl.BlockSpec((1, 1, s), lambda bi, hi, ki: (bi, 0, 0)),
-        pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki: (bi, 0, ki)),
-    ] if segmented else []
-    dk, dv, dbias_h = pl.pallas_call(
-        functools.partial(_attn_bwd_dkv_kernel, scale=scale,
-                          segmented=segmented, causal=causal),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
-            jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
-        ],
-        grid=(b, h, s_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, s, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ki: (bi, kv_head(hi), ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, ki: (bi, kv_head(hi), ki, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki: (bi, 0, ki)),
-        ] + dkv_seg_specs + [
-            pl.BlockSpec((1, 1, s, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, 1, block_k), lambda bi, hi, ki: (bi, hi, 0, ki)),
-        ],
-        interpret=interpret,
-    )(q, k, v, bias, *seg_operands, do, lse, delta)
-    dbias = jnp.sum(dbias_h, axis=1)               # (B, 1, S): Σ over heads
-    dk, dv = _sum_kv_groups(dk, dv, kv_heads, k.dtype)
-    return dq, dk, dv, dbias
+    stream = (_flash_bwd_fused_kb if dispatch.backward == "fused"
+              else _flash_bwd_kb)
+    return stream(q, k, v, bias, qseg, kseg, lse, do, delta,
+                  segmented=segmented, interpret=interpret,
+                  block_q=dispatch.bwd_block_q, block_k=dispatch.bwd_block_k,
+                  causal=causal)
 
 
 def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
@@ -1246,7 +1048,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                         block_k: int, causal: bool = False):
     """One-pass streaming backward (kernel docstring): one grid, one exp
     per (q-block, k-block) pair, full-length dk/dv VMEM accumulators —
-    gated to s_k ≤ FUSED_BWD_MAX by ``select_dispatch``."""
+    gated to what fits by ``select_dispatch``."""
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)

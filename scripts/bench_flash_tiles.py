@@ -42,6 +42,7 @@ import numpy as np
 from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
 H, D = 12, 64
+DTYPE = jnp.bfloat16      # the cells' input dtype
 ROWS_AT_512 = 32          # bert_s512's per-chip batch; rows × seq held fixed
 CALLS = 10
 MOSAIC = re.compile(r"^_flash_(fwd|bwd)")
@@ -49,25 +50,21 @@ MOSAIC = re.compile(r"^_flash_(fwd|bwd)")
 
 def variants(s: int) -> tuple[list, list]:
     """(forward dispatches, backward dispatches) worth timing at ``s``:
-    the module's own selection first, then every whole-K row block the
-    area allows, the 128-row tile the module shipped with, and the
-    streaming family."""
-    chosen = fa.select_dispatch(s, s, jnp.bfloat16)
+    the module's own selection first; then, forward, every whole-K row
+    block the area allows and the streaming family; backward, the fused
+    kernel and the two-pass pair on the streaming tile."""
+    chosen = fa.select_dispatch(s, s, DTYPE)
     stream = (fa._pick_block(s, fa.BLOCK_Q_KB), fa._pick_block(s, fa.BLOCK_K_KB))
     rows = [r for r in (128, 256, 512)
             if r <= s and r * s <= fa.BLOCK_Q * fa.MAX_SEQ_VMEM]
 
-    def make(family, bq, bk, backward="two_pass", bwd_family="whole_k",
-             bwd_bq=128, bwd_bk=128):
-        return fa.FlashDispatch(family, bq, bk, backward, bwd_family,
-                                bwd_bq, bwd_bk)
+    def make(family, bq, bk, backward="two_pass"):
+        return fa.FlashDispatch(family, bq, bk, backward, *stream)
 
     fwd = [chosen] + [make("whole_k", r, s) for r in rows]
     fwd.append(make("stream", *stream))
-    bwd = [chosen] + [make("whole_k", 128, s, "two_pass", "whole_k", r, r)
-                      for r in rows]
-    bwd.append(make("stream", *stream, "fused", "stream", *stream))
-    bwd.append(make("stream", *stream, "two_pass", "stream", *stream))
+    bwd = [chosen, make("stream", *stream, "fused"),
+           make("stream", *stream, "two_pass")]
     return _unique(fwd, lambda d: d[:3]), _unique(bwd, lambda d: d[3:])
 
 
@@ -83,16 +80,16 @@ def _unique(items, key):
 def inputs(s: int, abstract_on=None):
     b = max(1, ROWS_AT_512 * 512 // s)
     shapes = {
-        "q": ((b, H, s, D), jnp.bfloat16), "k": ((b, H, s, D), jnp.bfloat16),
-        "v": ((b, H, s, D), jnp.bfloat16), "bias": ((b, 1, s), jnp.float32),
-        "seg": ((b, 1, s), jnp.float32), "do": ((b, H, s, D), jnp.bfloat16),
-        "o": ((b, H, s, D), jnp.bfloat16), "lse": ((b, H, s, 1), jnp.float32),
+        "q": ((b, H, s, D), DTYPE), "k": ((b, H, s, D), DTYPE),
+        "v": ((b, H, s, D), DTYPE), "bias": ((b, 1, s), jnp.float32),
+        "seg": ((b, 1, s), jnp.float32), "do": ((b, H, s, D), DTYPE),
+        "o": ((b, H, s, D), DTYPE), "lse": ((b, H, s, 1), jnp.float32),
     }
     if abstract_on is not None:
         return b, {n: jax.ShapeDtypeStruct(sh, dt, sharding=abstract_on)
                    for n, (sh, dt) in shapes.items()}
     keys = jax.random.split(jax.random.key(s), 4)
-    arrays = {n: jax.random.normal(kk, shapes[n][0], jnp.bfloat16)
+    arrays = {n: jax.random.normal(kk, shapes[n][0], DTYPE)
               for n, kk in zip(("q", "k", "v", "do"), keys)}
     arrays["bias"] = jnp.zeros(shapes["bias"][0], jnp.float32)
     # Four packed documents of unequal length a row, as verify_flash_kernels.
@@ -178,12 +175,12 @@ def main(argv=None) -> int:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         abstract_on = SingleDeviceSharding(topo.devices[0])
-        fa.FUSED_BWD = True        # what v5e's verified list resolves to
     dev = jax.devices()[0]
     interpret = fa._interpret() and not args.compile_only
     print(f"flash tiles on {dev.platform} ({dev.device_kind}), "
           f"{'compile only for v5e' if args.compile_only else fa.kernel_mode()}"
-          f", H={H} D={D} bf16 {'segmented' if segmented else 'unsegmented'}",
+          f", H={H} D={D} {jnp.dtype(DTYPE).name} "
+          f"{'segmented' if segmented else 'unsegmented'}",
           flush=True)
     rows = []
     for s in args.seqs:
@@ -213,6 +210,7 @@ def main(argv=None) -> int:
                 print(json.dumps(row), flush=True)
     print(json.dumps({"platform": dev.platform, "device_kind": dev.device_kind,
                       "kernel_mode": fa.kernel_mode(),
+                      "dtype": jnp.dtype(DTYPE).name,
                       "compile_only": args.compile_only, "rows": rows}))
     return 1 if any("error" in r for r in rows) else 0
 

@@ -22,7 +22,7 @@
 # plan-manifest §0: graftcheck
 # plan-manifest §1: resnet
 # plan-manifest §13: prec-f32 prec-bf16 prec-bf16-fused prec-bf16-int8
-# plan-manifest §7: wk-verify-2048 wk2048-fused wk2048-two wk-verify-4096 wk4096-fused wk4096-two
+# plan-manifest §7: wk-verify-2048 wk-verify-4096
 # plan-manifest §8: pp-sanity pp-gpipe pp-1f1b pp-interleaved
 # plan-manifest §9: coll-f32 coll-bf16 coll-int8
 # plan-manifest §10: serve-clean serve-train serve-export serve-batched serve-unbatched

@@ -1,33 +1,44 @@
 """On-DEVICE compile-and-agree check for every flash-attention kernel.
 
-ops/flash_attention.py has seven Pallas kernels in two families; which
-ones a call runs, and on what tile, ``select_dispatch`` decides from the
-sequence lengths, the dtype and the platform (the module docstring has
-the rule, PERF.md the measurements). A training step reaches only what
-its length selects. This drives ``value_and_grad`` through all of them,
-segmented and unsegmented, at bf16:
+ops/flash_attention.py has five Pallas kernels: a whole-K and a
+streaming forward, the fused one-pass streaming backward, and the
+streaming two-pass pair. Which ones a call runs, and on what tile,
+``select_dispatch`` decides from the sequence lengths, the bytes of the
+input dtype and the platform (the module docstring has the rule, PERF.md
+the measurements). A training step reaches only what its shapes select.
+This drives ``value_and_grad`` through all of them, segmented and
+unsegmented:
 
-  case                seq   backward   what runs
-  cell_s512           512   as chosen  the default path at ``bert_s512``'s
-                                       shape: whole-K forward on 512 rows,
-                                       fused one-pass backward (v5e)
-  whole_k_short       512   two-pass   whole-K fwd/dq/dkv, 512-row blocks:
-                                       what a TPU off the verified list runs
-  whole_k_max         4096  two-pass   whole-K fwd/dq/dkv at MAX_SEQ_VMEM,
-                                       128-row blocks
-  kblocked            8192  two-pass   K-blocked fwd/dq/dkv, 512x1024 tiles
-  fused               8192  fused      K-blocked fwd + fused backward
-                                       (``bert_s8192``)
-  fused_takeover_min  128   fused      both ends and the middle of the band
-  fused_takeover      2048  fused      in which bf16 pairs the whole-K
-  fused_takeover_max  4096  fused      forward with the fused backward:
-                                       fused_whole_k_min(bf16)..MAX_SEQ_VMEM
-  causal_gqa_s512     512   as chosen  ``causal=True`` with one key/value
-  causal_gqa_s8192    8192  as chosen  head per four query heads: the mask
-                                       from indices, k/v through the block
-                                       index maps, blocks above the diagonal
-                                       or outside the document skipped;
-                                       8192 is ``lfm2_moe_s8192``'s call
+  case                seq   dtype  backward   what runs
+  cell_s512           512   bf16   as chosen  the default path at ``bert_s512``'s
+                                              shape: whole-K forward on 512 rows,
+                                              fused one-pass backward (v5e)
+  whole_k_short       512   bf16   two-pass   whole-K forward, streaming dq and
+                                              dk/dv on one 512x512 tile: what a
+                                              TPU off the verified list runs
+  whole_k_max         4096  bf16   two-pass   whole-K forward at MAX_SEQ_VMEM on
+                                              128-row blocks, two-pass pair on
+                                              512x1024 tiles
+  kblocked            8192  bf16   two-pass   streaming fwd/dq/dkv, 512x1024 tiles
+  fused               8192  bf16   fused      streaming fwd + fused backward
+                                              (``bert_s8192``)
+  fused_takeover_min  128   bf16   fused      both ends and the middle of the
+  fused_takeover      2048  bf16   fused      lengths that pair the whole-K
+  fused_takeover_max  4096  bf16   fused      forward with the fused backward:
+                                              BLOCK_Q..MAX_SEQ_VMEM
+  f32_s128 .. s4096   128, 512, 2048, 4096    the same pairing on float32 inputs,
+                            f32    as chosen  up to the longest the fused
+                                              backward's byte gate lets through
+  sub_tile            64    bf16   as chosen  a sequence under one tile: a key
+  f32_sub_tile        64    f32    as chosen  tile that is not whole lanes takes
+                                              the two-pass pair (Mosaic refuses
+                                              the fused kernel there)
+  causal_gqa_s512     512   bf16   as chosen  ``causal=True`` with one key/value
+  causal_gqa_s8192    8192  bf16   as chosen  head per four query heads: the mask
+                                              from indices, k/v through the block
+                                              index maps, blocks above the diagonal
+                                              or outside the document skipped;
+                                              8192 is ``lfm2_moe_s8192``'s call
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -77,20 +88,38 @@ GATE_VS_REFERENCE = 3e-2
 GATE_FUSED_VS_TWO_PASS = 3e-2
 
 
+# The platform's own answer, kept so that a case can hand the choice back
+# after another case has forced a backward through the module's one seam.
+_PLATFORM_RULE = fa.fused_bwd_enabled
+
+
+def _allow_fused(setting: bool | None) -> None:
+    fa.fused_bwd_enabled = (_PLATFORM_RULE if setting is None
+                            else lambda: setting)
+
+
 def _cases() -> dict:
-    """name -> (seq, FUSED_BWD setting for the case; None leaves the
-    choice to the platform, as a training run does)."""
+    """name -> (seq, whether the fused backward is allowed in the case:
+    None leaves that to the platform, as a training run does; input
+    dtype)."""
     vmem = fa.MAX_SEQ_VMEM
-    return {
-        "cell_s512": (max(vmem // 8, fa.BLOCK_Q), None),
-        "whole_k_short": (max(vmem // 8, fa.BLOCK_Q), False),
-        "whole_k_max": (vmem, False),
-        "kblocked": (2 * vmem, False),
-        "fused": (2 * vmem, True),
-        "fused_takeover_min": (fa.fused_whole_k_min(jnp.bfloat16), True),
-        "fused_takeover": (max(vmem // 2, fa.BLOCK_Q), True),
-        "fused_takeover_max": (vmem, True),
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    cases = {
+        "cell_s512": (max(vmem // 8, fa.BLOCK_Q), None, bf16),
+        "whole_k_short": (max(vmem // 8, fa.BLOCK_Q), False, bf16),
+        "whole_k_max": (vmem, False, bf16),
+        "kblocked": (2 * vmem, False, bf16),
+        "fused": (2 * vmem, True, bf16),
+        "fused_takeover_min": (fa.BLOCK_Q, True, bf16),
+        "fused_takeover": (max(vmem // 2, fa.BLOCK_Q), True, bf16),
+        "fused_takeover_max": (vmem, True, bf16),
     }
+    for seq in sorted({fa.BLOCK_Q, max(vmem // 8, fa.BLOCK_Q),
+                       max(vmem // 2, fa.BLOCK_Q), vmem}):
+        cases[f"f32_s{seq}"] = (seq, None, f32)
+    cases["sub_tile"] = (fa.BLOCK_Q // 2, None, bf16)
+    cases["f32_sub_tile"] = (fa.BLOCK_Q // 2, None, f32)
+    return cases
 
 
 # Query heads per key/value head in the causal cases.
@@ -98,19 +127,18 @@ KV_GROUP = 4
 
 
 def _causal_cases() -> dict:
-    """name -> (seq, FUSED_BWD setting): ``causal=True`` over grouped
-    key/value heads, on the path the platform chooses, in both kernel
-    families."""
+    """As ``_cases``: ``causal=True`` over grouped key/value heads, on
+    the path the platform chooses, under both forwards."""
     vmem = fa.MAX_SEQ_VMEM
     return {
-        "causal_gqa_s512": (max(vmem // 8, fa.BLOCK_Q), None),
-        "causal_gqa_s8192": (2 * vmem, None),
+        "causal_gqa_s512": (max(vmem // 8, fa.BLOCK_Q), None, jnp.bfloat16),
+        "causal_gqa_s8192": (2 * vmem, None, jnp.bfloat16),
     }
 
 
-def _inputs(seq: int, kv_heads: int = H):
+def _inputs(seq: int, kv_heads: int, dtype):
     kq, kk, kv = jax.random.split(jax.random.key(seq), 3)
-    q, k, v = (jax.random.normal(r, (B, seq, heads, D), jnp.bfloat16)
+    q, k, v = (jax.random.normal(r, (B, seq, heads, D), dtype)
                for r, heads in ((kq, H), (kk, kv_heads), (kv, kv_heads)))
     # Four packed documents of unequal length per row.
     cuts = np.array([0.15, 0.4, 0.8]) * seq
@@ -151,9 +179,9 @@ def _reference_fn(segmented: bool, causal: bool = False):
                       .reshape(b * h, s, d) for t in (q, k, v))
         segf = jnp.repeat(seg, h, axis=0)         # (B*H, S)
         out = jax.lax.map(one_head, (qf, kf, vf, segf))
-        # bf16 output like the kernels', so the loss sees the same values.
+        # Output in the kernels' dtype, so the loss sees the same values.
         out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-        return _loss_and_out(out.astype(jnp.bfloat16))
+        return _loss_and_out(out.astype(q.dtype))
     return loss
 
 
@@ -172,18 +200,20 @@ def _rel_l2(a, b) -> float:
     return float(np.linalg.norm(a - b) / max(float(np.linalg.norm(b)), 1e-30))
 
 
-def run_case(name: str, seq: int, setting: bool | None,
+def run_case(name: str, seq: int, setting: bool | None, dtype,
              two_pass_cache: dict, causal: bool = False) -> dict:
-    args = _inputs(seq, H // KV_GROUP if causal else H)
-    fa.FUSED_BWD = setting
-    dispatch = fa.select_dispatch(seq, seq, jnp.bfloat16)
+    args = _inputs(seq, H // KV_GROUP if causal else H, dtype)
+    _allow_fused(setting)
+    dispatch = fa.select_dispatch(seq, seq, dtype)
     fused = dispatch.backward == "fused"
-    rec = {"case": name, "seq": seq, "fused_bwd": fused, "causal": causal,
+    dtype_name = jnp.dtype(dtype).name
+    rec = {"case": name, "seq": seq, "dtype": dtype_name,
+           "fused_bwd": fused, "causal": causal,
            "kv_heads": int(args[1].shape[2]),
            "dispatch": dispatch._asdict(), "variants": {}}
     ok = True
     for segmented in (False, True):
-        fa.FUSED_BWD = setting
+        _allow_fused(setting)
         # Fresh outer trace per setting: the fused decision is read at
         # the custom_vjp layer, outside the inner jit's cache.
         got, mosaic_calls = _run(_kernel_fn(segmented, causal), args)
@@ -198,19 +228,21 @@ def run_case(name: str, seq: int, setting: bool | None,
             v for k, v in stats.items() if k.endswith("_vs_reference")
         ) <= GATE_VS_REFERENCE
         if fused:
-            two_pass = two_pass_cache.get((seq, segmented, causal))
+            two_pass = two_pass_cache.get(
+                (seq, dtype_name, segmented, causal))
             if two_pass is None:
-                fa.FUSED_BWD = False
+                _allow_fused(False)
                 two_pass, _ = _run(_kernel_fn(segmented, causal), args)
             diff = max(_rel_l2(g, t) for g, t in zip(got, two_pass))
             stats["rel_l2_vs_two_pass"] = diff
             good = good and diff <= GATE_FUSED_VS_TWO_PASS
         else:
-            two_pass_cache[(seq, segmented, causal)] = got
+            two_pass_cache[(seq, dtype_name, segmented, causal)] = got
         stats["ok"] = bool(good)
         ok = ok and good
         rec["variants"]["segmented" if segmented else "unsegmented"] = stats
-        print(f"{name} seq {seq} {'seg' if segmented else 'unseg'}: "
+        print(f"{name} seq {seq} {dtype_name} "
+              f"{'seg' if segmented else 'unseg'}: "
               + " ".join(f"{k}={v:.3e}" if isinstance(v, float) else f"{k}={v}"
                          for k, v in stats.items()), flush=True)
     rec["ok"] = ok
@@ -228,12 +260,11 @@ def main(argv) -> int:
     selected = argv or list(cases)
     platform.resolve_compilation_cache()
     dev = jax.devices()[0]
-    fa.FUSED_BWD = None
     stream_seq = 2 * fa.MAX_SEQ_VMEM
-    streaming_default = ("fused" if fa.fused_bwd_enabled()
-                         and stream_seq <= fa.FUSED_BWD_MAX else "two_pass")
+    streaming_default = fa.select_dispatch(
+        stream_seq, stream_seq, jnp.bfloat16).backward
     print(f"flash kernels on {dev.platform} ({dev.device_kind}), "
-          f"{fa.kernel_mode()} mode, B={B} H={H} D={D} bf16; default "
+          f"{fa.kernel_mode()} mode, B={B} H={H} D={D}; default bf16 "
           f"backward at seq {stream_seq}: {streaming_default}", flush=True)
     two_pass_cache: dict = {}
     results = [run_case(name, *cases[name], two_pass_cache,
@@ -242,9 +273,9 @@ def main(argv) -> int:
     ok = all(r["ok"] for r in results)
     if not ok:
         print("FLASH KERNEL MISMATCH — do not trust these kernels on this "
-              "backend/toolchain; for the fused backward "
-              "(FLASH_FUSED_BWD=0 keeps the two-pass) the flush ordering "
-              "is suspect", flush=True)
+              "backend/toolchain; where the fused backward disagrees the "
+              "flush ordering is suspect (take the generation off "
+              "FUSED_BWD_VERIFIED_PLATFORMS)", flush=True)
     print(json.dumps({
         "ok": ok, "platform": dev.platform, "device_kind": dev.device_kind,
         "kernel_mode": fa.kernel_mode(),

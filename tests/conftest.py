@@ -41,11 +41,12 @@ def devices():
 @pytest.fixture
 def pin_whole_k_rows(monkeypatch):
     """``pin(rows, s)`` for tests parametrised over ``rows``: ``"128-row"``
-    holds the whole-K flash kernels to the 128-row blocks they shipped
-    with (several blocks a head; the dk/dv kernel over several key
-    blocks), which ``select_dispatch`` no longer picks at short lengths;
-    ``"selected"`` leaves its choice alone. Either way it checks that an
-    f32 sequence of ``s`` then runs whole-K forward and backward."""
+    holds the whole-K flash forward to the 128-row blocks it shipped
+    with (several blocks a head, and several row blocks' visits to one
+    key block in the backward), which ``select_dispatch`` no longer
+    picks at short lengths; ``"selected"`` leaves its choice alone.
+    Either way it checks that an f32 sequence of ``s`` then runs the
+    whole-K forward, and the backward on the streaming tile."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
     def pin(rows: str, s: int) -> None:
@@ -53,9 +54,8 @@ def pin_whole_k_rows(monkeypatch):
             monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
         picked = fa.select_dispatch(s, s, "float32")
         want = 128 if rows == "128-row" else s
-        assert (picked.family, picked.block_q, picked.bwd_family,
-                picked.bwd_block_q, picked.bwd_block_k) == (
-            "whole_k", want, "whole_k", want, want)
+        assert (picked.family, picked.block_q) == ("whole_k", want)
+        assert (picked.bwd_block_q, picked.bwd_block_k) == (want, s)
 
     return pin
 

@@ -48,8 +48,8 @@ def test_flash_attention_with_mask(devices):
 
 @pytest.mark.parametrize("rows", ["selected", "128-row"])
 def test_flash_attention_backward_matches_xla(devices, pin_whole_k_rows, rows):
-    """The Pallas backward kernels (dq + dkv, online recompute) must match
-    XLA autodiff through the reference attention — for q, k AND v."""
+    """The Pallas backward (online recompute) must match XLA autodiff
+    through the reference attention — for q, k AND v."""
     from distributed_tensorflow_framework_tpu.ops.flash_attention import (
         flash_attention,
     )
@@ -295,12 +295,23 @@ def _streaming_reference(q, k, v, bias=None, segment_ids=None, block=128):
     return out.transpose(0, 2, 1, 3).astype(q.dtype)    # (B,S,H,D)
 
 
-def test_kblocked_kernels_match_whole_k(devices, monkeypatch):
-    """Forcing the K-blocked streaming kernels (MAX_SEQ_VMEM→128) on a
-    shape the whole-K kernels handle must reproduce the XLA reference for
-    output AND q/k/v grads — with a key mask in play."""
+def _allow_fused(monkeypatch, fused: bool):
+    """The module's one seam for the backward: what the platform rule
+    answers. ``False`` is a TPU generation off the verified list."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
+    monkeypatch.setattr(fa, "fused_bwd_enabled", lambda: fused)
+
+
+@pytest.mark.parametrize("backward", ["fused", "two_pass"])
+def test_kblocked_kernels_match_whole_k(devices, monkeypatch, backward):
+    """Forcing the K-blocked streaming forward (MAX_SEQ_VMEM→128) on a
+    shape the whole-K forward handles must reproduce the XLA reference
+    for output AND, through either streaming backward, q/k/v grads —
+    with a key mask in play."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    _allow_fused(monkeypatch, backward == "fused")
     monkeypatch.setattr(fa, "MAX_SEQ_VMEM", 128)
     # Pin the streaming tiles to 128 so s=384 gives a REAL 3-step
     # k-grid; the production 512/1024 targets would degenerate this
@@ -308,6 +319,8 @@ def test_kblocked_kernels_match_whole_k(devices, monkeypatch):
     # cross-block math (init / corr rescale / finalize).
     monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
     monkeypatch.setattr(fa, "BLOCK_K_KB", 128)
+    assert tuple(fa.select_dispatch(384, 384, jnp.float32)) == (
+        "stream", 128, 128, backward, 128, 128)
     q, k, v = _rand_qkv(jax.random.key(7), b=2, s=384, h=2, d=32)
     mask = jnp.ones((2, 1, 1, 384), bool).at[:, :, :, 300:].set(False)
 
@@ -331,7 +344,7 @@ def test_kblocked_kernels_match_whole_k(devices, monkeypatch):
 
 
 def test_fused_streaming_backward_matches_two_pass(devices, monkeypatch):
-    """FLASH_FUSED_BWD one-pass backward (round 5): on a forced
+    """The fused one-pass backward: on a forced
     streaming shape (MAX_SEQ_VMEM→128, 128-tiles, s=384 → real 3×3
     (q,k) block grid) the fused kernel's q/k/v grads must match BOTH the
     two-pass streaming kernels and the XLA reference — with a key mask,
@@ -364,9 +377,9 @@ def test_fused_streaming_backward_matches_two_pass(devices, monkeypatch):
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
     for seg_ids in (None, seg):
-        monkeypatch.setattr(fa, "FUSED_BWD", False)
+        _allow_fused(monkeypatch, False)
         g_two = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, seg_ids)
-        monkeypatch.setattr(fa, "FUSED_BWD", True)
+        _allow_fused(monkeypatch, True)
         g_fused = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, seg_ids)
         for name, a, b in zip("qkv", g_fused, g_two):
             # Identical block math, identical accumulation order → the
@@ -389,15 +402,14 @@ def test_fused_streaming_backward_matches_two_pass(devices, monkeypatch):
 
 def test_fused_streaming_backward_gate(devices, monkeypatch):
     """The fused path only engages below FUSED_BWD_MAX; above it the
-    two-pass kernels run even with the flag armed (VMEM accumulators
-    would not fit) — pinned by checking grads still match the XLA
-    reference with an absurdly low gate."""
+    two-pass kernels run even where the platform allows it (VMEM
+    accumulators would not fit) — pinned by checking grads still match
+    the XLA reference with an absurdly low gate."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "MAX_SEQ_VMEM", 128)
     monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
     monkeypatch.setattr(fa, "BLOCK_K_KB", 128)
-    monkeypatch.setattr(fa, "FUSED_BWD", True)
     monkeypatch.setattr(fa, "FUSED_BWD_MAX", 256)  # s=384 exceeds it
     q, k, v = _rand_qkv(jax.random.key(13), b=1, s=384, h=2, d=32)
 
@@ -427,74 +439,6 @@ def test_fused_streaming_backward_gate(devices, monkeypatch):
     monkeypatch.setattr(fa, "FUSED_BWD_MAX", 8192)
     jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     assert calls, "fused kernel did not run below FUSED_BWD_MAX"
-
-
-def test_fused_backward_takes_over_whole_k_regime(devices, monkeypatch):
-    """FUSED_WHOLE_K_MIN routing (round 5): for mid-length sequences the
-    fused one-pass streaming backward REPLACES the whole-K two-pass even
-    though the sequence fits VMEM (s ≤ MAX_SEQ_VMEM) — it pays one fewer
-    S² exp. Scaled-down constants stand in for the real ones
-    (MIN 256 / VMEM 1024 ≈ 2048 / 4096): s=384 sits in the whole-K
-    regime but above the fused takeover. Pins the DISPATCH via a spy and
-    the numerics against both the whole-K two-pass and the XLA
-    reference, masked and segmented."""
-    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
-
-    monkeypatch.setattr(fa, "MAX_SEQ_VMEM", 1024)
-    monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
-    monkeypatch.setattr(fa, "BLOCK_K_KB", 128)
-    monkeypatch.setattr(fa, "FUSED_BWD", True)
-    q, k, v = _rand_qkv(jax.random.key(17), b=2, s=384, h=2, d=32)
-    q = q.astype(jnp.bfloat16)
-    k = k.astype(jnp.bfloat16)
-    v = v.astype(jnp.bfloat16)
-    mask = jnp.ones((2, 1, 1, 384), bool).at[:, :, :, 320:].set(False)
-    seg = jnp.concatenate(
-        [jnp.zeros((2, 200), jnp.int32), jnp.ones((2, 184), jnp.int32)],
-        axis=1)
-
-    calls = []
-    orig = fa._flash_bwd_fused_kb
-    monkeypatch.setattr(
-        fa, "_flash_bwd_fused_kb",
-        lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
-
-    def loss(q, k, v, segment_ids=None):
-        out = fa.flash_attention(q, k, v, mask=mask,
-                                 segment_ids=segment_ids)
-        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
-
-    def loss_ref(q, k, v, segment_ids=None):
-        attn_mask = mask
-        if segment_ids is not None:
-            same = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
-            attn_mask = mask & same
-        out = dot_product_attention(q, k, v, mask=attn_mask)
-        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
-
-    # Below the takeover threshold the whole-K two-pass still runs.
-    monkeypatch.setattr(fa, "FUSED_WHOLE_K_MIN", 512)  # s=384 below it
-    g_whole = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, None)
-    assert not calls, "fused kernel ran below FUSED_WHOLE_K_MIN"
-
-    # At/above it the fused streaming backward takes over — in the
-    # whole-K regime (384 ≤ MAX_SEQ_VMEM=1024).
-    monkeypatch.setattr(fa, "FUSED_WHOLE_K_MIN", 256)
-    for seg_ids in (None, seg):
-        calls.clear()
-        g_fused = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, seg_ids)
-        assert calls, "fused kernel did not take over the whole-K regime"
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v, seg_ids)
-        # vs the XLA reference always; vs the whole-K two-pass where one
-        # was computed (unsegmented arm) — the distinct comparison.
-        pairs = [("ref", g_ref)] + ([("whole-k", g_whole)]
-                                    if seg_ids is None else [])
-        for tag, ref in pairs:
-            for name, a, b in zip("qkv", g_fused, ref):
-                np.testing.assert_allclose(
-                    np.asarray(a, np.float32), np.asarray(b, np.float32),
-                    rtol=4e-2, atol=4e-2,
-                    err_msg=f"d{name} vs {tag}, seg={seg_ids is not None}")
 
 
 def _selection_case(s, segmented, dtype):
@@ -563,60 +507,62 @@ def test_selected_dispatch_matches_xla_reference(devices, s, segmented,
 
 @pytest.mark.parametrize("segmented", [False, True], ids=["plain", "packed"])
 @pytest.mark.parametrize("s", [128, 384, 512, 1024])
-def test_selection_on_a_verified_chip_matches_xla_reference(
+def test_selection_on_an_unverified_platform_matches_xla_reference(
         devices, monkeypatch, s, segmented):
-    """The same check with the fused backward resolved as a platform on
-    the verified list resolves it (off the chip ``fused_bwd_enabled()``
-    is False): bf16 then takes the whole-K forward and the one-pass
-    backward at every length, which is what ``bert_s512`` runs."""
+    """The same check with the fused backward refused, as on a TPU
+    generation off the verified list (the default cases above cover the
+    fused one): bf16 then takes the whole-K forward and the streaming
+    two-pass pair at every length."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
-    monkeypatch.setattr(fa, "FUSED_BWD", True)
+    _allow_fused(monkeypatch, False)
     picked = fa.select_dispatch(s, s, jnp.bfloat16)
-    assert (picked.family, picked.backward) == ("whole_k", "fused")
+    assert (picked.family, picked.backward) == ("whole_k", "two_pass")
     _assert_matches_xla_reference(s, segmented, jnp.bfloat16)
     logged = {(e["s"], e["dtype"], e["segmented"]): e
               for e in fa.dispatch_log()}
     entry = logged[(s, "bfloat16", segmented)]
     assert (entry["family"], entry["block_q"], entry["backward"]) == (
-        "whole_k", picked.block_q, "fused")
+        "whole_k", picked.block_q, "two_pass")
 
 
 # (s, s_k, dtype, fused backward allowed) -> the dispatch, at the shipped
-# thresholds. ``True`` is what v5e resolves to, ``False`` a TPU
-# generation off the verified list (and every CPU run).
+# thresholds. ``True`` is what v5e and every backend that is not a TPU
+# resolve to, ``False`` a TPU generation off the verified list.
 _DISPATCH_TABLE = [
-    ((64, 64, "bfloat16", True),
-     ("whole_k", 64, 64, "two_pass", "whole_k", 64, 64)),
+    ((64, 64, "bfloat16", True),          # key tile under one lane tile
+     ("whole_k", 64, 64, "two_pass", 64, 64)),
     ((128, 128, "bfloat16", True),
-     ("whole_k", 128, 128, "fused", "stream", 128, 128)),
+     ("whole_k", 128, 128, "fused", 128, 128)),
     ((384, 384, "bfloat16", True),
-     ("whole_k", 384, 384, "fused", "stream", 384, 384)),
+     ("whole_k", 384, 384, "fused", 384, 384)),
     ((512, 512, "bfloat16", True),        # bert_s512
-     ("whole_k", 512, 512, "fused", "stream", 512, 512)),
+     ("whole_k", 512, 512, "fused", 512, 512)),
     ((512, 512, "bfloat16", False),
-     ("whole_k", 512, 512, "two_pass", "whole_k", 512, 512)),
+     ("whole_k", 512, 512, "two_pass", 512, 512)),
     ((512, 512, "float32", True),
-     ("whole_k", 512, 512, "two_pass", "whole_k", 512, 512)),
+     ("whole_k", 512, 512, "fused", 512, 512)),
     ((640, 640, "bfloat16", True),        # 5·128: only 128 divides
-     ("whole_k", 128, 640, "fused", "stream", 128, 640)),
+     ("whole_k", 128, 640, "fused", 128, 640)),
     ((1024, 1024, "bfloat16", True),
-     ("whole_k", 512, 1024, "fused", "stream", 512, 1024)),
+     ("whole_k", 512, 1024, "fused", 512, 1024)),
     ((2048, 2048, "bfloat16", True),
-     ("whole_k", 256, 2048, "fused", "stream", 512, 1024)),
+     ("whole_k", 256, 2048, "fused", 512, 1024)),
     ((2048, 2048, "float32", True),
-     ("whole_k", 256, 2048, "two_pass", "whole_k", 256, 256)),
+     ("whole_k", 256, 2048, "fused", 512, 1024)),
     ((4096, 4096, "bfloat16", True),
-     ("whole_k", 128, 4096, "fused", "stream", 512, 1024)),
+     ("whole_k", 128, 4096, "fused", 512, 1024)),
     ((4096, 4096, "bfloat16", False),
-     ("whole_k", 128, 4096, "two_pass", "whole_k", 128, 128)),
+     ("whole_k", 128, 4096, "two_pass", 512, 1024)),
     ((8192, 8192, "bfloat16", True),      # bert_s8192
-     ("stream", 512, 1024, "fused", "stream", 512, 1024)),
+     ("stream", 512, 1024, "fused", 512, 1024)),
     ((8192, 8192, "bfloat16", False),
-     ("stream", 512, 1024, "two_pass", "stream", 512, 1024)),
+     ("stream", 512, 1024, "two_pass", 512, 1024)),
     ((16384, 16384, "bfloat16", True),    # over FUSED_BWD_MAX
-     ("stream", 512, 1024, "two_pass", "stream", 512, 1024)),
+     ("stream", 512, 1024, "two_pass", 512, 1024)),
 ]
+_TABLE_SHAPES = sorted({c[:3] for c, _ in _DISPATCH_TABLE}
+                       | {(4096, 4096, "float32"), (8192, 8192, "float32")})
 
 
 @pytest.mark.parametrize("case,want", _DISPATCH_TABLE,
@@ -629,7 +575,7 @@ def test_select_dispatch_table(monkeypatch, case, want):
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
     s, s_k, dtype, fused_allowed = case
-    monkeypatch.setattr(fa, "FUSED_BWD", fused_allowed)
+    _allow_fused(monkeypatch, fused_allowed)
     got = fa.select_dispatch(s, s_k, jnp.dtype(dtype))
     assert tuple(got) == want
     assert s % got.block_q == 0 and s_k % got.block_k == 0
@@ -637,17 +583,64 @@ def test_select_dispatch_table(monkeypatch, case, want):
     area = fa.BLOCK_Q * fa.MAX_SEQ_VMEM
     if got.family == "whole_k":
         assert got.block_k == s_k and got.block_q * s_k <= area
-    if got.bwd_family == "whole_k":
-        assert got.bwd_block_q * s_k <= area and got.bwd_block_k * s <= area
 
 
-def test_fused_backward_is_on_for_the_verified_platforms_only():
+def _on_a_tpu(monkeypatch, fa, device_kind):
+    """The platform rule as a process on a real TPU of ``device_kind``
+    resolves it, nothing memoised."""
+    import types
+
+    device = types.SimpleNamespace(device_kind=device_kind)
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa.jax, "devices", lambda *a: [device])
+    monkeypatch.setattr(fa, "fused_bwd_enabled",
+                        fa.fused_bwd_enabled.__wrapped__)
+
+
+@pytest.mark.parametrize("shape", _TABLE_SHAPES,
+                         ids=[f"{s}-{d}" for s, _, d in _TABLE_SHAPES])
+def test_selection_off_the_chip_is_the_verified_chips(monkeypatch, shape):
+    """With nothing patched this suite (``JAX_PLATFORMS=cpu``) selects
+    what a v5e selects, so tier-1 differentiates through the kernels the
+    cells run and an ahead-of-time compile from a CPU backend is of the
+    chip's program."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    assert jax.default_backend() == "cpu"
+    here = fa.select_dispatch(*shape)
+    _on_a_tpu(monkeypatch, fa, "TPU v5 lite")
+    assert fa.select_dispatch(*shape) == here
+
+
+def test_no_backward_holds_the_whole_opposing_sequence(monkeypatch):
+    """Every backward streams: whatever the lengths, the dtype and the
+    platform's answer, its tile is within the streaming targets."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    for fused_allowed in (True, False):
+        _allow_fused(monkeypatch, fused_allowed)
+        for shape in _TABLE_SHAPES:
+            got = fa.select_dispatch(*shape)
+            assert got.bwd_block_q <= fa.BLOCK_Q_KB, (shape, got)
+            assert got.bwd_block_k <= fa.BLOCK_K_KB, (shape, got)
+            assert fused_allowed or got.backward == "two_pass"
+
+
+def test_fused_backward_is_on_for_the_verified_platforms_only(
+        monkeypatch, caplog):
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
     assert fa.fused_bwd_verified("TPU v5 lite")
     assert fa.fused_bwd_verified("TPU v5e")
     assert not fa.fused_bwd_verified("TPU v4")
     assert not fa.fused_bwd_verified("TPU v6 lite")
+    # A real TPU off the list keeps the two-pass pair and says why,
+    # naming the list and the script that puts a generation on it.
+    _on_a_tpu(monkeypatch, fa, "TPU v6 lite")
+    with caplog.at_level("WARNING", logger=fa.log.name):
+        assert fa.select_dispatch(512, 512, jnp.bfloat16).backward == "two_pass"
+    assert "FUSED_BWD_VERIFIED_PLATFORMS" in caplog.text
+    assert "verify_flash_kernels.py" in caplog.text
 
 
 def test_pick_block_divisor_policy():
@@ -723,7 +716,7 @@ def test_kblocked_segmented_ring_matches_reference(devices, monkeypatch,
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
     from distributed_tensorflow_framework_tpu.parallel import ring
 
-    monkeypatch.setattr(fa, "FUSED_BWD", fused)
+    _allow_fused(monkeypatch, fused)
     # chunk = 256/4 = 64 > MAX_SEQ_VMEM(32) → K-blocked kernels with a
     # 16-wide block grid (nq = nk = 4), segments riding along.
     monkeypatch.setattr(fa, "MAX_SEQ_VMEM", 32)
@@ -864,17 +857,31 @@ def test_kernel_check_matrix_follows_the_module_thresholds():
     """scripts/verify_flash_kernels.py (the smoke's kernel leg) reaches
     every regime at the shipped thresholds, and the autotune plan's
     verify trials name cases it has."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
     from scripts import verify_flash_kernels as vfk
     from tools.autotune.plan import compile_chip_window_plan
 
+    bf16, f32 = jnp.bfloat16, jnp.float32
     cases = vfk._cases()
     assert cases == {
-        "cell_s512": (512, None), "whole_k_short": (512, False),
-        "whole_k_max": (4096, False), "kblocked": (8192, False),
-        "fused": (8192, True), "fused_takeover_min": (128, True),
-        "fused_takeover": (2048, True), "fused_takeover_max": (4096, True)}
+        "cell_s512": (512, None, bf16), "whole_k_short": (512, False, bf16),
+        "whole_k_max": (4096, False, bf16), "kblocked": (8192, False, bf16),
+        "fused": (8192, True, bf16), "fused_takeover_min": (128, True, bf16),
+        "fused_takeover": (2048, True, bf16),
+        "fused_takeover_max": (4096, True, bf16),
+        "f32_s128": (128, None, f32), "f32_s512": (512, None, f32),
+        "f32_s2048": (2048, None, f32), "f32_s4096": (4096, None, f32),
+        "sub_tile": (64, None, bf16), "f32_sub_tile": (64, None, f32)}
+    # Off the chip the float32 cases run the fused backward and the
+    # sub-tile ones the only backward that compiles for them.
+    for name, (seq, _, dtype) in cases.items():
+        if name.endswith("sub_tile"):
+            assert fa.select_dispatch(seq, seq, dtype).backward == "two_pass"
+        elif name.startswith("f32_"):
+            assert fa.select_dispatch(seq, seq, dtype).backward == "fused"
     assert vfk._causal_cases() == {
-        "causal_gqa_s512": (512, None), "causal_gqa_s8192": (8192, None)}
+        "causal_gqa_s512": (512, None, bf16),
+        "causal_gqa_s8192": (8192, None, bf16)}
     assert 12 % vfk.KV_GROUP == 0
     named = {a for t in compile_chip_window_plan()
              if "scripts/verify_flash_kernels.py" in t.argv

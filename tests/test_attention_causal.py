@@ -49,13 +49,12 @@ def reference_attention(q, k, v, seg, causal):
 
 
 def _force(monkeypatch, family, backward):
-    """Pin the kernels a call gets: ``whole_k`` or ``stream`` (on 128-wide
-    tiles, so S=256 crosses the diagonal and a block lies above it),
-    two-pass or fused backward."""
+    """Pin the kernels a call gets: the ``whole_k`` or the ``stream``
+    forward, the two-pass or the fused backward (on 128-wide tiles, so
+    S=256 crosses the diagonal and a block lies above it)."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
-    monkeypatch.setattr(fa, "FUSED_BWD", backward == "fused")
-    monkeypatch.setattr(fa, "FUSED_WHOLE_K_MIN", 128)
+    monkeypatch.setattr(fa, "fused_bwd_enabled", lambda: backward == "fused")
     monkeypatch.setattr(fa, "BLOCK_Q_KB", 128)
     monkeypatch.setattr(fa, "BLOCK_K_KB", 128)
     if family == "stream":

@@ -399,7 +399,6 @@ class TestChipWindowPlan:
         by_label = {t.label: t for t in trials}
         # Spot-check the load-bearing gates: measurement arms wait on
         # their verify/export predecessors.
-        assert by_label["wk2048-fused"].gate == "wk-verify-2048"
         assert by_label["fused-bwd"].gate == "fused-bwd-verify"
         assert by_label["serve-batched"].gate == "serve-export"
         # A failed preflight refuses the window (§0 contract).

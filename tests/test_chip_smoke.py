@@ -7,11 +7,14 @@ overridden — and in a directory that holds nothing else of the repo, it
 exits non-zero without the ``{"ok": true, ...}`` line.
 """
 
+import json
 import os
 import pathlib
 import shutil
 import subprocess
 import sys
+
+import pytest
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 PACKAGE = "distributed_tensorflow_framework_tpu"
@@ -72,9 +75,48 @@ def test_cache_counts_are_distinct_keys_not_log_lines(tmp_path, monkeypatch):
 
 
 def test_a_child_without_a_result_line_fails_its_leg():
-    import pytest
-
     smoke = _load_smoke()
     assert smoke._last_json('log line\n{"ok": true}\n') == {"ok": True}
     with pytest.raises(smoke.LegFailed):
         smoke._last_json("log line\nanother\n")
+
+
+def _kernel_result(cases):
+    return json.dumps({
+        "ok": True, "platform": "tpu", "kernel_mode": "mosaic",
+        "streaming_backward_default": "fused",
+        "cases": [{"case": name, "dtype": dtype, "variants": {
+            "unsegmented": {"mosaic_calls": calls,
+                            "out_rel_l2_vs_reference": 1e-3}}}
+            for name, dtype, calls in cases]})
+
+
+@pytest.mark.parametrize("cases,verdict", [
+    ([("cell_s512", "bfloat16", 2), ("f32_s512", "float32", 2),
+      ("sub_tile", "bfloat16", 3)], None),
+    ([("cell_s512", "bfloat16", 2)], "ran only"),
+    ([("cell_s512", "bfloat16", 2), ("f32_s512", "float32", 1)],
+     "f32_s512/unsegmented: 1 Mosaic calls"),
+], ids=["whole-matrix", "one-dtype", "interpreted-case"])
+def test_kernel_leg_wants_both_dtypes_compiled_by_mosaic(
+        tmp_path, monkeypatch, cases, verdict):
+    """The kernel leg passes the script no case names, so it runs the
+    script's own matrix, and holds the answer to: bf16 and float32 cases
+    both there (the dispatch rule names no dtype), a forward and a
+    backward kernel from Mosaic in every program."""
+    smoke = _load_smoke()
+    monkeypatch.setattr(smoke, "LOGS", tmp_path)
+    (tmp_path / "kernels.err").write_text("")
+    argvs = []
+    monkeypatch.setattr(
+        smoke, "run_child",
+        lambda name, argv, timeout: (argvs.append(argv),
+                                     _kernel_result(cases))[1])
+    device = {"platform": "tpu"}
+    if verdict is None:
+        out = smoke.leg_kernels(device)
+        assert out["cases"] == [c[0] for c in cases]
+    else:
+        with pytest.raises(smoke.LegFailed, match=verdict):
+            smoke.leg_kernels(device)
+    assert argvs[0][1:] == ["scripts/verify_flash_kernels.py"]

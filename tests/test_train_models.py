@@ -62,7 +62,8 @@ def test_run_meta_lists_the_attention_dispatch(devices, tmp_path):
             if (e["s"], e["s_k"], e["dtype"]) == (128, 128, "float32")]
     assert mine and all(
         (e["family"], e["block_q"], e["block_k"], e["backward"],
-         e["bwd_family"]) == ("whole_k", 128, 128, "two_pass", "whole_k")
+         e["bwd_block_q"], e["bwd_block_k"])
+        == ("whole_k", 128, 128, "fused", 128, 128)
         for e in mine)
 
 

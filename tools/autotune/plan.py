@@ -234,19 +234,14 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
     trials.append(_bench("13", "prec-bf16-int8",
                          BENCH_PRECISION="bf16_int8"))
 
-    # §7 whole-K takeover bands: numerics verify gates each pair.
-    for seq, bs, case in ((2048, 16, "fused_takeover"),
-                          (4096, 8, "fused_takeover_max")):
-        verify = f"wk-verify-{seq}"
+    # §7 the lengths that pair the whole-K forward with the fused
+    # backward: the numerics check alone (which backward wins there was
+    # settled on the chip, PERF.md §6 PR 25, and the loser is gone).
+    for seq, case in ((2048, "fused_takeover"),
+                      (4096, "fused_takeover_max")):
         trials.append(_script(
-            "7", verify, (PY, "scripts/verify_flash_kernels.py", case)))
-        trials.append(_bench(
-            "7", f"wk{seq}-fused", gate=verify, BENCH_WORKLOAD="bert",
-            BENCH_ATTN="pallas", BENCH_SEQ=seq, BENCH_BS=bs))
-        trials.append(_bench(
-            "7", f"wk{seq}-two", gate=verify, BENCH_WORKLOAD="bert",
-            BENCH_ATTN="pallas", BENCH_SEQ=seq, BENCH_BS=bs,
-            FLASH_FUSED_WHOLE_K_MIN=1000000000))
+            "7", f"wk-verify-{seq}",
+            (PY, "scripts/verify_flash_kernels.py", case)))
 
     # §8 pipeline-schedule A/B (pp-sanity: one cheap default run first).
     trials.append(_bench("8", "pp-sanity"))
@@ -346,7 +341,7 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
         trials.append(_bench(
             "3", f"tile-{q}-1024", BENCH_WORKLOAD="bert",
             BENCH_ATTN="pallas", BENCH_SEQ=8192, BENCH_BS=4,
-            FLASH_BLOCK_Q_KB=q, FLASH_BLOCK_K_KB=1024, FLASH_FUSED_BWD=0))
+            FLASH_BLOCK_Q_KB=q, FLASH_BLOCK_K_KB=1024))
     trials.append(_script(
         "4", "crossover",
         (PY, "scripts/bench_chunk_crossover.py", "256", "512", "1024",
@@ -356,8 +351,7 @@ def compile_chip_window_plan() -> list[PlannedTrial]:
                                    "kblocked", "fused")))
     trials.append(_bench(
         "4b", "fused-bwd", gate="fused-bwd-verify", BENCH_WORKLOAD="bert",
-        BENCH_ATTN="pallas", BENCH_SEQ=8192, BENCH_BS=4,
-        FLASH_FUSED_BWD=1))
+        BENCH_ATTN="pallas", BENCH_SEQ=8192, BENCH_BS=4))
     trials.append(_bench("4c", "bert-accum4", BENCH_WORKLOAD="bert",
                          BENCH_ACCUM=4))
     trials.append(_bench("5", "trace", BENCH_TRACE="/tmp/bench_trace"))
