@@ -65,7 +65,12 @@ def register_model(name: str, *, task: str = "classification"):
     inputs must match ``task``: "classification" (images → logits),
     "mlm" ((ids, mask[, segment_ids]) → logits) or "causal_lm" ((ids[,
     segment_ids[, positions]]) → logits, next-token ``targets``) — the
-    task picks the loss and batch wiring (train/step.py). The builder owns the
+    task picks the loss and batch wiring (train/step.py). An "mlm" module
+    whose ``__call__`` also takes a ``labelled`` keyword
+    (models/bert.LabelledWindows) is handed the targets in training and
+    returns models/bert.HeadSums in place of logits: its head runs on the
+    labelled positions only, as ``BertForMLM``'s does; one without it gets
+    its (B, S, V) logits put through the loss. The builder owns the
     interpretation of every other ModelConfig knob (e.g. ``remat``).
     Built-in names cannot be shadowed, and duplicate registrations fail
     loudly.

@@ -20,7 +20,9 @@ Param count pinned by test: 109.5M (BERT-base, tied MLM head).
 
 from __future__ import annotations
 
-from typing import Any
+import functools
+import math
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -229,6 +231,172 @@ class MLMHead(nn.Module):
         return logits.astype(jnp.float32) + bias
 
 
+class LabelledWindows(NamedTuple):
+    """What a training step hands :class:`BertForMLM` so that its head
+    runs on the labelled positions only (:func:`head_in_windows`)."""
+
+    targets: jax.Array   # (B, S): the label where one is, -1 elsewhere
+    width: int           # positions of a row the head takes at a time
+    sums: Callable       # (logits (B, P, V), targets (B, P)) -> (loss_sum,
+    #                      others): the scalar to differentiate, and a
+    #                      tree of float32 sums that are only reported
+
+
+class HeadSums(NamedTuple):
+    """What :func:`head_in_windows` returns, and :class:`BertForMLM` in
+    place of logits: ``LabelledWindows.sums`` added up over the windows,
+    and how many of them were computed (float32; 1.0 at best)."""
+
+    loss_sum: jax.Array
+    others: Any
+    windows: jax.Array
+
+
+# A window is this much wider than the labelled positions a row is
+# expected to hold, in whole lane tiles.
+HEAD_WINDOW_HEADROOM = 1.25
+HEAD_WINDOW_STEP = 128
+
+
+def head_window(seq_len: int, mask_prob: float) -> int:
+    """Positions of a row the MLM head takes at a time in training: the
+    smallest multiple of 128 that holds 1.25x the labelled positions
+    ``mask_prob`` promises, at most the row. A row's count is
+    Binomial(S, mask_prob), so at 0.15 the window is +6.6 s.d. at S=512
+    (128) and +9.6 at 8192 (1536); a row over it costs a further window
+    (:func:`head_in_windows`), never a position."""
+    want = HEAD_WINDOW_HEADROOM * mask_prob * seq_len
+    steps = max(math.ceil(want / HEAD_WINDOW_STEP - 1e-9), 1)
+    return min(steps * HEAD_WINDOW_STEP, seq_len)
+
+
+def _labelled_first(targets, rows: int):
+    """Per row of ``targets`` (B, S): its positions with the labelled ones
+    (``>= 0``) first, both runs in order, and the targets there; filled
+    to ``rows`` with position 0 under the label -1. Integers of (B, S)
+    only; one sort (the keys are distinct, so it need not be stable)."""
+    s = targets.shape[1]
+    at = jnp.arange(s, dtype=jnp.int32)
+    keys = jnp.sort(jnp.where(targets >= 0, at, at + s), axis=1,
+                    stable=False)
+    pos = jnp.where(keys >= s, keys - s, keys)
+    there = jnp.take_along_axis(targets, pos, axis=1)
+    fill = ((0, 0), (0, rows - s))
+    return jnp.pad(pos, fill), jnp.pad(there, fill, constant_values=-1)
+
+
+def _take_rows(hidden, pos):
+    """``hidden[b, pos[b, p]]``: (B, S, H), (B, P) -> (B, P, H), as the
+    product with a one-hot (B, P, S). Exact (one term of each sum is not
+    a zero), B stays a batch dimension so a batch sharded over chips
+    stays where it is, and the backward is the transposed product: on a
+    TPU both run on the MXU in microseconds where XLA's row gather and
+    its sorted scatter-add take a millisecond."""
+    with jax.named_scope("take_rows"):
+        pick = pos[..., None] == jnp.arange(hidden.shape[1], dtype=pos.dtype)
+        return jnp.matmul(pick.astype(hidden.dtype), hidden,
+                          precision=jax.lax.Precision.HIGHEST)
+
+
+def _plan(targets, width: int):
+    """Which positions each window takes: ``(pos, there, used)`` with
+    ``pos`` and ``there`` (windows, B, width), the positions labelled
+    first and the targets at them, and ``used`` the number of windows
+    that hold a label of some row, at least the first."""
+    b, s = targets.shape
+    n = -(-s // width)
+    with jax.named_scope("head"):
+        pos, there = _labelled_first(targets, n * width)
+        pos = pos.reshape(b, n, width).swapaxes(0, 1)
+        there = there.reshape(b, n, width).swapaxes(0, 1)
+        most = (targets >= 0).sum(axis=1).max()
+        return pos, there, jnp.maximum(-(-most // width), 1)
+
+
+def _one(window: Callable, plan, operands, hidden, w):
+    """``window`` on window ``w`` of the plan: ``(loss_sum, others)``."""
+    pos, there, _ = plan
+    with jax.named_scope("head"):
+        rows = _take_rows(hidden, pos[w])
+    return window(operands, rows, there[w])
+
+
+def _further(plan, first, step):
+    """``first`` plus ``step(w)`` for each further window in use."""
+    return jax.lax.while_loop(
+        lambda at: at[0] < plan[2],
+        lambda at: (at[0] + 1, jax.tree.map(jnp.add, at[1], step(at[0]))),
+        (jnp.int32(1), first))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def head_in_windows(window: Callable, width: int, operands, hidden, targets):
+    """``window(operands, rows, targets) -> (loss_sum, others)`` over the
+    labelled positions of ``hidden`` (B, S, H), ``width`` positions of
+    every row at a time.
+
+    Each row's positions are taken labelled first. The first window of
+    them is straight-line code, computed unconditionally, and holds every
+    label of a row with at most ``width``; further windows run only while
+    some row has labels left, in a ``lax.while_loop``, and add their
+    sums: exact for any count, at the price of one window per ``width``
+    labels of the fullest row.
+
+    JAX differentiates no ``while_loop``, so the backward pass is written
+    out: the first window's is what ``jax.vjp`` makes of it (the same
+    program as autodiff of the whole-row head, on a quarter of the rows,
+    so a compiler that shards the batch places the same collectives), and
+    a further window is differentiated inside the backward pass's own
+    loop, its forward pass re-run there, so no further window's logits
+    outlive it. ``others`` is reported, not differentiated.
+
+    Returns :class:`HeadSums`."""
+    plan = _plan(targets, width)
+    one = functools.partial(_one, window, plan, operands, hidden)
+    return HeadSums(*_further(plan, one(0), one),
+                    plan[2].astype(jnp.float32))
+
+
+def _window_vjp(window: Callable, plan, operands, hidden, w):
+    """``jax.vjp`` of window ``w``'s loss sum in ``operands`` and
+    ``hidden``: ``(loss_sum, back, others)``. JAX writes ``jvp(...)``
+    around the first scope it meets under ``jax.vjp``: this one, so that
+    the operations inside keep the ``head`` they carry in every other
+    pass (a trace folds to it)."""
+    def named(ops, rows_of):
+        with jax.named_scope("window"):
+            return _one(window, plan, ops, rows_of, w)
+
+    return jax.vjp(named, operands, hidden, has_aux=True)
+
+
+def _head_in_windows_fwd(window, width, operands, hidden, targets):
+    plan = _plan(targets, width)
+    loss_sum, back, others = _window_vjp(window, plan, operands, hidden, 0)
+    sums = _further(plan, (loss_sum, others),
+                    functools.partial(_one, window, plan, operands, hidden))
+    return (HeadSums(*sums, plan[2].astype(jnp.float32)),
+            (back, plan, operands, hidden))
+
+
+def _head_in_windows_bwd(window, width, res, g):
+    back, plan, operands, hidden = res
+
+    def grads(w):
+        # Behind a barrier, so that a compiler that shards the batch
+        # reduces a further window's parameter gradients where they are
+        # made, inside the loop: XLA otherwise moves that all-reduce
+        # behind the loop, where every step pays for it, the loop run or
+        # not (94 MB of float32 at BERT-base's vocabulary).
+        return jax.lax.optimization_barrier(
+            _window_vjp(window, plan, operands, hidden, w)[1](g.loss_sum))
+
+    return (*_further(plan, back(g.loss_sum), grads), None)
+
+
+head_in_windows.defvjp(_head_in_windows_fwd, _head_in_windows_bwd)
+
+
 # ---------------------------------------------------------------------------
 # Autoregressive decode path (serve/decode.py, docs/SERVING.md
 # "Autoregressive decode").
@@ -431,7 +599,12 @@ class BertForMLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, segment_ids=None,
-                 *, train: bool = True):
+                 *, train: bool = True,
+                 labelled: LabelledWindows | None = None):
+        """Logits at every position (B, S, V); or, given ``labelled`` (the
+        ``mlm`` training step does), in their place the :class:`HeadSums`
+        of :func:`head_in_windows`: the head and the sums it feeds run on
+        the labelled positions only."""
         position_ids = None
         if segment_ids is not None:
             # Positions restart at every segment boundary: each packed
@@ -489,8 +662,28 @@ class BertForMLM(nn.Module):
                 drop_total = drop_total + aux["drop_frac"]
                 n_moe += 1
 
-        logits = MLMHead(self.vocab_size, self.hidden_size, self.dtype,
-                         name="head")(x, emb_table)
+        head = MLMHead(self.vocab_size, self.hidden_size, self.dtype,
+                       name="head")
+        if labelled is None:
+            logits = head(x, emb_table)
+        else:
+            # The windows run under lax control flow, where a bound module
+            # cannot be called: the same head, applied as a function of
+            # its parameters.
+            free = head.clone(parent=None)
+
+            def window(operands, rows, targets):
+                params, table = operands
+                out = free.apply({"params": params}, rows, table)
+                with jax.named_scope("head"):
+                    return labelled.sums(out, targets)
+
+            # The rows are taken in the head's compute dtype: its first
+            # projection casts them anyway, and ``x`` leaves the last
+            # LayerNorm in float32.
+            logits = head_in_windows(
+                window, labelled.width, (head.variables["params"], emb_table),
+                x.astype(self.dtype), labelled.targets)
         if self.num_experts > 0:
             out = {
                 "logits": logits,

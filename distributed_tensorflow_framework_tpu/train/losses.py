@@ -69,14 +69,11 @@ def mlm_metrics_sums(
     ``weight_sum`` counts masked tokens of real (weight-1) examples — the
     exact denominator for masked-LM loss/accuracy.
     """
-    logits = logits.astype(jnp.float32)
     mask = mlm_mask(targets) * weight.astype(jnp.float32)[:, None]
-    safe_targets = jnp.maximum(targets, 0)
-    losses = optax.softmax_cross_entropy_with_integer_labels(logits, safe_targets)
-    correct = (jnp.argmax(logits, axis=-1) == safe_targets).astype(jnp.float32)
+    loss_sum, correct_sum = mlm_sums(logits, targets, mask)
     return {
-        "loss_sum": (losses * mask).sum(),
-        "mlm_acc_sum": (correct * mask).sum(),
+        "loss_sum": loss_sum,
+        "mlm_acc_sum": correct_sum,
         "weight_sum": mask.sum(),
     }
 
@@ -100,11 +97,42 @@ def causal_lm_loss(
     return loss, {"loss": loss, "lm_acc": metrics["mlm_acc"]}
 
 
+def mlm_sums(
+    logits: jax.Array, targets: jax.Array, mask: jax.Array | None = None
+) -> tuple[jax.Array, jax.Array]:
+    """Masked-LM CE and top-1 hits, each SUMMED over the positions
+    ``mask`` weights (default: the labelled ones). Takes any leading
+    shape, so it serves all the positions of a batch and a window of them
+    alike (models/bert.head_in_windows); the callers own the denominator."""
+    logits = logits.astype(jnp.float32)
+    if mask is None:
+        mask = mlm_mask(targets)
+    safe_targets = jnp.maximum(targets, 0)
+    losses = optax.softmax_cross_entropy_with_integer_labels(logits, safe_targets)
+    correct = (jnp.argmax(logits, axis=-1) == safe_targets).astype(jnp.float32)
+    return (losses * mask).sum(), (correct * mask).sum()
+
+
+def mlm_loss_of_sums(
+    loss_sum: jax.Array, correct_sum: jax.Array, targets: jax.Array
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """The batch's masked-LM mean from :func:`mlm_sums` of its labelled
+    positions, however they were gathered: the denominator is the whole
+    batch's labelled count."""
+    denom = jnp.maximum(mlm_mask(targets).sum(), 1.0)
+    loss = loss_sum / denom
+    return loss, {"loss": loss, "mlm_acc": correct_sum / denom}
+
+
 def mlm_loss(
     logits: jax.Array, targets: jax.Array
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """Masked-LM CE. ``targets`` holds the original token at masked
-    positions and -1 elsewhere."""
+    """Masked-LM CE over whole rows of logits. ``targets`` holds the
+    original token at masked positions and -1 elsewhere. The sums and
+    the mean of :func:`mlm_sums` and :func:`mlm_loss_of_sums`, kept
+    operation for operation as it was: a step that puts whole rows
+    through it (``causal_lm``, a model without a windowed head) compiles
+    to the program it had."""
     logits = logits.astype(jnp.float32)
     mask = mlm_mask(targets)
     safe_targets = jnp.maximum(targets, 0)

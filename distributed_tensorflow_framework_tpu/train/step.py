@@ -19,6 +19,7 @@ models (tested in tests/test_train_lenet.py::test_jit_and_shard_map_agree).
 
 from __future__ import annotations
 
+import inspect
 from typing import Any, Callable
 
 import jax
@@ -30,6 +31,8 @@ from distributed_tensorflow_framework_tpu.core.config import ExperimentConfig
 from distributed_tensorflow_framework_tpu.core import prng
 from distributed_tensorflow_framework_tpu.core.mesh import batch_spec
 from distributed_tensorflow_framework_tpu.models import get_model
+from distributed_tensorflow_framework_tpu.models.bert import (
+    LabelledWindows, head_window)
 from distributed_tensorflow_framework_tpu.parallel import sharding as shd
 from distributed_tensorflow_framework_tpu.parallel import collectives as coll
 from distributed_tensorflow_framework_tpu.parallel import zero
@@ -482,10 +485,33 @@ class StepBuilder:
             return self._microbatch_grads(state, batch)
         return self._accumulated_grads(state, batch, accum)
 
+    def mlm_head_window(self, seq_len: int) -> int:
+        """Positions of a row the MLM head and its loss run on at a time
+        in the training step, from what the step can observe; ``seq_len``
+        is the whole row, today's program. That is what a ``causal_lm``
+        gets (nearly every position is labelled), a mesh that shards the
+        sequence (a gather along a sharded S would cross chips: no cell
+        runs one, so nobody has measured it) and a model whose forward
+        takes no ``labelled`` (the pipelined stack, a registered model).
+        Otherwise ``data.mask_prob`` sizes it (models/bert.head_window)."""
+        call = getattr(type(self.model), "__call__", None)
+        if (self.task != "mlm" or self.mesh.shape.get("seq", 1) > 1
+                or call is None
+                or "labelled" not in inspect.signature(call).parameters):
+            return seq_len
+        return head_window(seq_len, self.config.data.mask_prob)
+
     def _microbatch_grads(self, state: TrainState, batch: Any):
         step_rng = prng.fold_in_step(state.rng, state.step)
         has_bn = self._has_bn(state)
         inputs = model_inputs(self.task, batch)
+        head_on = {}
+        if self.task == "mlm":
+            seq_len = batch["targets"].shape[1]
+            width = self.mlm_head_window(seq_len)
+            if width < seq_len:
+                head_on["labelled"] = LabelledWindows(
+                    batch["targets"], width, losses.mlm_sums)
 
         def loss_fn(params):
             variables = {"params": params}
@@ -497,6 +523,7 @@ class StepBuilder:
                 train=True,
                 mutable=["batch_stats"] if has_bn else False,
                 rngs={"dropout": step_rng},
+                **head_on,
             )
             if has_bn:
                 logits, new_model_state = out
@@ -528,7 +555,16 @@ class StepBuilder:
                     # reading a collapse signature (docs/DISTRIBUTED.md).
                     moe_zloss = logits.get("moe_zloss")
                     logits = logits["logits"]
-                loss, metrics = losses.mlm_loss(logits, batch["targets"])
+                if head_on:
+                    # The head ran on the labelled positions only and
+                    # hands back their sums; the window counter rides the
+                    # same fetch as the loss, like the routers' counters.
+                    loss, metrics = losses.mlm_loss_of_sums(
+                        logits.loss_sum, logits.others, batch["targets"])
+                    metrics["mlm_head_windows"] = logits.windows
+                else:
+                    loss, metrics = losses.mlm_loss(logits,
+                                                    batch["targets"])
                 if moe_aux is not None:
                     loss = loss + self.config.train.moe_aux_weight * moe_aux
                     metrics["moe_aux_loss"] = moe_aux
