@@ -249,13 +249,15 @@ class ModelConfig:
     #                  tail — near-zero recompute flops for roughly half
     #                  the activation bytes. ResNet only.
     remat_policy: str = "full"
-    # Decoder family (models/lfm2.py; name "lfm2*"). Knobs it shares with
-    # the BERT family keep their names: vocab_size, hidden_size,
-    # num_layers, num_heads, mlp_dim (the dense SwiGLU width), num_experts
-    # (the router's width), expert_topk, attention_impl, remat.
-    # One mixer kind per layer, "conv" (gated short convolution) or
-    # "full_attention" (causal grouped-query attention); its length must
-    # be num_layers.
+    # Decoder family (models/lfm2.py; names "lfm2*" and "smallthinker*").
+    # Knobs it shares with the BERT family keep their names: vocab_size,
+    # hidden_size, num_layers, num_heads, mlp_dim (the dense SwiGLU
+    # width), num_experts (the router's width), expert_topk,
+    # attention_impl, remat.
+    # One mixer kind per layer: "conv" (gated short convolution),
+    # "full_attention" (causal grouped-query attention) or
+    # "sliding_attention" (the same inside a window of sliding_window
+    # keys); its length must be num_layers.
     layer_types: list[str] = field(default_factory=list)
     # Leading layers with a dense feed-forward; the rest carry experts.
     num_dense_layers: int = 0
@@ -271,6 +273,34 @@ class ModelConfig:
     # nothing stands in for the other groups. 1 group = the whole layer.
     expert_groups: int = 1
     expert_group: int = 0
+    # What tells the family's models apart; every default is LFM2's.
+    head_dim: int = 0           # 0 = hidden_size // num_heads
+    # Keys a query of a "sliding_attention" layer sees, its own included
+    # (i - j < sliding_window).
+    sliding_window: int = 0
+    # Per layer, 1 where an attention layer rotates its queries and keys
+    # (rotary positions) and 0 where it uses no positions at all; empty =
+    # every attention layer rotates.
+    rope_layout: list[int] = field(default_factory=list)
+    qk_norm: bool = True        # RMSNorm over each head of q and of k
+    tie_embeddings: bool = True  # false: an output matrix of its own
+    # Std of the token embedding's normal init. A router that reads the
+    # un-normed stream (router_input: stream) sees what the stream holds
+    # at init: under 0.02 that is attention's output, whose part common to
+    # every token passes each attention layer whole while the tokens' own
+    # parts average away, and the routing collapses onto a few experts
+    # (PERF.md §6, PR 30); unit-variance embeddings keep the tokens' own
+    # content on top.
+    embed_init_std: float = 0.02
+    # What the router reads: "ffn_norm" (the normed tensor the experts
+    # read) or "stream" (the residual stream as it enters the layer,
+    # before the mixer and its norm).
+    router_input: str = "ffn_norm"
+    # "sigmoid_bias": sigmoid scores, top-k of score + selection bias,
+    # weights normalised over the chosen; "softmax_topk": top-k of the
+    # logits, softmax over the chosen, no bias.
+    router_score: str = "sigmoid_bias"
+    expert_activation: str = "silu"   # silu (SwiGLU) | relu (ReGLU)
 
 
 @config_dataclass

@@ -40,9 +40,11 @@ def _is_builtin_model_name(name: str) -> bool:
 
 def _is_lfm2_name(name: str) -> bool:
     """The causal decoder family of models/lfm2.py: ``lfm2``,
-    ``lfm2_moe``, ``lfm2-8b-a1b`` — the whole ``lfm2`` prefix is
-    reserved. One test for get_model, the task and the decode refusal."""
-    return name.lower().startswith("lfm2")
+    ``lfm2_moe``, ``lfm2-8b-a1b``, ``smallthinker``,
+    ``smallthinker_moe`` — the whole ``lfm2`` and ``smallthinker``
+    prefixes are reserved (the models differ by ModelConfig settings, not
+    by class). One test for get_model, the task and the decode refusal."""
+    return name.lower().startswith(("lfm2", "smallthinker"))
 
 
 def builtin_task(name: str) -> str:

@@ -420,10 +420,11 @@ def decode_support_reason(model_config) -> str | None:
     parameter tree by name; trees it does not know must be refused by
     name rather than failing as a KeyError mid-stream."""
     name = model_config.name.lower()
-    if name.startswith("lfm2"):
+    if name.startswith(("lfm2", "smallthinker")):
         return (f"model {model_config.name!r} (the lfm2 decoder family) "
-                f"trains only: serving it needs a per-layer cache of two "
-                f"kinds (keys/values for its attention layers, the last "
+                f"trains only: serving it needs a per-layer cache of "
+                f"several kinds (keys/values for its attention layers, a "
+                f"window of them for its sliding layers, the last "
                 f"conv_kernel-1 gated inputs for its short convolutions) "
                 f"that serve/decode.py does not have")
     if name not in ("bert", "bert_base", "bert-base"):

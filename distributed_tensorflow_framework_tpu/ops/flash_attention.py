@@ -34,14 +34,24 @@ per-pair kernel times behind each line are in PERF.md §6, PRs 25 and
                        to S=1024, 256 at 2048, 128 at 4096
   s_k > MAX_SEQ_VMEM   streaming forward on 512×1024 tiles
   backward, always     streaming tile; fused where it fits VMEM (to
-                       FUSED_BWD_MAX keys of 2 bytes, half as many of 4)
-                       and its key tile is whole lanes, the two-pass
-                       pair beyond that and on a real TPU whose
-                       generation is off FUSED_BWD_VERIFIED_PLATFORMS
+                       FUSED_BWD_MAX keys of 64 dims and 2 bytes: half
+                       as many of 4 bytes, half as many of 128 dims) and
+                       its key tile is whole lanes, the two-pass pair
+                       beyond that and on a real TPU whose generation is
+                       off FUSED_BWD_VERIFIED_PLATFORMS
 
 The rule names no dtype: what float32 changes is the bytes a tile holds
-(verified and timed on v5e, PERF.md §6, PR 28). Ring attention over the
-``seq`` mesh axis composes on top for sharded sequences.
+(verified and timed on v5e, PERF.md §6, PR 28), and what a wider head
+changes is the bytes of the fused backward's full-length scratch (PR
+30). Ring attention over the ``seq`` mesh axis composes on top for
+sharded sequences.
+
+``causal`` masks from indices inside the kernels and skips (not masks)
+the blocks wholly above the diagonal or, on packed rows, outside the
+document; ``window`` on top of it also skips the blocks wholly behind
+the window (``_block_needed``; the index maps name a needed block for a
+skipped visit, so the pipeline fetches nothing for it). Without either
+a call traces the plain kernels, equation for equation.
 
 The kernels run in interpret mode off-TPU, under the selection a
 verified chip makes, so the CPU test mesh differentiates through the
@@ -100,6 +110,11 @@ MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
 # with them: float32 compiles for v5e to 6144 keys and overflows VMEM at
 # 7168, bf16 compiles at 8192 (PERF.md §6, PR 28).
 FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
+# The head size FUSED_BWD_MAX is stated for: 8192 keys of 64 dims are
+# 4 MiB of dk/dv scratch (keys × head dims × 4 B × 2), and a head twice
+# as wide holds half the keys in the same bytes (128-wide bf16 heads
+# compile for v5e at 4096 keys; PERF.md §6, PR 30).
+FUSED_BWD_HEAD_DIM = 64
 # TPU generations (substrings of device_kind, lowercased) with recorded
 # scripts/verify_flash_kernels.py results: v5e, where chip_smoke.py
 # re-runs that check on every smoke (the fused backward agrees with the
@@ -111,22 +126,33 @@ FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
 FUSED_BWD_VERIFIED_PLATFORMS = ("v5 lite", "v5e")
 
 
-def _causal_mask(s, row0, col0):
+def _causal_mask(s, row0, col0, window=None):
     """Scores of one (rows, cols) block with every key later than its
-    query masked: the mask comes from the block's place in the sequence
-    (``row0``/``col0``: its first row and column), never from HBM."""
+    query masked and, under ``window``, every key ``window`` or more
+    positions before it: the mask comes from the block's place in the
+    sequence (``row0``/``col0``: its first row and column), never from
+    HBM."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (rows - cols < window)
+    return jnp.where(keep, s, NEG_INF)
 
 
-def _block_needed(qi, ki, block_q: int, block_k: int, qs=None, ks=None):
+def _block_needed(qi, ki, block_q: int, block_k: int, qs=None, ks=None,
+                  window=None):
     """Whether a causal (q-block, k-block) pair holds any pair to
-    compute: not wholly above the diagonal and, on packed rows, with a
-    document in common (the two blocks' ranges of segment ids overlap; a
-    conservative test that needs no order in the ids). A block that is
-    not needed is skipped, not masked."""
+    compute: not wholly above the diagonal, under ``window`` not wholly
+    behind it (the block's last key within ``window`` of its first row)
+    and, on packed rows, with a document in common (the two blocks'
+    ranges of segment ids overlap; a conservative test that needs no
+    order in the ids). A block that is not needed is skipped, not
+    masked."""
     needed = ki * block_k <= qi * block_q + (block_q - 1)
+    if window is not None:
+        needed = needed & (ki * block_k + (block_k - 1)
+                           > qi * block_q - window)
     if qs is not None:
         needed = needed & (jnp.max(ks) >= jnp.min(qs)) \
             & (jnp.min(ks) <= jnp.max(qs))
@@ -134,7 +160,8 @@ def _block_needed(qi, ki, block_q: int, block_k: int, qs=None, ks=None):
 
 
 def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
-                     scale: float, segmented: bool, causal: bool = False):
+                     scale: float, segmented: bool, causal: bool = False,
+                     window=None):
     # Segment-id refs only exist in the segmented variant — the common
     # unsegmented path carries no extra operands (and no VMEM traffic).
     if segmented:
@@ -161,7 +188,7 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
         ks = kseg_ref[0, 0]                       # (S,)
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
     if causal:
-        s = _causal_mask(s, pl.program_id(2) * q.shape[0], 0)
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0], 0, window)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -174,7 +201,8 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
     lse_ref[0, 0] = (m + jnp.log(l)).astype(jnp.float32)
 
 
-def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs):
+def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs,
+             window=None):
     """Run one (q-block, k-block) visit's ``compute``: always without
     ``causal`` (the trace is then the plain kernel's), under
     ``_block_needed`` with it."""
@@ -185,11 +213,12 @@ def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs):
     if seg_refs:
         qs, ks = seg_refs[0][0, 0], seg_refs[1][0, 0]
     pl.when(_block_needed(qi, ki, q_ref.shape[2], k_ref.shape[2],
-                          qs, ks))(compute)
+                          qs, ks, window))(compute)
 
 
 def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
-                        scale: float, segmented: bool, causal: bool = False):
+                        scale: float, segmented: bool, causal: bool = False,
+                        window=None):
     """K-blocked forward: grid (B, H, nq, nk) with nk innermost/sequential.
 
     Running-softmax state (m, l, acc) persists in VMEM scratch across the
@@ -224,7 +253,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
             ks = kseg_ref[0, 0]                       # (BK,)
             s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
         if causal:
-            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0], window)
         m_prev = m_ref[...]                           # (BQ, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -237,7 +266,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         m_ref[...] = m_new
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else ())
+             (qseg_ref, kseg_ref) if segmented else (), window)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -247,7 +276,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                            scale: float, segmented: bool,
-                           causal: bool = False):
+                           causal: bool = False, window=None):
     """K-blocked dQ: accumulate ds·k over streamed K/V tiles in scratch."""
     if segmented:
         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
@@ -276,7 +305,7 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
             ks = kseg_ref[0, 0]
             s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
         if causal:
-            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0], window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -289,7 +318,7 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         ) * scale
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else ())
+             (qseg_ref, kseg_ref) if segmented else (), window)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -298,7 +327,7 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                             scale: float, segmented: bool,
-                            causal: bool = False):
+                            causal: bool = False, window=None):
     """K-blocked dK/dV/dbias: grid (B, H, nk, nq) with the q-axis
     innermost/sequential; Q/dO stream through in block_q tiles while the
     (dk, dv, dbias) accumulators for one k-block live in scratch."""
@@ -333,7 +362,7 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
             ks = kseg_ref[0, 0]
             s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
         if causal:
-            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0])
+            s = _causal_mask(s, qi * q.shape[0], ki * k.shape[0], window)
         p = jnp.exp(s - lse)
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -351,7 +380,7 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         db_acc[...] = db_acc[...] + jnp.sum(ds, axis=0, keepdims=True)
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else ())
+             (qseg_ref, kseg_ref) if segmented else (), window)
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _finalize():
@@ -362,7 +391,7 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                               scale: float, segmented: bool,
-                              causal: bool = False):
+                              causal: bool = False, window=None):
     """Fused one-pass streaming backward: grid (B, H, nq, nk), BOTH inner
     axes sequential ("arbitrary"). Each (q-block, k-block) pair is
     visited once; its probability block is exp'd ONCE and feeds all four
@@ -428,7 +457,7 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
             ks = kseg_ref[0, 0]
             s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
         if causal:
-            s = _causal_mask(s, qi * q.shape[0], ki * bk)
+            s = _causal_mask(s, qi * q.shape[0], ki * bk, window)
         p = jnp.exp(s - lse)                          # the ONE exp per pair
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -452,7 +481,7 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         db_full[:, sl] = db_full[:, sl] + jnp.sum(ds, axis=0, keepdims=True)
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else ())
+             (qseg_ref, kseg_ref) if segmented else (), window)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize_dq():
@@ -540,9 +569,11 @@ class FlashDispatch(NamedTuple):
     bwd_block_k: int
 
 
-def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
-    """The one place a (q length, k length, input dtype) becomes kernels
-    and tiles; the platform enters through ``fused_bwd_enabled()``.
+def select_dispatch(s: int, s_k: int, dtype,
+                    head_dim: int = FUSED_BWD_HEAD_DIM) -> FlashDispatch:
+    """The one place a (q length, k length, input dtype, head size)
+    becomes kernels and tiles; the platform enters through
+    ``fused_bwd_enabled()``.
     Called at the custom_vjp layer, outside the jitted wrappers, so the
     module globals it reads are never frozen into a trace cache: the
     wrappers take the result as a static argument.
@@ -558,13 +589,18 @@ def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
         rows = BLOCK_Q * MAX_SEQ_VMEM // s_k
         forward = ("whole_k",
                    _pick_block(s, min(max(rows, BLOCK_Q), BLOCK_Q_KB)), s_k)
-    # The fused backward where it is allowed, fits VMEM (FUSED_BWD_MAX,
-    # in bytes of keys) and can slice its full-length dbias accumulator
-    # by whole lanes: on a key tile under BLOCK_Q Mosaic refuses the
-    # kernel ("cannot statically prove that index in dimension 1 is a
-    # multiple of 128"), and the two-pass pair is what runs such inputs.
+    # The fused backward where it is allowed, fits VMEM and can slice its
+    # full-length dbias accumulator by whole lanes: on a key tile under
+    # BLOCK_Q Mosaic refuses the kernel ("cannot statically prove that
+    # index in dimension 1 is a multiple of 128"), and the two-pass pair
+    # is what runs such inputs. What has to fit is the full-length dk/dv
+    # scratch, keys × head dims × 4 B × 2, beside tiles that grow with
+    # the head size and the input's bytes alike: the gate holds keys ×
+    # head dims × itemsize to what FUSED_BWD_MAX keys of
+    # FUSED_BWD_HEAD_DIM dims and 2 bytes come to.
     fused = (fused_bwd_enabled()
-             and s_k * jnp.dtype(dtype).itemsize <= 2 * FUSED_BWD_MAX
+             and s_k * head_dim * jnp.dtype(dtype).itemsize
+             <= FUSED_BWD_MAX * FUSED_BWD_HEAD_DIM * 2
              and stream_tile[1] % BLOCK_Q == 0)
     return FlashDispatch(*forward, "fused" if fused else "two_pass",
                          *stream_tile)
@@ -575,29 +611,32 @@ def select_dispatch(s: int, s_k: int, dtype) -> FlashDispatch:
 _dispatch_log: dict = {}
 
 
-def _dispatch(q, k, segmented: bool, causal: bool = False) -> FlashDispatch:
-    s, s_k = q.shape[2], k.shape[2]
-    dispatch = select_dispatch(s, s_k, q.dtype)
+def _dispatch(q, k, segmented: bool, causal: bool = False,
+              window=None) -> FlashDispatch:
+    s, s_k, d = q.shape[2], k.shape[2], q.shape[3]
+    dispatch = select_dispatch(s, s_k, q.dtype, d)
     _dispatch_log[(s, s_k, jnp.dtype(q.dtype).name, segmented, causal,
-                   q.shape[1], k.shape[1])] = dispatch
+                   q.shape[1], k.shape[1], d, window or 0)] = dispatch
     return dispatch
 
 
 def dispatch_log() -> list[dict]:
-    """Every distinct (s, s_k, dtype, segmented, causal, heads, kv_heads)
-    traced so far with the kernels and tiles it was given — the run-meta
-    record's ``flash_dispatch`` (train/loop.py), so a run says which
-    attention kernels its shapes selected without a trace."""
+    """Every distinct (s, s_k, dtype, segmented, causal, heads, kv_heads,
+    head_dim, window) traced so far with the kernels and tiles it was
+    given — the run-meta record's ``flash_dispatch`` (train/loop.py), so
+    a run says which attention kernels its shapes selected without a
+    trace. ``window`` is None for a call without one."""
     return [
         dict(s=s, s_k=s_k, dtype=dtype, segmented=segmented, causal=causal,
-             heads=heads, kv_heads=kv_heads, **dispatch._asdict())
-        for (s, s_k, dtype, segmented, causal, heads, kv_heads), dispatch
-        in sorted(_dispatch_log.items())
+             heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+             window=window or None, **dispatch._asdict())
+        for (s, s_k, dtype, segmented, causal, heads, kv_heads, head_dim,
+             window), dispatch in sorted(_dispatch_log.items())
     ]
 
 
 def _make_fused(segmented: bool, return_lse: bool,
-                causal: bool = False):
+                causal: bool = False, window=None):
     """Build the custom-VJP fused attention for one (segmented, lse)
     variant. Unsegmented signature: (q, k, v, bias) — the common path
     carries NO segment operands or VMEM traffic. Segmented adds
@@ -609,22 +648,28 @@ def _make_fused(segmented: bool, return_lse: bool,
     saved. ``causal`` masks every key later than its query, from indices
     inside the kernels; k and v may carry fewer heads than q (a whole
     divisor: grouped-query attention), reached through the block index
-    maps and never repeated in memory.
+    maps and never repeated in memory. ``window`` (with ``causal``) also
+    masks every key ``window`` or more positions before its query.
     """
+    # Without a window the wrappers are called as they always were, so
+    # their traces (and the jit caches' keys) are the parent's.
+    win = {} if window is None else {"window": window}
     if segmented:
         @jax.custom_vjp
         def fused(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True, causal),
-                                causal=causal)
+                                dispatch=_dispatch(q, k, True, causal,
+                                                   window),
+                                causal=causal, **win)
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True, causal),
-                                causal=causal)
+                                dispatch=_dispatch(q, k, True, causal,
+                                                   window),
+                                causal=causal, **win)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, qseg, kseg, o, lse)
 
@@ -634,8 +679,8 @@ def _make_fused(segmented: bool, return_lse: bool,
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, qseg, kseg, o, lse, do, dlse=dlse,
                 segmented=True, interpret=_interpret(),
-                dispatch=_dispatch(q, k, True, causal),
-                                causal=causal)
+                dispatch=_dispatch(q, k, True, causal, window),
+                causal=causal, **win)
             return (dq, dk, dv, dbias,
                     jnp.zeros_like(qseg), jnp.zeros_like(kseg))
     else:
@@ -643,15 +688,17 @@ def _make_fused(segmented: bool, return_lse: bool,
         def fused(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False, causal),
-                                causal=causal)
+                                dispatch=_dispatch(q, k, False, causal,
+                                                   window),
+                                causal=causal, **win)
             return (o, lse) if return_lse else o
 
         def fwd(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False, causal),
-                                causal=causal)
+                                dispatch=_dispatch(q, k, False, causal,
+                                                   window),
+                                causal=causal, **win)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, o, lse)
 
@@ -661,8 +708,8 @@ def _make_fused(segmented: bool, return_lse: bool,
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, o, lse, do, dlse=dlse,
                 segmented=False, interpret=_interpret(),
-                dispatch=_dispatch(q, k, False, causal),
-                                causal=causal)
+                dispatch=_dispatch(q, k, False, causal, window),
+                causal=causal, **win)
             return dq, dk, dv, dbias
 
     fused.defvjp(fwd, bwd)
@@ -673,6 +720,12 @@ _FUSED = {(seg, lse): _make_fused(seg, lse)
           for seg in (False, True) for lse in (False, True)}
 _FUSED_CAUSAL = {seg: _make_fused(seg, False, causal=True)
                  for seg in (False, True)}
+
+
+@functools.cache
+def _fused_window(segmented: bool, window: int):
+    """The causal variant with a window, one per distinct window."""
+    return _make_fused(segmented, False, causal=True, window=window)
 
 
 def chunk_supported(s: int) -> bool:
@@ -730,10 +783,10 @@ def flash_attention_chunk(q, k, v, bias, q_seg=None, kv_seg=None):
 
 @functools.partial(jax.jit,
                    static_argnames=("segmented", "interpret", "dispatch",
-                                    "causal"))
+                                    "causal", "window"))
 def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
                interpret: bool, dispatch: FlashDispatch,
-               causal: bool = False):
+               causal: bool = False, window=None):
     b, h, s, d = q.shape
     kv_head = _kv_head_map(h, k.shape[1])
     s_k = k.shape[2]
@@ -743,7 +796,7 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         return _flash_fwd_kb(q, k, v, bias, qseg, kseg,
                              segmented=segmented, interpret=interpret,
                              block_q=block_q, block_k=dispatch.block_k,
-                             causal=causal)
+                             causal=causal, window=window)
     grid = (b, h, s // block_q)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -762,7 +815,7 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         operands += [qseg, kseg]
     return pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, segmented=segmented,
-                          causal=causal),
+                          causal=causal, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
@@ -790,23 +843,50 @@ def _kv_head_map(heads: int, kv_heads: int):
     return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
 
 
-def _last_k_block(causal: bool, block_q: int, block_k: int):
+def _last_k_block(causal: bool, block_q: int, block_k: int, window=None):
     """``(qi, ki) -> k-block to fetch``: under ``causal`` a visit above
-    the diagonal names the last block the row block needs, so the
-    pipeline fetches nothing new for a visit the kernel skips."""
+    the diagonal names the last block the row block needs and, under
+    ``window``, a visit behind the window the first, so the pipeline
+    fetches nothing new for a visit the kernel skips."""
     if not causal:
         return lambda qi, ki: ki
-    return lambda qi, ki: jnp.minimum(
-        ki, (qi * block_q + (block_q - 1)) // block_k)
+    if window is None:
+        return lambda qi, ki: jnp.minimum(
+            ki, (qi * block_q + (block_q - 1)) // block_k)
+    return lambda qi, ki: jnp.clip(
+        ki, jnp.maximum(qi * block_q - (window - 1), 0) // block_k,
+        (qi * block_q + (block_q - 1)) // block_k)
 
 
-def _first_q_block(causal: bool, block_q: int, block_k: int):
+def _first_q_block(causal: bool, block_q: int, block_k: int, window=None,
+                   n_q: int = 0):
     """``(ki, qi) -> q-block to fetch`` for the dk/dv kernel, whose
     q-axis is the sequential one: row blocks wholly before a key block
-    are skipped, so they name the first one needed."""
+    are skipped, so they name the first one needed; under ``window`` the
+    row blocks wholly past it name the last one needed (of ``n_q``)."""
     if not causal:
         return lambda ki, qi: qi
-    return lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
+    if window is None:
+        return lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
+    return lambda ki, qi: jnp.clip(
+        qi, (ki * block_k) // block_q,
+        jnp.minimum((ki * block_k + (block_k - 1) + (window - 1)) // block_q,
+                    n_q - 1))
+
+
+def window_block_counts(s: int, s_k: int, block_q: int, block_k: int,
+                        window: int) -> tuple[int, int]:
+    """``(visited, causal)``: how many (q-block, k-block) visits of a
+    causal call on these tiles hold a pair inside ``window``, and how
+    many hold a pair at all (``_block_needed`` without segments, counted
+    in Python: the window layers' ``attn_window_block_share``)."""
+    visited = causal = 0
+    for q0 in range(0, s, block_q):
+        for k0 in range(0, s_k, block_k):
+            if k0 <= q0 + block_q - 1:
+                causal += 1
+                visited += k0 + block_k - 1 > q0 - window
+    return visited, causal
 
 
 def _sum_kv_groups(dk, dv, kv_heads: int, dtype):
@@ -861,7 +941,7 @@ def _kb_params(interpret: bool, n_parallel: int = 3):
 
 def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
                   interpret: bool, block_q: int, block_k: int,
-                  causal: bool = False):
+                  causal: bool = False, window=None):
     """Streaming forward: sequential k-axis grid + VMEM-scratch running
     softmax (kernel docstring)."""
     b, h, s, d = q.shape
@@ -869,7 +949,7 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
     scale = 1.0 / (d ** 0.5)
     grid = (b, h, s // block_q, s_k // block_k)
     kv_head = _kv_head_map(h, k.shape[1])
-    k_blk = _last_k_block(causal, block_q, block_k)
+    k_blk = _last_k_block(causal, block_q, block_k, window)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -890,7 +970,7 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
         operands += [qseg, kseg]
     return pl.pallas_call(
         functools.partial(_attn_fwd_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal),
+                          segmented=segmented, causal=causal, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
@@ -915,10 +995,10 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
 
 @functools.partial(jax.jit,
                    static_argnames=("segmented", "interpret", "dispatch",
-                                    "causal"))
+                                    "causal", "window"))
 def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
                interpret: bool, dispatch: FlashDispatch, dlse=None,
-               causal: bool = False):
+               causal: bool = False, window=None):
     if segmented:
         qseg, kseg, o, lse, do = seg_then_rest
     else:
@@ -938,12 +1018,12 @@ def _flash_bwd(q, k, v, bias, *seg_then_rest, segmented: bool,
     return stream(q, k, v, bias, qseg, kseg, lse, do, delta,
                   segmented=segmented, interpret=interpret,
                   block_q=dispatch.bwd_block_q, block_k=dispatch.bwd_block_k,
-                  causal=causal)
+                  causal=causal, window=window)
 
 
 def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                   segmented: bool, interpret: bool, block_q: int,
-                  block_k: int, causal: bool = False):
+                  block_k: int, causal: bool = False, window=None):
     """Two-pass streaming backward: dQ accumulates over a sequential
     k-axis, dK/dV/dbias over a sequential q-axis; no whole-sequence
     operand in VMEM (kernel docstrings)."""
@@ -953,8 +1033,8 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     kv_heads = k.shape[1]
     kv_head = _kv_head_map(h, kv_heads)
     dkv_dtype = k.dtype if h == kv_heads else jnp.float32
-    k_blk = _last_k_block(causal, block_q, block_k)
-    q_blk = _first_q_block(causal, block_q, block_k)
+    k_blk = _last_k_block(causal, block_q, block_k, window)
+    q_blk = _first_q_block(causal, block_q, block_k, window, s // block_q)
 
     seg_operands = [qseg, kseg] if segmented else []
     dq_seg_specs = [
@@ -964,7 +1044,7 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     ] if segmented else []
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal),
+                          segmented=segmented, causal=causal, window=window),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         grid=(b, h, s // block_q, s_k // block_k),
         in_specs=[
@@ -999,7 +1079,7 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     ] if segmented else []
     dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal),
+                          segmented=segmented, causal=causal, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
@@ -1045,7 +1125,7 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
 
 def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                         segmented: bool, interpret: bool, block_q: int,
-                        block_k: int, causal: bool = False):
+                        block_k: int, causal: bool = False, window=None):
     """One-pass streaming backward (kernel docstring): one grid, one exp
     per (q-block, k-block) pair, full-length dk/dv VMEM accumulators —
     gated to what fits by ``select_dispatch``."""
@@ -1055,7 +1135,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     kv_heads = k.shape[1]
     kv_head = _kv_head_map(h, kv_heads)
     dkv_dtype = k.dtype if h == kv_heads else jnp.float32
-    k_blk = _last_k_block(causal, block_q, block_k)
+    k_blk = _last_k_block(causal, block_q, block_k, window)
 
     seg_operands = [qseg, kseg] if segmented else []
     seg_specs = [
@@ -1065,7 +1145,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     ] if segmented else []
     dq, dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_fused_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal),
+                          segmented=segmented, causal=causal, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
@@ -1117,7 +1197,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
 
 
 def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
-                    causal: bool = False):
+                    causal: bool = False, window: int | None = None):
     """Fused attention. q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv a
     divisor of H (grouped-query attention: each key/value head serves
     H/H_kv query heads, reached through the kernels' block index maps); mask: (B,1,1,S) bool or None;
@@ -1127,6 +1207,11 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
     ``causal``: a query sees no later key; the mask comes from indices
     inside the kernels, and the streaming kernels skip (not mask) blocks
     wholly above the diagonal or, on packed rows, outside the document.
+    ``window`` (an integer ``W``, with ``causal``): a query at ``i`` sees
+    a key at ``j`` only if ``i - j < W``, on top of ``causal`` and the
+    segments; blocks wholly behind the window are skipped like the ones
+    above the diagonal, the edge blocks masked from indices. ``None`` is
+    no window, and traces the programs it always did.
 
     ``mesh``: the physical mesh when the caller is global-view (``jit``)
     code over more than one device. A Mosaic kernel has no partitioning
@@ -1146,7 +1231,10 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
     if (mesh is not None and mesh.size > 1
             and not jax.sharding.get_abstract_mesh().manual_axes):
         return _flash_attention_sharded(q, k, v, mask, segment_ids, mesh,
-                                        causal)
+                                        causal, window)
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and at least one key")
     b, s, hh, d = q.shape
     if s % min(BLOCK_Q, s):
         raise ValueError(f"seq len {s} must be a multiple of {BLOCK_Q}")
@@ -1159,8 +1247,11 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
     else:
         bias = jnp.zeros((b, 1, s), jnp.float32)
     segmented = segment_ids is not None
-    fused = (_FUSED_CAUSAL[segmented] if causal
-             else _FUSED[(segmented, False)])
+    if window is not None:
+        fused = _fused_window(segmented, int(window))
+    else:
+        fused = (_FUSED_CAUSAL[segmented] if causal
+                 else _FUSED[(segmented, False)])
     if segmented:
         seg = _seg_f32(segment_ids)
         out = fused(qt, kt, vt, bias, seg, seg)
@@ -1170,7 +1261,7 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
 
 
 def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh,
-                             causal: bool = False):
+                             causal: bool = False, window=None):
     """``flash_attention`` per device under a ``shard_map`` over all of
     ``mesh`` (see its ``mesh`` argument)."""
     from jax.sharding import PartitionSpec as P
@@ -1190,7 +1281,7 @@ def _flash_attention_sharded(q, k, v, mask, segment_ids, mesh,
                if pair[0] is not None}
 
     def per_device(q, k, v, *rest):
-        return flash_attention(q, k, v, causal=causal,
+        return flash_attention(q, k, v, causal=causal, window=window,
                                **dict(zip(present, rest)))
 
     return jax.shard_map(

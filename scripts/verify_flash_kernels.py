@@ -39,6 +39,18 @@ unsegmented:
                                               index maps, blocks above the diagonal
                                               or outside the document skipped;
                                               8192 is ``lfm2_moe_s8192``'s call
+  window_d128_s4096   4096  bf16   as chosen  ``window=`` on top of that, heads of
+  window_d128_s8192   8192  bf16   as chosen  128 dims, seven query heads a
+  window_d128_s8192_fused  8192    fused      key/value head: a window of 1024 at
+  window_d128_s16384  16384 bf16   as chosen  4096 keys (whole-K forward, fused
+                                              backward), of 4096 at 8192 and
+                                              16,384 (streaming kernels, blocks
+                                              behind the window skipped; the
+                                              two-pass pair by the scratch gate,
+                                              and at 8192 the fused backward too,
+                                              its gate raised for the case);
+                                              16,384 is ``smallthinker_s16384``'s
+                                              window layers' call
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -136,10 +148,27 @@ def _causal_cases() -> dict:
     }
 
 
-def _inputs(seq: int, kv_heads: int, dtype):
+# The window cases' shapes: ``smallthinker_s16384``'s head size and group.
+WINDOW_HEADS, WINDOW_KV_HEADS, WINDOW_D = 14, 2, 128
+
+
+def _window_cases() -> dict:
+    """name -> (seq, fused allowed, dtype, window, FUSED_BWD_MAX for the
+    case or None for the module's)."""
+    vmem, bf16 = fa.MAX_SEQ_VMEM, jnp.bfloat16
+    return {
+        "window_d128_s4096": (vmem, None, bf16, vmem // 4, None),
+        "window_d128_s8192": (2 * vmem, None, bf16, vmem, None),
+        "window_d128_s8192_fused": (2 * vmem, True, bf16, vmem,
+                                    4 * fa.FUSED_BWD_MAX),
+        "window_d128_s16384": (4 * vmem, None, bf16, vmem, None),
+    }
+
+
+def _inputs(seq: int, kv_heads: int, dtype, heads: int = H, d: int = D):
     kq, kk, kv = jax.random.split(jax.random.key(seq), 3)
-    q, k, v = (jax.random.normal(r, (B, seq, heads, D), dtype)
-               for r, heads in ((kq, H), (kk, kv_heads), (kv, kv_heads)))
+    q, k, v = (jax.random.normal(r, (B, seq, n, d), dtype)
+               for r, n in ((kq, heads), (kk, kv_heads), (kv, kv_heads)))
     # Four packed documents of unequal length per row.
     cuts = np.array([0.15, 0.4, 0.8]) * seq
     seg = np.searchsorted(cuts, np.arange(seq), side="right") + 1
@@ -150,14 +179,15 @@ def _loss_and_out(out):
     return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
 
 
-def _kernel_fn(segmented: bool, causal: bool = False):
+def _kernel_fn(segmented: bool, causal: bool = False, window=None):
     def loss(q, k, v, seg):
         return _loss_and_out(fa.flash_attention(
-            q, k, v, segment_ids=seg if segmented else None, causal=causal))
+            q, k, v, segment_ids=seg if segmented else None, causal=causal,
+            window=window))
     return loss
 
 
-def _reference_fn(segmented: bool, causal: bool = False):
+def _reference_fn(segmented: bool, causal: bool = False, window=None):
     """float32 attention, one (batch, head) at a time under ``lax.map`` —
     an (S, S) score block per step, never (B, H, S, S). Grouped key/value
     heads are repeated for their query heads (the gradient sums back)."""
@@ -169,6 +199,9 @@ def _reference_fn(segmented: bool, causal: bool = False):
         if causal:
             at = jnp.arange(s.shape[0])
             s = jnp.where(at[:, None] >= at[None, :], s, fa.NEG_INF)
+            if window is not None:
+                s = jnp.where(at[:, None] - at[None, :] < window, s,
+                              fa.NEG_INF)
         return jax.nn.softmax(s, axis=-1) @ v
 
     def loss(q, k, v, seg):
@@ -178,7 +211,9 @@ def _reference_fn(segmented: bool, causal: bool = False):
         qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
                       .reshape(b * h, s, d) for t in (q, k, v))
         segf = jnp.repeat(seg, h, axis=0)         # (B*H, S)
-        out = jax.lax.map(one_head, (qf, kf, vf, segf))
+        # Under jax.checkpoint: the backward keeps no (S, S) block of a
+        # head but the one it works on (14 of them are 15 GB at 16,384).
+        out = jax.lax.map(jax.checkpoint(one_head), (qf, kf, vf, segf))
         # Output in the kernels' dtype, so the loss sees the same values.
         out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
         return _loss_and_out(out.astype(q.dtype))
@@ -201,23 +236,28 @@ def _rel_l2(a, b) -> float:
 
 
 def run_case(name: str, seq: int, setting: bool | None, dtype,
-             two_pass_cache: dict, causal: bool = False) -> dict:
-    args = _inputs(seq, H // KV_GROUP if causal else H, dtype)
+             two_pass_cache: dict, causal: bool = False, window=None) -> dict:
+    if window is None:
+        args = _inputs(seq, H // KV_GROUP if causal else H, dtype)
+    else:
+        args = _inputs(seq, WINDOW_KV_HEADS, dtype, WINDOW_HEADS, WINDOW_D)
+    head_dim = int(args[0].shape[-1])
     _allow_fused(setting)
-    dispatch = fa.select_dispatch(seq, seq, dtype)
+    dispatch = fa.select_dispatch(seq, seq, dtype, head_dim)
     fused = dispatch.backward == "fused"
     dtype_name = jnp.dtype(dtype).name
     rec = {"case": name, "seq": seq, "dtype": dtype_name,
-           "fused_bwd": fused, "causal": causal,
-           "kv_heads": int(args[1].shape[2]),
+           "fused_bwd": fused, "causal": causal, "window": window,
+           "head_dim": head_dim, "kv_heads": int(args[1].shape[2]),
            "dispatch": dispatch._asdict(), "variants": {}}
+    key = (seq, dtype_name, causal, window, head_dim)
     ok = True
     for segmented in (False, True):
         _allow_fused(setting)
         # Fresh outer trace per setting: the fused decision is read at
         # the custom_vjp layer, outside the inner jit's cache.
-        got, mosaic_calls = _run(_kernel_fn(segmented, causal), args)
-        want, _ = _run(_reference_fn(segmented, causal), args)
+        got, mosaic_calls = _run(_kernel_fn(segmented, causal, window), args)
+        want, _ = _run(_reference_fn(segmented, causal, window), args)
         stats = {
             "mosaic_calls": mosaic_calls,
             "finite": bool(all(np.isfinite(t).all() for t in got)),
@@ -228,16 +268,16 @@ def run_case(name: str, seq: int, setting: bool | None, dtype,
             v for k, v in stats.items() if k.endswith("_vs_reference")
         ) <= GATE_VS_REFERENCE
         if fused:
-            two_pass = two_pass_cache.get(
-                (seq, dtype_name, segmented, causal))
+            two_pass = two_pass_cache.get((*key, segmented))
             if two_pass is None:
                 _allow_fused(False)
-                two_pass, _ = _run(_kernel_fn(segmented, causal), args)
+                two_pass, _ = _run(_kernel_fn(segmented, causal, window),
+                                   args)
             diff = max(_rel_l2(g, t) for g, t in zip(got, two_pass))
             stats["rel_l2_vs_two_pass"] = diff
             good = good and diff <= GATE_FUSED_VS_TWO_PASS
         else:
-            two_pass_cache[(seq, dtype_name, segmented, causal)] = got
+            two_pass_cache[(*key, segmented)] = got
         stats["ok"] = bool(good)
         ok = ok and good
         rec["variants"]["segmented" if segmented else "unsegmented"] = stats
@@ -250,8 +290,8 @@ def run_case(name: str, seq: int, setting: bool | None, dtype,
 
 
 def main(argv) -> int:
-    causal_cases = _causal_cases()
-    cases = {**_cases(), **causal_cases}
+    causal_cases, window_cases = _causal_cases(), _window_cases()
+    cases = {**_cases(), **causal_cases, **window_cases}
     unknown = [a for a in argv if a not in cases]
     if unknown:
         print(f"unknown case(s) {unknown}; known: {sorted(cases)}",
@@ -267,9 +307,21 @@ def main(argv) -> int:
           f"{fa.kernel_mode()} mode, B={B} H={H} D={D}; default bf16 "
           f"backward at seq {stream_seq}: {streaming_default}", flush=True)
     two_pass_cache: dict = {}
-    results = [run_case(name, *cases[name], two_pass_cache,
-                        causal=name in causal_cases)
-               for name in selected]
+    results = []
+    for name in selected:
+        if name in window_cases:
+            seq, setting, dtype, window, fused_max = window_cases[name]
+            module_max = fa.FUSED_BWD_MAX
+            fa.FUSED_BWD_MAX = fused_max or module_max
+            try:
+                results.append(run_case(name, seq, setting, dtype,
+                                        two_pass_cache, causal=True,
+                                        window=window))
+            finally:
+                fa.FUSED_BWD_MAX = module_max
+        else:
+            results.append(run_case(name, *cases[name], two_pass_cache,
+                                    causal=name in causal_cases))
     ok = all(r["ok"] for r in results)
     if not ok:
         print("FLASH KERNEL MISMATCH — do not trust these kernels on this "
