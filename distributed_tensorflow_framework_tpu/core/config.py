@@ -238,7 +238,11 @@ class ModelConfig:
     # (jax.checkpoint via nn.remat): trades ~30% more FLOPs for O(layers)
     # less activation memory — the lever for long-context / big-model
     # fits. Supported for the bert models (numerics parity tested); other
-    # model families reject it rather than silently ignore it.
+    # model families reject it rather than silently ignore it. A decoder
+    # layer (models/lfm2.py) keeps, beside its input, its attention
+    # kernels' output and logsumexp (O(S·D) a layer): the re-run forward
+    # pass recomputes everything but the Mosaic forward;
+    # precision.remat_policy: save_nothing is the full re-run.
     remat: bool = False
     # What the remat blocks may keep from the forward pass:
     #   "full"       — save nothing; replay the whole block (max memory
@@ -602,12 +606,15 @@ class PrecisionConfig:
     # Selective rematerialization policy mapped onto
     # jax.checkpoint_policies for the remat-capable models and the
     # pipeline stages:
-    #   "none"          — defer to model.remat/model.remat_policy;
+    #   "none"          — defer to model.remat/model.remat_policy (a
+    #                     decoder layer then keeps its attention
+    #                     kernels' output and logsumexp, no more);
     #   "dots_saveable" — save matmul outputs, replay the cheap
     #                     elementwise tail (recompute ≈ free, roughly
     #                     half the activation bytes);
-    #   "save_nothing"  — save only block inputs, replay everything
-    #                     (max memory savings, max recompute — the
+    #   "save_nothing"  — save only block inputs, replay everything,
+    #                     the attention kernels' forward included (max
+    #                     memory savings, max recompute — the
     #                     long-context fit lever).
     # Needs model.remat=true (pipeline stages excepted) and conflicts
     # with resnet's model.remat_policy="conv_saved" spelling.

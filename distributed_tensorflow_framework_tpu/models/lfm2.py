@@ -36,6 +36,20 @@ Packed rows (``segment_ids``, 0 on padding): attention stays inside a
 document, ``positions`` restart at each document, and a convolution tap
 that would reach across a document boundary reads zero.
 
+``remat`` checkpoints each layer (``nn.remat``). With no policy handed
+down (``precision.remat_policy: none``) a layer keeps, beside its input,
+the two values its attention backward kernels read from the forward
+kernel: the output and the logsumexp (ops/flash_attention.py's
+``RESIDUAL_NAMES``; O(S·D) a layer, as large as the input), and the
+re-run forward pass recomputes everything else. That engages wherever
+the layer's attention is the direct Pallas call (one device, or inside
+manual axes). Where there are no names to keep the layer re-runs whole,
+to the same result: ``attention_impl: xla``, conv layers, and the
+``shard_map`` the kernels wrap themselves in under a multi-device
+``jit``, whose equation hides the names from the layer's checkpoint.
+``precision.remat_policy: save_nothing`` is the full re-run,
+``dots_saveable`` keeps the products' outputs.
+
 Scopes a trace can be read by (docs/OBSERVABILITY.md):
 ``layerN/short_conv/{in_proj,gate_conv,out_proj}``, ``layerN/attn/...``
 (``full_attention``), ``layerN/attn_window/...`` (``sliding_attention``),
@@ -362,9 +376,17 @@ class Lfm2ForCausalLM(nn.Module):
         x = embed(input_ids)
         block_cls = Lfm2Block
         if self.remat:
-            kwargs = ({"policy": self.ckpt_policy}
-                      if self.ckpt_policy is not None else {})
-            block_cls = nn.remat(Lfm2Block, **kwargs)
+            policy = self.ckpt_policy
+            if policy is None:
+                # Keep what the attention backward kernels read, so the
+                # forward kernel is not run again (module docstring).
+                from distributed_tensorflow_framework_tpu.ops.flash_attention import (
+                    RESIDUAL_NAMES,
+                )
+
+                policy = jax.checkpoint_policies.save_only_these_names(
+                    *RESIDUAL_NAMES)
+            block_cls = nn.remat(Lfm2Block, policy=policy)
         totals = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}
         n_moe = 0
         for i, kind in enumerate(self.layer_types):

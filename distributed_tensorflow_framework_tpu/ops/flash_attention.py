@@ -53,6 +53,20 @@ the window (``_block_needed``; the index maps name a needed block for a
 skipped visit, so the pipeline fetches nothing for it). Without either
 a call traces the plain kernels, equation for equation.
 
+Every custom-VJP ``fwd`` rule names the two kernel outputs its ``bwd``
+reads (``jax.ad_checkpoint.checkpoint_name``): the output as
+``ATTN_OUT_NAME`` and a lane-dense (B, H, S) copy of the logsumexp as
+``ATTN_LSE_NAME`` (as ``f32[B,H,S,1]`` a kept logsumexp is held padded to
+128 lanes in HBM; PERF.md §6, PR 31). A ``jax.checkpoint`` whose policy
+is ``save_only_these_names(*RESIDUAL_NAMES)`` then keeps them from the
+first forward pass and the forward kernel is dead code in the re-run
+(``model.remat`` of the decoder family, models/lfm2.py); without such a
+policy the names are inert and a program compiles to what it did. Under
+a multi-device ``jit`` the call is wrapped in a ``shard_map``
+(``_flash_attention_sharded``), the names sit inside that equation where
+no policy of the enclosing checkpoint sees them, and the layer re-runs
+whole, to the same result.
+
 The kernels run in interpret mode off-TPU, under the selection a
 verified chip makes, so the CPU test mesh differentiates through the
 kernels the chip runs; tests/test_attention.py pins fwd+bwd numerics
@@ -68,6 +82,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 log = logging.getLogger(__name__)
@@ -635,6 +650,28 @@ def dispatch_log() -> list[dict]:
     ]
 
 
+# The names of the two forward-kernel outputs the backward kernels read,
+# for a ``jax.checkpoint`` policy to keep
+# (``save_only_these_names(*RESIDUAL_NAMES)``, models/lfm2.py): with both
+# kept, the forward kernel is dead code in the re-run forward pass.
+ATTN_OUT_NAME = "flash_attention_out"
+ATTN_LSE_NAME = "flash_attention_lse"
+RESIDUAL_NAMES = (ATTN_OUT_NAME, ATTN_LSE_NAME)
+
+
+def _name_residuals(o, lse):
+    """Tag the forward kernel's outputs inside a custom-VJP ``fwd`` rule,
+    outside the jitted wrapper and before the residual tuple takes them:
+    the values the backward reads are then the named ones (a tag on the
+    caller's side of the ``custom_vjp`` names another variable and keeps
+    nothing). The logsumexp is named as (B, H, S) and handed on as the
+    reshape of that: kept as the kernel leaves it, ``f32[B,H,S,1]``, it
+    costs a layer 128 times its values in HBM. Inert without a policy
+    that asks for the names."""
+    return (checkpoint_name(o, ATTN_OUT_NAME),
+            checkpoint_name(lse[..., 0], ATTN_LSE_NAME)[..., None])
+
+
 def _make_fused(segmented: bool, return_lse: bool,
                 causal: bool = False, window=None):
     """Build the custom-VJP fused attention for one (segmented, lse)
@@ -670,6 +707,7 @@ def _make_fused(segmented: bool, return_lse: bool,
                                 dispatch=_dispatch(q, k, True, causal,
                                                    window),
                                 causal=causal, **win)
+            o, lse = _name_residuals(o, lse)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, qseg, kseg, o, lse)
 
@@ -699,6 +737,7 @@ def _make_fused(segmented: bool, return_lse: bool,
                                 dispatch=_dispatch(q, k, False, causal,
                                                    window),
                                 causal=causal, **win)
+            o, lse = _name_residuals(o, lse)
             out = (o, lse) if return_lse else o
             return out, (q, k, v, bias, o, lse)
 

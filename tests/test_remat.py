@@ -9,13 +9,20 @@ parity + eval equality + finite training instead of whole-model bitwise
 gradients (see test_inception_remat_block_parity_and_trains).
 """
 
+import functools
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_policies
 
-from distributed_tensorflow_framework_tpu.core.config import ModelConfig
+from distributed_tensorflow_framework_tpu.core.config import (
+    ModelConfig, PrecisionConfig, load_config)
 from distributed_tensorflow_framework_tpu.models import get_model
+from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
 
 def _tiny_bert(remat: bool) -> ModelConfig:
@@ -188,3 +195,255 @@ def test_remat_rejected_with_pipeline():
     cfg.pipeline_stages = 2
     with pytest.raises(ValueError, match="pipelined"):
         get_model(cfg)
+
+
+# ------------------------------------------------------------------------
+# What ``model.remat`` keeps from a decoder layer's forward pass (PR 31):
+# the attention kernels' output and logsumexp, so the forward kernel is
+# dead code in the re-run forward pass.
+DECODER_LAYERS = ["full_attention", "sliding_attention", "conv"]
+DEC_S, DEC_VOCAB = 128, 256
+
+
+def _decoder(layers=DECODER_LAYERS, *, remat=True, policy="none",
+             impl="pallas"):
+    """A global layer, a window layer and a short convolution over
+    experts, at tiny widths; ``policy`` is ``precision.remat_policy``."""
+    cfg = ModelConfig(
+        name="smallthinker_moe", vocab_size=DEC_VOCAB, hidden_size=64,
+        num_layers=len(layers), layer_types=list(layers),
+        rope_layout=[int(k == "sliding_attention") for k in layers],
+        sliding_window=24, num_dense_layers=0, num_heads=4, num_kv_heads=2,
+        head_dim=32, qk_norm=False, moe_mlp_dim=32, num_experts=8,
+        expert_topk=2, router_input="stream", router_score="softmax_topk",
+        expert_activation="relu", tie_embeddings=False, embed_init_std=1.0,
+        norm_eps=1e-6, rope_theta=1.5e6, dtype="float32",
+        attention_impl=impl, dropout_rate=0.0, remat=remat)
+    return get_model(cfg, precision=PrecisionConfig(remat_policy=policy))
+
+
+def _decoder_inputs(segmented: bool):
+    rng = np.random.default_rng(5)
+    ids = jnp.asarray(rng.integers(0, DEC_VOCAB, (2, DEC_S)), jnp.int32)
+    if not segmented:
+        return (ids,)
+    # two documents a row, cut where no block is aligned with them
+    cuts = np.array([[50], [90]])
+    return (ids, jnp.asarray(1 + (np.arange(DEC_S)[None, :] >= cuts),
+                             jnp.int32))
+
+
+def _decoder_grad(model, inputs):
+    def loss(params):
+        out = model.apply({"params": params}, *inputs)
+        return (out["logits"].astype(jnp.float32) ** 2).mean()
+
+    return jax.grad(loss)
+
+
+@functools.cache
+def _decoder_params(segmented: bool):
+    return _decoder(remat=False).init(
+        jax.random.key(0), *_decoder_inputs(segmented))["params"]
+
+
+@functools.cache
+def _decoder_grads(segmented: bool, remat: bool, policy: str):
+    """Op by op, as XLA fuses nothing then: what is compared is the
+    arithmetic the policies leave, not one compile against another."""
+    inputs = _decoder_inputs(segmented)
+    return jax.device_get(_decoder_grad(
+        _decoder(remat=remat, policy=policy), inputs)(
+            _decoder_params(segmented)))
+
+
+def _forward_kernel_calls(fn, *args) -> tuple[int, int]:
+    text = str(jax.make_jaxpr(fn)(*args))
+    return (len(re.findall(r"name=_flash_fwd\b", text)),
+            len(re.findall(r"name=_flash_bwd\b", text)))
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["whole_rows", "packed_rows"])
+@pytest.mark.parametrize("other", [(True, "save_nothing"), (False, "none")],
+                         ids=["save_nothing", "remat_off"])
+def test_kept_attention_residuals_leave_every_gradient_alone(
+        devices, segmented, other):
+    """The backward kernels read the output and logsumexp the first
+    forward pass produced, not an identical second copy: every
+    parameter's gradient equals the full re-run's and the un-remat'd
+    model's, to 0.0."""
+    kept = _decoder_grads(segmented, True, "none")
+    want = _decoder_grads(segmented, *other)
+    flat_kept = dict(jax.tree_util.tree_leaves_with_path(kept))
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+    assert flat_kept.keys() == flat_want.keys() and len(flat_kept) > 20
+    for path, g in flat_kept.items():
+        assert np.any(g), jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(g, flat_want[path],
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["whole_rows", "packed_rows"])
+@pytest.mark.parametrize("layers,remat,policy,forward_calls", [
+    (DECODER_LAYERS, True, "none", 2),          # once an attention layer
+    (DECODER_LAYERS, True, "save_nothing", 4),  # the full re-run: twice
+    (DECODER_LAYERS, True, "dots_saveable", 4),  # what it says, no names
+    (DECODER_LAYERS, False, "none", 2),
+    (DECODER_LAYERS + ["conv", "conv"], True, "none", 2),   # conv adds none
+    (["conv", "conv"], True, "none", 0),
+], ids=["kept", "save_nothing", "dots_saveable", "remat_off", "more_conv",
+        "conv_only"])
+def test_forward_kernel_calls_in_the_gradients_jaxpr(
+        devices, segmented, layers, remat, policy, forward_calls):
+    inputs = _decoder_inputs(segmented)
+    model = _decoder(layers, remat=remat, policy=policy)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), *inputs)["params"])
+    attention_layers = sum(k != "conv" for k in layers)
+    assert _forward_kernel_calls(_decoder_grad(model, inputs), params) == (
+        forward_calls, attention_layers)
+
+
+def test_the_xla_attention_has_no_names_to_keep(devices):
+    """``attention_impl: xla`` under the same policy: no kernel, no name,
+    the layer re-runs whole as under ``save_nothing``."""
+    inputs = _decoder_inputs(True)
+    texts = []
+    for policy in ("none", "save_nothing"):
+        model = _decoder(policy=policy, impl="xla")
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.key(0), *inputs)["params"])
+        text = str(jax.make_jaxpr(_decoder_grad(model, inputs))(params))
+        assert "_flash_fwd" not in text
+        assert not set(fa.RESIDUAL_NAMES) & set(
+            re.findall(r"name=(\w+)", text))
+        texts.append(re.sub(r"policy=.*", "policy=", text))
+    assert texts[0] == texts[1]
+
+
+def _fused_variant(kind: str, segmented: bool):
+    if kind == "plain":
+        return fa._FUSED[(segmented, False)]
+    if kind == "return_lse":
+        return fa._FUSED[(segmented, True)]
+    if kind == "causal":
+        return fa._FUSED_CAUSAL[segmented]
+    return fa._fused_window(segmented, 48)
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["unsegmented", "segmented"])
+@pytest.mark.parametrize("kind", ["plain", "causal", "window", "return_lse"])
+def test_every_fused_variant_names_both_residuals(devices, kind, segmented):
+    """Each custom-VJP ``fwd`` rule tags the kernel's output and a
+    lane-dense (B, H, S) logsumexp, and what ``bwd`` reads are the tagged
+    values: under a policy that keeps the two names the forward kernel is
+    traced once, under one that keeps nothing twice."""
+    b, h, hk, s, d = 1, 4, 2, 128, 32
+    fused = _fused_variant(kind, segmented)
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, hk, s, d), jnp.float32)
+    operands = [q, kv, kv, jax.ShapeDtypeStruct((b, 1, s), jnp.float32)]
+    if segmented:
+        operands += [jax.ShapeDtypeStruct((b, 1, s), jnp.float32)] * 2
+
+    def loss(*args):
+        out = fused(*args)
+        if kind == "return_lse":     # the ring merge differentiates both
+            return out[0].sum() + out[1].sum()
+        return out.sum()
+
+    def grad_under(policy):
+        return jax.grad(jax.checkpoint(loss, policy=policy),
+                        argnums=(0, 1, 2))
+
+    keep = checkpoint_policies.save_only_these_names(*fa.RESIDUAL_NAMES)
+    text = str(jax.make_jaxpr(grad_under(keep))(*operands))
+    named = {name: shape for shape, name in re.findall(
+        r":f32\[([\d,]*)\] = name\[name=(\w+)\]", text)}
+    assert named == {fa.ATTN_OUT_NAME: f"{b},{h},{s},{d}",
+                     fa.ATTN_LSE_NAME: f"{b},{h},{s}"}
+    assert _forward_kernel_calls(grad_under(keep), *operands) == (1, 1)
+    assert _forward_kernel_calls(
+        grad_under(checkpoint_policies.nothing_saveable), *operands) == (2, 1)
+    # one name is not enough: the backward needs both from the kernel
+    for name in fa.RESIDUAL_NAMES:
+        assert _forward_kernel_calls(grad_under(
+            checkpoint_policies.save_only_these_names(name)),
+            *operands) == (2, 1)
+
+
+def _bert_step_text():
+    from distributed_tensorflow_framework_tpu.core.mesh import create_mesh
+    from distributed_tensorflow_framework_tpu.train.step import StepBuilder
+
+    cfg = load_config(base={
+        "name": "remat-tags-test", "mesh": {"data": 1},
+        "model": {"name": "bert", "vocab_size": 96, "hidden_size": 32,
+                  "num_layers": 2, "num_heads": 2, "mlp_dim": 64,
+                  "max_seq_len": 128, "dtype": "float32",
+                  "dropout_rate": 0.0, "attention_impl": "pallas"},
+        "data": {"name": "synthetic_mlm", "vocab_size": 64,
+                 "global_batch_size": 2, "seq_len": 128, "mask_prob": 0.15},
+        "optimizer": {"name": "sgd_momentum", "learning_rate": 0.1},
+        "train": {"total_steps": 2}})
+    builder = StepBuilder(cfg, create_mesh(cfg.mesh,
+                                           devices=jax.devices()[:1]))
+    sample = {k: jax.ShapeDtypeStruct((2, 128), jnp.int32)
+              for k in ("input_ids", "attention_mask", "targets",
+                        "segment_ids")}
+    return _compiled_step(builder, sample)
+
+
+def _decoder_step_text():
+    from distributed_tensorflow_framework_tpu.core.mesh import create_mesh
+    from distributed_tensorflow_framework_tpu.train.step import StepBuilder
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_config(
+        os.path.join(root, "configs", "smallthinker_21b_a3b.yaml"), [
+            "model.num_layers=2",
+            "model.layer_types=[full_attention,sliding_attention]",
+            "model.rope_layout=[0,1]", "model.sliding_window=24",
+            "model.hidden_size=64", "model.num_heads=4",
+            "model.num_kv_heads=2", "model.head_dim=32",
+            "model.moe_mlp_dim=32", "model.num_experts=8",
+            "model.expert_topk=2", "model.expert_groups=4",
+            f"model.vocab_size={DEC_VOCAB}", f"data.vocab_size={DEC_VOCAB}",
+            f"data.seq_len={DEC_S}", "data.global_batch_size=2",
+            "mesh.data=1", "model.dtype=float32", "model.remat=false"])
+    assert cfg.model.attention_impl == "pallas" and not cfg.model.remat
+    builder = StepBuilder(cfg, create_mesh(cfg.mesh,
+                                           devices=jax.devices()[:1]))
+    sample = {k: jax.ShapeDtypeStruct((2, DEC_S), jnp.int32)
+              for k in ("input_ids", "targets", "segment_ids", "positions")}
+    return _compiled_step(builder, sample)
+
+
+def _compiled_step(builder, sample) -> str:
+    """The train step compiled from shapes alone: nothing runs."""
+    state = jax.eval_shape(builder._create_state,
+                           jax.ShapeDtypeStruct((1,), jnp.uint32), sample)
+    return builder.make_train_step(sample).lower(
+        state, sample).compile().as_text()
+
+
+@pytest.mark.parametrize("step_text", [_bert_step_text, _decoder_step_text],
+                         ids=["bert", "decoder_without_remat"])
+def test_the_tags_are_inert_without_a_policy_that_asks_for_them(
+        devices, monkeypatch, step_text):
+    """BERT's step and an un-remat'd decoder's compile to the program
+    they compile to under the parent's rules (no tag, the logsumexp as
+    the kernel left it), metadata and all: a name is no operation, and
+    the lane-dense logsumexp is a reshape there and back that XLA folds."""
+    texts = []
+    for rules in (None, lambda o, lse: (o, lse)):
+        jax.clear_caches()
+        if rules is not None:
+            monkeypatch.setattr(fa, "_name_residuals", rules)
+        texts.append(step_text())
+    tagged, untagged = texts
+    assert "_flash_fwd" in tagged and "_flash_bwd" in tagged
+    assert tagged == untagged
