@@ -253,7 +253,8 @@ class ModelConfig:
     #                  tail — near-zero recompute flops for roughly half
     #                  the activation bytes. ResNet only.
     remat_policy: str = "full"
-    # Decoder family (models/lfm2.py; names "lfm2*" and "smallthinker*").
+    # Decoder family (models/lfm2.py; names "lfm2*", "smallthinker*" and
+    # "nemotron*").
     # Knobs it shares with the BERT family keep their names: vocab_size,
     # hidden_size, num_layers, num_heads, mlp_dim (the dense SwiGLU
     # width), num_experts (the router's width), expert_topk,
@@ -261,13 +262,18 @@ class ModelConfig:
     # One mixer kind per layer: "conv" (gated short convolution),
     # "full_attention" (causal grouped-query attention) or
     # "sliding_attention" (the same inside a window of sliding_window
-    # keys); its length must be num_layers.
+    # keys), each followed by a feed-forward; or a layer of ONE sublayer,
+    # x + sublayer(RMSNorm(x)): "mamba2_only" (the Mamba-2 mixer),
+    # "attention_only" (causal grouped-query attention) or "experts_only"
+    # (the expert feed-forward). Its length must be num_layers.
     layer_types: list[str] = field(default_factory=list)
     # Leading layers with a dense feed-forward; the rest carry experts.
     num_dense_layers: int = 0
     num_kv_heads: int = 0       # 0 = as many as num_heads
     moe_mlp_dim: int = 0        # width of one expert's SwiGLU
-    conv_kernel: int = 3        # taps of the short convolution (conv_L_cache)
+    # taps of the short convolution (conv_L_cache), or of a Mamba-2
+    # layer's convolution over xBC
+    conv_kernel: int = 3
     rope_theta: float = 1000000.0
     norm_eps: float = 1e-5
     # The share of an expert-parallel deployment this process computes:
@@ -304,7 +310,47 @@ class ModelConfig:
     # weights normalised over the chosen; "softmax_topk": top-k of the
     # logits, softmax over the chosen, no bias.
     router_score: str = "sigmoid_bias"
-    expert_activation: str = "silu"   # silu (SwiGLU) | relu (ReGLU)
+    # silu (SwiGLU) | relu (ReGLU): gated, W2(act(W1 x) * W3 x);
+    # relu2: not gated, W2 relu(W1 x)^2
+    expert_activation: str = "silu"
+    # One tensor-parallel share of the one-sublayer kinds, taken wherever
+    # a layer has something to split: tensor_groups chips divide it in
+    # contiguous runs and this is chip tensor_group. An attention layer
+    # holds num_heads / tensor_groups query heads with the key/value heads
+    # they read, a Mamba-2 layer mamba_num_heads / tensor_groups heads with
+    # mamba_groups / tensor_groups B/C groups, an experts_only layer's
+    # shared expert moe_shared_dim / tensor_groups of its hidden units; each
+    # adds its heads' (units') part of the out-projection's sum and
+    # nothing stands in for the others. Shares are whole groups or the
+    # model is refused; so are the mixer-then-feed-forward kinds, whose
+    # dense feed-forward has no such split.
+    tensor_groups: int = 1
+    tensor_group: int = 0
+    # Mamba-2 layers ("mamba2_only"): heads of mamba_head_dim channels,
+    # mamba_groups B/C groups of ssm_state_size state dims (n_groups),
+    # the recurrence computed in chunks of mamba_chunk tokens.
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_groups: int = 1
+    ssm_state_size: int = 128
+    mamba_chunk: int = 128
+    # Experts in a latent: they read x W_a (hidden -> moe_latent_dim) and
+    # their weighted sum goes back through W_b; the router still reads
+    # the stream. 0: the experts read the stream itself.
+    moe_latent_dim: int = 0
+    # Width of a shared expert W2 relu(W1 x)^2 beside the routed ones, on
+    # the stream; 0: none.
+    moe_shared_dim: int = 0
+    routed_scaling: float = 1.0   # on the routed experts' weights
+    # Std of the normal init of every residual branch's output projection
+    # in the one-sublayer kinds (a Mamba-2 layer's out_proj, attention's
+    # attn_out, an expert layer's latent_out and its shared expert's
+    # down): the scaled output init of the GPT-2 / Megatron-LM recipes,
+    # 0.02 / sqrt(2 x layers). 0: the fan-in rule of every other
+    # projection. With unit-variance embeddings it keeps each token's own
+    # content on top of the stream at a random init, so a router behind a
+    # norm spreads its tokens evenly (PERF.md section 6, PR 32).
+    out_proj_init_std: float = 0.0
 
 
 @config_dataclass

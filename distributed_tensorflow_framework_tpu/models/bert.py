@@ -420,12 +420,13 @@ def decode_support_reason(model_config) -> str | None:
     parameter tree by name; trees it does not know must be refused by
     name rather than failing as a KeyError mid-stream."""
     name = model_config.name.lower()
-    if name.startswith(("lfm2", "smallthinker")):
+    if name.startswith(("lfm2", "smallthinker", "nemotron")):
         return (f"model {model_config.name!r} (the lfm2 decoder family) "
                 f"trains only: serving it needs a per-layer cache of "
                 f"several kinds (keys/values for its attention layers, a "
                 f"window of them for its sliding layers, the last "
-                f"conv_kernel-1 gated inputs for its short convolutions) "
+                f"conv_kernel-1 gated inputs for its short convolutions, "
+                f"the recurrent state of its Mamba-2 layers) "
                 f"that serve/decode.py does not have")
     if name not in ("bert", "bert_base", "bert-base"):
         return (f"model {model_config.name!r} has no causal decode head "

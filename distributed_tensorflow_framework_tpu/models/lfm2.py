@@ -1,11 +1,15 @@
 """The causal decoder family: a stack whose mixer is chosen per layer,
-over dropless experts. Two published models run through it, told apart
+over dropless experts. Three published models run through it, told apart
 by ``ModelConfig`` settings alone: LFM2-MoE (LiquidAI LFM2-8B-A1B,
 ``model_type: lfm2_moe``: gated short convolutions, grouped-query
 attention, bias-routed SwiGLU experts, a tied head; every default below
-is what it runs) and SmallThinker (PowerInfer SmallThinker-21BA3B: global
+is what it runs), SmallThinker (PowerInfer SmallThinker-21BA3B: global
 layers without positions beside rotary window layers, a router that
-reads the stream before attention, ReGLU experts, an untied head).
+reads the stream before attention, ReGLU experts, an untied head) and
+Nemotron-H (NVIDIA Nemotron-3-Super-120B-A12B, ``model_type:
+nemotron_h``: layers of one sublayer each: Mamba-2 mixers, attention
+without positions, a LatentMoE of ungated squared-ReLU experts beside a
+shared expert).
 
 Pre-norm blocks, ``h = x + mixer(RMSNorm(x))``, ``y = h + ffn(RMSNorm(h))``,
 one mixer kind per layer (``ModelConfig.layer_types``):
@@ -24,6 +28,38 @@ one mixer kind per layer (``ModelConfig.layer_types``):
   sliding_attention  the same, and a query at ``i`` sees a key at ``j``
                      only if ``i - j < sliding_window``.
 
+Or a layer is ONE sublayer, ``y = x + sublayer(RMSNorm(x))``
+(``SoloBlock``):
+
+  mamba2_only        the Mamba-2 mixer (``Mamba2Mixer``): in-projection
+                     to gate ``z``, ``xBC`` and ``dt``; a depthwise causal
+                     convolution of ``conv_kernel`` taps with bias and
+                     SiLU over ``xBC``; the selective state-space
+                     recurrence with one scalar decay a head as a chunked
+                     scan (ops/ssm_scan.py, chunks of ``mamba_chunk``),
+                     ``D`` skip; a gated RMSNorm per B/C group;
+                     out-projection.
+  attention_only     ``full_attention``'s mixer alone.
+  experts_only       ``DroplessMoE`` alone, with what ``moe_latent_dim``
+                     (experts in a latent of the stream),
+                     ``moe_shared_dim`` (a shared expert beside them) and
+                     ``routed_scaling`` say.
+
+``tensor_groups`` / ``tensor_group`` give a process one tensor-parallel
+share of the one-sublayer kinds, as ``expert_groups`` gives it its
+experts, taken wherever a layer has something to split: an attention
+layer holds ``num_heads / tensor_groups`` query heads with the key/value
+heads they read, a Mamba-2 layer ``mamba_num_heads / tensor_groups``
+heads with ``mamba_groups / tensor_groups`` B/C groups (its gated norm is
+over its own groups), and an ``experts_only`` layer's shared expert
+``moe_shared_dim / tensor_groups`` of its hidden units (an ungated unit
+is elementwise in them); each adds its heads' (or units') part of the
+out-projection's sum and nothing stands in for the others. A share that
+would split a B/C group, or a key/value head's queries unevenly, is
+refused, and so is one over the mixer-then-feed-forward kinds (their
+dense feed-forward has no such split). Routers, latent projections and
+norms are whole on every chip.
+
 The first ``num_dense_layers`` layers carry a dense SwiGLU feed-forward
 (``mlp_dim``), the rest ``DroplessMoE`` (models/moe.py): no dropped
 token, this process's share of the experts, the router's scores
@@ -33,8 +69,9 @@ RMSNorm and a head out: the embedding transposed (``tie_embeddings``) or
 a matrix of its own.
 
 Packed rows (``segment_ids``, 0 on padding): attention stays inside a
-document, ``positions`` restart at each document, and a convolution tap
-that would reach across a document boundary reads zero.
+document, ``positions`` restart at each document, a convolution tap
+that would reach across a document boundary reads zero, and a Mamba-2
+layer's state starts from zero at each document.
 
 ``remat`` checkpoints each layer (``nn.remat``). With no policy handed
 down (``precision.remat_policy: none``) a layer keeps, beside its input,
@@ -54,11 +91,16 @@ Scopes a trace can be read by (docs/OBSERVABILITY.md):
 ``layerN/short_conv/{in_proj,gate_conv,out_proj}``, ``layerN/attn/...``
 (``full_attention``), ``layerN/attn_window/...`` (``sliding_attention``),
 ``layerN/{mlp_in,mlp_up,mlp_out}``,
-``layerN/moe/{router,dispatch,experts,combine}``, ``lm_head``.
+``layerN/moe/{router,dispatch,experts,combine}`` and, around them,
+``layerN/moe/{latent_in,latent_out,shared}``,
+``layerN/mamba/{in_proj,conv,scan,gate_norm,out_proj}``, ``lm_head``.
+Counters beside the logits: ``moe_*`` (the mean over the layers that
+have experts), ``attn_window_block_share`` and ``ssm_resets``.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -67,9 +109,13 @@ import jax.numpy as jnp
 
 from distributed_tensorflow_framework_tpu.models.layers import dense_kernel_init
 from distributed_tensorflow_framework_tpu.models.moe import (
-    DroplessMoE, held_experts)
+    DroplessMoE, check_expert_settings, held_experts, projection)
 
+# A layer is a mixer and then a feed-forward ...
 LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
+# ... or ONE sublayer, ``x + sublayer(RMSNorm(x))``: a mixer alone or the
+# expert feed-forward alone.
+SOLO_KINDS = ("mamba2_only", "attention_only", "experts_only")
 # The attention module's name, and so its scope in a trace, by kind.
 ATTENTION_SCOPES = {"full_attention": "attn",
                     "sliding_attention": "attn_window"}
@@ -78,14 +124,23 @@ ROUTER_INPUTS = ("ffn_norm", "stream")
 # the model's expert layers and named ``moe_<key>`` in the step's metrics.
 MOE_COUNTERS = ("local_assignments", "load_max_mean", "dropped",
                 "local_share", "compact")
+# How Mamba-2 draws the step size a head starts from (``dt_bias`` is its
+# inverse softplus): log-uniform between the two, floored. The published
+# ``time_step_min/max/floor``; they shape this init and nothing else.
+MAMBA_DT_INIT = (1e-3, 1e-1, 1e-4)
+
+
+def document_starts(segment_ids: jax.Array) -> jax.Array:
+    """True at each row's first token and wherever the document changes."""
+    return jnp.concatenate(
+        [jnp.ones_like(segment_ids[:, :1], bool),
+         segment_ids[:, 1:] != segment_ids[:, :-1]], axis=1)
 
 
 def document_positions(segment_ids: jax.Array) -> jax.Array:
     """Position of each token inside its own document."""
     idx = jnp.arange(segment_ids.shape[1], dtype=jnp.int32)[None, :]
-    new_doc = jnp.concatenate(
-        [jnp.ones_like(segment_ids[:, :1], bool),
-         segment_ids[:, 1:] != segment_ids[:, :-1]], axis=1)
+    new_doc = document_starts(segment_ids)
     return idx - jax.lax.cummax(jnp.where(new_doc, idx, 0), axis=1)
 
 
@@ -147,6 +202,107 @@ class ShortConv(nn.Module):
         return _dense(h, self.dtype, "out_proj")(y)
 
 
+def _mamba_dt_bias_init(key, shape, dtype=jnp.float32):
+    low, high, floor = MAMBA_DT_INIT
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    math.log(low), math.log(high)))
+    dt = jnp.maximum(dt, floor)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _mamba_a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
+                                      16.0)).astype(dtype)
+
+
+def _conv_init(taps: int):
+    """Uniform within ``1/sqrt(taps)``, for a depthwise convolution's
+    taps and its bias: its fan-in is its taps."""
+    bound = 1.0 / math.sqrt(taps)
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def step_size(dt, dt_bias):
+    """``Δ = softplus(dt + dt_bias)``, float32, with no clamp."""
+    return nn.softplus(dt.astype(jnp.float32) + dt_bias)
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm_group(y ⊙ silu(z)) ⊙ scale``: the gate first, then the
+    norm with its mean square over each of ``groups`` runs of channels;
+    float32."""
+    lead, d = y.shape[:-1], y.shape[-1]
+    y = (y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).reshape(
+        *lead, groups, d // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + eps)
+    return y.reshape(*lead, d) * scale
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer (arXiv:2405.21060) over the heads and B/C groups
+    this process holds: ``[z | xBC | dt] = u W_in``; ``xBC`` through a
+    depthwise causal convolution with bias and SiLU; ``[x | B | C] =
+    xBC``, head ``h`` of ``head_dim`` channels reading group ``h //
+    (heads / groups)`` of ``state`` dims; ``Δ = softplus(dt + dt_bias)``,
+    ``a = -exp(A_log)``, the recurrence ``S_t = exp(Δ_t a) S_{t-1} + Δ_t
+    x_t ⊗ B_t``, ``y_t = S_t C_t + D x_t`` as a chunked scan
+    (ops/ssm_scan.py); ``RMSNorm_group(y ⊙ silu(z)) ⊙ w`` with the mean
+    square over each group's channels; ``W_out``. State and convolution
+    start anew at each document (``segment_ids``)."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    kernel: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    out_init_std: float = 0.0        # 0: the fan-in rule
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        from distributed_tensorflow_framework_tpu.ops.ssm_scan import (
+            chunked_ssm_scan,
+        )
+
+        bsz, s, h = x.shape
+        n_h, p, g, n = self.heads, self.head_dim, self.groups, self.state
+        d_in, d_bc = n_h * p, g * n
+        proj = _dense(2 * d_in + 2 * d_bc + n_h, self.dtype, "in_proj")(x)
+        z, xbc, dt = jnp.split(proj, [d_in, 2 * d_in + 2 * d_bc], axis=-1)
+        taps = self.param("conv_kernel", _conv_init(self.kernel),
+                          (self.kernel, d_in + 2 * d_bc), jnp.float32)
+        conv_bias = self.param("conv_bias", _conv_init(self.kernel),
+                               (d_in + 2 * d_bc,), jnp.float32)
+        with jax.named_scope("conv"):
+            xbc = nn.silu(causal_depthwise_conv(
+                xbc, taps.astype(self.dtype), segment_ids)
+                + conv_bias.astype(self.dtype))
+        dt_bias = self.param("dt_bias", _mamba_dt_bias_init, (n_h,),
+                             jnp.float32)
+        a_log = self.param("A_log", _mamba_a_log_init, (n_h,), jnp.float32)
+        skip = self.param("D", nn.initializers.ones, (n_h,), jnp.float32)
+        with jax.named_scope("scan"):
+            xs, b, c = jnp.split(xbc, [d_in, d_in + d_bc], axis=-1)
+            xs = xs.reshape(bsz, s, n_h, p)
+            y = chunked_ssm_scan(
+                xs, step_size(dt, dt_bias),
+                -jnp.exp(a_log), b.reshape(bsz, s, g, n),
+                c.reshape(bsz, s, g, n), segment_ids, chunk=self.chunk)
+            y = y + skip[:, None] * xs.astype(jnp.float32)
+        scale = self.param("norm_scale", nn.initializers.ones, (d_in,),
+                           jnp.float32)
+        with jax.named_scope("gate_norm"):
+            y = gated_group_norm(y.reshape(bsz, s, d_in), z, scale, g,
+                                 self.norm_eps).astype(self.dtype)
+        return projection(h, self.dtype, "out_proj", self.out_init_std)(y)
+
+
 def rotary(x, positions, theta: float):
     """Half-rotation rotary embedding over the whole head: ``x`` (B, S,
     N, D), ``positions`` (B, S); float32."""
@@ -194,6 +350,7 @@ class GroupedQueryAttention(nn.Module):
     window: int | None = None    # a query sees this many keys, its own last
     rope: bool = True            # rotary positions on q and k
     qk_norm: bool = True         # RMSNorm over each head of q and of k
+    out_init_std: float = 0.0    # of attn_out; 0: the fan-in rule
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
@@ -231,7 +388,8 @@ class GroupedQueryAttention(nn.Module):
             raise ValueError(
                 f"attention_impl {self.attention_impl!r} is not wired for "
                 f"the lfm2 family (pallas | xla)")
-        return _dense(h, self.dtype, "attn_out")(out.reshape(b, s, n * d))
+        return projection(h, self.dtype, "attn_out", self.out_init_std)(
+            out.reshape(b, s, n * d))
 
 
 class Lfm2Block(nn.Module):
@@ -299,6 +457,48 @@ class Lfm2Block(nn.Module):
         return x + y.astype(x.dtype), counters
 
 
+class SoloBlock(nn.Module):
+    """``x + sublayer(RMSNorm(x))`` with ONE sublayer (``SOLO_KINDS``):
+    the Mamba-2 mixer, attention, or the expert feed-forward.
+    ``sublayer`` holds the keyword arguments of that module."""
+
+    kind: str
+    sublayer: Any                # kwargs of the kind's module
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x, segment_ids, positions):
+        normed = RMSNorm(self.norm_eps, name="norm")(x)
+        counters = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}
+        if self.kind == "mamba2_only":
+            y = Mamba2Mixer(**self.sublayer, name="mamba")(
+                normed, segment_ids)
+        elif self.kind == "attention_only":
+            y = GroupedQueryAttention(**self.sublayer, name="attn")(
+                normed, segment_ids, positions)
+        else:
+            y, counters = DroplessMoE(**self.sublayer, name="moe")(normed)
+        return x + y.astype(x.dtype), counters
+
+
+def layer_has_experts(kinds, num_dense_layers: int, i: int) -> bool:
+    """Whether layer ``i`` of a stack of ``kinds`` carries experts."""
+    if kinds[i] in SOLO_KINDS:
+        return kinds[i] == "experts_only"
+    return i >= num_dense_layers
+
+
+def held_heads(heads: int, groups: int, group: int) -> range:
+    """The heads (or B/C groups, or key/value heads) group ``group`` of
+    ``groups`` holds: a contiguous run of ``heads // groups``."""
+    if groups < 1 or heads % groups or not 0 <= group < groups:
+        raise ValueError(
+            f"cannot give group {group} of {groups} a whole share of "
+            f"{heads} heads")
+    n = heads // groups
+    return range(group * n, (group + 1) * n)
+
+
 class Lfm2ForCausalLM(nn.Module):
     vocab_size: int
     hidden_size: int
@@ -329,6 +529,96 @@ class Lfm2ForCausalLM(nn.Module):
     router_input: str = "ffn_norm"
     router_score: str = "sigmoid_bias"
     expert_activation: str = "silu"
+    # One tensor-parallel share: this process holds run ``tensor_group``
+    # of ``tensor_groups`` of every mixer's heads (and B/C groups) and of
+    # a shared expert's hidden units.
+    tensor_groups: int = 1
+    tensor_group: int = 0
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_groups: int = 1
+    mamba_state: int = 128
+    mamba_chunk: int = 128
+    moe_latent_dim: int = 0
+    moe_shared_dim: int = 0
+    routed_scaling: float = 1.0
+    out_proj_init_std: float = 0.0
+
+    def has_experts(self, i: int) -> bool:
+        return layer_has_experts(self.layer_types, self.num_dense_layers, i)
+
+    def tensor_share(self) -> dict | None:
+        """Which heads of each mixer and which units of the shared expert
+        this process holds, for the run's opening record; None for the
+        whole model."""
+        if self.tensor_groups == 1:
+            return None
+        share = {"groups": self.tensor_groups, "group": self.tensor_group}
+        held = lambda n: list(held_heads(  # noqa: E731
+            n, self.tensor_groups, self.tensor_group))
+        if set(self.layer_types) - {"mamba2_only", "experts_only"}:
+            share["attention"] = {
+                "heads": self.num_heads, "held": held(self.num_heads),
+                "kv_heads": self.num_kv_heads,
+                "kv_held": self._kv_heads_held()}
+        if "mamba2_only" in self.layer_types:
+            share["mamba2"] = {
+                "heads": self.mamba_heads, "held": held(self.mamba_heads),
+                "bc_groups": self.mamba_groups,
+                "bc_held": held(self.mamba_groups)}
+        if self.moe_shared_dim and "experts_only" in self.layer_types:
+            units = held_heads(self.moe_shared_dim, self.tensor_groups,
+                               self.tensor_group)
+            share["shared_expert"] = {
+                "units": self.moe_shared_dim,
+                "held": [units.start, units.stop]}    # a half-open run
+        return share
+
+    def _kv_heads_held(self) -> list:
+        """The key/value heads the held query heads read."""
+        per_kv = self.num_heads // self.num_kv_heads
+        held = held_heads(self.num_heads, self.tensor_groups, self.tensor_group)
+        return sorted({q // per_kv for q in held})
+
+    def _rotates(self, i: int) -> bool:
+        return bool(self.rope_layout[i]) if self.rope_layout else True
+
+    def _attention_held(self) -> tuple:
+        """``(query heads, key/value heads, head size)`` of an attention
+        layer here."""
+        return (self.num_heads // self.tensor_groups,
+                len(self._kv_heads_held()),
+                self.head_dim or self.hidden_size // self.num_heads)
+
+    def solo_sublayer(self, kind: str, i: int) -> dict:
+        """The keyword arguments of the one sublayer of layer ``i``, of a
+        ``SOLO_KINDS`` kind, as ``SoloBlock`` takes them."""
+        if kind == "mamba2_only":
+            return dict(
+                heads=self.mamba_heads // self.tensor_groups,
+                head_dim=self.mamba_head_dim,
+                groups=self.mamba_groups // self.tensor_groups,
+                state=self.mamba_state, kernel=self.conv_kernel,
+                chunk=self.mamba_chunk, norm_eps=self.norm_eps,
+                out_init_std=self.out_proj_init_std, dtype=self.dtype)
+        if kind == "attention_only":
+            heads, kv_heads, head_dim = self._attention_held()
+            return dict(
+                num_heads=heads, num_kv_heads=kv_heads,
+                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                dtype=self.dtype, attention_impl=self.attention_impl,
+                mesh=self.mesh, head_dim=head_dim, rope=self._rotates(i),
+                qk_norm=self.qk_norm, out_init_std=self.out_proj_init_std)
+        return dict(
+            num_experts=self.num_experts, mlp_dim=self.moe_mlp_dim,
+            topk=self.expert_topk, groups=self.expert_groups,
+            group=self.expert_group, dtype=self.dtype,
+            score=self.router_score, activation=self.expert_activation,
+            latent_dim=self.moe_latent_dim,
+            # a shared expert is split over the tensor groups by its units
+            shared_dim=self.moe_shared_dim // self.tensor_groups,
+            weight_scale=self.routed_scaling,
+            out_init_std=self.out_proj_init_std)
 
     def window_block_share(self, seq_len: int) -> float | None:
         """Visited ÷ causal (q-block, k-block) visits of the window
@@ -342,8 +632,7 @@ class Lfm2ForCausalLM(nn.Module):
         from distributed_tensorflow_framework_tpu.ops import flash_attention
 
         tile = flash_attention.select_dispatch(
-            seq_len, seq_len, self.dtype,
-            self.head_dim or self.hidden_size // self.num_heads)
+            seq_len, seq_len, self.dtype, self._attention_held()[2])
         visited, causal = flash_attention.window_block_counts(
             seq_len, seq_len, tile.bwd_block_q, tile.bwd_block_k,
             self.sliding_window)
@@ -352,7 +641,7 @@ class Lfm2ForCausalLM(nn.Module):
     def expert_share(self) -> dict | None:
         """Which experts this process holds and of how many groups, for
         the run's opening record; None for a stack of dense layers."""
-        if self.num_dense_layers >= len(self.layer_types):
+        if not any(map(self.has_experts, range(len(self.layer_types)))):
             return None
         held = held_experts(self.num_experts, self.expert_groups,
                             self.expert_group)
@@ -374,7 +663,7 @@ class Lfm2ForCausalLM(nn.Module):
                              self.embed_init_std),
                          name="embed")
         x = embed(input_ids)
-        block_cls = Lfm2Block
+        block_cls, solo_cls = Lfm2Block, SoloBlock
         if self.remat:
             policy = self.ckpt_policy
             if policy is None:
@@ -387,28 +676,35 @@ class Lfm2ForCausalLM(nn.Module):
                 policy = jax.checkpoint_policies.save_only_these_names(
                     *RESIDUAL_NAMES)
             block_cls = nn.remat(Lfm2Block, policy=policy)
+            solo_cls = nn.remat(SoloBlock, policy=policy)
         totals = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}
         n_moe = 0
+        heads, kv_heads, head_dim = self._attention_held()
         for i, kind in enumerate(self.layer_types):
-            dense_ffn = i < self.num_dense_layers
-            x, counters = block_cls(
-                kind=kind, dense_ffn=dense_ffn, num_heads=self.num_heads,
-                num_kv_heads=self.num_kv_heads,
-                mlp_dim=self.mlp_dim, moe_mlp_dim=self.moe_mlp_dim,
-                num_experts=self.num_experts, expert_topk=self.expert_topk,
-                expert_groups=self.expert_groups,
-                expert_group=self.expert_group,
-                conv_kernel=self.conv_kernel, rope_theta=self.rope_theta,
-                norm_eps=self.norm_eps, dtype=self.dtype,
-                attention_impl=self.attention_impl, mesh=self.mesh,
-                head_dim=self.head_dim, sliding_window=self.sliding_window,
-                rope=bool(self.rope_layout[i]) if self.rope_layout else True,
-                qk_norm=self.qk_norm, router_input=self.router_input,
-                router_score=self.router_score,
-                expert_activation=self.expert_activation,
-                name=f"layer{i}",
-            )(x, segment_ids, positions)
-            if not dense_ffn:
+            if kind in SOLO_KINDS:
+                block = solo_cls(
+                    kind=kind, sublayer=self.solo_sublayer(kind, i),
+                    norm_eps=self.norm_eps, name=f"layer{i}")
+            else:
+                block = block_cls(
+                    kind=kind, dense_ffn=i < self.num_dense_layers,
+                    num_heads=heads, num_kv_heads=kv_heads,
+                    mlp_dim=self.mlp_dim, moe_mlp_dim=self.moe_mlp_dim,
+                    num_experts=self.num_experts,
+                    expert_topk=self.expert_topk,
+                    expert_groups=self.expert_groups,
+                    expert_group=self.expert_group,
+                    conv_kernel=self.conv_kernel, rope_theta=self.rope_theta,
+                    norm_eps=self.norm_eps, dtype=self.dtype,
+                    attention_impl=self.attention_impl, mesh=self.mesh,
+                    head_dim=head_dim, sliding_window=self.sliding_window,
+                    rope=self._rotates(i), qk_norm=self.qk_norm,
+                    router_input=self.router_input,
+                    router_score=self.router_score,
+                    expert_activation=self.expert_activation,
+                    name=f"layer{i}")
+            x, counters = block(x, segment_ids, positions)
+            if self.has_experts(i):
                 totals = {key: totals[key] + counters[key]
                           for key in MOE_COUNTERS}
                 n_moe += 1
@@ -428,6 +724,12 @@ class Lfm2ForCausalLM(nn.Module):
         share = self.window_block_share(input_ids.shape[1])
         if share is not None:
             counters["attn_window_block_share"] = jnp.float32(share)
+        if "mamba2_only" in self.layer_types:
+            # Document starts the scan resets at in these rows (the
+            # row's first token is one).
+            counters["ssm_resets"] = jnp.sum(
+                document_starts(segment_ids) & (segment_ids > 0)
+            ).astype(jnp.float32)
         if not counters:
             return logits
         return {"logits": logits, **counters}
@@ -436,9 +738,10 @@ class Lfm2ForCausalLM(nn.Module):
 def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
     """``ModelConfig`` -> module, with the family's own checks."""
     kinds = tuple(config.layer_types)
-    if len(kinds) != config.num_layers or set(kinds) - set(LAYER_KINDS):
+    all_kinds = LAYER_KINDS + SOLO_KINDS
+    if len(kinds) != config.num_layers or set(kinds) - set(all_kinds):
         raise ValueError(
-            f"model.layer_types must name one of {LAYER_KINDS} for each of "
+            f"model.layer_types must name one of {all_kinds} for each of "
             f"model.num_layers={config.num_layers} layers, got {kinds}")
     heads = config.num_heads
     kv_heads = config.num_kv_heads or heads
@@ -462,13 +765,22 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
     if config.router_input not in ROUTER_INPUTS:
         raise ValueError(f"model.router_input must be one of "
                          f"{ROUTER_INPUTS}, got {config.router_input!r}")
-    has_experts = config.num_dense_layers < config.num_layers
+    has_experts = any(layer_has_experts(kinds, config.num_dense_layers, i)
+                      for i in range(len(kinds)))
     if has_experts and not (config.num_experts > 0 and config.moe_mlp_dim > 0
                             and 1 <= config.expert_topk <= config.num_experts):
         raise ValueError(
             "layers past model.num_dense_layers carry experts: set "
             "model.num_experts, model.moe_mlp_dim and 1 <= "
             "model.expert_topk <= model.num_experts")
+    if has_experts:
+        check_expert_settings(config.router_score, config.expert_activation)
+    if config.out_proj_init_std and set(kinds) & set(LAYER_KINDS):
+        raise ValueError(
+            f"model.out_proj_init_std is wired for the one-sublayer kinds "
+            f"{SOLO_KINDS} only; {sorted(set(kinds) & set(LAYER_KINDS))} "
+            f"keep the fan-in rule")
+    _check_tensor_share(config, kinds, heads, kv_heads)
     return Lfm2ForCausalLM(
         vocab_size=config.vocab_size, hidden_size=config.hidden_size,
         layer_types=kinds, num_dense_layers=config.num_dense_layers,
@@ -485,4 +797,53 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
         tie_embeddings=config.tie_embeddings,
         embed_init_std=config.embed_init_std,
         router_input=config.router_input, router_score=config.router_score,
-        expert_activation=config.expert_activation)
+        expert_activation=config.expert_activation,
+        tensor_groups=config.tensor_groups, tensor_group=config.tensor_group,
+        mamba_heads=config.mamba_num_heads,
+        mamba_head_dim=config.mamba_head_dim,
+        mamba_groups=config.mamba_groups, mamba_state=config.ssm_state_size,
+        mamba_chunk=config.mamba_chunk,
+        moe_latent_dim=config.moe_latent_dim,
+        moe_shared_dim=config.moe_shared_dim,
+        routed_scaling=config.routed_scaling,
+        out_proj_init_std=config.out_proj_init_std)
+
+
+def _check_tensor_share(config, kinds, heads: int, kv_heads: int) -> None:
+    """A tensor share is whole groups: whole heads for every chip, no
+    key/value head's queries or B/C group's heads split unevenly, the
+    shared expert's units in even runs."""
+    groups, group = config.tensor_groups, config.tensor_group
+    if "mamba2_only" in kinds:
+        m_heads, m_groups = config.mamba_num_heads, config.mamba_groups
+        if m_heads < 1 or m_groups < 1 or m_heads % m_groups:
+            raise ValueError(
+                f"a mamba2_only layer needs model.mamba_num_heads (got "
+                f"{m_heads}) as a multiple of model.mamba_groups (got "
+                f"{m_groups})")
+    if groups == 1 and group == 0:
+        return
+    if groups < 1 or not 0 <= group < groups:
+        raise ValueError(f"model.tensor_group={group} is not one of "
+                         f"model.tensor_groups={groups}")
+    if set(kinds) & set(LAYER_KINDS):
+        raise ValueError(
+            f"model.tensor_groups > 1 is wired for the one-sublayer kinds "
+            f"{SOLO_KINDS} only; {sorted(set(kinds) & set(LAYER_KINDS))} "
+            f"carry a feed-forward (and a convolution) it does not split")
+    if set(kinds) - {"mamba2_only", "experts_only"} and (
+            heads % groups or (kv_heads % groups and groups % kv_heads)):
+        raise ValueError(
+            f"model.tensor_groups={groups} does not divide attention's "
+            f"{heads} query heads over {kv_heads} key/value heads into "
+            f"whole, even shares")
+    if "experts_only" in kinds and config.moe_shared_dim % groups:
+        raise ValueError(
+            f"model.tensor_groups={groups} does not divide the shared "
+            f"expert's {config.moe_shared_dim} units "
+            f"(model.moe_shared_dim)")
+    if "mamba2_only" in kinds and config.mamba_groups % groups:
+        raise ValueError(
+            f"model.tensor_groups={groups} splits a B/C group of the Mamba-2 "
+            f"layers (model.mamba_groups={config.mamba_groups}): its gated "
+            f"norm would cross chips")

@@ -422,15 +422,20 @@ def route_softmax_topk(gate_logits: jax.Array, topk: int
 ROUTER_SCORES = ("sigmoid_bias", "softmax_topk")
 # Gated experts ``W2(act(W1 x) ⊙ W3 x)``: SwiGLU or ReGLU.
 EXPERT_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+# Experts without a gate, ``W2 act(W1 x)``: two grouped products, no
+# ``W3``. ``relu2`` is the squared ReLU.
+UNGATED_ACTIVATIONS = {"relu2": lambda x: jnp.square(nn.relu(x))}
 
 
 def check_expert_settings(score: str, activation: str) -> None:
     if score not in ROUTER_SCORES:
         raise ValueError(f"model.router_score must be one of "
                          f"{ROUTER_SCORES}, got {score!r}")
-    if activation not in EXPERT_ACTIVATIONS:
-        raise ValueError(f"model.expert_activation must be one of "
-                         f"{tuple(EXPERT_ACTIVATIONS)}, got {activation!r}")
+    if activation not in (*EXPERT_ACTIVATIONS, *UNGATED_ACTIVATIONS):
+        raise ValueError(
+            f"model.expert_activation must be one of "
+            f"{(*EXPERT_ACTIVATIONS, *UNGATED_ACTIVATIONS)}, got "
+            f"{activation!r}")
 
 
 # A group may be sent a quarter more than its even share of the
@@ -542,14 +547,16 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def _sorted_experts(rows: int, activation: str, start, into, operands,
                     routing):
-    """Dispatch, the held experts (gated, ``activation`` on the gate) and
-    combine for the window of ``rows`` sorted assignments from sorted row
-    ``start``: ``into`` (T, H) float32 plus that window's part of the
-    layer's result. ``operands`` are
-    ``(tokens (T, H), weights (T, K) float32, w1, w3, w2)``, all but the
-    weights in the compute dtype; ``routing`` is what ``sort_by_expert``
-    returns, its ``order`` long enough for the window."""
-    tokens, weights, w1, w3, w2 = operands
+    """Dispatch, the held experts (gated with ``activation`` on the gate,
+    or ``W2 act(W1 x)`` for an ungated one) and combine for the window of
+    ``rows`` sorted assignments from sorted row ``start``: ``into``
+    (T, H) float32 plus that window's part of the layer's result.
+    ``operands`` are ``(tokens (T, H), weights (T, K) float32, w1, w3,
+    w2)``, or ``(tokens, weights, w1, w2)`` for ungated experts, all but
+    the weights in the compute dtype; ``routing`` is what
+    ``sort_by_expert`` returns, its ``order`` long enough for the
+    window."""
+    tokens, weights, *kernels = operands
     order, inverse, group_sizes, local = routing
     order = jax.lax.dynamic_slice_in_dim(order, start, rows)
     slot = inverse - start
@@ -563,8 +570,13 @@ def _sorted_experts(rows: int, activation: str, start, into, operands,
     with jax.named_scope("experts"):
         grouped = lambda lhs, w: jax.lax.ragged_dot(  # noqa: E731
             lhs, w, sizes)
-        hidden = EXPERT_ACTIVATIONS[activation](grouped(xs, w1)) \
-            * grouped(xs, w3)
+        if activation in UNGATED_ACTIVATIONS:
+            w1, w2 = kernels
+            hidden = UNGATED_ACTIVATIONS[activation](grouped(xs, w1))
+        else:
+            w1, w3, w2 = kernels
+            hidden = EXPERT_ACTIVATIONS[activation](grouped(xs, w1)) \
+                * grouped(xs, w3)
         ys = grouped(hidden, w2)
     with jax.named_scope("combine"):
         return _combine(jnp.where(valid, ys, 0),
@@ -678,6 +690,32 @@ def sort_by_expert(experts: jax.Array, first: int, held: int):
     return order, inverse, group_sizes, local
 
 
+def projection(features: int, dtype, name: str, init_std: float = 0.0
+                ) -> nn.Dense:
+    """No bias, float32 parameters; normal(0, ``init_std``) where one is
+    given, else the fan-in rule."""
+    init = nn.initializers.normal(init_std) if init_std \
+        else dense_kernel_init
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, kernel_init=init, name=name)
+
+
+class SharedExpert(nn.Module):
+    """``V2 relu(V1 x)²``: the ungated squared-ReLU unit every token
+    takes beside its routed experts."""
+
+    mlp_dim: int
+    dtype: Any = jnp.bfloat16
+    out_init_std: float = 0.0        # of ``down``; 0: the fan-in rule
+
+    @nn.compact
+    def __call__(self, x):
+        up = projection(self.mlp_dim, self.dtype, "up")(x.astype(self.dtype))
+        return projection(x.shape[-1], self.dtype, "down",
+                           self.out_init_std)(
+            UNGATED_ACTIVATIONS["relu2"](up))
+
+
 class DroplessMoE(nn.Module):
     """Expert feed-forward without capacity or dropped tokens.
 
@@ -701,8 +739,16 @@ class DroplessMoE(nn.Module):
     width ``mlp_dim`` (``activation``: SwiGLU or ReGLU) and all
     held experts run as three grouped matrix products
     (``jax.lax.ragged_dot``: on TPU one Mosaic grouped-matmul kernel each,
-    rows beyond the groups untouched); rows that belong to no held expert
-    are zeroed on both sides of the products. With more than one group
+    rows beyond the groups untouched), or an ungated unit ``W2 act(W1 x)``
+    (``relu2``: the squared ReLU) as two; rows that belong to no held
+    expert are zeroed on both sides of the products. With ``latent_dim``
+    the experts read and write a latent of that width (``latent_in`` and
+    ``latent_out``, whole on every process), so dispatch, sorted buffer
+    and combine carry rows of ``latent_dim`` while the router reads the
+    stream; ``weight_scale`` multiplies the routed sum's weights;
+    ``shared_dim`` adds ``V2 relu(V1 x)²`` on the stream itself, an expert
+    every token takes and every process computes alike. With more than
+    one group
     the pass over the buffer is the body of a ``lax.while_loop`` on the
     device (no sync) that runs while local assignments are left: once for
     a routing within ``R``, again on the next ``R`` sorted rows for one
@@ -727,7 +773,12 @@ class DroplessMoE(nn.Module):
     group: int = 0
     dtype: Any = jnp.bfloat16
     score: str = "sigmoid_bias"      # one of ROUTER_SCORES
-    activation: str = "silu"         # one of EXPERT_ACTIVATIONS
+    activation: str = "silu"         # gated (EXPERT_ACTIVATIONS) or not
+    latent_dim: int = 0              # 0: the experts read the stream
+    shared_dim: int = 0              # 0: no shared expert
+    weight_scale: float = 1.0        # on the routed sum's weights
+    out_init_std: float = 0.0        # of latent_out and the shared
+                                     # expert's down; 0: the fan-in rule
 
     @nn.compact
     def __call__(self, x: jax.Array, route_from: jax.Array | None = None
@@ -758,12 +809,20 @@ class DroplessMoE(nn.Module):
             # For whoever applies the model with mutable=["intermediates"]
             # (tests, the router-agreement count of PERF.md); else a no-op.
             self.sow("intermediates", "experts", experts)
-        w1 = self.param("w1", expert_kernel_init, (held, h, self.mlp_dim),
-                        jnp.float32)
-        w3 = self.param("w3", expert_kernel_init, (held, h, self.mlp_dim),
-                        jnp.float32)
-        w2 = self.param("w2", expert_kernel_init, (held, self.mlp_dim, h),
-                        jnp.float32)
+            if self.weight_scale != 1.0:
+                weights = weights * self.weight_scale
+        stream = tokens
+        if self.latent_dim:
+            tokens = projection(self.latent_dim, self.dtype, "latent_in")(
+                tokens.astype(self.dtype))
+        width = tokens.shape[-1]
+        names = ("w1", "w2") if self.activation in UNGATED_ACTIVATIONS \
+            else ("w1", "w3", "w2")
+        w = {name: self.param(
+            name, expert_kernel_init,
+            (held, self.mlp_dim, width) if name == "w2"
+            else (held, width, self.mlp_dim), jnp.float32)
+             for name in names}
         with jax.named_scope("dispatch"):
             order, inverse, group_sizes, local = sort_by_expert(
                 experts, mine.start, held)
@@ -771,18 +830,25 @@ class DroplessMoE(nn.Module):
             routing = (order, inverse, group_sizes, local)
             tokens = tokens.astype(self.dtype)
         with jax.named_scope("experts"):
-            kernels = [w.astype(self.dtype) for w in (w1, w3, w2)]
+            kernels = [w[name].astype(self.dtype) for name in names]
         operands = (tokens, weights, *kernels)
         rows = held_rows(t * k, held, self.num_experts)
         if rows == t * k:
             out = _sorted_experts(
-                rows, self.activation, 0, jnp.zeros((t, h), jnp.float32),
+                rows, self.activation, 0, jnp.zeros((t, width), jnp.float32),
                 operands, routing)
         else:
             # whole windows to slice, whatever the last one's start
             routing = (jnp.pad(order, (0, -(t * k) % rows)), *routing[1:])
             out = _in_windows(rows, self.activation, operands, routing)
         out = out.astype(self.dtype)
+        if self.latent_dim:
+            out = projection(h, self.dtype, "latent_out",
+                              self.out_init_std)(out)
+        if self.shared_dim:
+            out = out + SharedExpert(self.shared_dim, self.dtype,
+                                     self.out_init_std,
+                                     name="shared")(stream)
         sizes = group_sizes.astype(jnp.float32)
         counters = {
             "local_assignments": placed.astype(jnp.float32),

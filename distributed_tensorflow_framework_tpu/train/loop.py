@@ -228,6 +228,10 @@ class Trainer:
                 # as the model says it; None where it has no such layer.
                 expert_share=getattr(self.builder.model, "expert_share",
                                      lambda: None)(),
+                # Which heads of each mixer, likewise (a tensor-parallel
+                # share); None for the whole model.
+                tensor_share=getattr(self.builder.model, "tensor_share",
+                                   lambda: None)(),
             )
         self.writer.telemetry.emit(
             telemetry.KIND_DATA_SHARD, step=self.host_step,
