@@ -240,8 +240,11 @@ class ModelConfig:
     # fits. Supported for the bert models (numerics parity tested); other
     # model families reject it rather than silently ignore it. A decoder
     # layer (models/lfm2.py) keeps, beside its input, its attention
-    # kernels' output and logsumexp (O(S·D) a layer): the re-run forward
-    # pass recomputes everything but the Mosaic forward;
+    # kernels' output and logsumexp (O(S·D) a layer) and what its expert
+    # layer's routing decided (the float32 logits, the chosen experts
+    # and their scores, the sort by expert): the re-run forward pass
+    # recomputes everything but the Mosaic forward, the router's
+    # product, the top-k, the scores' gather and the sorts;
     # precision.remat_policy: save_nothing is the full re-run.
     remat: bool = False
     # What the remat blocks may keep from the forward pass:
@@ -654,13 +657,15 @@ class PrecisionConfig:
     # pipeline stages:
     #   "none"          — defer to model.remat/model.remat_policy (a
     #                     decoder layer then keeps its attention
-    #                     kernels' output and logsumexp, no more);
+    #                     kernels' output and logsumexp and its expert
+    #                     layer's routing, no more);
     #   "dots_saveable" — save matmul outputs, replay the cheap
     #                     elementwise tail (recompute ≈ free, roughly
     #                     half the activation bytes);
     #   "save_nothing"  — save only block inputs, replay everything,
-    #                     the attention kernels' forward included (max
-    #                     memory savings, max recompute — the
+    #                     the attention kernels' forward and the
+    #                     experts' routing included (max memory
+    #                     savings, max recompute — the
     #                     long-context fit lever).
     # Needs model.remat=true (pipeline stages excepted) and conflicts
     # with resnet's model.remat_policy="conv_saved" spelling.

@@ -73,19 +73,31 @@ document, ``positions`` restart at each document, a convolution tap
 that would reach across a document boundary reads zero, and a Mamba-2
 layer's state starts from zero at each document.
 
-``remat`` checkpoints each layer (``nn.remat``). With no policy handed
-down (``precision.remat_policy: none``) a layer keeps, beside its input,
-the two values its attention backward kernels read from the forward
-kernel: the output and the logsumexp (ops/flash_attention.py's
-``RESIDUAL_NAMES``; O(S·D) a layer, as large as the input), and the
-re-run forward pass recomputes everything else. That engages wherever
-the layer's attention is the direct Pallas call (one device, or inside
-manual axes). Where there are no names to keep the layer re-runs whole,
-to the same result: ``attention_impl: xla``, conv layers, and the
-``shard_map`` the kernels wrap themselves in under a multi-device
-``jit``, whose equation hides the names from the layer's checkpoint.
+What ``model.remat`` keeps: ``remat`` checkpoints each layer
+(``nn.remat``). With no policy handed down (``precision.remat_policy:
+none``) a layer keeps, beside its input, two sets of named values, and
+the re-run forward pass recomputes everything else:
+
+  * the two values its attention backward kernels read from the forward
+    kernel, the output and the logsumexp (ops/flash_attention.py's
+    ``RESIDUAL_NAMES``; O(S·D) a layer, as large as the input). That
+    engages wherever the layer's attention is the direct Pallas call
+    (one device, or inside manual axes); ``attention_impl: xla``, conv
+    layers, and the ``shard_map`` the kernels wrap themselves in under a
+    multi-device ``jit``, whose equation hides the names from the
+    layer's checkpoint, have no such names, and that part of the layer
+    re-runs whole, to the same result;
+  * what its expert layer's routing decided (models/moe.py's
+    ``ROUTING_NAMES``): the router's float32 logits (T × num_experts),
+    the chosen experts and their scores (T × K each) and the sort by
+    expert (``order``, ``inverse``: T·K int32 each; ``group_sizes``: one
+    a held expert), so the router's product, ``lax.top_k``, the gather
+    of the chosen scores, the two argsorts and the count run once a
+    step; the scores and the weights are recomputed from the kept
+    values, because the backward pass differentiates through them.
+
 ``precision.remat_policy: save_nothing`` is the full re-run,
-``dots_saveable`` keeps the products' outputs.
+``dots_saveable`` keeps the products' outputs and no name.
 
 Scopes a trace can be read by (docs/OBSERVABILITY.md):
 ``layerN/short_conv/{in_proj,gate_conv,out_proj}``, ``layerN/attn/...``
@@ -109,7 +121,8 @@ import jax.numpy as jnp
 
 from distributed_tensorflow_framework_tpu.models.layers import dense_kernel_init
 from distributed_tensorflow_framework_tpu.models.moe import (
-    DroplessMoE, check_expert_settings, held_experts, projection)
+    ROUTING_NAMES, DroplessMoE, check_expert_settings, held_experts,
+    projection)
 
 # A layer is a mixer and then a feed-forward ...
 LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
@@ -667,14 +680,16 @@ class Lfm2ForCausalLM(nn.Module):
         if self.remat:
             policy = self.ckpt_policy
             if policy is None:
-                # Keep what the attention backward kernels read, so the
-                # forward kernel is not run again (module docstring).
+                # Keep what the attention backward kernels read and what
+                # the expert layer's routing decided, so neither the
+                # forward kernel nor the top-k, the chosen scores' gather
+                # and the sorts are run again (module docstring).
                 from distributed_tensorflow_framework_tpu.ops.flash_attention import (
                     RESIDUAL_NAMES,
                 )
 
                 policy = jax.checkpoint_policies.save_only_these_names(
-                    *RESIDUAL_NAMES)
+                    *RESIDUAL_NAMES, *ROUTING_NAMES)
             block_cls = nn.remat(Lfm2Block, policy=policy)
             solo_cls = nn.remat(SoloBlock, policy=policy)
         totals = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}

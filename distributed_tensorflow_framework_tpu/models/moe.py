@@ -21,6 +21,8 @@ over all experts and computes its own experts' part of the result: the
 sorted buffer has rows for the held share of the ``T·K`` assignments
 (``held_rows``), and the layer goes over that buffer in a loop on the
 device: once for a routing within it, again for every bufferful more.
+What its routing decides carries names (``ROUTING_NAMES``) that a remat
+policy can keep, so a checkpointed layer chooses and sorts once a step.
 
 Design points of the capacity layer:
   * **Two dispatchers, one semantics** (parity pinned in tests/test_moe.py):
@@ -48,6 +50,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_tensorflow_framework_tpu.models.layers import dense_kernel_init
 
@@ -392,6 +395,58 @@ def held_experts(num_experts: int, groups: int, group: int) -> range:
     return range(group * n, (group + 1) * n)
 
 
+# What the routing decides, named (``jax.ad_checkpoint.checkpoint_name``)
+# for a remat policy to keep (``save_only_these_names(*ROUTING_NAMES)``,
+# models/lfm2.py): the router's float32 logits, the chosen experts and
+# their scores, and the sort by expert's ``order`` (as the windows slice
+# it), ``inverse`` and ``group_sizes``. With them kept, the product that
+# made the logits, the top-k, the gather of the chosen scores (an XLA
+# gather of K in E a token: as long as the top-k on a TPU), the two
+# argsorts and the count have no reader in the forward pass that the
+# backward pass re-runs; the scores and the weights are worked out there
+# again from the kept values, because the backward pass differentiates
+# through them. A name is an identity for every other caller.
+LOGITS_NAME = "moe_router_logits"
+EXPERTS_NAME = "moe_experts"
+CHOSEN_NAME = "moe_chosen_scores"
+ORDER_NAME = "moe_order"
+INVERSE_NAME = "moe_inverse"
+GROUP_SIZES_NAME = "moe_group_sizes"
+ROUTING_NAMES = (LOGITS_NAME, EXPERTS_NAME, CHOSEN_NAME, ORDER_NAME,
+                 INVERSE_NAME, GROUP_SIZES_NAME)
+
+
+def _top_experts(by: jax.Array, topk: int) -> tuple[jax.Array, jax.Array]:
+    """``lax.top_k`` of ``by`` as a choice: ``(values, experts (T, K)
+    int32, named)``, neither with a gradient, so a pass that was handed
+    the choice runs no top-k."""
+    values, experts = jax.lax.top_k(jax.lax.stop_gradient(by), topk)
+    return values, checkpoint_name(experts.astype(jnp.int32), EXPERTS_NAME)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _as_gathered(width: int, scores, experts, values):
+    """``take_along_axis(scores (T, width), experts, -1)`` for a caller
+    that holds those ``values`` already (``lax.top_k``'s own): they are
+    returned as given, and differentiated as the gather they stand for."""
+    del width, scores, experts
+    return values
+
+
+def _as_gathered_fwd(width, scores, experts, values):
+    del width, scores
+    return values, experts
+
+
+def _as_gathered_bwd(width, experts, g):
+    rows = jnp.arange(experts.shape[0])[:, None]
+    return (jnp.zeros((experts.shape[0], width), g.dtype).at[
+        rows, experts].add(g), None, None)
+
+
+_as_gathered.defvjp(_as_gathered_fwd, _as_gathered_bwd)
+
+
 def route_sigmoid_topk(gate_logits: jax.Array, bias: jax.Array, topk: int
                        ) -> tuple[jax.Array, jax.Array]:
     """Bias-routed top-k over sigmoid scores, in float32.
@@ -401,11 +456,11 @@ def route_sigmoid_topk(gate_logits: jax.Array, bias: jax.Array, topk: int
     no gradient); their weights are ``s_e / (sum of the chosen s + 1e-6)``.
     Returns ``(experts (T, K) int32, weights (T, K) float32)``."""
     scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
-    _, experts = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), topk)
-    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    _, experts = _top_experts(scores + bias.astype(jnp.float32), topk)
+    chosen = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
+                             CHOSEN_NAME)
     weights = chosen / (chosen.sum(axis=-1, keepdims=True) + ROUTER_NORM_EPS)
-    return experts.astype(jnp.int32), weights
+    return experts, weights
 
 
 def route_softmax_topk(gate_logits: jax.Array, topk: int
@@ -415,8 +470,11 @@ def route_softmax_topk(gate_logits: jax.Array, topk: int
     weights ``softmax(logits[chosen])`` (a softmax over all experts
     renormalised over the chosen gives the same numbers). No selection
     bias. Returns ``(experts (T, K) int32, weights (T, K) float32)``."""
-    chosen, experts = jax.lax.top_k(gate_logits.astype(jnp.float32), topk)
-    return experts.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+    logits = gate_logits.astype(jnp.float32)
+    values, experts = _top_experts(logits, topk)
+    chosen = checkpoint_name(
+        _as_gathered(logits.shape[-1], logits, experts, values), CHOSEN_NAME)
+    return experts, jax.nn.softmax(chosen, axis=-1)
 
 
 ROUTER_SCORES = ("sigmoid_bias", "softmax_topk")
@@ -758,6 +816,15 @@ class DroplessMoE(nn.Module):
     result. A routing over ``R`` is slower by the rows it adds, and the
     result is the same for any routing.
 
+    What the routing decides is named for a remat policy to keep
+    (``ROUTING_NAMES``: the logits, the chosen experts and their scores,
+    ``order`` as the windows slice it, ``inverse``, ``group_sizes``): a
+    layer under ``nn.remat`` whose policy keeps them (models/lfm2.py's
+    default) takes them from its first forward pass, and the product, the
+    top-k, the scores' gather, the sorts and the count are dead code in
+    the pass the backward re-runs;
+    both paths (one group, several) read them from this one call site.
+
     Returns ``(out (B, S, H), counters)``; the counters are explicit
     outputs so they survive ``nn.remat``: ``local_assignments`` (rows
     computed here), ``load_max_mean`` (fullest held expert ÷ mean),
@@ -800,8 +867,9 @@ class DroplessMoE(nn.Module):
                 "expert_bias", nn.initializers.normal(EXPERT_BIAS_INIT_STD),
                 (self.num_experts,), jnp.float32) \
                 if self.score == "sigmoid_bias" else None
-            logits = jnp.dot(routed.astype(jnp.float32), gate,
-                             precision=jax.lax.Precision.HIGHEST)
+            logits = checkpoint_name(
+                jnp.dot(routed.astype(jnp.float32), gate,
+                        precision=jax.lax.Precision.HIGHEST), LOGITS_NAME)
             if bias is not None:
                 experts, weights = route_sigmoid_topk(logits, bias, k)
             else:
@@ -827,20 +895,26 @@ class DroplessMoE(nn.Module):
             order, inverse, group_sizes, local = sort_by_expert(
                 experts, mine.start, held)
             placed = group_sizes.sum()
-            routing = (order, inverse, group_sizes, local)
             tokens = tokens.astype(self.dtype)
         with jax.named_scope("experts"):
             kernels = [w[name].astype(self.dtype) for name in names]
         operands = (tokens, weights, *kernels)
         rows = held_rows(t * k, held, self.num_experts)
+
+        def routing(order):
+            return (checkpoint_name(order, ORDER_NAME),
+                    checkpoint_name(inverse, INVERSE_NAME),
+                    checkpoint_name(group_sizes, GROUP_SIZES_NAME), local)
+
         if rows == t * k:
             out = _sorted_experts(
                 rows, self.activation, 0, jnp.zeros((t, width), jnp.float32),
-                operands, routing)
+                operands, routing(order))
         else:
             # whole windows to slice, whatever the last one's start
-            routing = (jnp.pad(order, (0, -(t * k) % rows)), *routing[1:])
-            out = _in_windows(rows, self.activation, operands, routing)
+            out = _in_windows(
+                rows, self.activation, operands,
+                routing(jnp.pad(order, (0, -(t * k) % rows))))
         out = out.astype(self.dtype)
         if self.latent_dim:
             out = projection(h, self.dtype, "latent_out",

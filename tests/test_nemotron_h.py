@@ -870,13 +870,15 @@ TINY = {
         "model.expert_groups=4", "model.expert_group=1"]),
 }
 # sha256 of the lowered train step (StableHLO text, no locations) of the
-# two tiny cuts above at the parent commit (dc9e774), by
-# ``lowered_step_digest`` run there.
+# two tiny cuts above, by ``lowered_step_digest``: at PR 33, whose remat'd
+# layers keep the expert layer's routing and lower to another text than
+# PR 31's (dc9e774: 935ebde3... and 4a57729c...), which is what PR 32's
+# tree still lowered to.
 PARENT_STEP = {
     "lfm2":
-        "935ebde33d59703d3d4cafc189a2199edbc0c3bfacd9fda7b88230abc58e2492",
+        "4877857c625d8295825fcda9302f4bd009d820a71c8844f72776f4d204c0757f",
     "smallthinker":
-        "4a57729c47dc538ec2f81f942088f3310cedd302a4b7c2656e3c4d5879e56073",
+        "19d076bc9a393ee704dda25d94c5d2737ec057d58ada8a4404ec2eb6801e5cad",
 }
 
 
@@ -913,6 +915,6 @@ def lowered_step_digest(which: str) -> str:
 def test_the_decoders_already_there_lower_to_the_parents_step(devices, which):
     """``lfm2_8b_a1b`` and ``smallthinker_21b_a3b`` (tiny cuts of their
     shipped YAMLs, bfloat16, remat, the kernels): the lowered train step
-    is the parent commit's text, byte for byte. Every new setting
-    defaults to what they run."""
+    is the recorded text, byte for byte. Every setting this family has
+    gained since defaults to what they run."""
     assert lowered_step_digest(which) == PARENT_STEP[which]

@@ -9,6 +9,7 @@ parity + eval equality + finite training instead of whole-model bitwise
 gradients (see test_inception_remat_block_parity_and_trains).
 """
 
+import collections
 import functools
 import os
 import re
@@ -21,7 +22,8 @@ from jax.ad_checkpoint import checkpoint_policies
 
 from distributed_tensorflow_framework_tpu.core.config import (
     ModelConfig, PrecisionConfig, load_config)
-from distributed_tensorflow_framework_tpu.models import get_model
+from distributed_tensorflow_framework_tpu.models import get_model, moe
+from distributed_tensorflow_framework_tpu.models import lfm2 as family
 from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
 
 
@@ -306,9 +308,11 @@ def test_forward_kernel_calls_in_the_gradients_jaxpr(
         forward_calls, attention_layers)
 
 
-def test_the_xla_attention_has_no_names_to_keep(devices):
+def test_the_xla_attention_has_no_names_to_keep(devices, monkeypatch):
     """``attention_impl: xla`` under the same policy: no kernel, no name,
-    the layer re-runs whole as under ``save_nothing``."""
+    the layer re-runs whole as under ``save_nothing`` (the expert
+    layer's names apart, PR 33: taken out here)."""
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
     inputs = _decoder_inputs(True)
     texts = []
     for policy in ("none", "save_nothing"):
@@ -446,4 +450,268 @@ def test_the_tags_are_inert_without_a_policy_that_asks_for_them(
         texts.append(step_text())
     tagged, untagged = texts
     assert "_flash_fwd" in tagged and "_flash_bwd" in tagged
+    assert tagged == untagged
+
+
+# ------------------------------------------------------------------------
+# What ``model.remat`` keeps from an expert layer's forward pass (PR 33):
+# the router's logits, the chosen experts and their scores and the sort by
+# expert, so the product, the top-k, the scores' gather, the two argsorts
+# and the count are dead code in the re-run forward pass.
+EXP_B, EXP_S, EXP_LAYERS = 2, 256, 2     # two expert layers in each stack
+EXPERT_STACKS = {
+    # sigmoid scores with a selection bias, SwiGLU experts, a dense layer
+    "lfm2": dict(
+        name="lfm2_moe", num_layers=3,
+        layer_types=["conv", "full_attention", "conv"], num_dense_layers=1,
+        num_heads=4, num_kv_heads=2, mlp_dim=128, moe_mlp_dim=32,
+        num_experts=8, expert_topk=2),
+    # softmax over the chosen logits, a router that reads the stream
+    # before attention (``route_from``), ReGLU experts
+    "smallthinker": dict(
+        name="smallthinker_moe", num_layers=2,
+        layer_types=["full_attention", "sliding_attention"],
+        rope_layout=[0, 1], sliding_window=24, num_dense_layers=0,
+        num_heads=4, num_kv_heads=2, head_dim=32, qk_norm=False,
+        moe_mlp_dim=32, num_experts=8, expert_topk=2, router_input="stream",
+        router_score="softmax_topk", expert_activation="relu",
+        tie_embeddings=False, embed_init_std=1.0, norm_eps=1e-6,
+        rope_theta=1.5e6),
+    # layers of one sublayer: ungated experts in a latent beside a shared
+    # expert, scaled weights
+    "nemotron_h": dict(
+        name="nemotron_h", num_layers=3,
+        layer_types=["experts_only", "mamba2_only", "experts_only"],
+        rope_layout=[0, 0, 0], num_dense_layers=0, num_heads=8,
+        num_kv_heads=2, head_dim=16, qk_norm=False, mamba_num_heads=8,
+        mamba_head_dim=8, mamba_groups=2, ssm_state_size=16, mamba_chunk=32,
+        conv_kernel=4, moe_mlp_dim=24, moe_latent_dim=32, moe_shared_dim=48,
+        num_experts=16, expert_topk=3, routed_scaling=5.0,
+        router_score="sigmoid_bias", expert_activation="relu2",
+        tie_embeddings=False, norm_eps=1e-5, rope_theta=10000.0),
+}
+EXPERT_CASES = [(kind, groups) for kind in EXPERT_STACKS for groups in (1, 4)]
+EXPERT_IDS = [f"{kind}-groups{groups}" for kind, groups in EXPERT_CASES]
+# ``model.remat`` and ``precision.remat_policy``
+KEPT, SAVE_NOTHING, REMAT_OFF = (True, "none"), (True, "save_nothing"), \
+    (False, "none")
+
+
+def _expert_stack(kind: str, groups: int, remat: bool, policy: str):
+    cfg = ModelConfig(
+        vocab_size=DEC_VOCAB, hidden_size=64, dtype="float32",
+        attention_impl="xla", dropout_rate=0.0, remat=remat,
+        expert_groups=groups, **EXPERT_STACKS[kind])
+    # one group runs ``_sorted_experts`` under JAX's own differentiation,
+    # several the loop over windows with its hand-written backward
+    rows = moe.held_rows(EXP_B * EXP_S * cfg.expert_topk,
+                         cfg.num_experts // groups, cfg.num_experts)
+    assert (rows < EXP_B * EXP_S * cfg.expert_topk) == (groups > 1)
+    return get_model(cfg, precision=PrecisionConfig(remat_policy=policy))
+
+
+def _expert_inputs():
+    rng = np.random.default_rng(11)
+    return (jnp.asarray(rng.integers(0, DEC_VOCAB, (EXP_B, EXP_S)),
+                        jnp.int32),)
+
+
+def _expert_loss(model):
+    def loss(params):
+        out = model.apply({"params": params}, *_expert_inputs())
+        return (out["logits"].astype(jnp.float32) ** 2).mean(), {
+            key: out[f"moe_{key}"] for key in family.MOE_COUNTERS}
+
+    return loss
+
+
+@functools.cache
+def _expert_params(kind: str, groups: int):
+    return _expert_stack(kind, groups, False, "none").init(
+        jax.random.key(0), *_expert_inputs())["params"]
+
+
+@functools.cache
+def _expert_step(kind: str, groups: int, remat: bool, policy: str):
+    """Loss, the five counters and every gradient, op by op (as
+    ``_decoder_grads``: the arithmetic the policies leave is compared)."""
+    (loss, counters), grads = jax.value_and_grad(
+        _expert_loss(_expert_stack(kind, groups, remat, policy)),
+        has_aux=True)(_expert_params(kind, groups))
+    return jax.device_get((loss, counters, grads))
+
+
+def _equations(jaxpr, counts=None) -> dict:
+    """How often each primitive stands in ``jaxpr``, the equations of
+    every sub-jaxpr (a ``pjit``, the re-run pass's ``checkpoint``, a
+    loop's body) counted where they stand."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _equations(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("kind,groups", EXPERT_CASES, ids=EXPERT_IDS)
+@pytest.mark.parametrize("remat,policy,passes", [
+    (*KEPT, 1),             # once an expert layer
+    (*SAVE_NOTHING, 2),     # the full re-run: twice
+    (True, "dots_saveable", 2),   # what it says: the logits, not the choice
+    (*REMAT_OFF, 1),
+], ids=["kept", "save_nothing", "dots_saveable", "remat_off"])
+def test_routing_equations_in_the_gradients_jaxpr(
+        devices, kind, groups, remat, policy, passes):
+    """``lax.top_k`` and the two argsorts of ``sort_by_expert`` stand
+    once an expert layer in the gradient's jaxpr under the default policy
+    and twice under the full re-run; the router's product stands a third
+    time only where the logits are not kept."""
+    model = _expert_stack(kind, groups, remat, policy)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          _expert_params(kind, groups))
+    grad = jax.grad(lambda p: _expert_loss(model)(p)[0])
+    counts = _equations(jax.make_jaxpr(grad)(params).jaxpr)
+    assert counts["top_k"] == EXP_LAYERS * passes
+    assert counts["sort"] == 2 * EXP_LAYERS * passes
+    if (remat, policy) != SAVE_NOTHING:
+        return
+    kept = _equations(jax.make_jaxpr(jax.grad(lambda p: _expert_loss(
+        _expert_stack(kind, groups, *KEPT))(p)[0]))(params).jaxpr)
+    # the logits' product and the count (a scatter-add of ones) as well,
+    # and the gather of the chosen sigmoid scores (the softmax router
+    # takes ``top_k``'s values)
+    assert counts["dot_general"] - kept["dot_general"] == EXP_LAYERS
+    assert counts["scatter-add"] - kept["scatter-add"] == EXP_LAYERS
+    assert counts["gather"] - kept["gather"] == (
+        0 if kind == "smallthinker" else EXP_LAYERS)
+    if groups > 1:       # and the pad of ``order`` to whole windows
+        assert counts["pad"] - kept["pad"] == EXP_LAYERS
+
+
+@pytest.mark.parametrize("kind,groups", EXPERT_CASES, ids=EXPERT_IDS)
+@pytest.mark.parametrize("other", [SAVE_NOTHING, REMAT_OFF],
+                         ids=["save_nothing", "remat_off"])
+def test_kept_routing_leaves_loss_gradients_and_counters_alone(
+        devices, kind, groups, other):
+    """The re-run pass reads the first pass's own logits, choice and
+    sort, not an identical second copy: loss, every parameter's gradient
+    and the five ``moe_*`` counters equal the full re-run's and the
+    un-remat'd model's, to 0.0."""
+    loss, counters, grads = _expert_step(kind, groups, *KEPT)
+    want_loss, want_counters, want_grads = _expert_step(kind, groups, *other)
+    assert loss == want_loss and np.isfinite(loss)
+    assert counters.keys() == set(family.MOE_COUNTERS) and all(
+        counters[key] == want_counters[key] for key in counters)
+    assert counters["dropped"] == 0.0 and counters["local_share"] == (
+        1.0 if groups == 1 else pytest.approx(0.25, abs=0.1))
+    flat = dict(jax.tree_util.tree_leaves_with_path(grads))
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    assert flat.keys() == flat_want.keys() and len(flat) > 15
+    for path, g in flat.items():
+        name = jax.tree_util.keystr(path)
+        # the selection bias enters the choice only
+        assert np.any(g) != ("expert_bias" in name), name
+        np.testing.assert_array_equal(g, flat_want[path], err_msg=name)
+
+
+# What one kept name leaves out of the re-run pass, as the difference of
+# the equation counts from a policy that keeps nothing.
+ONE_NAME = {
+    moe.LOGITS_NAME: {"dot_general": 1},
+    moe.EXPERTS_NAME: {"top_k": 1},
+    # ``take_along_axis`` of the sigmoid scores
+    moe.CHOSEN_NAME: {"gather": 1},
+    # ``inverse`` is the argsort of the order as sorted, not as named
+    moe.ORDER_NAME: {"pad": 1},
+    moe.INVERSE_NAME: {"sort": 1},
+    moe.GROUP_SIZES_NAME: {"scatter-add": 1},
+}
+
+
+@pytest.mark.parametrize("score,groups", [
+    ("sigmoid_bias", 1), ("sigmoid_bias", 4), ("softmax_topk", 4)])
+@pytest.mark.parametrize("names", [
+    *[(name,) for name in moe.ROUTING_NAMES],
+    (moe.ORDER_NAME, moe.INVERSE_NAME), moe.ROUTING_NAMES],
+    ids=lambda names: "+".join(n.removeprefix("moe_") for n in names)
+    if len(names) < len(moe.ROUTING_NAMES) else "all")
+def test_each_routing_name_keeps_what_it_names(devices, score, groups, names):
+    """``DroplessMoE`` under ``jax.checkpoint`` with one name in the
+    policy: only the operation that made the named value leaves the
+    re-run pass; ``order`` and ``inverse`` together take both argsorts."""
+    assert set(ONE_NAME) == set(moe.ROUTING_NAMES)
+    layer = moe.DroplessMoE(num_experts=8, mlp_dim=32, topk=2, groups=groups,
+                            dtype=jnp.float32, score=score)
+    x = jax.ShapeDtypeStruct((EXP_B, EXP_S, 64), jnp.float32)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros(x.shape))["params"])
+
+    def counts_under(*kept):
+        run = jax.checkpoint(
+            lambda p, x: layer.apply({"params": p}, x)[0].sum(),
+            policy=checkpoint_policies.save_only_these_names(*kept))
+        return _equations(
+            jax.make_jaxpr(jax.grad(run, argnums=(0, 1)))(params, x).jaxpr)
+
+    nothing, got = counts_under(), counts_under(*names)
+    assert (nothing["top_k"], nothing["sort"]) == (2, 4)
+    want: dict = {}
+    for name in names:
+        for op, n in ONE_NAME[name].items():
+            want[op] = want.get(op, 0) + n
+    if groups == 1:
+        want.pop("pad", None)        # one group slices no window
+    if score == "softmax_topk":
+        # ``top_k`` makes both the choice and its scores there: it leaves
+        # when both are kept, and there is no gather to leave
+        want.pop("gather", None)
+        if not {moe.EXPERTS_NAME, moe.CHOSEN_NAME} <= set(names):
+            want.pop("top_k", None)
+    if {moe.ORDER_NAME, moe.INVERSE_NAME} <= set(names):
+        want["sort"] = 2
+    gone = {op: nothing[op] - got[op]
+            for op in ("dot_general", "top_k", "gather", "sort", "scatter-add",
+                       "pad")
+            if nothing[op] != got[op]}
+    assert gone == want
+    # a kept value's identity equation stands once, not twice
+    assert nothing["name"] - got["name"] == len(names)
+
+
+def _resnet_step_text():
+    from distributed_tensorflow_framework_tpu.core.mesh import create_mesh
+    from distributed_tensorflow_framework_tpu.train.step import StepBuilder
+
+    cfg = load_config(base={
+        "name": "remat-tags-test", "mesh": {"data": 1},
+        "model": {"name": "resnet18_cifar", "num_classes": 10,
+                  "dtype": "float32", "remat": True},
+        "data": {"name": "cifar10", "num_classes": 10, "image_size": 32,
+                 "global_batch_size": 2},
+        "optimizer": {"name": "sgd_momentum", "learning_rate": 0.1},
+        "train": {"total_steps": 2}})
+    builder = StepBuilder(cfg, create_mesh(cfg.mesh,
+                                           devices=jax.devices()[:1]))
+    sample = {"image": jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32),
+              "label": jax.ShapeDtypeStruct((2,), jnp.int32)}
+    return _compiled_step(builder, sample)
+
+
+@pytest.mark.parametrize("step_text,experts", [
+    (_bert_step_text, False), (_resnet_step_text, False),
+    (_decoder_step_text, True)],
+    ids=["bert", "resnet", "decoder_without_remat"])
+def test_the_routings_names_are_inert_without_a_policy_that_asks_for_them(
+        devices, monkeypatch, step_text, experts):
+    """BERT's step, ResNet's (under its own ``nn.remat``) and an
+    un-remat'd decoder's compile to the program they compile to with no
+    routing value named, metadata and all: a name is no operation."""
+    texts = []
+    for named in (True, False):
+        jax.clear_caches()
+        if not named:
+            monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+        texts.append(step_text())
+    tagged, untagged = texts
+    assert ("moe/router" in tagged) == experts
     assert tagged == untagged
