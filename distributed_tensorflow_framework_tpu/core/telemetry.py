@@ -67,10 +67,19 @@ KIND_CRASH_LOOP = "crash_loop"
 # blocked ≪ total; the sync fallback shows blocked == total.
 KIND_CKPT_SAVE = "ckpt_save"
 # One per process: wall time from trainer construction to the first
-# completed step (restore + input build + compile). The supervisor-relaunch
-# cost the persistent XLA compilation cache (core/platform.py) exists to
-# shrink.
+# dispatch of the step returning — the step is then in flight, not
+# complete — (restore + input build + compile, and whatever the caller
+# did while it held the trainer). The supervisor-relaunch cost the
+# persistent XLA compilation cache (core/platform.py) exists to shrink.
+# ``extra`` says where it went: ``phases_s`` (seconds per span of the
+# loop's recorder, the ``startup:*`` ones and the first ``snapshot``,
+# ``infeed`` and ``train_step``), ``outside_s`` (the rest: between the
+# program's spans), ``process_s`` (OS process start → construction) and
+# ``compile`` (what JAX traced, compiled and loaded meanwhile:
+# core/profiling.CompileLog).
 KIND_STARTUP = "startup"
+# ``extra`` fields of a KIND_STARTUP event that the rollup keeps as they are.
+STARTUP_PARTS = ("phases_s", "outside_s", "process_s", "compile")
 # In-process recovery ladder (train/anomaly.py, docs/RESILIENCE.md): a
 # detected bad step (non-finite metric, loss spike, grad-norm explosion),
 # the in-memory rollback that answered it, the data range skipped by
@@ -434,7 +443,8 @@ def summarize_events(path: str) -> dict:
     summarizing. Returns event counts by kind, the step span, a
     ``ckpt_saves`` section (save count, async count, and loop-blocked vs
     total save milliseconds — the async-pipeline win is blocked ≪ total),
-    a ``startups`` list (restart → first-step latency per process), a
+    a ``startups`` list (restart → first-step latency per process, and
+    its ``STARTUP_PARTS`` where the event has them), a
     ``collectives`` section (the last per-step wire/logical byte tally and
     the resulting wire_compression ratio), and a
     ``recovery`` section: quarantined checkpoint steps, restore fallbacks
@@ -587,6 +597,7 @@ def summarize_events(path: str) -> dict:
                 "step": step,
                 "time_to_first_step_s": extra.get("time_to_first_step_s"),
                 "restored_step": extra.get("restored_step"),
+                **{k: extra[k] for k in STARTUP_PARTS if k in extra},
             })
         elif kind == KIND_PIPELINE:
             pipeline = dict(extra)
@@ -961,6 +972,25 @@ def _fmt_axes(axes: dict | None) -> str:
     return "{" + ", ".join(parts) + "}" if parts else "{1 device}"
 
 
+def _startup_parts(startup: Mapping[str, Any]) -> str:
+    """The tail of the summary's ``startup:`` line: the three largest
+    phases, what lay outside them, and how many executables the restart
+    loaded from the compile cache and how many it compiled."""
+    parts = []
+    phases = startup.get("phases_s") or {}
+    top = sorted(phases.items(), key=lambda kv: kv[1], reverse=True)[:3]
+    if top:
+        parts.append(", ".join(f"{name} {sec:.1f}s" for name, sec in top))
+    outside = startup.get("outside_s")
+    if isinstance(outside, (int, float)):
+        parts.append(f"outside {outside:.1f}s")
+    comp = startup.get("compile") or {}
+    if comp:
+        parts.append(f"{comp.get('cache_hits', 0)} loaded, "
+                     f"{comp.get('xla_compiles', 0)} compiled")
+    return ": " + "; ".join(parts) if parts else ""
+
+
 def format_run_summary(summary: dict) -> str:
     """Human-readable rendering of ``summarize_events`` output."""
     lines = [f"run summary: {summary['path']}"]
@@ -1239,6 +1269,7 @@ def format_run_summary(summary: dict) -> str:
             f"  startup: {t_str} to first step"
             + (f" (restored step {s['restored_step']})"
                if s.get("restored_step") is not None else " (fresh)")
+            + _startup_parts(s)
         )
     rec = summary["recovery"]
     activity = (

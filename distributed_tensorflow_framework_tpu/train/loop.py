@@ -75,28 +75,54 @@ def _scale_batch(batch: dict, factor: float) -> dict:
 class Trainer:
     def __init__(self, config: ExperimentConfig, runtime: MeshRuntime | None = None):
         setup_logging()
-        # Startup-latency clock: construction → first completed step covers
+        # Startup-latency clock: construction → the first dispatch of the
+        # step returning (the step itself is still in flight then) covers
         # restore + input build + compile, the relaunch cost a supervisor
-        # pays on every preemption (emitted as a KIND_STARTUP event).
+        # pays on every preemption (emitted as a KIND_STARTUP event), and
+        # whatever the caller does while it holds the trainer in between.
         self._init_t = time.perf_counter()
         self._init_mono = time.monotonic()  # train.startup span backfill
+        # What the process had already spent when construction began
+        # (interpreter, imports, backend start, config), by the OS's
+        # record of when it started the process.
+        self._process_s = profiling.process_age_s()
         self._startup_emitted = False
         self._restored_step: int | None = None
         self.config = config
-        self.runtime = runtime or initialize_runtime(config.mesh)
+        # The loop's recorder (core/profiling.py): every stretch of an
+        # iteration runs under one of its phases — the per-phase totals
+        # become ``time_*_ms`` at every log interval and feed the goodput
+        # ledger, each occurrence lands in a ring (the loop timeline,
+        # dumped beside the flight recorder) and in any profile as a
+        # trace annotation. The cheap always-on signal for "is the input
+        # pipeline the wall?" (SURVEY.md §7 hard part 1), and for "which
+        # span of which iteration was long?", without capturing a trace.
+        # Made first: every stretch from here to the first dispatch runs
+        # under one of its ``startup:*`` spans (ring and annotation, no
+        # total: the goodput ledger charges that wall as one bucket), and
+        # its compile log says what JAX traced, compiled and loaded.
+        self.timer = profiling.StepTimer()
+        if runtime is None:
+            with self.timer.span("startup:runtime"):
+                runtime = initialize_runtime(config.mesh)
+        self.runtime = runtime
         self.mesh = self.runtime.mesh
-        self.dataset = get_dataset(
-            config.data,
-            process_index=self.runtime.process_index,
-            process_count=self.runtime.process_count,
-        )
+        with self.timer.span("startup:dataset"):
+            self.dataset = get_dataset(
+                config.data,
+                process_index=self.runtime.process_index,
+                process_count=self.runtime.process_count,
+            )
         self.builder = StepBuilder(config, self.mesh)
-        self.writer = MetricWriter(
-            logdir=(config.checkpoint.directory or None),
-            is_chief=self.runtime.is_chief,
-            process_index=self.runtime.process_index,
-            process_count=self.runtime.process_count,
-        )
+        # With a log directory the chief's writer brings in TensorBoard's
+        # event writer: seconds of imports on the first use in a process.
+        with self.timer.span("startup:writer"):
+            self.writer = MetricWriter(
+                logdir=(config.checkpoint.directory or None),
+                is_chief=self.runtime.is_chief,
+                process_index=self.runtime.process_index,
+                process_count=self.runtime.process_count,
+            )
         self.run_id = self.writer.run_id
         # In-process recovery ladder (train/anomaly.py): detect → rollback
         # → re-warmup → escalate. None when resilience.rollback=false —
@@ -119,16 +145,13 @@ class Trainer:
             process_id=(self.runtime.process_index
                         if self.runtime.process_count > 1 else None))
         self._startup_accounted = False
-        # The loop's recorder (core/profiling.py): every stretch of an
-        # iteration runs under one of its phases — the per-phase totals
-        # become ``time_*_ms`` at every log interval and feed the goodput
-        # ledger, each occurrence lands in a ring (the loop timeline,
-        # dumped beside the flight recorder) and in any profile as a
-        # trace annotation. The cheap always-on signal for "is the input
-        # pipeline the wall?" (SURVEY.md §7 hard part 1), and for "which
-        # span of which iteration was long?", without capturing a trace.
-        self.timer = profiling.StepTimer()
         self._judged = None  # the newest ring entry ``slow_step`` has judged
+        # ``recompile`` events: what the compile log read at the last
+        # check and when that was, and the spans under which the loop
+        # itself said "compile".
+        self._compiles_seen = self.timer.compiles.logged
+        self._compiles_seen_ns = time.time_ns()
+        self._announced: list[tuple[int, int]] = []
         self._self_charged: set[str] = set()  # hook phases goodput skips
         # Periodic HBM sampling (core/memstats.py): device.memory_stats()
         # where the backend has it, host RSS where it doesn't.
@@ -181,26 +204,29 @@ class Trainer:
         mesh_shape = {k: int(v) for k, v in self.mesh.shape.items()}
         data_parallel = (mesh_shape.get("data", 1)
                          * mesh_shape.get("fsdp", 1)) or None
+        span = self.timer.span
         try:
-            shard_layout = data_shard.shard_plan(
-                data_shard.ShardAssignment(
-                    process_index=self.runtime.process_index,
-                    process_count=self.runtime.process_count),
-                global_batch=self.config.data.global_batch_size,
-                data_parallel=data_parallel,
-                shard_mode=self.config.data.shard_mode)
-            # Peek one batch for shapes, then restore the stream to the
-            # start.
-            start_state = self.dataset.state()
-            host_batch = next(self.dataset)
-            self.dataset.restore(start_state)
-            sample = to_global(host_batch, self.mesh)
-            # Kept for post-rollback re-jitting (LR re-warmup rebuilds
-            # the optimizer, which needs a recompile against the same
-            # shapes).
-            self._sample = sample
-            self.state = self.builder.init_state(
-                self.config.train.seed, sample)
+            with span("startup:sample"):
+                shard_layout = data_shard.shard_plan(
+                    data_shard.ShardAssignment(
+                        process_index=self.runtime.process_index,
+                        process_count=self.runtime.process_count),
+                    global_batch=self.config.data.global_batch_size,
+                    data_parallel=data_parallel,
+                    shard_mode=self.config.data.shard_mode)
+                # Peek one batch for shapes, then restore the stream to
+                # the start.
+                start_state = self.dataset.state()
+                host_batch = next(self.dataset)
+                self.dataset.restore(start_state)
+                sample = to_global(host_batch, self.mesh)
+                # Kept for post-rollback re-jitting (LR re-warmup rebuilds
+                # the optimizer, which needs a recompile against the same
+                # shapes).
+                self._sample = sample
+            with span("startup:init_state"):
+                self.state = self.builder.init_state(
+                    self.config.train.seed, sample)
         finally:
             # The run's opening record, written whether or not the init
             # above succeeded. It waits for the init because that traces
@@ -259,6 +285,39 @@ class Trainer:
                 peak_inflight=pipe_sched.peak_inflight(
                     name, stages, micro, virtual),
             )
+        with span("startup:make_step"):
+            self._make_step(sample)
+        # eval_step compiles from the EVAL stream's sample batch (its
+        # element spec differs from training: weight key, no aug). Built
+        # HERE rather than at the first evaluate() when eval will run, so
+        # any eval-config error (e.g. a native reader with no exact-eval
+        # path) fails at startup — not hours in, after training finishes.
+        self.eval_step = None
+        # eval_steps > 0 is the single eval on-switch (eval_interval alone
+        # does nothing — default_hooks logs that case), so only then pay
+        # the eval pipeline build + compile up front.
+        if self.config.train.eval_steps > 0:
+            with span("startup:eval_build"):
+                self._ensure_eval()
+        # Checkpoint manager + auto-restore (MonitoredTrainingSession
+        # contract: restore latest from checkpoint_dir if present).
+        if self.config.checkpoint.restore_step >= 0 and not (
+                self.config.checkpoint.directory
+                and self.config.checkpoint.restore):
+            # The knob's contract is fail-loudly; silently starting from
+            # scratch because restore is off would be the exact fallback
+            # it exists to prevent.
+            raise ValueError(
+                "checkpoint.restore_step set but restoring is disabled — "
+                "need checkpoint.directory non-empty and "
+                "checkpoint.restore=true"
+            )
+        with span("startup:restore"):
+            self._open_checkpoints()
+
+    def _make_step(self, sample) -> None:
+        """The jitted train step for ``sample``'s shapes and, where a
+        profile or the memory analysis is armed, its compiled form."""
         self.train_step = self.builder.make_train_step(sample)
         if getattr(self.builder, "_zero", False):
             # One record of the static shard/bucket plan so byte and
@@ -296,30 +355,10 @@ class Trainer:
             # Static memory budget of the step (KIND_MEMORY with
             # extra.analysis) — free here, the compile is already paid.
             self.memstats.capture_compiled(compiled, label="train_step")
-        # eval_step compiles from the EVAL stream's sample batch (its
-        # element spec differs from training: weight key, no aug). Built
-        # HERE rather than at the first evaluate() when eval will run, so
-        # any eval-config error (e.g. a native reader with no exact-eval
-        # path) fails at startup — not hours in, after training finishes.
-        self.eval_step = None
-        # eval_steps > 0 is the single eval on-switch (eval_interval alone
-        # does nothing — default_hooks logs that case), so only then pay
-        # the eval pipeline build + compile up front.
-        if self.config.train.eval_steps > 0:
-            self._ensure_eval()
-        # Checkpoint manager + auto-restore (MonitoredTrainingSession
-        # contract: restore latest from checkpoint_dir if present).
-        if self.config.checkpoint.restore_step >= 0 and not (
-                self.config.checkpoint.directory
-                and self.config.checkpoint.restore):
-            # The knob's contract is fail-loudly; silently starting from
-            # scratch because restore is off would be the exact fallback
-            # it exists to prevent.
-            raise ValueError(
-                "checkpoint.restore_step set but restoring is disabled — "
-                "need checkpoint.directory non-empty and "
-                "checkpoint.restore=true"
-            )
+
+    def _open_checkpoints(self) -> None:
+        """The checkpoint manager, and the newest (or the named)
+        checkpoint restored into the state, where there is a directory."""
         if self.config.checkpoint.directory:
             from distributed_tensorflow_framework_tpu.ckpt import CheckpointManager
 
@@ -421,65 +460,27 @@ class Trainer:
     def train(self, hooks: list | None = None) -> dict[str, float]:
         if self.state is None:
             self.build()
-        ck = self.config.checkpoint
-        if (self._ckpt_manager is not None and ck.restore_step >= 0
-                and ck.restore_step < (self._ckpt_manager.latest_step() or 0)):
-            # Saving a branched lineage into a directory that already holds
-            # NEWER steps would silently no-op at every already-saved step
-            # (CheckpointManager.save skips existing steps) and a restart
-            # would re-restore restore_step, losing the branch. Evaluating
-            # an old snapshot (--eval-only) is fine; branched TRAINING
-            # needs a fresh directory.
-            raise ValueError(
-                f"checkpoint.restore_step={ck.restore_step} is older than "
-                f"the directory's latest step "
-                f"({self._ckpt_manager.latest_step()}) — training would "
-                f"interleave two lineages. Copy the checkpoint into a "
-                f"fresh checkpoint.directory to branch, or use --eval-only."
-            )
         cfg = self.config.train
         hooks = self.default_hooks() if hooks is None else hooks
-        # The worker-side root span: parented on the supervisor's attempt
-        # span (DTF_TRACE_CTX) when one launched us, a fresh trace
-        # otherwise. Startup/step-window/ckpt/rollback spans chain under
-        # it; left open on a crash so the flight recorder's open-span
-        # snapshot still shows the fault's ancestry.
-        self.run_span = self.tracer.start(
-            "worker.run", self._trace_parent,
-            process=self.runtime.process_index, start_step=self.host_step)
-        for h in hooks:
-            h.on_start(self)
-
-        last_metrics: dict[str, float] = {}
-        infeed = prefetch_to_device(
-            self.dataset, self.mesh, size=self.config.data.prefetch,
-            background=self.config.data.async_infeed,
-            deadline_s=self.config.resilience.infeed_deadline_s,
-        )
-        if self._ckpt_manager is not None:
-            # Every save records the prefetch watermark (batches the
-            # producer ran ahead) in its data-state commit record — the
-            # post-mortem "how far ahead was the infeed?" number.
-            self._ckpt_manager.set_data_sources(
-                watermark_source=infeed.watermark)
         timer = self.timer
         timer.step = self.host_step
+        with timer.span("startup:loop_entry"):
+            infeed = self._enter_loop(hooks)
+        last_metrics: dict[str, float] = {}
         # Bounded dispatch-ahead (train.dispatch_ahead): a deque of each
         # in-flight step's metrics; once full, sync on the OLDEST entry
         # before dispatching another step (a scalar device_get of that
         # step's first metric).
         pending: collections.deque = collections.deque()
-        if not self._startup_accounted:
-            # Construction → loop entry (restore + input/eval build; the
-            # first compile lands in the recompile bucket at dispatch).
-            self._startup_accounted = True
-            self.goodput.add(
-                "startup", time.perf_counter() - self._init_t)
         if self.recovery is not None:
             # Baseline snapshot: the ladder must be able to roll back even
             # if the first anomaly lands before the first clean fetch.
             # (After the startup bucket closed: its wall is ``snapshot``'s.)
             self._snapshot(force=True)
+        # ``recompile`` events are of the loop: what was compiled on the
+        # way here is the startup event's.
+        self._compiles_seen = timer.compiles.logged
+        self._compiles_seen_ns = time.time_ns()
         hook_phases = [(h, f"hook:{type(h).__name__}") for h in hooks]
         self._self_charged = {
             phase for h, phase in hook_phases
@@ -543,8 +544,11 @@ class Trainer:
                 if compiling:
                     self._recompile_pending = False
                     self.goodput.count("recompiles")
-                    # An iteration that compiled is no ``slow_step``.
+                    # An iteration that compiled is no ``slow_step``, and
+                    # what JAX compiled under it no ``recompile``.
                     self._judged = timer.spans[-1]
+                    self._announced.append(
+                        (self._judged[2], self._judged[2] + self._judged[3]))
                 if cfg.dispatch_ahead > 0:
                     pending.append(metrics)
                 self.host_step += 1
@@ -552,17 +556,11 @@ class Trainer:
                     # Restart → first-step latency (restore + input build +
                     # compile): the number the persistent XLA compilation
                     # cache (core/platform.resolve_compilation_cache)
-                    # exists to shrink.
+                    # exists to shrink. Taken as the first dispatch
+                    # returns: the step is in flight, not complete.
                     self._startup_emitted = True
-                    self.writer.telemetry.emit(
-                        telemetry.KIND_STARTUP, step=self.host_step,
-                        time_to_first_step_s=(
-                            time.perf_counter() - self._init_t),
-                        restored_step=self._restored_step,
-                        compilation_cache_dir=(
-                            jax.config.jax_compilation_cache_dir or None),
-                    )
-                    # Construction → first completed step as one span:
+                    self._emit_startup(time.perf_counter() - self._init_t)
+                    # Construction → first dispatch returned as one span:
                     # the relaunch cost a coordinated restart pays, and
                     # the segment the gang drill expects on the critical
                     # path after a supervisor-driven relaunch.
@@ -674,6 +672,77 @@ class Trainer:
                 end_step=self.host_step)
         return last_metrics
 
+    def _enter_loop(self, hooks: list):
+        """What ``train()`` owes before its first iteration: the lineage
+        check, the run's root span, the hooks' ``on_start``, the infeed
+        (returned), and the startup bucket's close."""
+        ck = self.config.checkpoint
+        if (self._ckpt_manager is not None and ck.restore_step >= 0
+                and ck.restore_step < (self._ckpt_manager.latest_step() or 0)):
+            # Saving a branched lineage into a directory that already holds
+            # NEWER steps would silently no-op at every already-saved step
+            # (CheckpointManager.save skips existing steps) and a restart
+            # would re-restore restore_step, losing the branch. Evaluating
+            # an old snapshot (--eval-only) is fine; branched TRAINING
+            # needs a fresh directory.
+            raise ValueError(
+                f"checkpoint.restore_step={ck.restore_step} is older than "
+                f"the directory's latest step "
+                f"({self._ckpt_manager.latest_step()}) — training would "
+                f"interleave two lineages. Copy the checkpoint into a "
+                f"fresh checkpoint.directory to branch, or use --eval-only."
+            )
+        # The worker-side root span: parented on the supervisor's attempt
+        # span (DTF_TRACE_CTX) when one launched us, a fresh trace
+        # otherwise. Startup/step-window/ckpt/rollback spans chain under
+        # it; left open on a crash so the flight recorder's open-span
+        # snapshot still shows the fault's ancestry.
+        self.run_span = self.tracer.start(
+            "worker.run", self._trace_parent,
+            process=self.runtime.process_index, start_step=self.host_step)
+        for h in hooks:
+            h.on_start(self)
+
+        infeed = prefetch_to_device(
+            self.dataset, self.mesh, size=self.config.data.prefetch,
+            background=self.config.data.async_infeed,
+            deadline_s=self.config.resilience.infeed_deadline_s,
+        )
+        if self._ckpt_manager is not None:
+            # Every save records the prefetch watermark (batches the
+            # producer ran ahead) in its data-state commit record — the
+            # post-mortem "how far ahead was the infeed?" number.
+            self._ckpt_manager.set_data_sources(
+                watermark_source=infeed.watermark)
+        if not self._startup_accounted:
+            # Construction → loop entry (restore + input/eval build; the
+            # first compile lands in the recompile bucket at dispatch).
+            self._startup_accounted = True
+            self.goodput.add(
+                "startup", time.perf_counter() - self._init_t)
+        return infeed
+
+    def _emit_startup(self, time_to_first_step_s: float) -> None:
+        """The ``startup`` event, with where the time went: the timer's
+        spans up to now (frozen as its ``startup``), what lay between
+        them, what the process had spent before construction, and what
+        JAX traced, compiled and loaded meanwhile."""
+        phases_s = self.timer.freeze_startup(self.host_step - 1)
+        self.writer.telemetry.emit(
+            telemetry.KIND_STARTUP, step=self.host_step,
+            time_to_first_step_s=time_to_first_step_s,
+            restored_step=self._restored_step,
+            compilation_cache_dir=(
+                jax.config.jax_compilation_cache_dir or None),
+            phases_s={k: round(v, 6) for k, v in phases_s.items()},
+            # What the caller did while it held the trainer, between the
+            # program's spans (under train.py, the few statements there).
+            outside_s=round(
+                time_to_first_step_s - sum(phases_s.values()), 6),
+            process_s=self._process_s,
+            compile=self.timer.compile_summary(),
+        )
+
     # ------------------------------------------------------- loop timeline --
     def _fetch_bookkeeping(self) -> None:
         """What a metrics-fetch iteration owes after the fetch: the
@@ -722,7 +791,10 @@ class Trainer:
         step lost the time, and under which span. Called ahead
         of the fetch, while the device is busy. The iteration still
         running (this fetch's own: its fetch and hooks are yet to come)
-        waits for the next call, unless ``final``."""
+        waits for the next call, unless ``final``. And one ``recompile``
+        event per compile that the loop did not ask for."""
+        if self.timer.compiles.logged != self._compiles_seen:
+            self._report_recompiles()
         block = []
         for span in reversed(self.timer.spans):
             if span is self._judged:
@@ -739,6 +811,52 @@ class Trainer:
             self.writer.telemetry.emit(
                 telemetry.KIND_HEALTH, step=slow["step"],
                 health={"event": "slow_step", **slow})
+
+    def _report_recompiles(self) -> None:
+        """One ``recompile`` health event per backend compile or cache
+        load that the compile log has gained since the last call, that
+        began under no span of the loop's own ``compile`` phase (the
+        first step, the step after a rollback's rebuild) and that took,
+        with the tracing and lowering ahead of it, over
+        ``profiling.SLOW_FLOOR_MS``: the function's name, the step, and
+        the ring span it began under — a device wait included, where
+        another thread compiled while the loop waited."""
+        log_ = self.timer.compiles
+        now_ns = time.time_ns()
+        entries = log_.since(self._compiles_seen_ns)
+        self._compiles_seen, self._compiles_seen_ns = log_.logged, now_ns
+        announced, self._announced = self._announced, []
+        lead_ns = 0  # tracing and lowering since the last backend compile
+        for kind, fun_name, start_ns, duration_ns, cache_hit, xla_ns in entries:
+            if kind != "xla":
+                lead_ns += duration_ns - xla_ns
+                continue
+            trace_ns, lead_ns = lead_ns, 0
+            if ((trace_ns + duration_ns) * 1e-6 <= profiling.SLOW_FLOOR_MS
+                    or any(a <= start_ns < b for a, b in announced)):
+                continue
+            under = None  # the ring is in order of ends: stop at an earlier one
+            for s in reversed(self.timer.spans):
+                if s[2] + s[3] <= start_ns:
+                    break
+                if s[2] <= start_ns:
+                    under = s
+                    break
+            found = {
+                "event": "recompile",
+                "step": under[1] if under else self.timer.step,
+                "fun_name": fun_name,
+                "trace_ms": round(trace_ns * 1e-6, 3),
+                "xla_ms": round(duration_ns * 1e-6, 3),
+                "cache_hit": cache_hit,
+                "under": under[0] if under else None}
+            log.warning(
+                "step %d compiled %s under %s: %.1f ms tracing and "
+                "lowering, %.1f ms in XLA (cache hit: %s)", found["step"],
+                fun_name, found["under"], found["trace_ms"],
+                found["xla_ms"], cache_hit)
+            self.writer.telemetry.emit(
+                telemetry.KIND_HEALTH, step=found["step"], health=found)
 
     def _dump_timeline(self) -> str | None:
         """``loop_timeline-<pid>.json`` beside the flight recorder's

@@ -125,7 +125,7 @@ def test_timeline_dump_schema(tmp_path):
     with open(path) as fh:
         doc = json.load(fh)
     assert set(doc) == {"schema", "pid", "clock", "offset_ns", "final_step",
-                        "spans"}
+                        "spans", "startup", "compiles"}
     assert doc["schema"] == profiling.TIMELINE_SCHEMA == "dtf-loop-timeline/1"
     assert doc["pid"] == os.getpid() and doc["final_step"] == 3
     assert doc["clock"] == "time.time_ns" and doc["offset_ns"] == 0
