@@ -40,7 +40,9 @@ Bucket definitions (seconds of host wall time; docs/OBSERVABILITY.md):
                  rebuild inside ``_maybe_recover``
   snapshot       the recovery ladder's device→host copy of the train
                  state (loop-entry baseline, then every
-                 ``resilience.snapshot_interval_steps`` at a fetch)
+                 ``resilience.snapshot_interval_steps`` at a fetch): a
+                 blocking copy whole, or a two-phase snapshot's launch,
+                 its shares' starts and the wait of its finish
   bookkeeping    the rest of a metrics-fetch iteration: the slow-step
                  check, phase means, anomaly classification, this
                  ledger, memory sampling, the packing census, the
@@ -128,7 +130,7 @@ class GoodputLedger:
         self._t0 = now if t0_perf is None else float(t0_perf)
         self.t0_wall = time.time() - (now - self._t0)
         self._buckets: dict[str, float] = {}
-        self._counters: dict[str, int] = {}
+        self._counters: dict[str, int | float] = {}
         self._last_emit = self._t0
         if writer is not None:
             writer.add_listener(self._observe)
@@ -145,7 +147,9 @@ class GoodputLedger:
         with self._lock:
             self._buckets[bucket] = self._buckets.get(bucket, 0.0) + seconds
 
-    def count(self, name: str, n: int = 1) -> None:
+    def count(self, name: str, n: int | float = 1) -> None:
+        """Tally ``n`` more of ``name``: events, bytes, or (a name that
+        ends in ``_s``) seconds."""
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
 
@@ -210,7 +214,8 @@ class GoodputLedger:
             "wall_s": wall,
             "goodput_frac": (productive / wall) if wall > 0 else 0.0,
             "buckets": {b: round(s, 4) for b, s in buckets.items()},
-            "counters": counters,
+            "counters": {c: round(n, 4) if isinstance(n, float) else n
+                         for c, n in counters.items()},
         }
 
     def _emit(self, step: int | None, final: bool) -> dict | None:
@@ -254,13 +259,13 @@ def _stitch_host(attempts: list[dict], classifications: list[str]) -> dict:
     classify the restart gaps between coverage windows, and close the
     books so buckets (gaps included) sum to that host's measured span."""
     buckets: dict[str, float] = {}
-    counters: dict[str, int] = {}
+    counters: dict[str, int | float] = {}
     gaps: list[dict] = []
     for i, att in enumerate(attempts):
         for b, s in att["buckets"].items():
             buckets[b] = buckets.get(b, 0.0) + float(s)
         for c, n in att["counters"].items():
-            counters[c] = counters.get(c, 0) + int(n)
+            counters[c] = counters.get(c, 0) + n
         if i + 1 < len(attempts):
             gap = attempts[i + 1]["t0"] - (att["t0"] + att["wall_s"])
             cls = (classifications[i] if i < len(classifications)

@@ -878,7 +878,7 @@ def summarize_events(path: str) -> dict:
         # the per-attempt t0 intervals and supervisor classifications —
         # goodput.stitch_attempts() builds that cross-attempt table.
         buckets: dict[str, float] = {}
-        counters: dict[str, int] = {}
+        counters: dict[str, int | float] = {}
         wall = productive = 0.0
         for snap in goodput_by_run.values():
             w = float(snap.get("wall_s") or 0.0)
@@ -888,7 +888,7 @@ def summarize_events(path: str) -> dict:
             for b, s in snap["buckets"].items():
                 buckets[b] = buckets.get(b, 0.0) + float(s)
             for c, n in snap["counters"].items():
-                counters[c] = counters.get(c, 0) + int(n)
+                counters[c] = counters.get(c, 0) + n
         goodput = {
             "attempts": len(goodput_by_run),
             "wall_s": wall,

@@ -464,6 +464,11 @@ class Trainer:
         hooks = self.default_hooks() if hooks is None else hooks
         timer = self.timer
         timer.step = self.host_step
+        if self.recovery is not None:
+            # The two-phase snapshot's device copy, compiled here from the
+            # state's avals: set-up's cost, never a compile in the loop.
+            with timer.span("startup:snapshot_program"):
+                self.recovery.prepare_overlap(self.state)
         with timer.span("startup:loop_entry"):
             infeed = self._enter_loop(hooks)
         last_metrics: dict[str, float] = {}
@@ -551,6 +556,12 @@ class Trainer:
                         (self._judged[2], self._judged[2] + self._judged[3]))
                 if cfg.dispatch_ahead > 0:
                     pending.append(metrics)
+                rec = self.recovery
+                if rec is not None and rec.pending and rec.pending.unsent:
+                    # A launched snapshot's next share sets out for the
+                    # host behind this step, ahead of the next batch.
+                    with timer.phase("snapshot"):
+                        rec.send_pending()
                 self.host_step += 1
                 if not self._startup_emitted:
                     # Restart → first-step latency (restore + input build +
@@ -585,6 +596,11 @@ class Trainer:
                     # it costs the sync nothing.
                     with timer.phase("bookkeeping"):
                         self._report_slow_steps()
+                    # A launched snapshot lands here, likewise: the host
+                    # waits for its bytes while the steps in flight run.
+                    # Ahead of every fetch, so ahead of whatever a fetch
+                    # leads to: a rollback, the next snapshot.
+                    self._finish_snapshot()
                     with timer.phase("metrics_fetch"):
                         host_metrics = {
                             k: float(v)
@@ -625,6 +641,10 @@ class Trainer:
             # Stop the background producer (async_infeed): it must not
             # keep pulling from the dataset the caller may reuse/restore.
             infeed.close()
+            if self.recovery is not None:
+                # Nothing past the loop rolls back: a snapshot still on
+                # its way is dropped, and its device copy freed.
+                self.recovery.drop_pending()
             if self._ckpt_manager is not None:
                 # The final force-save (CheckpointHook.on_end) must not
                 # poll a closed infeed's queue for its watermark.
@@ -906,19 +926,54 @@ class Trainer:
                 time.sleep(backoff)
 
     def _snapshot(self, force: bool = False) -> None:
-        """The ladder's device→host copy of the train state, when one is
-        due, under the ``snapshot`` span and the ``snapshots`` /
-        ``snapshot_bytes`` goodput counters."""
+        """The ladder's copy of the train state, when one is due, under
+        the ``snapshot`` span and the ``snapshots`` / ``snapshot_bytes``
+        goodput counters: launched on the device to land beside the next
+        steps (``snapshots_overlapped``) where the recovery manager takes
+        that path, else the blocking device→host copy — the baseline's
+        (``force``: nothing is in flight to hide a transfer behind)."""
         rec = self.recovery
         if not rec.snapshot_due(self.host_step, force):
             return
         with self.timer.phase("snapshot"):
-            took = rec.take_snapshot(
+            overlapped = not force and rec.launch_snapshot(
+                self.host_step, self.state, data_state=self.data_ckpt_state,
+                step_temp_bytes=self._step_temp_bytes,
+                # in shares, one behind each step up to the next fetch
+                spread_over=self.config.train.log_interval)
+            took = overlapped or rec.take_snapshot(
                 self.host_step, self.state,
                 data_state=self.data_ckpt_state, force=force)
         if took:
             self.goodput.count("snapshots")
-            self.goodput.count("snapshot_bytes", rec.ring.latest().nbytes)
+            self.goodput.count(
+                "snapshot_bytes",
+                (rec.pending if overlapped else rec.ring.latest()).nbytes)
+        if overlapped:
+            self.goodput.count("snapshots_overlapped")
+
+    def _finish_snapshot(self) -> None:
+        """A launched snapshot into the ring, under the ``snapshot`` span;
+        ``snapshot_finish_wait_s`` counts the host seconds that blocked."""
+        rec = self.recovery
+        if rec is None or rec.pending is None:
+            return
+        with self.timer.phase("snapshot"):
+            waited = rec.finish_pending()
+        self.goodput.count("snapshot_finish_wait_s", waited)
+
+    def _step_temp_bytes(self) -> int | None:
+        """The compiled step's temporaries by its own memory analysis,
+        for the snapshot's headroom test (made once); None where there is
+        none. After the first dispatch the lowering and the executable
+        are cached: nothing is traced or compiled here."""
+        try:
+            compiled = self.train_step.lower(self.state, self._sample).compile()
+        except Exception:
+            log.exception("no memory analysis of the train step")
+            return None
+        analysis = memstats.compiled_memory_analysis(compiled) or {}
+        return analysis.get("temp_bytes")
 
     def _maybe_recover(self, host_metrics: dict[str, float]) -> dict[str, float] | None:
         """Classify a fetched-metrics step; roll back if anomalous.

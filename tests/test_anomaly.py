@@ -5,7 +5,9 @@ end-to-end rollback on the LeNet slice: detector thresholds (non-finite /
 grad-norm ceiling / EWMA loss-spike with warmup), the snapshot ring's
 bit-exact device→host→device round trip, RecoveryManager policy
 (snapshot cadence, rollback budget, escalation provenance, telemetry
-emissions), and the ResilienceConfig validation seams. The subprocess
+emissions), the two-phase snapshot (launched on the device, landed
+beside the next steps; its headroom test, its fallbacks, its counters),
+and the ResilienceConfig validation seams. The subprocess
 drills that prove the ladder under real fault injection live in
 tests/test_recovery_drills.py (tier-2 by their slow marks).
 """
@@ -160,6 +162,342 @@ def test_snapshot_restore_bit_exact(devices):
     for lr, ll in zip(jax.tree.leaves(restored),
                       jax.tree.leaves(trainer.state)):
         assert lr.sharding == ll.sharding
+
+
+# ------------------------------------------------- two-phase snapshot ----
+
+ROOMY = (10 << 20, 1 << 30)      # (bytes_in_use, bytes_limit): fits
+
+
+@pytest.fixture
+def roomy(monkeypatch):
+    """XLA:CPU reports no memory statistics, so the headroom test refuses
+    there: stub the seam to a device with room."""
+    monkeypatch.setattr(anomaly, "device_memory", lambda d: ROOMY)
+
+
+def _packed_host(state):
+    return jax.device_get(
+        state.replace(rng=jax.random.key_data(state.rng)))
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _built(**over):
+    trainer = Trainer(lenet_config(**over))
+    trainer.build()
+    trainer.recovery.prepare_overlap(trainer.state)
+    return trainer, trainer.recovery
+
+
+def _run_steps(trainer, n):
+    """``n`` steps of the loop's own jitted step: each donates the live
+    state's buffers, as the loop's dispatches do behind a launch."""
+    for _ in range(n):
+        trainer.state, _ = trainer.train_step(trainer.state, trainer._sample)
+
+
+@pytest.mark.parametrize("before,between", [(0, 1), (3, 4)])
+def test_launched_snapshot_lands_bit_exact_after_further_steps(
+        devices, roomy, before, between):
+    """Launched at step N, finished M steps later: the ring's entry is
+    step N's state to the bit — params, optimizer state, the step counter
+    and the typed key — though every buffer it was copied from has been
+    donated since; and it restores onto the live placements."""
+    trainer, rec = _built()
+    _run_steps(trainer, before)
+    ref = _packed_host(trainer.state)
+    assert rec.launch_snapshot(before, trainer.state,
+                               data_state={"consumed": before},
+                               step_temp_bytes=lambda: 0)
+    assert rec.pending.step == before and len(rec.ring) == 0
+    _run_steps(trainer, between)
+    assert rec.finish_pending() >= 0.0
+    assert rec.pending is None and rec.ring.steps == [before]
+    snap = rec.ring.latest()
+    _assert_trees_equal(ref, snap.host)
+    assert int(snap.host.step) == before
+    assert snap.data_state == {"consumed": before}
+    assert snap.nbytes == sum(
+        np.asarray(x).nbytes for x in jax.tree.leaves(ref))
+    restored = anomaly.restore_state(snap.host, snap.shardings,
+                                     like=trainer.state)
+    _assert_trees_equal(ref, _packed_host(restored))
+    for lr, ll in zip(jax.tree.leaves(restored),
+                      jax.tree.leaves(trainer.state)):
+        assert lr.sharding == ll.sharding
+
+
+def test_transfer_sets_out_a_share_at_a_time(devices, roomy):
+    """The runtime serves transfers in the order asked, so a launch asks
+    for one of ``spread_over`` shares of the copy's bytes and
+    ``send_pending`` for each further one; a finish takes whatever is
+    left with it, and what lands is the whole state all the same."""
+    trainer, rec = _built()
+    ref = _packed_host(trainer.state)
+    leaves = len(jax.tree.leaves(ref))
+    assert rec.launch_snapshot(1, trainer.state, step_temp_bytes=lambda: 0,
+                               spread_over=4)
+    pend = rec.pending
+    assert pend.share_bytes == -(-pend.nbytes // 4)
+    left = [len(pend.unsent)]
+    assert 0 < left[0] < leaves
+    while pend.unsent:
+        rec.send_pending()
+        left.append(len(pend.unsent))
+    assert left == sorted(left, reverse=True) and 2 <= len(left) <= 4
+    rec.send_pending()                       # nothing left: a no-op
+    _run_steps(trainer, 2)
+    rec.finish_pending()
+    _assert_trees_equal(ref, rec.ring.latest().host)
+    # and a finish with most of it unsent
+    assert rec.launch_snapshot(2, trainer.state, spread_over=1000)
+    assert 0.8 * leaves < len(rec.pending.unsent) < leaves
+    ref = _packed_host(trainer.state)
+    rec.finish_pending()
+    _assert_trees_equal(ref, rec.ring.latest().host)
+    rec.send_pending()                       # nothing pending: a no-op
+
+
+def test_rollback_finishes_a_pending_snapshot_first(devices, roomy):
+    """A rollback wants the newest snapshot: one still on its way lands
+    first, and the rollback is to ITS step and data state."""
+    trainer, rec = _built()
+    assert rec.take_snapshot(0, trainer.state, data_state={"consumed": 0},
+                             force=True)
+    _run_steps(trainer, 2)
+    ref = _packed_host(trainer.state)
+    assert rec.launch_snapshot(2, trainer.state, data_state={"consumed": 2},
+                               step_temp_bytes=lambda: 0)
+    _run_steps(trainer, 3)
+    assert rec.can_rollback() and rec.pending is not None
+    assert rec.provenance()["snapshot_steps"] == [0, 2]
+    state, snap = rec.rollback(trainer.state, from_step=5)
+    assert rec.pending is None and rec.ring.steps == [0, 2]
+    assert snap.step == 2 and snap.data_state == {"consumed": 2}
+    _assert_trees_equal(ref, _packed_host(state))
+
+
+def test_next_snapshot_due_finishes_the_one_before(devices, roomy):
+    """Ring order and depth as with blocking copies: a launch (or a
+    blocking copy) lands the pending snapshot ahead of itself."""
+    trainer, rec = _built(**{"resilience.snapshot_depth": 2})
+    for step in (1, 2, 3):
+        _run_steps(trainer, 1)
+        assert rec.launch_snapshot(step, trainer.state,
+                                   step_temp_bytes=lambda: 0)
+        assert rec.pending.step == step
+        assert rec.ring.steps == [1, 2, 3][:step - 1][-2:]
+    assert rec.provenance()["snapshot_steps"] == [2, 3]
+    _run_steps(trainer, 1)
+    assert rec.take_snapshot(4, trainer.state, force=True)
+    assert rec.pending is None and rec.ring.steps == [3, 4]
+    assert int(rec.ring.latest().host.step) == 4
+
+
+class _Device:
+    platform, id = "fake", 0
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("stats,want", [
+    # What the allocator holds now and its limit; a lifetime peak near
+    # the limit (a set-up that held the state twice) is not read.
+    ({"bytes_in_use": 6 << 30, "peak_bytes_in_use": 15 << 30,
+      "bytes_limit": 16 << 30}, (6 << 30, 16 << 30)),
+    ({"bytes_in_use": 1}, None),
+    ({}, None),
+    (None, None),
+])
+def test_device_memory_reads_what_is_live_not_the_lifetime_peak(stats, want):
+    assert anomaly.device_memory(_Device(stats)) == want
+
+
+GIB = 1 << 30
+
+
+@pytest.mark.parametrize("in_use,temp,admitted,reason", [
+    # state 0.5 MB: everything hangs on what is live and the step's temps
+    (6 * GIB, 3 * GIB, True, None),
+    (6 * GIB, int(9.3 * GIB), False, "too little"),  # inside the 5% margin
+    (12 * GIB, 4 * GIB, False, "too little"),
+    (6 * GIB, None, False, "memory analysis is unavailable"),
+    (None, 3 * GIB, False, "reports no memory statistics"),
+])
+def test_headroom_test_takes_the_blocking_path_where_it_must(
+        devices, monkeypatch, in_use, temp, admitted, reason):
+    trainer, rec = _built()
+    monkeypatch.setattr(
+        anomaly, "device_memory",
+        lambda d: None if in_use is None else (in_use, 16 * GIB))
+    calls = []
+    launched = rec.launch_snapshot(
+        1, trainer.state, step_temp_bytes=lambda: calls.append(1) or temp)
+    assert launched is admitted
+    assert rec.headroom["admitted"] is admitted
+    assert (rec.pending is not None) is admitted
+    if reason:
+        assert reason in rec.headroom["reason"]
+    else:
+        assert rec.headroom["spare_bytes"] == (
+            16 * GIB - in_use - temp - rec.headroom["state_bytes"]
+            - int(anomaly.OVERLAP_MARGIN * 16 * GIB))
+    # made once: the verdict stands for the run
+    assert rec.launch_snapshot(2, trainer.state,
+                               step_temp_bytes=lambda: calls.append(1) or 0
+                               ) is admitted
+    assert len(calls) == 1
+
+
+def test_no_copy_program_no_launch(devices, roomy):
+    trainer = Trainer(lenet_config())
+    trainer.build()
+    rec = trainer.recovery  # prepare_overlap never ran
+    assert not rec.launch_snapshot(1, trainer.state,
+                                   step_temp_bytes=lambda: 0)
+    assert "no copy program" in rec.headroom["reason"]
+
+
+def _out_of_memory(*_):
+    raise jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 5.68G. That was not possible.")
+
+
+def test_copy_out_of_memory_switches_to_the_blocking_path(devices, roomy):
+    trainer, rec = _built()
+    assert rec.launch_snapshot(1, trainer.state, step_temp_bytes=lambda: 0)
+    rec._copy_program = _out_of_memory
+    assert not rec.launch_snapshot(2, trainer.state)
+    assert rec.pending is None and rec.ring.steps == [1]   # 1 landed first
+    good = anomaly.RecoveryManager.prepare_overlap
+    good(rec, trainer.state)            # a sound program again: still off
+    assert not rec.launch_snapshot(3, trainer.state)
+    # any other runtime error is not swallowed
+    rec2 = _built()[1]
+    rec2._copy_program = lambda s: (_ for _ in ()).throw(
+        jax.errors.JaxRuntimeError("INTERNAL: something else"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="INTERNAL"):
+        rec2.launch_snapshot(1, trainer.state, step_temp_bytes=lambda: 0)
+
+
+def _train_counting(monkeypatch, tmp_path, **over):
+    """A 40-step LeNet run, a snapshot every 10 steps and a fetch every
+    5; returns the trainer, its goodput counters and its health events."""
+    cfg = lenet_config(**{
+        "train.total_steps": 40, "train.log_interval": 5,
+        "resilience.snapshot_interval_steps": 10,
+        "checkpoint.directory": str(tmp_path), **over})
+    trainer = Trainer(cfg)
+    health = []
+    trainer.writer.telemetry.add_listener(
+        lambda ev: health.append(ev["health"])
+        if ev.get("kind") == telemetry.KIND_HEALTH else None)
+    trainer.train(hooks=[])
+    return trainer, trainer.goodput.snapshot()["counters"], health
+
+
+def test_loop_counts_overlapped_snapshots_and_compiles_nothing_in_the_loop(
+        devices, roomy, monkeypatch, tmp_path):
+    """The loop's side: the copy program is compiled in set-up (its span
+    is among the startup's), the baseline is the blocking copy, every
+    periodic snapshot is launched and lands at the next fetch, the last
+    one (launched at the final step) is dropped on the way out, and no
+    ``recompile`` event names the launch."""
+    launches = []
+    launch = anomaly.RecoveryManager.launch_snapshot
+    monkeypatch.setattr(
+        anomaly.RecoveryManager, "launch_snapshot",
+        lambda self, step, *a, **k: launches.append(
+            (step, self._copy_program is not None, list(self.ring.steps)))
+        or launch(self, step, *a, **k))
+    trainer, counters, health = _train_counting(monkeypatch, tmp_path)
+    rec = trainer.recovery
+    assert "startup:snapshot_program" in {s[0] for s in trainer.timer.startup}
+    # program there before step 10's launch; each launch found the one
+    # before it landed (at the fetch of step 15, 25, 35)
+    assert launches == [(10, True, [0]), (20, True, [0, 10]),
+                        (30, True, [10, 20]), (40, True, [20, 30])]
+    assert rec.pending is None and rec.ring.steps == [20, 30]
+    nbytes = anomaly.state_nbytes(trainer.state)[0]
+    assert counters["snapshots"] == 5
+    assert counters["snapshots_overlapped"] == 4
+    assert counters["snapshot_bytes"] == 5 * nbytes
+    assert counters["snapshot_finish_wait_s"] >= 0.0
+    assert counters["recompiles"] == 1
+    events = {h.get("event") for h in health}
+    assert "snapshot_overlap" in events and "recompile" not in events
+    assert [h["admitted"] for h in health
+            if h.get("event") == "snapshot_overlap"] == [True]
+    # under the loop's ``snapshot`` span: the baseline, the launches at
+    # 10..40, a share sent behind some of the steps that follow each, and
+    # the finishes ahead of the fetch of steps 15, 25 and 35
+    steps = [s[1] for s in trainer.timer.spans if s[0] == "snapshot"]
+    assert steps == sorted(steps)
+    assert {0, 10, 15, 20, 25, 30, 35, 40} <= set(steps)
+    assert all(s % 10 <= 5 for s in steps)
+
+
+@pytest.mark.parametrize("why", ["no_statistics", "too_little", "exhausted"])
+def test_loop_takes_the_blocking_path_and_counts_no_overlap(
+        devices, monkeypatch, tmp_path, why):
+    if why == "too_little":
+        monkeypatch.setattr(anomaly, "device_memory",
+                            lambda d: (16 * GIB - 1, 16 * GIB))
+    elif why == "exhausted":
+        monkeypatch.setattr(anomaly, "device_memory", lambda d: ROOMY)
+        monkeypatch.setattr(
+            anomaly.RecoveryManager, "prepare_overlap",
+            lambda self, state: setattr(self, "_copy_program",
+                                        _out_of_memory))
+    trainer, counters, health = _train_counting(monkeypatch, tmp_path)
+    assert counters["snapshots"] == 5
+    assert "snapshots_overlapped" not in counters
+    assert "snapshot_finish_wait_s" not in counters
+    assert trainer.recovery.ring.steps == [30, 40]
+    assert trainer.recovery.headroom["admitted"] is (why == "exhausted")
+
+
+def test_nan_batch_rolls_back_to_a_pending_snapshot(devices, roomy):
+    """The ladder's happy path with the two-phase snapshot: the snapshot
+    launched at step 10 lands ahead of step 15's fetch, whose anomaly
+    (the batch poisoned at step 12) then rolls back to it."""
+    faults.install("nan_grads:12")
+    cfg = lenet_config(**{
+        "train.total_steps": 30, "train.log_interval": 5,
+        "resilience.snapshot_interval_steps": 10})
+    trainer = Trainer(cfg)
+    landed, rollbacks = [], []
+    finish = anomaly.RecoveryManager.finish_pending
+    trainer.build()
+    trainer.writer.telemetry.add_listener(
+        lambda ev: rollbacks.append(ev["health"]["to_step"])
+        if ev.get("kind") == telemetry.KIND_ROLLBACK else None)
+
+    def watched(self):
+        if self.pending is not None:
+            landed.append((self.pending.step, trainer.host_step))
+        return finish(self)
+
+    anomaly.RecoveryManager.finish_pending = watched
+    try:
+        metrics = trainer.train()
+    finally:
+        anomaly.RecoveryManager.finish_pending = finish
+    assert trainer.recovery.total_rollbacks == 1
+    assert trainer.host_step == 30 and math.isfinite(float(metrics["loss"]))
+    assert landed[0] == (10, 15) and rollbacks == [10]
+    assert trainer.recovery.ring.steps[-1] > 10  # and went on snapshotting
 
 
 # --------------------------------------------------- recovery manager ----

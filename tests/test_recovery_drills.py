@@ -27,6 +27,7 @@ RECOVERY_DRIVER = """
 import sys
 import jax; jax.config.update('jax_platforms','cpu')
 from distributed_tensorflow_framework_tpu.cli.train import main
+{prelude}
 sys.exit(
  main(['--set','model.name=lenet5','--set','model.dtype=float32',
       '--set','data.name=synthetic_images','--set','data.image_size=28',
@@ -41,9 +42,19 @@ sys.exit(
 """
 
 
-def _driver(ckpt: str, steps: int, overrides: dict[str, str]) -> str:
+# XLA:CPU reports no memory statistics, so a child takes the blocking
+# snapshot; this line gives it a device with room, and with it the
+# two-phase snapshot (train/anomaly.py).
+ROOMY_DEVICE = (
+    "from distributed_tensorflow_framework_tpu.train import anomaly; "
+    "anomaly.device_memory = lambda d: (1 << 20, 1 << 34)")
+
+
+def _driver(ckpt: str, steps: int, overrides: dict[str, str],
+            prelude: str = "") -> str:
     extra = "".join(f",\n      '--set','{k}={v}'" for k, v in overrides.items())
-    return RECOVERY_DRIVER.format(ckpt=ckpt, steps=steps, extra=extra)
+    return RECOVERY_DRIVER.format(ckpt=ckpt, steps=steps, extra=extra,
+                                  prelude=prelude)
 
 
 def _run_child(prog: str, env_extra: dict, timeout: float = 420.0):
@@ -61,16 +72,19 @@ def _events(ckpt_dir: str, kind: str) -> list[dict]:
 
 @pytest.mark.slow
 @pytest.mark.slowest
-def test_nan_recovers_in_process_no_relaunch(tmp_path):
+@pytest.mark.parametrize("two_phase", [False, True])
+def test_nan_recovers_in_process_no_relaunch(tmp_path, two_phase):
     """Acceptance drill 1: DTF_FAULTS=nan_grads:30 poisons one batch; the
     run must detect at the next metric fetch, roll back to the last clean
     snapshot, skip the poisoned region, and FINISH — rc=0, one process,
-    zero relaunches, with the full event trail on disk."""
+    zero relaunches, with the full event trail on disk. The same with
+    the snapshots launched on the device and landed beside the next
+    steps: the rollback finds step 20's in the ring all the same."""
     ckpt = str(tmp_path / "ckpt")
     prog = _driver(ckpt, steps=60, overrides={
         "resilience.snapshot_interval_steps": "10",
         "resilience.lr_rewarmup_steps": "5",
-    })
+    }, prelude=ROOMY_DEVICE if two_phase else "")
     r = _run_child(prog, {"DTF_FAULTS": "nan_grads:30"})
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     # in process: no checkpoint restore ever happened
@@ -94,6 +108,11 @@ def test_nan_recovers_in_process_no_relaunch(tmp_path):
     text = telemetry.format_run_summary(summary)
     assert "rollback: step 30 -> 20" in text
     assert "batches skipped: 10" in text
+    counters = summary["goodput"]["counters"]
+    assert counters["snapshots"] >= 6
+    # every periodic snapshot took the path the device's room allows
+    assert counters.get("snapshots_overlapped", 0) == (
+        counters["snapshots"] - 1 if two_phase else 0)
 
 
 @pytest.mark.slow

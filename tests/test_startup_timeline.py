@@ -31,8 +31,8 @@ SLEEP_S = 0.3          # what the caller does between build() and train()
 STARTUP_SPANS = [      # in the order they run, checkpoint directory or not
     "startup:runtime", "startup:dataset", "startup:writer",
     "startup:sample", "startup:init_state", "startup:make_step",
-    "startup:eval_build", "startup:restore", "startup:loop_entry",
-    "snapshot", "infeed", "train_step"]
+    "startup:eval_build", "startup:restore", "startup:snapshot_program",
+    "startup:loop_entry", "snapshot", "infeed", "train_step"]
 
 
 def _cfg(**train_overrides):
@@ -103,9 +103,9 @@ def test_startup_event_says_where_the_time_went(startup_run):
 def test_what_the_caller_did_is_outside_every_phase(startup_run):
     extra = startup_run["startup"]
     assert SLEEP_S <= extra["outside_s"] < SLEEP_S + 0.25
-    # the sleep sits between ``restore``'s end and ``loop_entry``'s start
+    # the sleep sits between ``restore``'s end and ``train()``'s first span
     spans = {s[0]: s for s in startup_run["doc"]["startup"]}
-    gap_ns = spans["startup:loop_entry"][2] - (
+    gap_ns = spans["startup:snapshot_program"][2] - (
         spans["startup:restore"][2] + spans["startup:restore"][3])
     assert SLEEP_S * 1e9 <= gap_ns < (SLEEP_S + 0.25) * 1e9
 
@@ -130,7 +130,7 @@ def test_startup_spans_are_in_the_file_after_the_ring_overflowed(startup_run):
     for (_, _, s0, d0), (_, _, s1, _) in zip(doc["startup"], doc["startup"][1:]):
         assert s1 >= s0 + d0 - 200_000  # two clocks: allow 0.2 ms of skew
     # ``step`` is the step the loop started from; the first iteration is 1
-    assert [s[1] for s in doc["startup"]] == [0] * 10 + [1, 1]
+    assert [s[1] for s in doc["startup"]] == [0] * 11 + [1, 1]
 
 
 def test_startup_spans_enter_no_total(startup_run):
