@@ -265,7 +265,8 @@ class ModelConfig:
     # One mixer kind per layer: "conv" (gated short convolution),
     # "full_attention" (causal grouped-query attention) or
     # "sliding_attention" (the same inside a window of sliding_window
-    # keys), each followed by a feed-forward; or a layer of ONE sublayer,
+    # keys, over sliding_num_heads query heads where that is set), each
+    # followed by a feed-forward; or a layer of ONE sublayer,
     # x + sublayer(RMSNorm(x)): "mamba2_only" (the Mamba-2 mixer),
     # "attention_only" (causal grouped-query attention) or "experts_only"
     # (the expert feed-forward). Its length must be num_layers.
@@ -296,6 +297,32 @@ class ModelConfig:
     # every attention layer rotates.
     rope_layout: list[int] = field(default_factory=list)
     qk_norm: bool = True        # RMSNorm over each head of q and of k
+    # Query heads of a "sliding_attention" layer, over the same
+    # num_kv_heads; 0 = num_heads, like every other attention layer.
+    sliding_num_heads: int = 0
+    # The rotary rule (half rotation at rope_theta over the whole head by
+    # default). rope_fraction: the share of each head's dims, its first,
+    # that rotates (the rest pass unrotated). rope_yarn_factor > 0: YaRN
+    # frequencies (arXiv:2309.00071): those that turn fewer than
+    # rope_yarn_beta_slow times in rope_yarn_original_len positions are
+    # divided by the factor, those that turn more than
+    # rope_yarn_beta_fast times are kept, a linear ramp over the rotated
+    # dims between. rope_attention_factor multiplies cos and sin.
+    rope_fraction: float = 1.0
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_len: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
+    # A rule of their own for the "sliding_attention" layers: plain
+    # frequencies at sliding_rope_theta over the first
+    # sliding_rope_fraction of each head. 0 = they share the rule above.
+    sliding_rope_theta: float = 0.0
+    sliding_rope_fraction: float = 1.0
+    # "per_head": every attention layer multiplies each query head's
+    # output by sigmoid(u W_g), one scalar a head and token read from the
+    # layer's normed input u in float32, before the out-projection.
+    attention_gate: str = "none"
     tie_embeddings: bool = True  # false: an output matrix of its own
     # Std of the token embedding's normal init. A router that reads the
     # un-normed stream (router_input: stream) sees what the stream holds
@@ -316,17 +343,19 @@ class ModelConfig:
     # silu (SwiGLU) | relu (ReGLU): gated, W2(act(W1 x) * W3 x);
     # relu2: not gated, W2 relu(W1 x)^2
     expert_activation: str = "silu"
-    # One tensor-parallel share of the one-sublayer kinds, taken wherever
-    # a layer has something to split: tensor_groups chips divide it in
-    # contiguous runs and this is chip tensor_group. An attention layer
-    # holds num_heads / tensor_groups query heads with the key/value heads
-    # they read, a Mamba-2 layer mamba_num_heads / tensor_groups heads with
-    # mamba_groups / tensor_groups B/C groups, an experts_only layer's
-    # shared expert moe_shared_dim / tensor_groups of its hidden units; each
-    # adds its heads' (units') part of the out-projection's sum and
+    # One tensor-parallel share, taken wherever a layer has something to
+    # split: tensor_groups chips divide it in contiguous runs and this is
+    # chip tensor_group. An attention layer of any kind holds its query
+    # heads / tensor_groups (num_heads, or sliding_num_heads in a window
+    # layer) with the key/value heads they read, a Mamba-2 layer
+    # mamba_num_heads / tensor_groups heads with mamba_groups /
+    # tensor_groups B/C groups, a dense feed-forward mlp_dim /
+    # tensor_groups and a shared expert moe_shared_dim / tensor_groups of
+    # their hidden units (a unit, gated or not, is elementwise in them);
+    # each adds its heads' (units') part of the out-projection's sum and
     # nothing stands in for the others. Shares are whole groups or the
-    # model is refused; so are the mixer-then-feed-forward kinds, whose
-    # dense feed-forward has no such split.
+    # model is refused; so is a "conv" layer, whose gated convolution has
+    # no such split.
     tensor_groups: int = 1
     tensor_group: int = 0
     # Mamba-2 layers ("mamba2_only"): heads of mamba_head_dim channels,
@@ -341,14 +370,17 @@ class ModelConfig:
     # their weighted sum goes back through W_b; the router still reads
     # the stream. 0: the experts read the stream itself.
     moe_latent_dim: int = 0
-    # Width of a shared expert W2 relu(W1 x)^2 beside the routed ones, on
-    # the stream; 0: none.
+    # Width of a shared expert beside the routed ones, on what they read,
+    # in every layer that has experts; 0: none. It is a unit of the
+    # experts' own form: gated, V_d(act(V_g x) * V_u x), under a gated
+    # expert_activation, and V2 relu(V1 x)^2 under relu2.
     moe_shared_dim: int = 0
     routed_scaling: float = 1.0   # on the routed experts' weights
     # Std of the normal init of every residual branch's output projection
-    # in the one-sublayer kinds (a Mamba-2 layer's out_proj, attention's
-    # attn_out, an expert layer's latent_out and its shared expert's
-    # down): the scaled output init of the GPT-2 / Megatron-LM recipes,
+    # (a Mamba-2 layer's out_proj, attention's attn_out, a dense
+    # feed-forward's mlp_out, an expert layer's latent_out and its shared
+    # expert's down; not a "conv" layer's, which is refused): the scaled
+    # output init of the GPT-2 / Megatron-LM recipes,
     # 0.02 / sqrt(2 x layers). 0: the fan-in rule of every other
     # projection. With unit-variance embeddings it keeps each token's own
     # content on top of the stream at a random init, so a router behind a

@@ -41,11 +41,13 @@ def _is_builtin_model_name(name: str) -> bool:
 def _is_lfm2_name(name: str) -> bool:
     """The causal decoder family of models/lfm2.py: ``lfm2``,
     ``lfm2_moe``, ``lfm2-8b-a1b``, ``smallthinker``,
-    ``smallthinker_moe``, ``nemotron_h`` — the whole ``lfm2``,
-    ``smallthinker`` and ``nemotron`` prefixes are reserved (the models
-    differ by ModelConfig settings, not by class). One test for
+    ``smallthinker_moe``, ``nemotron_h``, ``laguna`` — the whole
+    ``lfm2``, ``smallthinker``, ``nemotron`` and ``laguna`` prefixes are
+    reserved (the models differ by ModelConfig settings, not by class).
+    One test for
     get_model, the task and the decode refusal."""
-    return name.lower().startswith(("lfm2", "smallthinker", "nemotron"))
+    return name.lower().startswith(
+        ("lfm2", "smallthinker", "nemotron", "laguna"))
 
 
 def builtin_task(name: str) -> str:

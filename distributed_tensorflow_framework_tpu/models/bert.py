@@ -420,7 +420,7 @@ def decode_support_reason(model_config) -> str | None:
     parameter tree by name; trees it does not know must be refused by
     name rather than failing as a KeyError mid-stream."""
     name = model_config.name.lower()
-    if name.startswith(("lfm2", "smallthinker", "nemotron")):
+    if name.startswith(("lfm2", "smallthinker", "nemotron", "laguna")):
         return (f"model {model_config.name!r} (the lfm2 decoder family) "
                 f"trains only: serving it needs a per-layer cache of "
                 f"several kinds (keys/values for its attention layers, a "

@@ -1,5 +1,5 @@
 """The causal decoder family: a stack whose mixer is chosen per layer,
-over dropless experts. Three published models run through it, told apart
+over dropless experts. Four published models run through it, told apart
 by ``ModelConfig`` settings alone: LFM2-MoE (LiquidAI LFM2-8B-A1B,
 ``model_type: lfm2_moe``: gated short convolutions, grouped-query
 attention, bias-routed SwiGLU experts, a tied head; every default below
@@ -9,7 +9,11 @@ reads the stream before attention, ReGLU experts, an untied head) and
 Nemotron-H (NVIDIA Nemotron-3-Super-120B-A12B, ``model_type:
 nemotron_h``: layers of one sublayer each: Mamba-2 mixers, attention
 without positions, a LatentMoE of ungated squared-ReLU experts beside a
-shared expert).
+shared expert) and Laguna (poolside Laguna-S-2.1, ``model_type:
+laguna``: window layers with more query heads than the global layers
+and a rotary rule of their own, YaRN frequencies over half of a global
+layer's head, a per-head output gate, softmax-routed SwiGLU experts with
+scaled weights beside a gated shared expert).
 
 Pre-norm blocks, ``h = x + mixer(RMSNorm(x))``, ``y = h + ffn(RMSNorm(h))``,
 one mixer kind per layer (``ModelConfig.layer_types``):
@@ -21,12 +25,24 @@ one mixer kind per layer (``ModelConfig.layer_types``):
   full_attention     q over ``num_heads``, k and v over ``num_kv_heads``
                      of ``head_dim`` dims, no bias; RMSNorm over each
                      head's dims on q and k (``qk_norm``); rotary
-                     positions (half rotation) on the whole head where
-                     ``rope_layout`` says so; causal softmax attention,
-                     each key/value head serving ``num_heads /
-                     num_kv_heads`` query heads; ``W_o``.
+                     positions (half rotation) where ``rope_layout``
+                     says so, by the layer's rule (``RotaryRule``: over
+                     the whole head at ``rope_theta``, or over the first
+                     ``rope_fraction`` of it, with YaRN frequencies
+                     under ``rope_yarn_factor`` and cos and sin times
+                     ``rope_attention_factor``); causal softmax
+                     attention, each key/value head serving ``num_heads
+                     / num_kv_heads`` query heads; with
+                     ``attention_gate: per_head`` each head's result
+                     times ``sigmoid(u W_g)``, one scalar a head and
+                     token read from the layer's normed input
+                     (``gate_heads``); ``W_o``.
   sliding_attention  the same, and a query at ``i`` sees a key at ``j``
-                     only if ``i - j < sliding_window``.
+                     only if ``i - j < sliding_window``; over
+                     ``sliding_num_heads`` query heads where that is set,
+                     and by a plain rule of its own at
+                     ``sliding_rope_theta`` over ``sliding_rope_fraction``
+                     of the head where that is set.
 
 Or a layer is ONE sublayer, ``y = x + sublayer(RMSNorm(x))``
 (``SoloBlock``):
@@ -46,25 +62,28 @@ Or a layer is ONE sublayer, ``y = x + sublayer(RMSNorm(x))``
                      ``routed_scaling`` say.
 
 ``tensor_groups`` / ``tensor_group`` give a process one tensor-parallel
-share of the one-sublayer kinds, as ``expert_groups`` gives it its
-experts, taken wherever a layer has something to split: an attention
-layer holds ``num_heads / tensor_groups`` query heads with the key/value
-heads they read, a Mamba-2 layer ``mamba_num_heads / tensor_groups``
-heads with ``mamba_groups / tensor_groups`` B/C groups (its gated norm is
-over its own groups), and an ``experts_only`` layer's shared expert
-``moe_shared_dim / tensor_groups`` of its hidden units (an ungated unit
-is elementwise in them); each adds its heads' (or units') part of the
-out-projection's sum and nothing stands in for the others. A share that
-would split a B/C group, or a key/value head's queries unevenly, is
-refused, and so is one over the mixer-then-feed-forward kinds (their
-dense feed-forward has no such split). Routers, latent projections and
+share, as ``expert_groups`` gives it its experts, taken wherever a layer
+has something to split: an attention layer of any kind holds its query
+heads ``/ tensor_groups`` (``num_heads``, or ``sliding_num_heads`` in a
+window layer) with the key/value heads they read and the gate's columns
+of those heads, a Mamba-2 layer ``mamba_num_heads / tensor_groups`` heads
+with ``mamba_groups / tensor_groups`` B/C groups (its gated norm is over
+its own groups), a dense feed-forward ``mlp_dim / tensor_groups`` and a
+shared expert ``moe_shared_dim / tensor_groups`` of their hidden units
+(a unit, gated or not, is elementwise in them); each adds its heads' (or
+units') part of the out-projection's sum and nothing stands in for the
+others. A share that would split a B/C group, or a key/value head's
+queries unevenly, is refused, and so is one over a ``conv`` layer (its
+gated convolution has no such split). Routers, latent projections and
 norms are whole on every chip.
 
 The first ``num_dense_layers`` layers carry a dense SwiGLU feed-forward
 (``mlp_dim``), the rest ``DroplessMoE`` (models/moe.py): no dropped
 token, this process's share of the experts, the router's scores
-(``router_score``), what it reads (``router_input``) and the experts'
-activation (``expert_activation``) as set. Token embedding in, a final
+(``router_score``), what it reads (``router_input``), the experts'
+activation (``expert_activation``), the factor on their weights
+(``routed_scaling``) and a shared expert of their form beside them
+(``moe_shared_dim``) as set. Token embedding in, a final
 RMSNorm and a head out: the embedding transposed (``tie_embeddings``) or
 a matrix of its own.
 
@@ -102,18 +121,22 @@ the re-run forward pass recomputes everything else:
 Scopes a trace can be read by (docs/OBSERVABILITY.md):
 ``layerN/short_conv/{in_proj,gate_conv,out_proj}``, ``layerN/attn/...``
 (``full_attention``), ``layerN/attn_window/...`` (``sliding_attention``),
+inside either ``qk_norm_rope`` (norm, rotation and cast of q and k) and
+``attn_gate`` (the gate's projection and its product with the kernel's
+result),
 ``layerN/{mlp_in,mlp_up,mlp_out}``,
 ``layerN/moe/{router,dispatch,experts,combine}`` and, around them,
 ``layerN/moe/{latent_in,latent_out,shared}``,
 ``layerN/mamba/{in_proj,conv,scan,gate_norm,out_proj}``, ``lm_head``.
 Counters beside the logits: ``moe_*`` (the mean over the layers that
-have experts), ``attn_window_block_share`` and ``ssm_resets``.
+have experts), ``attn_window_block_share``, ``ssm_resets`` and
+``attn_gate_mean`` (the gate's mean over heads, tokens and gated layers).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -137,6 +160,10 @@ ROUTER_INPUTS = ("ffn_norm", "stream")
 # the model's expert layers and named ``moe_<key>`` in the step's metrics.
 MOE_COUNTERS = ("local_assignments", "load_max_mean", "dropped",
                 "local_share", "compact")
+# What a gated attention layer reports beside them: the mean of its gate
+# over heads and tokens, averaged over the gated layers and named
+# ``attn_gate_mean`` in the step's metrics.
+GATE_COUNTER = "attn_gate"
 # How Mamba-2 draws the step size a head starts from (``dt_bias`` is its
 # inverse softplus): log-uniform between the two, floored. The published
 # ``time_step_min/max/floor``; they shape this init and nothing else.
@@ -316,17 +343,77 @@ class Mamba2Mixer(nn.Module):
         return projection(h, self.dtype, "out_proj", self.out_init_std)(y)
 
 
-def rotary(x, positions, theta: float):
-    """Half-rotation rotary embedding over the whole head: ``x`` (B, S,
-    N, D), ``positions`` (B, S); float32."""
+class RotaryRule(NamedTuple):
+    """What a layer's rotation does beyond the plain rule (half rotation
+    over the whole head at the layer's theta): ``fraction`` of each
+    head's dims, its first, rotate and the rest pass; with
+    ``yarn_factor`` the frequencies are YaRN's (``yarn_inv_freq``);
+    ``attention_factor`` multiplies cos and sin."""
+    fraction: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_len: int,
+                          beta_fast: float, beta_slow: float
+                          ) -> tuple[int, int]:
+    """``(low, high)``: the rotated pairs between which YaRN's ramp
+    runs. Pair ``r`` of ``dim`` rotated dims turns ``original_len · θ^(-2r
+    / dim) / 2π`` times in ``original_len`` positions; ``low`` is the
+    last pair that turns ``beta_fast`` times or more (rounded down),
+    ``high`` the first that turns ``beta_slow`` times or fewer (rounded
+    up), both inside the head."""
+    def pair_turning(times: float) -> float:
+        return dim * math.log(original_len / (times * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    return (max(math.floor(pair_turning(beta_fast)), 0),
+            min(math.ceil(pair_turning(beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, rule: RotaryRule):
+    """YaRN frequencies (arXiv:2309.00071) of ``dim`` rotated dims:
+    ``f_i = θ^(-2i/dim)`` kept where a pair turns often (``i <= low``),
+    divided by ``yarn_factor`` where it turns rarely (``i >= high``), and
+    ``f_i / factor · ramp_i + f_i · (1 - ramp_i)`` on the linear ramp
+    between; float32, ``(dim / 2,)``."""
+    freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    low, high = yarn_correction_range(
+        dim, theta, rule.yarn_original_len, rule.yarn_beta_fast,
+        rule.yarn_beta_slow)
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0)
+    return freq / rule.yarn_factor * ramp + freq * (1.0 - ramp)
+
+
+def rotary(x, positions, theta: float, rule: RotaryRule | None = None):
+    """Half-rotation rotary embedding: ``x`` (B, S, N, D), ``positions``
+    (B, S); float32. Over the whole head at plain frequencies, or as
+    ``rule`` says."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (B,S,D/2)
+    rule = rule or RotaryRule()
+    rot = int(d * rule.fraction)
+    if rule.yarn_factor:
+        inv_freq = yarn_inv_freq(rot, theta, rule)
+    else:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (B,S,R/2)
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[:, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[:, :, None, :]
+    if rule.attention_factor != 1.0:
+        cos, sin = cos * rule.attention_factor, sin * rule.attention_factor
     x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    turned = x if rot == d else x[..., :rot]
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    turned = turned * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    if rot == d:
+        return turned
+    return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
 def causal_attention_xla(q, k, v, segment_ids=None, dtype=jnp.float32,
@@ -351,7 +438,25 @@ def causal_attention_xla(q, k, v, segment_ids=None, dtype=jnp.float32,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+ATTENTION_GATES = ("none", "per_head")
+
+
+def gate_heads(out, u, kernel):
+    """The per-head output gate: ``out`` (B, S, N, D), the kernels'
+    result, times ``g = sigmoid(u W_g)`` (B, S, N), one scalar a head and
+    token read from the layer's normed input ``u``; float32 end to end (a
+    gate near 0 or 1 is decided by a few parts in a thousand). Returns
+    ``(g ⊙ out, g)``."""
+    g = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), kernel,
+                               precision=jax.lax.Precision.HIGHEST))
+    return out.astype(jnp.float32) * g[..., None], g
+
+
 class GroupedQueryAttention(nn.Module):
+    """Causal grouped-query attention over the heads it is given.
+    Returns the out-projection's result, and with a ``gate`` the pair
+    ``(result, mean of the gate)``: the counter ``attn_gate_mean``."""
+
     num_heads: int
     num_kv_heads: int
     rope_theta: float = 1e6
@@ -364,6 +469,8 @@ class GroupedQueryAttention(nn.Module):
     rope: bool = True            # rotary positions on q and k
     qk_norm: bool = True         # RMSNorm over each head of q and of k
     out_init_std: float = 0.0    # of attn_out; 0: the fan-in rule
+    rope_rule: Any = None        # a RotaryRule; None: the plain rule
+    gate: str = "none"           # one of ATTENTION_GATES
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
@@ -378,7 +485,7 @@ class GroupedQueryAttention(nn.Module):
             if self.qk_norm:
                 t = RMSNorm(self.norm_eps, name=norm_name)(t)
             if self.rope:
-                t = rotary(t, positions, self.rope_theta)
+                t = rotary(t, positions, self.rope_theta, self.rope_rule)
             return t.astype(self.dtype)
 
         with jax.named_scope("qk_norm_rope"):
@@ -401,8 +508,14 @@ class GroupedQueryAttention(nn.Module):
             raise ValueError(
                 f"attention_impl {self.attention_impl!r} is not wired for "
                 f"the lfm2 family (pallas | xla)")
-        return projection(h, self.dtype, "attn_out", self.out_init_std)(
-            out.reshape(b, s, n * d))
+        out_proj = projection(h, self.dtype, "attn_out", self.out_init_std)
+        if self.gate == "none":
+            return out_proj(out.reshape(b, s, n * d))
+        kernel = self.param("gate", dense_kernel_init, (h, n), jnp.float32)
+        with jax.named_scope("attn_gate"):
+            out, g = gate_heads(out, x, kernel)
+            out, gate_mean = out.astype(self.dtype), jnp.mean(g)
+        return out_proj(out.reshape(b, s, n * d)), gate_mean
 
 
 class Lfm2Block(nn.Module):
@@ -429,6 +542,11 @@ class Lfm2Block(nn.Module):
     router_input: str = "ffn_norm"   # one of ROUTER_INPUTS
     router_score: str = "sigmoid_bias"
     expert_activation: str = "silu"
+    rope_rule: Any = None        # this layer's RotaryRule; None: plain
+    attention_gate: str = "none"
+    moe_shared_dim: int = 0      # a shared expert's units held here
+    routed_scaling: float = 1.0
+    out_init_std: float = 0.0    # of attn_out, mlp_out, the shared down
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
@@ -445,8 +563,13 @@ class Lfm2Block(nn.Module):
                 head_dim=self.head_dim,
                 window=self.sliding_window if sliding else None,
                 rope=self.rope, qk_norm=self.qk_norm,
+                out_init_std=self.out_init_std, rope_rule=self.rope_rule,
+                gate=self.attention_gate,
                 name=ATTENTION_SCOPES[self.kind],
             )(normed, segment_ids, positions)
+        gate_mean = None
+        if self.kind != "conv" and self.attention_gate != "none":
+            mixed, gate_mean = mixed
         x = x + mixed
         normed = RMSNorm(self.norm_eps, name="ffn_norm")(x)
         # A type-stable counter dict either way (zeros under a dense
@@ -455,7 +578,8 @@ class Lfm2Block(nn.Module):
         if self.dense_ffn:
             gate = _dense(self.mlp_dim, self.dtype, "mlp_in")(normed)
             up = _dense(self.mlp_dim, self.dtype, "mlp_up")(normed)
-            y = _dense(x.shape[-1], self.dtype, "mlp_out")(nn.silu(gate) * up)
+            y = projection(x.shape[-1], self.dtype, "mlp_out",
+                           self.out_init_std)(nn.silu(gate) * up)
         else:
             # The router reads what the experts read, or the stream as it
             # entered the layer (before the mixer and its norm).
@@ -465,8 +589,13 @@ class Lfm2Block(nn.Module):
                 topk=self.expert_topk, groups=self.expert_groups,
                 group=self.expert_group, dtype=self.dtype,
                 score=self.router_score, activation=self.expert_activation,
+                shared_dim=self.moe_shared_dim,
+                weight_scale=self.routed_scaling,
+                out_init_std=self.out_init_std,
                 name="moe",
             )(normed, *route_from)
+        if gate_mean is not None:
+            counters = {**counters, GATE_COUNTER: gate_mean}
         return x + y.astype(x.dtype), counters
 
 
@@ -489,6 +618,9 @@ class SoloBlock(nn.Module):
         elif self.kind == "attention_only":
             y = GroupedQueryAttention(**self.sublayer, name="attn")(
                 normed, segment_ids, positions)
+            if self.sublayer.get("gate", "none") != "none":
+                y, gate_mean = y
+                counters = {**counters, GATE_COUNTER: gate_mean}
         else:
             y, counters = DroplessMoE(**self.sublayer, name="moe")(normed)
         return x + y.astype(x.dtype), counters
@@ -556,51 +688,86 @@ class Lfm2ForCausalLM(nn.Module):
     moe_shared_dim: int = 0
     routed_scaling: float = 1.0
     out_proj_init_std: float = 0.0
+    sliding_num_heads: int = 0   # a window layer's query heads; 0: num_heads
+    rope_rule: Any = None        # RotaryRule of the layers at rope_theta
+    sliding_rope_theta: float = 0.0  # > 0: the window layers' own rule ...
+    sliding_rope_rule: Any = None    # ... which is this one (None: plain)
+    attention_gate: str = "none"
 
     def has_experts(self, i: int) -> bool:
         return layer_has_experts(self.layer_types, self.num_dense_layers, i)
 
     def tensor_share(self) -> dict | None:
-        """Which heads of each mixer and which units of the shared expert
-        this process holds, for the run's opening record; None for the
-        whole model."""
+        """Which heads of each mixer (attention's by layer kind where the
+        window layers have a head count of their own) and which hidden
+        units of the dense feed-forward and of the shared expert this
+        process holds, for the run's opening record; None for the whole
+        model."""
         if self.tensor_groups == 1:
             return None
         share = {"groups": self.tensor_groups, "group": self.tensor_group}
         held = lambda n: list(held_heads(  # noqa: E731
             n, self.tensor_groups, self.tensor_group))
-        if set(self.layer_types) - {"mamba2_only", "experts_only"}:
-            share["attention"] = {
-                "heads": self.num_heads, "held": held(self.num_heads),
-                "kv_heads": self.num_kv_heads,
-                "kv_held": self._kv_heads_held()}
-        if "mamba2_only" in self.layer_types:
+
+        def attention(kind):
+            heads = self._query_heads(kind)
+            return {"heads": heads, "held": held(heads),
+                    "kv_heads": self.num_kv_heads,
+                    "kv_held": self._kv_heads_held(heads)}
+
+        def units(n):
+            run = held_heads(n, self.tensor_groups, self.tensor_group)
+            return {"units": n, "held": [run.start, run.stop]}  # half-open
+
+        kinds = set(self.layer_types)
+        # the window layers apart where their head count is their own
+        own_window = {"sliding_attention"} & kinds \
+            if self.sliding_num_heads else set()
+        if kinds - {"mamba2_only", "experts_only"} - own_window:
+            share["attention"] = attention("full_attention")
+        if own_window:
+            share["attention_window"] = attention("sliding_attention")
+        if "mamba2_only" in kinds:
             share["mamba2"] = {
                 "heads": self.mamba_heads, "held": held(self.mamba_heads),
                 "bc_groups": self.mamba_groups,
                 "bc_held": held(self.mamba_groups)}
-        if self.moe_shared_dim and "experts_only" in self.layer_types:
-            units = held_heads(self.moe_shared_dim, self.tensor_groups,
-                               self.tensor_group)
-            share["shared_expert"] = {
-                "units": self.moe_shared_dim,
-                "held": [units.start, units.stop]}    # a half-open run
+        layers = range(len(self.layer_types))
+        if any(self.layer_types[i] in LAYER_KINDS
+               and not self.has_experts(i) for i in layers):
+            share["dense_ffn"] = units(self.mlp_dim)
+        if self.moe_shared_dim and any(map(self.has_experts, layers)):
+            share["shared_expert"] = units(self.moe_shared_dim)
         return share
 
-    def _kv_heads_held(self) -> list:
-        """The key/value heads the held query heads read."""
-        per_kv = self.num_heads // self.num_kv_heads
-        held = held_heads(self.num_heads, self.tensor_groups, self.tensor_group)
+    def _query_heads(self, kind: str) -> int:
+        """Query heads of an attention layer of ``kind``, uncut."""
+        if kind == "sliding_attention" and self.sliding_num_heads:
+            return self.sliding_num_heads
+        return self.num_heads
+
+    def _kv_heads_held(self, heads: int) -> list:
+        """The key/value heads the held of ``heads`` query heads read."""
+        per_kv = heads // self.num_kv_heads
+        held = held_heads(heads, self.tensor_groups, self.tensor_group)
         return sorted({q // per_kv for q in held})
 
     def _rotates(self, i: int) -> bool:
         return bool(self.rope_layout[i]) if self.rope_layout else True
 
-    def _attention_held(self) -> tuple:
+    def _rotary(self, kind: str) -> tuple:
+        """``(theta, RotaryRule or None)`` of an attention layer of
+        ``kind``."""
+        if kind == "sliding_attention" and self.sliding_rope_theta:
+            return self.sliding_rope_theta, self.sliding_rope_rule
+        return self.rope_theta, self.rope_rule
+
+    def _attention_held(self, kind: str = "full_attention") -> tuple:
         """``(query heads, key/value heads, head size)`` of an attention
-        layer here."""
-        return (self.num_heads // self.tensor_groups,
-                len(self._kv_heads_held()),
+        layer of ``kind`` here."""
+        heads = self._query_heads(kind)
+        return (heads // self.tensor_groups,
+                len(self._kv_heads_held(heads)),
                 self.head_dim or self.hidden_size // self.num_heads)
 
     def solo_sublayer(self, kind: str, i: int) -> dict:
@@ -621,7 +788,8 @@ class Lfm2ForCausalLM(nn.Module):
                 rope_theta=self.rope_theta, norm_eps=self.norm_eps,
                 dtype=self.dtype, attention_impl=self.attention_impl,
                 mesh=self.mesh, head_dim=head_dim, rope=self._rotates(i),
-                qk_norm=self.qk_norm, out_init_std=self.out_proj_init_std)
+                qk_norm=self.qk_norm, out_init_std=self.out_proj_init_std,
+                rope_rule=self.rope_rule, gate=self.attention_gate)
         return dict(
             num_experts=self.num_experts, mlp_dim=self.moe_mlp_dim,
             topk=self.expert_topk, groups=self.expert_groups,
@@ -645,7 +813,8 @@ class Lfm2ForCausalLM(nn.Module):
         from distributed_tensorflow_framework_tpu.ops import flash_attention
 
         tile = flash_attention.select_dispatch(
-            seq_len, seq_len, self.dtype, self._attention_held()[2])
+            seq_len, seq_len, self.dtype,
+            self._attention_held("sliding_attention")[2])
         visited, causal = flash_attention.window_block_counts(
             seq_len, seq_len, tile.bwd_block_q, tile.bwd_block_k,
             self.sliding_window)
@@ -694,22 +863,25 @@ class Lfm2ForCausalLM(nn.Module):
             solo_cls = nn.remat(SoloBlock, policy=policy)
         totals = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}
         n_moe = 0
-        heads, kv_heads, head_dim = self._attention_held()
+        gates = []               # the gated attention layers' gate means
         for i, kind in enumerate(self.layer_types):
             if kind in SOLO_KINDS:
                 block = solo_cls(
                     kind=kind, sublayer=self.solo_sublayer(kind, i),
                     norm_eps=self.norm_eps, name=f"layer{i}")
             else:
+                heads, kv_heads, head_dim = self._attention_held(kind)
+                rope_theta, rope_rule = self._rotary(kind)
                 block = block_cls(
                     kind=kind, dense_ffn=i < self.num_dense_layers,
                     num_heads=heads, num_kv_heads=kv_heads,
-                    mlp_dim=self.mlp_dim, moe_mlp_dim=self.moe_mlp_dim,
+                    mlp_dim=self.mlp_dim // self.tensor_groups,
+                    moe_mlp_dim=self.moe_mlp_dim,
                     num_experts=self.num_experts,
                     expert_topk=self.expert_topk,
                     expert_groups=self.expert_groups,
                     expert_group=self.expert_group,
-                    conv_kernel=self.conv_kernel, rope_theta=self.rope_theta,
+                    conv_kernel=self.conv_kernel, rope_theta=rope_theta,
                     norm_eps=self.norm_eps, dtype=self.dtype,
                     attention_impl=self.attention_impl, mesh=self.mesh,
                     head_dim=head_dim, sliding_window=self.sliding_window,
@@ -717,12 +889,18 @@ class Lfm2ForCausalLM(nn.Module):
                     router_input=self.router_input,
                     router_score=self.router_score,
                     expert_activation=self.expert_activation,
+                    rope_rule=rope_rule, attention_gate=self.attention_gate,
+                    moe_shared_dim=self.moe_shared_dim // self.tensor_groups,
+                    routed_scaling=self.routed_scaling,
+                    out_init_std=self.out_proj_init_std,
                     name=f"layer{i}")
             x, counters = block(x, segment_ids, positions)
             if self.has_experts(i):
                 totals = {key: totals[key] + counters[key]
                           for key in MOE_COUNTERS}
                 n_moe += 1
+            if GATE_COUNTER in counters:
+                gates.append(counters[GATE_COUNTER])
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.tie_embeddings:
             head = embed.embedding
@@ -739,6 +917,8 @@ class Lfm2ForCausalLM(nn.Module):
         share = self.window_block_share(input_ids.shape[1])
         if share is not None:
             counters["attn_window_block_share"] = jnp.float32(share)
+        if gates:
+            counters["attn_gate_mean"] = sum(gates) / len(gates)
         if "mamba2_only" in self.layer_types:
             # Document starts the scan resets at in these rows (the
             # row's first token is one).
@@ -763,6 +943,16 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
     if heads % kv_heads:
         raise ValueError(f"model.num_heads={heads} is no multiple of "
                          f"model.num_kv_heads={kv_heads}")
+    if config.sliding_num_heads % kv_heads:
+        raise ValueError(
+            f"model.sliding_num_heads={config.sliding_num_heads} (a window "
+            f"layer's query heads) is no multiple of "
+            f"model.num_kv_heads={kv_heads}")
+    if config.attention_gate not in ATTENTION_GATES:
+        raise ValueError(f"model.attention_gate must be one of "
+                         f"{ATTENTION_GATES}, got {config.attention_gate!r}")
+    rope_rule, sliding_rope_rule = _rotary_rules(
+        config, config.head_dim or config.hidden_size // heads)
     if "sliding_attention" in kinds and config.sliding_window < 1:
         raise ValueError(
             "a sliding_attention layer needs model.sliding_window >= 1 "
@@ -790,11 +980,11 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
             "model.expert_topk <= model.num_experts")
     if has_experts:
         check_expert_settings(config.router_score, config.expert_activation)
-    if config.out_proj_init_std and set(kinds) & set(LAYER_KINDS):
+    if config.out_proj_init_std and "conv" in kinds:
         raise ValueError(
-            f"model.out_proj_init_std is wired for the one-sublayer kinds "
-            f"{SOLO_KINDS} only; {sorted(set(kinds) & set(LAYER_KINDS))} "
-            f"keep the fan-in rule")
+            f"model.out_proj_init_std is wired for the attention kinds and "
+            f"the one-sublayer kinds {SOLO_KINDS}; a conv layer's "
+            f"out-projection keeps the fan-in rule")
     _check_tensor_share(config, kinds, heads, kv_heads)
     return Lfm2ForCausalLM(
         vocab_size=config.vocab_size, hidden_size=config.hidden_size,
@@ -821,13 +1011,62 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
         moe_latent_dim=config.moe_latent_dim,
         moe_shared_dim=config.moe_shared_dim,
         routed_scaling=config.routed_scaling,
-        out_proj_init_std=config.out_proj_init_std)
+        out_proj_init_std=config.out_proj_init_std,
+        sliding_num_heads=config.sliding_num_heads,
+        rope_rule=rope_rule, sliding_rope_theta=config.sliding_rope_theta,
+        sliding_rope_rule=sliding_rope_rule,
+        attention_gate=config.attention_gate)
+
+
+def _rotary_rules(config, head_dim: int) -> tuple:
+    """``(rule of the layers at rope_theta, rule of the window layers at
+    sliding_rope_theta)``, each None for the plain rule, refused where a
+    setting cannot be a rule."""
+    def rotated(fraction: float, name: str) -> None:
+        dims = head_dim * fraction
+        if not 0.0 < fraction <= 1.0 or dims != int(dims) or int(dims) % 2:
+            raise ValueError(
+                f"model.{name}={fraction} must leave an even number of a "
+                f"head's {head_dim} dims to rotate (0 < fraction <= 1)")
+
+    rotated(config.rope_fraction, "rope_fraction")
+    rotated(config.sliding_rope_fraction, "sliding_rope_fraction")
+    if config.rope_yarn_factor and not (
+            config.rope_yarn_factor >= 1.0
+            and config.rope_yarn_original_len > 0
+            and config.rope_yarn_beta_fast > config.rope_yarn_beta_slow > 0):
+        raise ValueError(
+            "model.rope_yarn_factor > 0 (YaRN frequencies) needs a factor "
+            ">= 1, model.rope_yarn_original_len > 0 and "
+            "model.rope_yarn_beta_fast > model.rope_yarn_beta_slow > 0, got "
+            f"{config.rope_yarn_factor}, {config.rope_yarn_original_len}, "
+            f"{config.rope_yarn_beta_fast}, {config.rope_yarn_beta_slow}")
+    if config.rope_attention_factor <= 0 or config.sliding_rope_theta < 0:
+        raise ValueError(
+            "model.rope_attention_factor must be positive and "
+            "model.sliding_rope_theta zero (the window layers share the "
+            "rule at model.rope_theta) or a theta of their own, got "
+            f"{config.rope_attention_factor}, {config.sliding_rope_theta}")
+    yarn = dict(
+        yarn_factor=float(config.rope_yarn_factor),
+        yarn_original_len=int(config.rope_yarn_original_len),
+        yarn_beta_fast=float(config.rope_yarn_beta_fast),
+        yarn_beta_slow=float(config.rope_yarn_beta_slow)) \
+        if config.rope_yarn_factor else {}
+    rule = RotaryRule(
+        fraction=float(config.rope_fraction),
+        attention_factor=float(config.rope_attention_factor), **yarn)
+    sliding = RotaryRule(fraction=float(config.sliding_rope_fraction))
+    plain = RotaryRule()
+    return (None if rule == plain else rule,
+            None if sliding == plain else sliding)
 
 
 def _check_tensor_share(config, kinds, heads: int, kv_heads: int) -> None:
-    """A tensor share is whole groups: whole heads for every chip, no
-    key/value head's queries or B/C group's heads split unevenly, the
-    shared expert's units in even runs."""
+    """A tensor share is whole groups: whole heads for every chip, by
+    layer kind, no key/value head's queries or B/C group's heads split
+    unevenly, the dense feed-forward's and the shared expert's units in
+    even runs."""
     groups, group = config.tensor_groups, config.tensor_group
     if "mamba2_only" in kinds:
         m_heads, m_groups = config.mamba_num_heads, config.mamba_groups
@@ -841,22 +1080,35 @@ def _check_tensor_share(config, kinds, heads: int, kv_heads: int) -> None:
     if groups < 1 or not 0 <= group < groups:
         raise ValueError(f"model.tensor_group={group} is not one of "
                          f"model.tensor_groups={groups}")
-    if set(kinds) & set(LAYER_KINDS):
+    if "conv" in kinds:
         raise ValueError(
-            f"model.tensor_groups > 1 is wired for the one-sublayer kinds "
-            f"{SOLO_KINDS} only; {sorted(set(kinds) & set(LAYER_KINDS))} "
-            f"carry a feed-forward (and a convolution) it does not split")
-    if set(kinds) - {"mamba2_only", "experts_only"} and (
-            heads % groups or (kv_heads % groups and groups % kv_heads)):
-        raise ValueError(
-            f"model.tensor_groups={groups} does not divide attention's "
-            f"{heads} query heads over {kv_heads} key/value heads into "
-            f"whole, even shares")
-    if "experts_only" in kinds and config.moe_shared_dim % groups:
+            f"model.tensor_groups > 1 is wired for the attention kinds and "
+            f"the one-sublayer kinds {SOLO_KINDS}; a conv layer's gated "
+            f"convolution has no such split")
+    # query heads of each kind of attention layer present
+    by_kind = {(config.sliding_num_heads or heads)
+               if kind == "sliding_attention" else heads
+               for kind in kinds if kind in (*ATTENTION_SCOPES,
+                                             "attention_only")}
+    for n in sorted(by_kind):
+        if n % groups or (kv_heads % groups and groups % kv_heads):
+            raise ValueError(
+                f"model.tensor_groups={groups} does not divide attention's "
+                f"{n} query heads over {kv_heads} key/value heads into "
+                f"whole, even shares")
+    layers = range(len(kinds))
+    has_experts = [layer_has_experts(kinds, config.num_dense_layers, i)
+                   for i in layers]
+    if any(has_experts) and config.moe_shared_dim % groups:
         raise ValueError(
             f"model.tensor_groups={groups} does not divide the shared "
             f"expert's {config.moe_shared_dim} units "
             f"(model.moe_shared_dim)")
+    if config.mlp_dim % groups and any(
+            kinds[i] in LAYER_KINDS and not has_experts[i] for i in layers):
+        raise ValueError(
+            f"model.tensor_groups={groups} does not divide the dense "
+            f"feed-forward's {config.mlp_dim} units (model.mlp_dim)")
     if "mamba2_only" in kinds and config.mamba_groups % groups:
         raise ValueError(
             f"model.tensor_groups={groups} splits a B/C group of the Mamba-2 "

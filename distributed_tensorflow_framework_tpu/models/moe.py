@@ -759,19 +759,27 @@ def projection(features: int, dtype, name: str, init_std: float = 0.0
 
 
 class SharedExpert(nn.Module):
-    """``V2 relu(V1 x)²``: the ungated squared-ReLU unit every token
-    takes beside its routed experts."""
+    """The unit every token takes beside its routed experts, of the
+    experts' own form: ``V2 act(V1 x)`` (``up``, ``down``) under an
+    ungated ``activation`` (``relu2``: the squared ReLU), ``V_d(act(V_g
+    x) ⊙ V_u x)`` (``gate``, ``up``, ``down``) under a gated one."""
 
     mlp_dim: int
     dtype: Any = jnp.bfloat16
     out_init_std: float = 0.0        # of ``down``; 0: the fan-in rule
+    activation: str = "relu2"
 
     @nn.compact
     def __call__(self, x):
-        up = projection(self.mlp_dim, self.dtype, "up")(x.astype(self.dtype))
+        x = x.astype(self.dtype)
+        up = projection(self.mlp_dim, self.dtype, "up")(x)
+        if self.activation in UNGATED_ACTIVATIONS:
+            hidden = UNGATED_ACTIVATIONS[self.activation](up)
+        else:
+            hidden = EXPERT_ACTIVATIONS[self.activation](
+                projection(self.mlp_dim, self.dtype, "gate")(x)) * up
         return projection(x.shape[-1], self.dtype, "down",
-                           self.out_init_std)(
-            UNGATED_ACTIVATIONS["relu2"](up))
+                           self.out_init_std)(hidden)
 
 
 class DroplessMoE(nn.Module):
@@ -804,8 +812,10 @@ class DroplessMoE(nn.Module):
     ``latent_out``, whole on every process), so dispatch, sorted buffer
     and combine carry rows of ``latent_dim`` while the router reads the
     stream; ``weight_scale`` multiplies the routed sum's weights;
-    ``shared_dim`` adds ``V2 relu(V1 x)²`` on the stream itself, an expert
-    every token takes and every process computes alike. With more than
+    ``shared_dim`` adds a unit of the experts' own form on the stream
+    itself (``SharedExpert``: ``V2 relu(V1 x)²`` under ``relu2``, gated
+    under a gated ``activation``), an expert every token takes and every
+    process computes alike. With more than
     one group
     the pass over the buffer is the body of a ``lax.while_loop`` on the
     device (no sync) that runs while local assignments are left: once for
@@ -921,7 +931,7 @@ class DroplessMoE(nn.Module):
                               self.out_init_std)(out)
         if self.shared_dim:
             out = out + SharedExpert(self.shared_dim, self.dtype,
-                                     self.out_init_std,
+                                     self.out_init_std, self.activation,
                                      name="shared")(stream)
         sizes = group_sizes.astype(jnp.float32)
         counters = {

@@ -51,6 +51,11 @@ unsegmented:
                                               its gate raised for the case);
                                               16,384 is ``smallthinker_s16384``'s
                                               window layers' call
+  window512_d128_s16384  16384  bf16  as chosen  a window of 512, narrower than the
+                                              1024-key tile, nine query heads over
+                                              one key/value head:
+                                              ``laguna_s_s16384``'s window layers'
+                                              call
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -150,6 +155,8 @@ def _causal_cases() -> dict:
 
 # The window cases' shapes: ``smallthinker_s16384``'s head size and group.
 WINDOW_HEADS, WINDOW_KV_HEADS, WINDOW_D = 14, 2, 128
+# ... and the cases with (query heads, key/value heads) of their own.
+WINDOW_CASE_HEADS = {"window512_d128_s16384": (9, 1)}
 
 
 def _window_cases() -> dict:
@@ -162,6 +169,7 @@ def _window_cases() -> dict:
         "window_d128_s8192_fused": (2 * vmem, True, bf16, vmem,
                                     4 * fa.FUSED_BWD_MAX),
         "window_d128_s16384": (4 * vmem, None, bf16, vmem, None),
+        "window512_d128_s16384": (4 * vmem, None, bf16, 512, None),
     }
 
 
@@ -240,7 +248,9 @@ def run_case(name: str, seq: int, setting: bool | None, dtype,
     if window is None:
         args = _inputs(seq, H // KV_GROUP if causal else H, dtype)
     else:
-        args = _inputs(seq, WINDOW_KV_HEADS, dtype, WINDOW_HEADS, WINDOW_D)
+        heads, kv_heads = WINDOW_CASE_HEADS.get(
+            name, (WINDOW_HEADS, WINDOW_KV_HEADS))
+        args = _inputs(seq, kv_heads, dtype, heads, WINDOW_D)
     head_dim = int(args[0].shape[-1])
     _allow_fused(setting)
     dispatch = fa.select_dispatch(seq, seq, dtype, head_dim)
@@ -250,7 +260,7 @@ def run_case(name: str, seq: int, setting: bool | None, dtype,
            "fused_bwd": fused, "causal": causal, "window": window,
            "head_dim": head_dim, "kv_heads": int(args[1].shape[2]),
            "dispatch": dispatch._asdict(), "variants": {}}
-    key = (seq, dtype_name, causal, window, head_dim)
+    key = (seq, dtype_name, causal, window, head_dim, int(args[0].shape[2]))
     ok = True
     for segmented in (False, True):
         _allow_fused(setting)

@@ -135,3 +135,85 @@ def test_fleet_autoscale_and_tenant_knobs_validated():
     assert cfg.serve.fleet_autoscale is True
     assert cfg.serve.fleet_max_replicas == 4
     assert cfg.serve.tenant_quota_rps == 2.5
+
+
+# --- decoder family: heads and rotary rules by layer kind, the gate, shares --
+def _laguna_overrides(*more):
+    return ["model.num_layers=3",
+            "model.layer_types=[full_attention,sliding_attention,"
+            "full_attention]", "model.hidden_size=64", "model.num_heads=4",
+            "model.sliding_num_heads=6", "model.num_kv_heads=2",
+            "model.head_dim=16", "model.sliding_window=24",
+            "model.mlp_dim=96", "model.moe_mlp_dim=24",
+            "model.moe_shared_dim=32", "model.num_experts=16",
+            "model.expert_topk=3", "model.vocab_size=256",
+            "data.vocab_size=256", "data.seq_len=128", *more]
+
+
+def _laguna_yaml():
+    import os
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "configs", "laguna_s_2_1.yaml")
+
+
+def test_rotary_rules_by_layer_kind_parse_from_overrides():
+    from distributed_tensorflow_framework_tpu.models import get_model
+
+    cfg = load_config(_laguna_yaml(), _laguna_overrides(
+        "model.rope_yarn_factor=8", "model.rope_yarn_original_len=32",
+        "model.rope_fraction=0.25", "model.sliding_rope_theta=50",
+        "model.sliding_rope_fraction=0.5", "model.attention_gate=per_head"))
+    m = cfg.model
+    assert (m.rope_yarn_factor, m.rope_yarn_original_len, m.rope_fraction,
+            m.sliding_rope_theta, m.sliding_rope_fraction) == (
+                8.0, 32, 0.25, 50.0, 0.5)
+    model = get_model(m)
+    assert model._rotary("full_attention") == (500000.0, model.rope_rule)
+    assert model.rope_rule.fraction == 0.25
+    assert model.rope_rule.yarn_factor == 8.0
+    assert model._rotary("sliding_attention")[0] == 50.0
+    assert model._rotary("sliding_attention")[1].fraction == 0.5
+    # no theta of their own: the window layers share the global rule
+    shared = get_model(load_config(_laguna_yaml(), _laguna_overrides(
+        "model.sliding_rope_theta=0", "model.rope_yarn_original_len=32")
+    ).model)
+    assert shared._rotary("sliding_attention") == shared._rotary(
+        "full_attention")
+    # the defaults are the plain rule: no rule object at all
+    plain = get_model(load_config(_laguna_yaml(), _laguna_overrides(
+        "model.sliding_rope_theta=0", "model.rope_yarn_factor=0",
+        "model.rope_fraction=1", "model.rope_attention_factor=1")).model)
+    assert plain.rope_rule is None and plain.sliding_rope_rule is None
+
+
+@pytest.mark.parametrize("bad,says", [
+    (["model.tensor_groups=4"], "whole, even shares"),   # 6 window heads
+    (["model.tensor_groups=2", "model.mlp_dim=97"], "dense"),
+    (["model.tensor_groups=2", "model.moe_shared_dim=33"], "shared"),
+    (["model.tensor_groups=2", "model.tensor_group=2"], "is not one of"),
+    (["model.sliding_num_heads=5"], "sliding_num_heads"),
+    (["model.attention_gate=per_channel"], "attention_gate"),
+    (["model.rope_fraction=0.3"], "rope_fraction"),
+    (["model.rope_yarn_original_len=0"], "rope_yarn"),
+    (["model.sliding_rope_theta=-1"], "sliding_rope_theta"),
+])
+def test_shares_and_rotary_rules_by_kind_are_validated(bad, says):
+    from distributed_tensorflow_framework_tpu.models import get_model
+
+    cfg = load_config(_laguna_yaml(), _laguna_overrides(
+        "model.rope_yarn_original_len=32", *bad))
+    with pytest.raises(ValueError, match=says):
+        get_model(cfg.model)
+
+
+def test_a_share_of_heads_by_kind_and_of_both_unit_runs_is_legal():
+    from distributed_tensorflow_framework_tpu.models import get_model
+
+    cfg = load_config(_laguna_yaml(), _laguna_overrides(
+        "model.rope_yarn_original_len=32", "model.tensor_groups=2",
+        "model.tensor_group=1", "model.expert_groups=4"))
+    share = get_model(cfg.model).tensor_share()
+    assert share["attention"]["held"] == [2, 3]
+    assert share["attention_window"]["held"] == [3, 4, 5]
+    assert share["dense_ffn"] == {"units": 96, "held": [48, 96]}
+    assert share["shared_expert"] == {"units": 32, "held": [16, 32]}
