@@ -129,8 +129,9 @@ result),
 ``layerN/moe/{latent_in,latent_out,shared}``,
 ``layerN/mamba/{in_proj,conv,scan,gate_norm,out_proj}``, ``lm_head``.
 Counters beside the logits: ``moe_*`` (the mean over the layers that
-have experts), ``attn_window_block_share``, ``ssm_resets`` and
-``attn_gate_mean`` (the gate's mean over heads, tokens and gated layers).
+have experts), ``attn_window_block_share``, ``attn_window_grid_share``,
+``ssm_resets`` and ``attn_gate_mean`` (the gate's mean over heads, tokens
+and gated layers).
 """
 
 from __future__ import annotations
@@ -801,24 +802,49 @@ class Lfm2ForCausalLM(nn.Module):
             weight_scale=self.routed_scaling,
             out_init_std=self.out_proj_init_std)
 
+    def _window_dispatch(self, seq_len: int):
+        """The kernels and tiles the window layers' calls take on rows
+        of ``seq_len``; None for a model without such layers or without
+        the kernels."""
+        if ("sliding_attention" not in self.layer_types
+                or self.attention_impl != "pallas"):
+            return None
+        from distributed_tensorflow_framework_tpu.ops import flash_attention
+
+        return flash_attention.select_dispatch(
+            seq_len, seq_len, self.dtype,
+            self._attention_held("sliding_attention")[2])
+
     def window_block_share(self, seq_len: int) -> float | None:
         """Visited ÷ causal (q-block, k-block) visits of the window
         layers' kernels on rows of ``seq_len``, at the tiles the kernels
         take (``ops/flash_attention.window_block_counts``: a static
         count, segments aside); None for a model without such layers or
         without the kernels."""
-        if ("sliding_attention" not in self.layer_types
-                or self.attention_impl != "pallas"):
+        tile = self._window_dispatch(seq_len)
+        if tile is None:
             return None
         from distributed_tensorflow_framework_tpu.ops import flash_attention
 
-        tile = flash_attention.select_dispatch(
-            seq_len, seq_len, self.dtype,
-            self._attention_held("sliding_attention")[2])
         visited, causal = flash_attention.window_block_counts(
             seq_len, seq_len, tile.bwd_block_q, tile.bwd_block_k,
             self.sliding_window)
         return visited / causal
+
+    def window_grid_share(self, seq_len: int) -> float | None:
+        """Visited ÷ launched programs of the window layers' forward and
+        backward kernels on rows of ``seq_len``
+        (``ops/flash_attention.window_grid``: how much of the grid a
+        window call builds does work; static like
+        ``window_block_share``, and None where it is)."""
+        tile = self._window_dispatch(seq_len)
+        if tile is None:
+            return None
+        from distributed_tensorflow_framework_tpu.ops import flash_attention
+
+        grid = flash_attention.window_grid(seq_len, seq_len,
+                                           self.sliding_window, tile)
+        return grid["visited"] / grid["launched"]
 
     def expert_share(self) -> dict | None:
         """Which experts this process holds and of how many groups, for
@@ -917,6 +943,8 @@ class Lfm2ForCausalLM(nn.Module):
         share = self.window_block_share(input_ids.shape[1])
         if share is not None:
             counters["attn_window_block_share"] = jnp.float32(share)
+            counters["attn_window_grid_share"] = jnp.float32(
+                self.window_grid_share(input_ids.shape[1]))
         if gates:
             counters["attn_gate_mean"] = sum(gates) / len(gates)
         if "mamba2_only" in self.layer_types:

@@ -50,8 +50,16 @@ sharded sequences.
 the blocks wholly above the diagonal or, on packed rows, outside the
 document; ``window`` on top of it also skips the blocks wholly behind
 the window (``_block_needed``; the index maps name a needed block for a
-skipped visit, so the pipeline fetches nothing for it). Without either
-a call traces the plain kernels, equation for equation.
+skipped visit, so the pipeline fetches nothing for it). A skipped visit
+is still a program (~0.3 µs each, PERF.md §6, PR 37), so under
+``window`` the sequential axis of the streaming forward, dq and dk/dv
+kernels is only as long as the blocks a window can reach from one block
+of the parallel axis, and counts from the first of them (``_k_axis``,
+``_q_axis``): 5 and 10 programs where the row has 16 key and 32 row
+blocks, at 16,384 keys under a window of 4096 on 512 × 1024 tiles. The
+fused backward keeps every key block on its axis: its dk/dv scratch is
+indexed by the absolute block. Without ``causal`` or ``window`` a call
+traces the plain kernels, equation for equation.
 
 Every custom-VJP ``fwd`` rule names the two kernel outputs its ``bwd``
 reads (``jax.ad_checkpoint.checkpoint_name``): the output as
@@ -217,24 +225,31 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 
 def _guarded(compute, causal: bool, qi, ki, q_ref, k_ref, seg_refs,
-             window=None):
+             window=None, reached=None):
     """Run one (q-block, k-block) visit's ``compute``: always without
     ``causal`` (the trace is then the plain kernel's), under
-    ``_block_needed`` with it."""
+    ``_block_needed`` with it and, on a window call's short axis, only
+    where the block is one the axis' reach holds (``_visit``: a program
+    past it stands for no block of the row)."""
     if not causal:
         compute()
         return
     qs = ks = None
     if seg_refs:
         qs, ks = seg_refs[0][0, 0], seg_refs[1][0, 0]
-    pl.when(_block_needed(qi, ki, q_ref.shape[2], k_ref.shape[2],
-                          qs, ks, window))(compute)
+    needed = _block_needed(qi, ki, q_ref.shape[2], k_ref.shape[2],
+                           qs, ks, window)
+    if reached is not None:
+        needed = needed & reached
+    pl.when(needed)(compute)
 
 
 def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                         scale: float, segmented: bool, causal: bool = False,
-                        window=None):
-    """K-blocked forward: grid (B, H, nq, nk) with nk innermost/sequential.
+                        window=None, reach=None):
+    """K-blocked forward: grid (B, H, nq, nk) with nk innermost/sequential
+    (under ``window`` as long as the blocks a window reaches, ``reach``
+    saying which key block a program stands for: ``_k_axis``).
 
     Running-softmax state (m, l, acc) persists in VMEM scratch across the
     k-blocks of one q-block; K/V stream through in block_k tiles so no
@@ -246,10 +261,11 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         qseg_ref, kseg_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-    ki = pl.program_id(3)
+    kr = pl.program_id(3)
     qi = pl.program_id(2) if causal else None
+    ki, reached = _visit(reach, qi, kr)
 
-    @pl.when(ki == 0)
+    @pl.when(kr == 0)
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
@@ -281,9 +297,9 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         m_ref[...] = m_new
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else (), window)
+             (qseg_ref, kseg_ref) if segmented else (), window, reached)
 
-    @pl.when(ki == pl.num_programs(3) - 1)
+    @pl.when(kr == pl.num_programs(3) - 1)
     def _finalize():
         o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l_ref[...])
@@ -291,16 +307,18 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
 
 def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                            scale: float, segmented: bool,
-                           causal: bool = False, window=None):
-    """K-blocked dQ: accumulate ds·k over streamed K/V tiles in scratch."""
+                           causal: bool = False, window=None, reach=None):
+    """K-blocked dQ: accumulate ds·k over streamed K/V tiles in scratch
+    (the forward's grid and ``reach``)."""
     if segmented:
         qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
     else:
         do_ref, lse_ref, delta_ref, dq_ref, acc_ref = rest
-    ki = pl.program_id(3)
+    kr = pl.program_id(3)
     qi = pl.program_id(2) if causal else None
+    ki, reached = _visit(reach, qi, kr)
 
-    @pl.when(ki == 0)
+    @pl.when(kr == 0)
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
@@ -333,18 +351,20 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         ) * scale
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else (), window)
+             (qseg_ref, kseg_ref) if segmented else (), window, reached)
 
-    @pl.when(ki == pl.num_programs(3) - 1)
+    @pl.when(kr == pl.num_programs(3) - 1)
     def _finalize():
         dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
                             scale: float, segmented: bool,
-                            causal: bool = False, window=None):
+                            causal: bool = False, window=None, reach=None):
     """K-blocked dK/dV/dbias: grid (B, H, nk, nq) with the q-axis
-    innermost/sequential; Q/dO stream through in block_q tiles while the
+    innermost/sequential (under ``window`` as long as the row blocks a
+    key block reaches, ``reach`` saying which one a program stands for:
+    ``_q_axis``); Q/dO stream through in block_q tiles while the
     (dk, dv, dbias) accumulators for one k-block live in scratch."""
     if segmented:
         (qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
@@ -352,10 +372,11 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     else:
         (do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dbias_ref, dk_acc, dv_acc, db_acc) = rest
-    qi = pl.program_id(3)
+    qr = pl.program_id(3)
     ki = pl.program_id(2) if causal else None
+    qi, reached = _visit(reach, ki, qr)
 
-    @pl.when(qi == 0)
+    @pl.when(qr == 0)
     def _init():
         dk_acc[...] = jnp.zeros(dk_acc.shape, dk_acc.dtype)
         dv_acc[...] = jnp.zeros(dv_acc.shape, dv_acc.dtype)
@@ -395,9 +416,9 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         db_acc[...] = db_acc[...] + jnp.sum(ds, axis=0, keepdims=True)
 
     _guarded(_compute, causal, qi, ki, q_ref, k_ref,
-             (qseg_ref, kseg_ref) if segmented else (), window)
+             (qseg_ref, kseg_ref) if segmented else (), window, reached)
 
-    @pl.when(qi == pl.num_programs(3) - 1)
+    @pl.when(qr == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
@@ -640,14 +661,19 @@ def dispatch_log() -> list[dict]:
     head_dim, window) traced so far with the kernels and tiles it was
     given — the run-meta record's ``flash_dispatch`` (train/loop.py), so
     a run says which attention kernels its shapes selected without a
-    trace. ``window`` is None for a call without one."""
-    return [
-        dict(s=s, s_k=s_k, dtype=dtype, segmented=segmented, causal=causal,
-             heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-             window=window or None, **dispatch._asdict())
-        for (s, s_k, dtype, segmented, causal, heads, kv_heads, head_dim,
-             window), dispatch in sorted(_dispatch_log.items())
-    ]
+    trace. ``window`` is None for a call without one, and with it the
+    lengths of a window call's sequential axes, ``k_axis`` and
+    ``q_axis`` (``window_grid``)."""
+    entries = []
+    for (s, s_k, dtype, segmented, causal, heads, kv_heads, head_dim,
+         window), dispatch in sorted(_dispatch_log.items()):
+        grid = window_grid(s, s_k, window, dispatch) if window else {}
+        entries.append(dict(
+            s=s, s_k=s_k, dtype=dtype, segmented=segmented, causal=causal,
+            heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            window=window or None, k_axis=grid.get("k_axis"),
+            q_axis=grid.get("q_axis"), **dispatch._asdict()))
+    return entries
 
 
 # The names of the two forward-kernel outputs the backward kernels read,
@@ -883,10 +909,12 @@ def _kv_head_map(heads: int, kv_heads: int):
 
 
 def _last_k_block(causal: bool, block_q: int, block_k: int, window=None):
-    """``(qi, ki) -> k-block to fetch``: under ``causal`` a visit above
-    the diagonal names the last block the row block needs and, under
-    ``window``, a visit behind the window the first, so the pipeline
-    fetches nothing new for a visit the kernel skips."""
+    """``(qi, ki) -> k-block to fetch`` on a k-axis as long as the row's
+    key blocks: under ``causal`` a visit above the diagonal names the
+    last block the row block needs and, under ``window`` (the fused
+    backward, whose scratch is indexed by the absolute key block), a
+    visit behind the window the first, so the pipeline fetches nothing
+    new for a visit the kernel skips."""
     if not causal:
         return lambda qi, ki: ki
     if window is None:
@@ -897,20 +925,88 @@ def _last_k_block(causal: bool, block_q: int, block_k: int, window=None):
         (qi * block_q + (block_q - 1)) // block_k)
 
 
-def _first_q_block(causal: bool, block_q: int, block_k: int, window=None,
-                   n_q: int = 0):
-    """``(ki, qi) -> q-block to fetch`` for the dk/dv kernel, whose
-    q-axis is the sequential one: row blocks wholly before a key block
-    are skipped, so they name the first one needed; under ``window`` the
-    row blocks wholly past it name the last one needed (of ``n_q``)."""
+def _first_q_block(causal: bool, block_q: int, block_k: int):
+    """``(ki, qi) -> q-block to fetch`` for the dk/dv kernel on a q-axis
+    as long as the row's blocks: row blocks wholly before a key block
+    are skipped, so they name the first one needed."""
     if not causal:
         return lambda ki, qi: qi
+    return lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
+
+
+def _k_reach(qi, block_q: int, block_k: int, window: int, n_k: int):
+    """``(first, last)`` of the key blocks (of ``n_k``) that hold a pair
+    inside diagonal and ``window`` for row block ``qi``: every block
+    between them does. ``qi`` is a Python int (the static counts) or a
+    traced index (index maps and kernels)."""
+    lo, hi = ((max, min) if isinstance(qi, int)
+              else (jnp.maximum, jnp.minimum))
+    return (lo(qi * block_q - (window - 1), 0) // block_k,
+            hi((qi * block_q + (block_q - 1)) // block_k, n_k - 1))
+
+
+def _q_reach(ki, block_q: int, block_k: int, window: int, n_q: int):
+    """``(first, last)`` of the row blocks (of ``n_q``) that hold a pair
+    inside diagonal and ``window`` for key block ``ki`` (the dk/dv
+    kernel's sequential axis), as ``_k_reach``."""
+    hi = min if isinstance(ki, int) else jnp.minimum
+    return ((ki * block_k) // block_q,
+            hi((ki * block_k + (block_k - 1) + (window - 1)) // block_q,
+               n_q - 1))
+
+
+def _reach_axis(reach, n_parallel: int):
+    """A window call's sequential axis from ``reach`` (``_k_reach`` or
+    ``_q_reach`` with the call's tiles bound): ``(length, reach,
+    fetch)``. The axis is as long as the widest reach of the
+    ``n_parallel`` blocks beside it; program ``r`` beside block ``p``
+    stands for block ``first(p) + r`` (``_visit``) and ``fetch(p, r)``
+    names the block the pipeline reads for it: that one, or ``last(p)``
+    for a program past the reach, so nothing new is fetched for it."""
+    length = max(last - first + 1
+                 for first, last in map(reach, range(n_parallel)))
+
+    def fetch(p, r):
+        first, last = reach(p)
+        return jnp.minimum(first + r, last)
+
+    return length, reach, fetch
+
+
+def _k_axis(causal: bool, block_q: int, block_k: int, window,
+            n_q: int, n_k: int):
+    """The sequential k-axis of the streaming forward and dq kernels:
+    ``(length, reach, fetch)``. Without ``window`` every key block has a
+    program (``reach`` None: program ``r`` is block ``r``) and
+    ``_last_k_block`` says what it fetches; with it the axis holds only
+    the blocks a window can reach (``_reach_axis``)."""
     if window is None:
-        return lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
-    return lambda ki, qi: jnp.clip(
-        qi, (ki * block_k) // block_q,
-        jnp.minimum((ki * block_k + (block_k - 1) + (window - 1)) // block_q,
-                    n_q - 1))
+        return n_k, None, _last_k_block(causal, block_q, block_k)
+    return _reach_axis(
+        functools.partial(_k_reach, block_q=block_q, block_k=block_k,
+                          window=window, n_k=n_k), n_q)
+
+
+def _q_axis(causal: bool, block_q: int, block_k: int, window,
+            n_q: int, n_k: int):
+    """The sequential q-axis of the dk/dv kernel, as ``_k_axis``."""
+    if window is None:
+        return n_q, None, _first_q_block(causal, block_q, block_k)
+    return _reach_axis(
+        functools.partial(_q_reach, block_q=block_q, block_k=block_k,
+                          window=window, n_q=n_q), n_k)
+
+
+def _visit(reach, p, r):
+    """Inside a kernel: the block that program ``r`` of the sequential
+    axis stands for beside parallel block ``p``, and whether ``p``
+    reaches it. On a full-length axis (``reach`` None) that is ``r``
+    itself and nothing to test."""
+    if reach is None:
+        return r, None
+    first, last = reach(p)
+    block = first + r
+    return block, block <= last
 
 
 def window_block_counts(s: int, s_k: int, block_q: int, block_k: int,
@@ -926,6 +1022,33 @@ def window_block_counts(s: int, s_k: int, block_q: int, block_k: int,
                 causal += 1
                 visited += k0 + block_k - 1 > q0 - window
     return visited, causal
+
+
+def window_grid(s: int, s_k: int, window: int,
+                dispatch: FlashDispatch) -> dict:
+    """What a window call's kernels launch a head and row under
+    ``dispatch``, counted in Python like ``window_block_counts``:
+    ``k_axis``, the sequential axis of the dq kernel (and of a streaming
+    forward: the same tile), ``q_axis``, the dk/dv kernel's (None under
+    the fused backward, which has no such kernel and keeps every key
+    block on its axis), and over forward and backward together the
+    programs ``launched`` and those of them ``visited`` (holding a pair
+    inside the window): the window layers' ``attn_window_grid_share``."""
+    block_q, block_k = dispatch.bwd_block_q, dispatch.bwd_block_k
+    n_q, n_k = s // block_q, s_k // block_k
+    visited = window_block_counts(s, s_k, block_q, block_k, window)[0]
+    k_len = _k_axis(True, block_q, block_k, window, n_q, n_k)[0]
+    q_len = _q_axis(True, block_q, block_k, window, n_q, n_k)[0]
+    fused = dispatch.backward == "fused"
+    backward = ((visited, n_q * n_k) if fused
+                else (2 * visited, n_q * k_len + n_k * q_len))
+    forward = ((visited, n_q * k_len) if dispatch.family == "stream"
+               # whole-K: a program a row block, none skipped
+               else (s // dispatch.block_q,) * 2)
+    return dict(k_axis=n_k if fused else k_len,
+                q_axis=None if fused else q_len,
+                visited=forward[0] + backward[0],
+                launched=forward[1] + backward[1])
 
 
 def _sum_kv_groups(dk, dv, kv_heads: int, dtype):
@@ -986,9 +1109,10 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
     b, h, s, d = q.shape
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
-    grid = (b, h, s // block_q, s_k // block_k)
+    n_k, reach, k_blk = _k_axis(causal, block_q, block_k, window,
+                                s // block_q, s_k // block_k)
+    grid = (b, h, s // block_q, n_k)
     kv_head = _kv_head_map(h, k.shape[1])
-    k_blk = _last_k_block(causal, block_q, block_k, window)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1009,7 +1133,8 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
         operands += [qseg, kseg]
     return pl.pallas_call(
         functools.partial(_attn_fwd_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal, window=window),
+                          segmented=segmented, causal=causal, window=window,
+                          reach=reach),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
@@ -1072,8 +1197,10 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     kv_heads = k.shape[1]
     kv_head = _kv_head_map(h, kv_heads)
     dkv_dtype = k.dtype if h == kv_heads else jnp.float32
-    k_blk = _last_k_block(causal, block_q, block_k, window)
-    q_blk = _first_q_block(causal, block_q, block_k, window, s // block_q)
+    n_k, k_reach, k_blk = _k_axis(causal, block_q, block_k, window,
+                                  s // block_q, s_k // block_k)
+    n_q, q_reach, q_blk = _q_axis(causal, block_q, block_k, window,
+                                  s // block_q, s_k // block_k)
 
     seg_operands = [qseg, kseg] if segmented else []
     dq_seg_specs = [
@@ -1083,9 +1210,10 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     ] if segmented else []
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal, window=window),
+                          segmented=segmented, causal=causal, window=window,
+                          reach=k_reach),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        grid=(b, h, s // block_q, s_k // block_k),
+        grid=(b, h, s // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1118,13 +1246,14 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     ] if segmented else []
     dk, dv, dbias_h = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel_kb, scale=scale,
-                          segmented=segmented, causal=causal, window=window),
+                          segmented=segmented, causal=causal, window=window,
+                          reach=q_reach),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
-        grid=(b, h, s_k // block_k, s // block_q),
+        grid=(b, h, s_k // block_k, n_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),

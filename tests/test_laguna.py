@@ -832,6 +832,25 @@ def test_the_kernel_check_has_the_cells_window_call():
         16384, 16384, tile.bwd_block_q, tile.bwd_block_k, 512) == (47, 272)
 
 
+def test_the_grid_share_is_the_kernels_count(devices):
+    """ISSUE 37's number at the cell's shapes: a window of 512 on
+    512 x 1024 tiles launches 32*2 + 32*2 + 16*3 = 176 programs a head
+    and row over the forward, dq and dk/dv kernels, 141 of them visits;
+    absent without the kernels."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    cfg = load_config(YAML, CUT).model
+    model = get_model(cfg)
+    assert model.window_grid_share(16384) == pytest.approx(141 / 176)
+    grid = fa.window_grid(
+        16384, 16384, cfg.sliding_window,
+        fa.select_dispatch(16384, 16384, jnp.bfloat16, cfg.head_dim))
+    assert (grid["k_axis"], grid["q_axis"], grid["launched"]) == (2, 3, 176)
+    assert get_model(load_config(
+        YAML, [*CUT, "model.attention_impl=xla"]).model).window_grid_share(
+            16384) is None
+
+
 def test_the_cells_cut_is_436_million_parameters(devices):
     """The cut of benchmarks/configs/laguna_s_2_1.json: shapes only,
     nothing of this size is built."""
@@ -926,6 +945,21 @@ def test_the_trainer_step_gives_the_references_loss_and_grad_norm(devices):
     assert float(metrics["moe_dropped"]) == 0.0
     assert 0.3 < float(metrics["attn_gate_mean"]) < 0.7
     assert 0.0 < float(metrics["attn_window_block_share"]) <= 1.0
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    model = get_model(cfg.model)
+    assert float(metrics["attn_window_grid_share"]) == pytest.approx(
+        model.window_grid_share(S))
+    window_calls = [e for e in fa.dispatch_log()
+                    if e["s"] == S and e["window"] == cfg.model.sliding_window]
+    grid = fa.window_grid(S, S, cfg.model.sliding_window,
+                          fa.select_dispatch(S, S, jnp.float32,
+                                             cfg.model.head_dim))
+    assert window_calls and all(
+        (e["k_axis"], e["q_axis"]) == (grid["k_axis"], grid["q_axis"])
+        for e in window_calls)
+    assert all(e["k_axis"] is None and e["q_axis"] is None
+               for e in fa.dispatch_log() if e["window"] is None)
     assert float(metrics["moe_local_assignments"]) == pytest.approx(
         float(metrics["moe_local_share"]) * 2 * S * TOPK)
 
@@ -954,12 +988,14 @@ def test_scopes_name_the_parts_the_benchmark_reads(devices):
 # sha256 of the lowered train step (StableHLO text, no locations) of tiny
 # cuts of the three decoder configurations' shipped YAMLs
 # (tests/test_nemotron_h.py ``TINY``, by its ``lowered_step_digest``), read
-# on the parent of this PR (36dde26) before any file changed.
+# on the parent of this PR (36dde26) before any file changed;
+# ``smallthinker`` again at PR 37, for the one constant output it adds
+# (tests/test_nemotron_h.py ``PARENT_STEP``).
 PARENT_STEP = {
     "lfm2":
         "4877857c625d8295825fcda9302f4bd009d820a71c8844f72776f4d204c0757f",
     "smallthinker":
-        "19d076bc9a393ee704dda25d94c5d2737ec057d58ada8a4404ec2eb6801e5cad",
+        "0d60ff459318fff1d65e5ff40ce02e546e9028a3e9102c93b8fd9f9eca1d79a3",
     "nemotron":
         "92ffaac8050fde1ad85cd3ef7f541d43154edc2c2a1e670a6f94936d66a92e36",
 }

@@ -873,12 +873,14 @@ TINY = {
 # two tiny cuts above, by ``lowered_step_digest``: at PR 33, whose remat'd
 # layers keep the expert layer's routing and lower to another text than
 # PR 31's (dc9e774: 935ebde3... and 4a57729c...), which is what PR 32's
-# tree still lowered to.
+# tree still lowered to. ``smallthinker`` at PR 37: PR 36's text (19d076bc...)
+# but for one more output, the constant ``attn_window_grid_share`` (a diff
+# of the two texts: the signature, one ``stablehlo.constant``, the return).
 PARENT_STEP = {
     "lfm2":
         "4877857c625d8295825fcda9302f4bd009d820a71c8844f72776f4d204c0757f",
     "smallthinker":
-        "19d076bc9a393ee704dda25d94c5d2737ec057d58ada8a4404ec2eb6801e5cad",
+        "0d60ff459318fff1d65e5ff40ce02e546e9028a3e9102c93b8fd9f9eca1d79a3",
 }
 
 

@@ -479,11 +479,17 @@ def test_the_trainer_step_gives_the_references_loss_and_grad_norm(devices):
         want_norm)
     assert float(metrics["moe_dropped"]) == 0.0
     assert float(metrics["moe_compact"]) == 1.0
-    # one 128 x 128 tile holds the whole row: nothing to skip at this size
+    # one 128 x 128 tile holds the whole row: nothing to skip at this size,
+    # and every program of the one-block grid is a visit
     assert float(metrics["attn_window_block_share"]) == 1.0
-    logged = {(e["window"], e["head_dim"], e["heads"], e["kv_heads"])
+    assert float(metrics["attn_window_grid_share"]) == 1.0
+    logged = {(e["window"], e["head_dim"], e["heads"], e["kv_heads"],
+               e["k_axis"], e["q_axis"])
               for e in fa.dispatch_log() if e["s"] == S}
-    assert {(None, HEAD_DIM, 4, 2), (WINDOW, HEAD_DIM, 4, 2)} <= logged
+    # the fused backward at this length: one key block on its axis, no
+    # dk/dv kernel; a call without a window says neither
+    assert {(None, HEAD_DIM, 4, 2, None, None),
+            (WINDOW, HEAD_DIM, 4, 2, 1, None)} <= logged
 
 
 def test_the_block_share_is_the_kernels_count(devices):
@@ -496,6 +502,51 @@ def test_the_block_share_is_the_kernels_count(devices):
             16384) is None
     lfm2 = load_config(os.path.join(ROOT, "configs", "lfm2_8b_a1b.yaml"), [])
     assert get_model(lfm2.model).window_block_share(8192) is None
+
+
+def test_the_grid_share_is_the_kernels_count(devices):
+    """ISSUE 37's number at the cell's shapes: 420 of the 480 programs a
+    head and row that the forward, dq and dk/dv kernels launch hold a
+    pair (32*5 + 32*5 + 16*10 where the full-length grid had 3 * 512);
+    absent without window layers and without the kernels, and at the
+    whole-K forward's lengths a count of that kernel's grid."""
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    cfg = load_config(YAML, CUT).model
+    model = get_model(cfg)
+    assert model.window_grid_share(16384) == pytest.approx(420 / 480)
+    tile = fa.select_dispatch(16384, 16384, jnp.bfloat16, cfg.head_dim)
+    grid = fa.window_grid(16384, 16384, cfg.sliding_window, tile)
+    assert (grid["k_axis"], grid["q_axis"]) == (5, 10)
+    assert model.window_grid_share(16384) == grid["visited"] / grid["launched"]
+    short = fa.window_grid(
+        2048, 2048, cfg.sliding_window,
+        fa.select_dispatch(2048, 2048, jnp.bfloat16, cfg.head_dim))
+    assert model.window_grid_share(2048) == (
+        short["visited"] / short["launched"])
+    assert get_model(load_config(
+        YAML, [*CUT, "model.attention_impl=xla"]).model).window_grid_share(
+            16384) is None
+    lfm2 = load_config(os.path.join(ROOT, "configs", "lfm2_8b_a1b.yaml"), [])
+    assert get_model(lfm2.model).window_grid_share(8192) is None
+
+
+def test_only_a_model_with_window_kernels_reports_the_grid_share(devices):
+    """``attn_window_grid_share`` rides the model's outputs beside
+    ``attn_window_block_share`` where there are window layers and the
+    kernels, and is absent otherwise."""
+    ids = jnp.zeros((1, S), jnp.int32)
+    for over, there in ((dict(attention_impl="pallas"), True),
+                        (dict(attention_impl="xla"), False),
+                        (dict(attention_impl="pallas",
+                              layer_types=["full_attention"] * len(LAYERS)),
+                         False)):
+        model = get_model(model_config(**over))
+        out = jax.eval_shape(
+            lambda model=model: model.apply(
+                model.init(jax.random.key(0), ids), ids))
+        assert ("attn_window_grid_share" in out) == there, over
+        assert ("attn_window_block_share" in out) == there, over
 
 
 def test_scopes_name_the_two_kinds_of_attention_layer(devices):
