@@ -256,17 +256,19 @@ class ModelConfig:
     #                  tail — near-zero recompute flops for roughly half
     #                  the activation bytes. ResNet only.
     remat_policy: str = "full"
-    # Decoder family (models/lfm2.py; names "lfm2*", "smallthinker*" and
-    # "nemotron*").
+    # Decoder family (models/lfm2.py; names "lfm2*", "smallthinker*",
+    # "nemotron*", "laguna*" and "kanana*").
     # Knobs it shares with the BERT family keep their names: vocab_size,
     # hidden_size, num_layers, num_heads, mlp_dim (the dense SwiGLU
     # width), num_experts (the router's width), expert_topk,
     # attention_impl, remat.
     # One mixer kind per layer: "conv" (gated short convolution),
-    # "full_attention" (causal grouped-query attention) or
+    # "full_attention" (causal grouped-query attention),
     # "sliding_attention" (the same inside a window of sliding_window
-    # keys, over sliding_num_heads query heads where that is set), each
-    # followed by a feed-forward; or a layer of ONE sublayer,
+    # keys, over sliding_num_heads query heads where that is set) or
+    # "latent_attention" (multi-head latent attention, the mla_*
+    # settings), each followed by a feed-forward; or a layer of ONE
+    # sublayer,
     # x + sublayer(RMSNorm(x)): "mamba2_only" (the Mamba-2 mixer),
     # "attention_only" (causal grouped-query attention) or "experts_only"
     # (the expert feed-forward). Its length must be num_layers.
@@ -314,6 +316,10 @@ class ModelConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_attention_factor: float = 1.0
+    # Which dims rotate together: "half" (dim i with i + rotated / 2) or
+    # "interleaved" (dims 2i and 2i + 1; rope_interleave), pair i at
+    # frequency theta^(-2i / rotated) either way.
+    rope_pairs: str = "half"
     # A rule of their own for the "sliding_attention" layers: plain
     # frequencies at sliding_rope_theta over the first
     # sliding_rope_fraction of each head. 0 = they share the rule above.
@@ -386,6 +392,16 @@ class ModelConfig:
     # content on top of the stream at a random init, so a router behind a
     # norm spreads its tokens evenly (PERF.md section 6, PR 32).
     out_proj_init_std: float = 0.0
+    # A "latent_attention" layer (multi-head latent attention, no query
+    # latent): keys and values come out of a latent of mla_kv_rank dims
+    # under its own RMSNorm, each of num_heads query/key heads is
+    # mla_nope_dim dims without positions and mla_rope_dim rotated ones
+    # (one rotated key a token, shared by every head), each value head
+    # mla_v_dim dims. 0: unset, and a latent layer is refused.
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
 
 
 @config_dataclass

@@ -419,12 +419,15 @@ def decode_support_reason(model_config) -> str | None:
     (None = supported). The pure-jnp decode forward walks the dense BERT
     parameter tree by name; trees it does not know must be refused by
     name rather than failing as a KeyError mid-stream."""
+    from distributed_tensorflow_framework_tpu.models import _is_lfm2_name
+
     name = model_config.name.lower()
-    if name.startswith(("lfm2", "smallthinker", "nemotron", "laguna")):
+    if _is_lfm2_name(name):
         return (f"model {model_config.name!r} (the lfm2 decoder family) "
                 f"trains only: serving it needs a per-layer cache of "
                 f"several kinds (keys/values for its attention layers, a "
-                f"window of them for its sliding layers, the last "
+                f"window of them for its sliding layers, a latent and one "
+                f"rotated key a token for its latent attention layers, the last "
                 f"conv_kernel-1 gated inputs for its short convolutions, "
                 f"the recurrent state of its Mamba-2 layers) "
                 f"that serve/decode.py does not have")

@@ -1,5 +1,5 @@
 """The causal decoder family: a stack whose mixer is chosen per layer,
-over dropless experts. Four published models run through it, told apart
+over dropless experts. Five published models run through it, told apart
 by ``ModelConfig`` settings alone: LFM2-MoE (LiquidAI LFM2-8B-A1B,
 ``model_type: lfm2_moe``: gated short convolutions, grouped-query
 attention, bias-routed SwiGLU experts, a tied head; every default below
@@ -13,7 +13,10 @@ shared expert) and Laguna (poolside Laguna-S-2.1, ``model_type:
 laguna``: window layers with more query heads than the global layers
 and a rotary rule of their own, YaRN frequencies over half of a global
 layer's head, a per-head output gate, softmax-routed SwiGLU experts with
-scaled weights beside a gated shared expert).
+scaled weights beside a gated shared expert) and kanana (kakaocorp
+kanana-2-30b-a3b, ``model_type: deepseek_v3``: multi-head latent
+attention with interleaved rotary pairs in every layer, bias-routed
+SwiGLU experts with scaled weights beside an unscaled shared expert).
 
 Pre-norm blocks, ``h = x + mixer(RMSNorm(x))``, ``y = h + ffn(RMSNorm(h))``,
 one mixer kind per layer (``ModelConfig.layer_types``):
@@ -30,7 +33,9 @@ one mixer kind per layer (``ModelConfig.layer_types``):
                      the whole head at ``rope_theta``, or over the first
                      ``rope_fraction`` of it, with YaRN frequencies
                      under ``rope_yarn_factor`` and cos and sin times
-                     ``rope_attention_factor``); causal softmax
+                     ``rope_attention_factor``; dims ``i`` and ``i +
+                     rot / 2`` turn together, or ``2i`` and ``2i + 1``
+                     under ``rope_pairs: interleaved``); causal softmax
                      attention, each key/value head serving ``num_heads
                      / num_kv_heads`` query heads; with
                      ``attention_gate: per_head`` each head's result
@@ -43,6 +48,17 @@ one mixer kind per layer (``ModelConfig.layer_types``):
                      and by a plain rule of its own at
                      ``sliding_rope_theta`` over ``sliding_rope_fraction``
                      of the head where that is set.
+  latent_attention   multi-head latent attention without a query latent
+                     (``LatentAttention``): ``q = u W_q``, heads of
+                     ``mla_nope_dim + mla_rope_dim``; ``[c, k_r] = u
+                     W_kva``, a latent of ``mla_kv_rank`` and ONE key of
+                     ``mla_rope_dim`` a token; ``[k_n, v] = RMSNorm(c)
+                     W_kvb``; the rotary parts of q and of that key turn
+                     by the layer's rule, and it is every head's; causal
+                     softmax over keys of ``mla_nope_dim + mla_rope_dim``
+                     and values of ``mla_v_dim`` (the kernels take value
+                     heads of their own width); ``W_o``. No q/k norm, no
+                     gate.
 
 Or a layer is ONE sublayer, ``y = x + sublayer(RMSNorm(x))``
 (``SoloBlock``):
@@ -66,7 +82,8 @@ share, as ``expert_groups`` gives it its experts, taken wherever a layer
 has something to split: an attention layer of any kind holds its query
 heads ``/ tensor_groups`` (``num_heads``, or ``sliding_num_heads`` in a
 window layer) with the key/value heads they read and the gate's columns
-of those heads, a Mamba-2 layer ``mamba_num_heads / tensor_groups`` heads
+of those heads (a latent attention layer ``W_q``'s and ``W_kvb``'s
+columns of its heads and ``W_o``'s rows of their values), a Mamba-2 layer ``mamba_num_heads / tensor_groups`` heads
 with ``mamba_groups / tensor_groups`` B/C groups (its gated norm is over
 its own groups), a dense feed-forward ``mlp_dim / tensor_groups`` and a
 shared expert ``moe_shared_dim / tensor_groups`` of their hidden units
@@ -123,7 +140,9 @@ Scopes a trace can be read by (docs/OBSERVABILITY.md):
 (``full_attention``), ``layerN/attn_window/...`` (``sliding_attention``),
 inside either ``qk_norm_rope`` (norm, rotation and cast of q and k) and
 ``attn_gate`` (the gate's projection and its product with the kernel's
-result),
+result), ``layerN/mla/{q_proj,mla_latent,qk_norm_rope,o_proj}``
+(``latent_attention``; ``mla_latent`` the latent's projections and norm
+and the shared key's broadcast into every head),
 ``layerN/{mlp_in,mlp_up,mlp_out}``,
 ``layerN/moe/{router,dispatch,experts,combine}`` and, around them,
 ``layerN/moe/{latent_in,latent_out,shared}``,
@@ -149,13 +168,15 @@ from distributed_tensorflow_framework_tpu.models.moe import (
     projection)
 
 # A layer is a mixer and then a feed-forward ...
-LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention",
+               "latent_attention")
 # ... or ONE sublayer, ``x + sublayer(RMSNorm(x))``: a mixer alone or the
 # expert feed-forward alone.
 SOLO_KINDS = ("mamba2_only", "attention_only", "experts_only")
 # The attention module's name, and so its scope in a trace, by kind.
 ATTENTION_SCOPES = {"full_attention": "attn",
-                    "sliding_attention": "attn_window"}
+                    "sliding_attention": "attn_window",
+                    "latent_attention": "mla"}
 ROUTER_INPUTS = ("ffn_norm", "stream")
 # What every expert layer reports (DroplessMoE's counters), averaged over
 # the model's expert layers and named ``moe_<key>`` in the step's metrics.
@@ -344,18 +365,25 @@ class Mamba2Mixer(nn.Module):
         return projection(h, self.dtype, "out_proj", self.out_init_std)(y)
 
 
+ROTARY_PAIRS = ("half", "interleaved")
+
+
 class RotaryRule(NamedTuple):
     """What a layer's rotation does beyond the plain rule (half rotation
     over the whole head at the layer's theta): ``fraction`` of each
     head's dims, its first, rotate and the rest pass; with
     ``yarn_factor`` the frequencies are YaRN's (``yarn_inv_freq``);
-    ``attention_factor`` multiplies cos and sin."""
+    ``attention_factor`` multiplies cos and sin; ``pairs`` is which dims
+    turn together, ``half`` (dim ``i`` with ``i + rot / 2``) or
+    ``interleaved`` (dims ``2i`` and ``2i + 1``), pair ``i`` at frequency
+    ``i`` either way."""
     fraction: float = 1.0
     yarn_factor: float = 0.0
     yarn_original_len: int = 0
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     attention_factor: float = 1.0
+    pairs: str = "half"          # one of ROTARY_PAIRS
 
 
 def yarn_correction_range(dim: int, theta: float, original_len: int,
@@ -392,9 +420,9 @@ def yarn_inv_freq(dim: int, theta: float, rule: RotaryRule):
 
 
 def rotary(x, positions, theta: float, rule: RotaryRule | None = None):
-    """Half-rotation rotary embedding: ``x`` (B, S, N, D), ``positions``
-    (B, S); float32. Over the whole head at plain frequencies, or as
-    ``rule`` says."""
+    """Rotary embedding: ``x`` (B, S, N, D), ``positions`` (B, S);
+    float32. Half rotation over the whole head at plain frequencies, or
+    as ``rule`` says."""
     d = x.shape[-1]
     rule = rule or RotaryRule()
     rot = int(d * rule.fraction)
@@ -404,6 +432,8 @@ def rotary(x, positions, theta: float, rule: RotaryRule | None = None):
         inv_freq = 1.0 / (
             theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (B,S,R/2)
+    if rule.pairs == "interleaved":
+        return _rotate_interleaved(x, angles, rule.attention_factor, rot)
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[:, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[:, :, None, :]
     if rule.attention_factor != 1.0:
@@ -413,6 +443,20 @@ def rotary(x, positions, theta: float, rule: RotaryRule | None = None):
     x1, x2 = jnp.split(turned, 2, axis=-1)
     turned = turned * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
     if rot == d:
+        return turned
+    return jnp.concatenate([turned, x[..., rot:]], axis=-1)
+
+
+def _rotate_interleaved(x, angles, factor: float, rot: int):
+    """``rotary`` over pairs ``(2i, 2i + 1)`` of the first ``rot`` dims at
+    ``angles`` (B, S, rot / 2), cos and sin times ``factor``; float32."""
+    cos = jnp.cos(angles)[:, :, None, :] * factor
+    sin = jnp.sin(angles)[:, :, None, :] * factor
+    x = x.astype(jnp.float32)
+    even, odd = x[..., 0:rot:2], x[..., 1:rot:2]
+    turned = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                       axis=-1).reshape(*x.shape[:-1], rot)
+    if rot == x.shape[-1]:
         return turned
     return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
@@ -519,6 +563,72 @@ class GroupedQueryAttention(nn.Module):
         return out_proj(out.reshape(b, s, n * d)), gate_mean
 
 
+class LatentDims(NamedTuple):
+    """The widths of a ``latent_attention`` layer: the key/value latent,
+    each head's query/key dims without positions and with them (one
+    rotated key a token, shared by every head), and its value dims."""
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434; without
+    a query latent) over the heads it is given, ``u`` the normed input:
+    ``q = u W_q`` as heads of ``nope_dim + rope_dim``; ``[c, k_r] = u
+    W_kva``, a latent of ``kv_rank`` and ONE rotary key of ``rope_dim`` a
+    token; ``[k_n, v] = RMSNorm(c) W_kvb`` per head; ``q_h = [q_n ;
+    rot(q_r)]``, ``k_h = [k_n ; rot(k_r)]`` with the same ``rot(k_r)`` in
+    every head; ``o = softmax(q kᵀ / sqrt(nope_dim + rope_dim)) v``, heads
+    of ``v_dim``; ``o W_o``. No bias, no q/k norm."""
+
+    num_heads: int
+    dims: LatentDims
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "xla"
+    mesh: Any = None
+    out_init_std: float = 0.0    # of o_proj; 0: the fan-in rule
+    rope_rule: Any = None        # a RotaryRule; None: the plain rule
+
+    @nn.compact
+    def __call__(self, x, segment_ids, positions):
+        b, s, h = x.shape
+        n = self.num_heads
+        rank, d_n, d_r, d_v = self.dims
+        q = _dense(n * (d_n + d_r), self.dtype, "q_proj")(x).reshape(
+            b, s, n, d_n + d_r)
+        with jax.named_scope("mla_latent"):
+            c, k_r = jnp.split(_dense(rank + d_r, self.dtype, "kv_a_proj")(x),
+                               [rank], axis=-1)
+            c = RMSNorm(self.norm_eps, name="kv_a_norm")(c).astype(self.dtype)
+            k_n, v = jnp.split(
+                _dense(n * (d_n + d_v), self.dtype, "kv_b_proj")(c).reshape(
+                    b, s, n, d_n + d_v), [d_n], axis=-1)
+        with jax.named_scope("qk_norm_rope"):
+            q_n, q_r = jnp.split(q, [d_n], axis=-1)
+            q_r = rotary(q_r, positions, self.rope_theta, self.rope_rule)
+            q = jnp.concatenate([q_n, q_r.astype(self.dtype)], axis=-1)
+            k_r = rotary(k_r[:, :, None, :], positions, self.rope_theta,
+                         self.rope_rule).astype(self.dtype)
+        with jax.named_scope("mla_latent"):
+            k = jnp.concatenate(
+                [k_n, jnp.broadcast_to(k_r, (b, s, n, d_r))], axis=-1)
+        if self.attention_impl == "pallas":
+            from distributed_tensorflow_framework_tpu.ops.flash_attention import (
+                flash_attention,
+            )
+
+            out = flash_attention(q, k, v, segment_ids=segment_ids,
+                                  causal=True, mesh=self.mesh)
+        else:
+            out = causal_attention_xla(q, k, v, segment_ids, self.dtype)
+        return projection(h, self.dtype, "o_proj", self.out_init_std)(
+            out.reshape(b, s, n * d_v))
+
+
 class Lfm2Block(nn.Module):
     kind: str                    # one of LAYER_KINDS
     dense_ffn: bool
@@ -548,6 +658,7 @@ class Lfm2Block(nn.Module):
     moe_shared_dim: int = 0      # a shared expert's units held here
     routed_scaling: float = 1.0
     out_init_std: float = 0.0    # of attn_out, mlp_out, the shared down
+    latent: Any = None           # LatentDims of a latent_attention layer
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
@@ -556,6 +667,13 @@ class Lfm2Block(nn.Module):
         if self.kind == "conv":
             mixed = ShortConv(self.conv_kernel, self.dtype,
                               name="short_conv")(normed, segment_ids)
+        elif self.kind == "latent_attention":
+            mixed = LatentAttention(
+                self.num_heads, self.latent, self.rope_theta, self.norm_eps,
+                self.dtype, self.attention_impl, self.mesh,
+                out_init_std=self.out_init_std, rope_rule=self.rope_rule,
+                name=ATTENTION_SCOPES[self.kind],
+            )(normed, segment_ids, positions)
         else:
             sliding = self.kind == "sliding_attention"
             mixed = GroupedQueryAttention(
@@ -694,6 +812,7 @@ class Lfm2ForCausalLM(nn.Module):
     sliding_rope_theta: float = 0.0  # > 0: the window layers' own rule ...
     sliding_rope_rule: Any = None    # ... which is this one (None: plain)
     attention_gate: str = "none"
+    latent: Any = None           # LatentDims of the latent_attention layers
 
     def has_experts(self, i: int) -> bool:
         return layer_has_experts(self.layer_types, self.num_dense_layers, i)
@@ -919,6 +1038,8 @@ class Lfm2ForCausalLM(nn.Module):
                     moe_shared_dim=self.moe_shared_dim // self.tensor_groups,
                     routed_scaling=self.routed_scaling,
                     out_init_std=self.out_proj_init_std,
+                    latent=self.latent if kind == "latent_attention"
+                    else None,
                     name=f"layer{i}")
             x, counters = block(x, segment_ids, positions)
             if self.has_experts(i):
@@ -979,8 +1100,10 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
     if config.attention_gate not in ATTENTION_GATES:
         raise ValueError(f"model.attention_gate must be one of "
                          f"{ATTENTION_GATES}, got {config.attention_gate!r}")
+    latent = _latent_dims(config, kinds)
     rope_rule, sliding_rope_rule = _rotary_rules(
-        config, config.head_dim or config.hidden_size // heads)
+        config, latent.rope_dim if latent
+        else config.head_dim or config.hidden_size // heads)
     if "sliding_attention" in kinds and config.sliding_window < 1:
         raise ValueError(
             "a sliding_attention layer needs model.sliding_window >= 1 "
@@ -1043,7 +1166,29 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
         sliding_num_heads=config.sliding_num_heads,
         rope_rule=rope_rule, sliding_rope_theta=config.sliding_rope_theta,
         sliding_rope_rule=sliding_rope_rule,
-        attention_gate=config.attention_gate)
+        attention_gate=config.attention_gate, latent=latent)
+
+
+def _latent_dims(config, kinds) -> LatentDims | None:
+    """The latent_attention layers' widths, refused where one is unset or
+    where a setting asks what such a layer does not do; None for a stack
+    without them."""
+    if "latent_attention" not in kinds:
+        return None
+    dims = LatentDims(config.mla_kv_rank, config.mla_nope_dim,
+                      config.mla_rope_dim, config.mla_v_dim)
+    if min(dims) < 1 or dims.rope_dim % 2:
+        raise ValueError(
+            "a latent_attention layer needs model.mla_kv_rank, "
+            "model.mla_nope_dim, model.mla_v_dim and an even "
+            f"model.mla_rope_dim, each >= 1, got {dims}")
+    if (config.qk_norm or config.attention_gate != "none"
+            or 0 in config.rope_layout):
+        raise ValueError(
+            "a latent_attention layer has no q/k norm and no gate and "
+            "always rotates: set model.qk_norm=false, "
+            "model.attention_gate=none and no 0 in model.rope_layout")
+    return dims
 
 
 def _rotary_rules(config, head_dim: int) -> tuple:
@@ -1059,6 +1204,9 @@ def _rotary_rules(config, head_dim: int) -> tuple:
 
     rotated(config.rope_fraction, "rope_fraction")
     rotated(config.sliding_rope_fraction, "sliding_rope_fraction")
+    if config.rope_pairs not in ROTARY_PAIRS:
+        raise ValueError(f"model.rope_pairs must be one of {ROTARY_PAIRS}, "
+                         f"got {config.rope_pairs!r}")
     if config.rope_yarn_factor and not (
             config.rope_yarn_factor >= 1.0
             and config.rope_yarn_original_len > 0
@@ -1083,7 +1231,8 @@ def _rotary_rules(config, head_dim: int) -> tuple:
         if config.rope_yarn_factor else {}
     rule = RotaryRule(
         fraction=float(config.rope_fraction),
-        attention_factor=float(config.rope_attention_factor), **yarn)
+        attention_factor=float(config.rope_attention_factor),
+        pairs=config.rope_pairs, **yarn)
     sliding = RotaryRule(fraction=float(config.sliding_rope_fraction))
     plain = RotaryRule()
     return (None if rule == plain else rule,

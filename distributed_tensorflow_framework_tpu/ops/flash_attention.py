@@ -9,7 +9,10 @@ direction. This is the flash-attention recompute pattern (PAPERS.md);
 XLA alone tiles but still round-trips the score tensor for the unfused
 einsum+softmax+einsum chain.
 
-Shapes: q, k, v are (B, S, H, D). Five kernels:
+Shapes: q and k are (B, S, H, D), v is (B, S, H, D_v): the value heads
+may be narrower or wider than the query/key heads (multi-head latent
+attention's 192 over 128), and o, dO and dV take D_v where q, k, dQ and
+dK take D; the softmax scale stays 1/√D. Five kernels:
 
   whole-K forward   a program holds a block of rows plus the FULL
                     opposing sequence in VMEM: no running-softmax state,
@@ -197,7 +200,7 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
     # at the 2x bf16 MXU rate. Only the p/ds downcasts below round.
     q = q_ref[0, 0]                               # (BQ, D)
     k = k_ref[0, 0]                               # (S, D)
-    v = v_ref[0, 0]                               # (S, D)
+    v = v_ref[0, 0]                               # (S, D_v)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -218,7 +221,7 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
     o = jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) / l                                         # (BQ, D)
+    ) / l                                         # (BQ, D_v)
     o_ref[0, 0] = o.astype(o_ref.dtype)
     # Per-row logsumexp: the only softmax statistic the backward needs.
     lse_ref[0, 0] = (m + jnp.log(l)).astype(jnp.float32)
@@ -274,7 +277,7 @@ def _attn_fwd_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     def _compute():
         q = q_ref[0, 0]                               # (BQ, D) input dtype
         k = k_ref[0, 0]                               # (BK, D)
-        v = v_ref[0, 0]                               # (BK, D)
+        v = v_ref[0, 0]                               # (BK, D_v)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -325,8 +328,8 @@ def _attn_bwd_dq_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     def _compute():
         q = q_ref[0, 0]                               # (BQ, D) input dtype
         k = k_ref[0, 0]                               # (BK, D)
-        v = v_ref[0, 0]                               # (BK, D)
-        do = do_ref[0, 0]                             # (BQ, D)
+        v = v_ref[0, 0]                               # (BK, D_v)
+        do = do_ref[0, 0]                             # (BQ, D_v)
         lse = lse_ref[0, 0]                           # (BQ, 1)
         delta = delta_ref[0, 0]                       # (BQ, 1)
         s = jax.lax.dot_general(
@@ -385,8 +388,8 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     def _compute():
         q = q_ref[0, 0]                               # (BQ, D) input dtype
         k = k_ref[0, 0]                               # (BK, D)
-        v = v_ref[0, 0]                               # (BK, D)
-        do = do_ref[0, 0]                             # (BQ, D)
+        v = v_ref[0, 0]                               # (BK, D_v)
+        do = do_ref[0, 0]                             # (BQ, D_v)
         lse = lse_ref[0, 0]                           # (BQ, 1)
         delta = delta_ref[0, 0]                       # (BQ, 1)
         s = jax.lax.dot_general(
@@ -403,7 +406,7 @@ def _attn_bwd_dkv_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                             # (BK, D)
+        )                                             # (BK, D_v)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -480,8 +483,8 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
     def _compute():
         q = q_ref[0, 0]                               # (BQ, D) input dtype
         k = k_ref[0, 0]                               # (BK, D)
-        v = v_ref[0, 0]                               # (BK, D)
-        do = do_ref[0, 0]                             # (BQ, D)
+        v = v_ref[0, 0]                               # (BK, D_v)
+        do = do_ref[0, 0]                             # (BQ, D_v)
         lse = lse_ref[0, 0]                           # (BQ, 1)
         delta = delta_ref[0, 0]                       # (BQ, 1)
         s = jax.lax.dot_general(
@@ -509,7 +512,7 @@ def _attn_bwd_fused_kernel_kb(q_ref, k_ref, v_ref, bias_ref, *rest,
         dv_full[sl, :] = dv_full[sl, :] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                             # (BK, D)
+        )                                             # (BK, D_v)
         dk_full[sl, :] = dk_full[sl, :] + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -606,10 +609,12 @@ class FlashDispatch(NamedTuple):
 
 
 def select_dispatch(s: int, s_k: int, dtype,
-                    head_dim: int = FUSED_BWD_HEAD_DIM) -> FlashDispatch:
+                    head_dim: int = FUSED_BWD_HEAD_DIM,
+                    v_head_dim: int | None = None) -> FlashDispatch:
     """The one place a (q length, k length, input dtype, head size)
     becomes kernels and tiles; the platform enters through
-    ``fused_bwd_enabled()``.
+    ``fused_bwd_enabled()``. ``v_head_dim`` is the value heads' width
+    where it is not ``head_dim``'s.
     Called at the custom_vjp layer, outside the jitted wrappers, so the
     module globals it reads are never frozen into a trace cache: the
     wrappers take the result as a static argument.
@@ -630,47 +635,50 @@ def select_dispatch(s: int, s_k: int, dtype,
     # BLOCK_Q Mosaic refuses the kernel ("cannot statically prove that
     # index in dimension 1 is a multiple of 128"), and the two-pass pair
     # is what runs such inputs. What has to fit is the full-length dk/dv
-    # scratch, keys × head dims × 4 B × 2, beside tiles that grow with
-    # the head size and the input's bytes alike: the gate holds keys ×
-    # head dims × itemsize to what FUSED_BWD_MAX keys of
-    # FUSED_BWD_HEAD_DIM dims and 2 bytes come to.
+    # scratch, keys × (key dims + value dims) × 4 B, beside tiles that
+    # grow with the head sizes and the input's bytes alike: the gate
+    # holds keys × both widths × itemsize to what FUSED_BWD_MAX keys of
+    # FUSED_BWD_HEAD_DIM dims each and 2 bytes come to.
+    widths = head_dim + (head_dim if v_head_dim is None else v_head_dim)
     fused = (fused_bwd_enabled()
-             and s_k * head_dim * jnp.dtype(dtype).itemsize
-             <= FUSED_BWD_MAX * FUSED_BWD_HEAD_DIM * 2
+             and s_k * widths * jnp.dtype(dtype).itemsize
+             <= FUSED_BWD_MAX * FUSED_BWD_HEAD_DIM * 2 * 2
              and stream_tile[1] % BLOCK_Q == 0)
     return FlashDispatch(*forward, "fused" if fused else "two_pass",
                          *stream_tile)
 
 
-# (s, s_k, dtype name, segmented) -> FlashDispatch, one entry per
-# distinct call traced in this process: what dispatch_log() reports.
+# (s, s_k, dtype name, segmented, causal, heads, kv_heads, head_dim,
+# v_head_dim, window) -> FlashDispatch, one entry per distinct call
+# traced in this process: what dispatch_log() reports.
 _dispatch_log: dict = {}
 
 
-def _dispatch(q, k, segmented: bool, causal: bool = False,
+def _dispatch(q, k, v, segmented: bool, causal: bool = False,
               window=None) -> FlashDispatch:
-    s, s_k, d = q.shape[2], k.shape[2], q.shape[3]
-    dispatch = select_dispatch(s, s_k, q.dtype, d)
+    s, s_k, d, d_v = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    dispatch = select_dispatch(s, s_k, q.dtype, d, d_v)
     _dispatch_log[(s, s_k, jnp.dtype(q.dtype).name, segmented, causal,
-                   q.shape[1], k.shape[1], d, window or 0)] = dispatch
+                   q.shape[1], k.shape[1], d, d_v, window or 0)] = dispatch
     return dispatch
 
 
 def dispatch_log() -> list[dict]:
     """Every distinct (s, s_k, dtype, segmented, causal, heads, kv_heads,
-    head_dim, window) traced so far with the kernels and tiles it was
-    given — the run-meta record's ``flash_dispatch`` (train/loop.py), so
-    a run says which attention kernels its shapes selected without a
-    trace. ``window`` is None for a call without one, and with it the
-    lengths of a window call's sequential axes, ``k_axis`` and
-    ``q_axis`` (``window_grid``)."""
+    head_dim, v_head_dim, window) traced so far with the kernels and
+    tiles it was given — the run-meta record's ``flash_dispatch``
+    (train/loop.py), so a run says which attention kernels its shapes
+    selected without a trace. ``window`` is None for a call without one,
+    and with it the lengths of a window call's sequential axes,
+    ``k_axis`` and ``q_axis`` (``window_grid``)."""
     entries = []
     for (s, s_k, dtype, segmented, causal, heads, kv_heads, head_dim,
-         window), dispatch in sorted(_dispatch_log.items()):
+         v_head_dim, window), dispatch in sorted(_dispatch_log.items()):
         grid = window_grid(s, s_k, window, dispatch) if window else {}
         entries.append(dict(
             s=s, s_k=s_k, dtype=dtype, segmented=segmented, causal=causal,
             heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            v_head_dim=v_head_dim,
             window=window or None, k_axis=grid.get("k_axis"),
             q_axis=grid.get("q_axis"), **dispatch._asdict()))
     return entries
@@ -722,7 +730,7 @@ def _make_fused(segmented: bool, return_lse: bool,
         def fused(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True, causal,
+                                dispatch=_dispatch(q, k, v, True, causal,
                                                    window),
                                 causal=causal, **win)
             return (o, lse) if return_lse else o
@@ -730,7 +738,7 @@ def _make_fused(segmented: bool, return_lse: bool,
         def fwd(q, k, v, bias, qseg, kseg):
             o, lse = _flash_fwd(q, k, v, bias, qseg, kseg,
                                 segmented=True, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, True, causal,
+                                dispatch=_dispatch(q, k, v, True, causal,
                                                    window),
                                 causal=causal, **win)
             o, lse = _name_residuals(o, lse)
@@ -743,7 +751,7 @@ def _make_fused(segmented: bool, return_lse: bool,
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, qseg, kseg, o, lse, do, dlse=dlse,
                 segmented=True, interpret=_interpret(),
-                dispatch=_dispatch(q, k, True, causal, window),
+                dispatch=_dispatch(q, k, v, True, causal, window),
                 causal=causal, **win)
             return (dq, dk, dv, dbias,
                     jnp.zeros_like(qseg), jnp.zeros_like(kseg))
@@ -752,7 +760,7 @@ def _make_fused(segmented: bool, return_lse: bool,
         def fused(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False, causal,
+                                dispatch=_dispatch(q, k, v, False, causal,
                                                    window),
                                 causal=causal, **win)
             return (o, lse) if return_lse else o
@@ -760,7 +768,7 @@ def _make_fused(segmented: bool, return_lse: bool,
         def fwd(q, k, v, bias):
             o, lse = _flash_fwd(q, k, v, bias,
                                 segmented=False, interpret=_interpret(),
-                                dispatch=_dispatch(q, k, False, causal,
+                                dispatch=_dispatch(q, k, v, False, causal,
                                                    window),
                                 causal=causal, **win)
             o, lse = _name_residuals(o, lse)
@@ -773,7 +781,7 @@ def _make_fused(segmented: bool, return_lse: bool,
             dq, dk, dv, dbias = _flash_bwd(
                 q, k, v, bias, o, lse, do, dlse=dlse,
                 segmented=False, interpret=_interpret(),
-                dispatch=_dispatch(q, k, False, causal, window),
+                dispatch=_dispatch(q, k, v, False, causal, window),
                 causal=causal, **win)
             return dq, dk, dv, dbias
 
@@ -853,6 +861,7 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
                interpret: bool, dispatch: FlashDispatch,
                causal: bool = False, window=None):
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_head = _kv_head_map(h, k.shape[1])
     s_k = k.shape[2]
     scale = 1.0 / (d ** 0.5)
@@ -867,7 +876,7 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, s_k, d),
                      lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
-        pl.BlockSpec((1, 1, s_k, d),
+        pl.BlockSpec((1, 1, s_k, d_v),
                      lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
         pl.BlockSpec((1, 1, s_k), lambda bi, hi, qi: (bi, 0, 0)),
     ]
@@ -882,13 +891,14 @@ def _flash_fwd(q, k, v, bias, qseg=None, kseg=None, *, segmented: bool,
         functools.partial(_attn_fwd_kernel, scale=scale, segmented=segmented,
                           causal=causal, window=window),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d_v),
+                         lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
         ],
         interpret=interpret,
@@ -1054,11 +1064,11 @@ def window_grid(s: int, s_k: int, window: int,
 def _sum_kv_groups(dk, dv, kv_heads: int, dtype):
     """Per-query-head dk/dv partials (float32 when heads share a
     key/value head) summed over each group."""
-    b, h, s_k, d = dk.shape
+    b, h, s_k = dk.shape[:3]
     if h == kv_heads:
         return dk, dv
-    fold = lambda t: t.reshape(b, kv_heads, h // kv_heads, s_k, d).sum(  # noqa: E731
-        axis=2).astype(dtype)
+    fold = lambda t: t.reshape(b, kv_heads, h // kv_heads, s_k,  # noqa: E731
+                               t.shape[-1]).sum(axis=2).astype(dtype)
     return fold(dk), fold(dv)
 
 
@@ -1107,7 +1117,7 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
     """Streaming forward: sequential k-axis grid + VMEM-scratch running
     softmax (kernel docstring)."""
     b, h, s, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     scale = 1.0 / (d ** 0.5)
     n_k, reach, k_blk = _k_axis(causal, block_q, block_k, window,
                                 s // block_q, s_k // block_k)
@@ -1118,7 +1128,7 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, block_k, d),
                      lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
-        pl.BlockSpec((1, 1, block_k, d),
+        pl.BlockSpec((1, 1, block_k, d_v),
                      lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
         pl.BlockSpec((1, 1, block_k),
                      lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
@@ -1136,19 +1146,19 @@ def _flash_fwd_kb(q, k, v, bias, qseg, kseg, *, segmented: bool,
                           segmented=segmented, causal=causal, window=window,
                           reach=reach),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         scratch_shapes=_vmem_scratch(
-            ((block_q, d), jnp.float32),
+            ((block_q, d_v), jnp.float32),
             ((block_q, 1), jnp.float32),
             ((block_q, 1), jnp.float32),
         ),
@@ -1192,7 +1202,7 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     k-axis, dK/dV/dbias over a sequential q-axis; no whole-sequence
     operand in VMEM (kernel docstrings)."""
     b, h, s, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     scale = 1.0 / (d ** 0.5)
     kv_heads = k.shape[1]
     kv_head = _kv_head_map(h, kv_heads)
@@ -1219,12 +1229,12 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, qi, ki: (bi, kv_head(hi), k_blk(qi, ki), 0)),
             pl.BlockSpec((1, 1, block_k),
                      lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
         ] + dq_seg_specs + [
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1250,7 +1260,7 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                           reach=q_reach),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d_v), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
         grid=(b, h, s_k // block_k, n_q),
@@ -1259,11 +1269,11 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                          lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, ki, qi: (bi, kv_head(hi), ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, ki, qi: (bi, kv_head(hi), ki, 0)),
             pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki, qi: (bi, 0, ki)),
         ] + dkv_seg_specs + [
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, d_v),
                          lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, ki, qi: (bi, hi, q_blk(ki, qi), 0)),
@@ -1273,14 +1283,14 @@ def _flash_bwd_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, 1, block_k),
                          lambda bi, hi, ki, qi: (bi, hi, 0, ki)),
         ],
         scratch_shapes=_vmem_scratch(
             ((block_k, d), jnp.float32),
-            ((block_k, d), jnp.float32),
+            ((block_k, d_v), jnp.float32),
             ((1, block_k), jnp.float32),
         ),
         interpret=interpret,
@@ -1298,7 +1308,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
     per (q-block, k-block) pair, full-length dk/dv VMEM accumulators —
     gated to what fits by ``select_dispatch``."""
     b, h, s, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     scale = 1.0 / (d ** 0.5)
     kv_heads = k.shape[1]
     kv_head = _kv_head_map(h, kv_heads)
@@ -1317,7 +1327,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
-            jax.ShapeDtypeStruct((b, h, s_k, d), dkv_dtype),
+            jax.ShapeDtypeStruct((b, h, s_k, d_v), dkv_dtype),
             jax.ShapeDtypeStruct((b, h, 1, s_k), jnp.float32),
         ],
         grid=(b, h, s // block_q, s_k // block_k),
@@ -1327,13 +1337,13 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, qi, ki: (bi, kv_head(hi),
                                                  k_blk(qi, ki), 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, qi, ki: (bi, kv_head(hi),
                                                  k_blk(qi, ki), 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bi, hi, qi, ki: (bi, 0, k_blk(qi, ki))),
         ] + seg_specs + [
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -1345,7 +1355,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, 1, block_k),
                          lambda bi, hi, qi, ki: (bi, hi, 0, ki)),
@@ -1353,7 +1363,7 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
         scratch_shapes=_vmem_scratch(
             ((block_q, d), jnp.float32),
             ((s_k, d), jnp.float32),
-            ((s_k, d), jnp.float32),
+            ((s_k, d_v), jnp.float32),
             ((1, s_k), jnp.float32),
         ),
         interpret=interpret,
@@ -1366,7 +1376,8 @@ def _flash_bwd_fused_kb(q, k, v, bias, qseg, kseg, lse, do, delta, *,
 
 def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
                     causal: bool = False, window: int | None = None):
-    """Fused attention. q: (B, S, H, D); k, v: (B, S, H_kv, D) with H_kv a
+    """Fused attention. q: (B, S, H, D); k: (B, S, H_kv, D); v: (B, S,
+    H_kv, D_v), D_v any width (D where the heads are alike), with H_kv a
     divisor of H (grouped-query attention: each key/value head serves
     H/H_kv query heads, reached through the kernels' block index maps); mask: (B,1,1,S) bool or None;
     segment_ids: (B, S) int packed-sequence ids or None — tokens attend
@@ -1393,7 +1404,7 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, mesh=None,
     as is. Interpret mode (CPU tests) takes the same wrap, so the CPU
     mesh compiles the structure the chips run.
 
-    Returns (B, S, H, D) in q's dtype. Differentiable end to end with
+    Returns (B, S, H, D_v) in q's dtype. Differentiable end to end with
     Pallas forward AND backward kernels (module docstring).
     """
     if (mesh is not None and mesh.size > 1

@@ -56,6 +56,14 @@ unsegmented:
                                               one key/value head:
                                               ``laguna_s_s16384``'s window layers'
                                               call
+  latent_d192_v128_s2048   2048  bf16  as chosen  ``causal=True`` with value heads
+  latent_d192_v128_s16384  16384 bf16  as chosen  narrower than the query/key heads:
+                                              16 heads of 192 over values of 128,
+                                              a key/value head a query head; the
+                                              fused backward at 2048, the
+                                              streaming kernels and the two-pass
+                                              pair at 16,384, ``kanana2_s16384``'s
+                                              latent attention call
 
 Every case is held to a float32 ``jax.numpy`` reference computed one
 head at a time (so it fits at any length), and the fused backward is
@@ -173,10 +181,23 @@ def _window_cases() -> dict:
     }
 
 
-def _inputs(seq: int, kv_heads: int, dtype, heads: int = H, d: int = D):
+# The latent attention cases' (heads, query/key dims, value dims).
+LATENT_HEADS, LATENT_D, LATENT_D_V = 16, 192, 128
+
+
+def _latent_cases() -> dict:
+    """As ``_cases``, causal over value heads of their own width."""
+    vmem, bf16 = fa.MAX_SEQ_VMEM, jnp.bfloat16
+    return {"latent_d192_v128_s2048": (max(vmem // 2, fa.BLOCK_Q), None, bf16),
+            "latent_d192_v128_s16384": (4 * vmem, None, bf16)}
+
+
+def _inputs(seq: int, kv_heads: int, dtype, heads: int = H, d: int = D,
+            d_v: int | None = None):
     kq, kk, kv = jax.random.split(jax.random.key(seq), 3)
-    q, k, v = (jax.random.normal(r, (B, seq, n, d), dtype)
-               for r, n in ((kq, heads), (kk, kv_heads), (kv, kv_heads)))
+    q, k, v = (jax.random.normal(r, (B, seq, n, w), dtype)
+               for r, n, w in ((kq, heads, d), (kk, kv_heads, d),
+                               (kv, kv_heads, d_v or d)))
     # Four packed documents of unequal length per row.
     cuts = np.array([0.15, 0.4, 0.8]) * seq
     seg = np.searchsorted(cuts, np.arange(seq), side="right") + 1
@@ -217,13 +238,13 @@ def _reference_fn(segmented: bool, causal: bool = False, window=None):
         group = h // k.shape[2]
         k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
-                      .reshape(b * h, s, d) for t in (q, k, v))
+                      .reshape(b * h, s, t.shape[-1]) for t in (q, k, v))
         segf = jnp.repeat(seg, h, axis=0)         # (B*H, S)
         # Under jax.checkpoint: the backward keeps no (S, S) block of a
         # head but the one it works on (14 of them are 15 GB at 16,384).
         out = jax.lax.map(jax.checkpoint(one_head), (qf, kf, vf, segf))
         # Output in the kernels' dtype, so the loss sees the same values.
-        out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        out = out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
         return _loss_and_out(out.astype(q.dtype))
     return loss
 
@@ -244,23 +265,29 @@ def _rel_l2(a, b) -> float:
 
 
 def run_case(name: str, seq: int, setting: bool | None, dtype,
-             two_pass_cache: dict, causal: bool = False, window=None) -> dict:
-    if window is None:
+             two_pass_cache: dict, causal: bool = False, window=None,
+             latent: bool = False) -> dict:
+    if latent:
+        args = _inputs(seq, LATENT_HEADS, dtype, LATENT_HEADS, LATENT_D,
+                       LATENT_D_V)
+    elif window is None:
         args = _inputs(seq, H // KV_GROUP if causal else H, dtype)
     else:
         heads, kv_heads = WINDOW_CASE_HEADS.get(
             name, (WINDOW_HEADS, WINDOW_KV_HEADS))
         args = _inputs(seq, kv_heads, dtype, heads, WINDOW_D)
-    head_dim = int(args[0].shape[-1])
+    head_dim, v_head_dim = int(args[0].shape[-1]), int(args[2].shape[-1])
     _allow_fused(setting)
-    dispatch = fa.select_dispatch(seq, seq, dtype, head_dim)
+    dispatch = fa.select_dispatch(seq, seq, dtype, head_dim, v_head_dim)
     fused = dispatch.backward == "fused"
     dtype_name = jnp.dtype(dtype).name
     rec = {"case": name, "seq": seq, "dtype": dtype_name,
            "fused_bwd": fused, "causal": causal, "window": window,
-           "head_dim": head_dim, "kv_heads": int(args[1].shape[2]),
+           "head_dim": head_dim, "v_head_dim": v_head_dim,
+           "kv_heads": int(args[1].shape[2]),
            "dispatch": dispatch._asdict(), "variants": {}}
-    key = (seq, dtype_name, causal, window, head_dim, int(args[0].shape[2]))
+    key = (seq, dtype_name, causal, window, head_dim, v_head_dim,
+           int(args[0].shape[2]))
     ok = True
     for segmented in (False, True):
         _allow_fused(setting)
@@ -301,7 +328,8 @@ def run_case(name: str, seq: int, setting: bool | None, dtype,
 
 def main(argv) -> int:
     causal_cases, window_cases = _causal_cases(), _window_cases()
-    cases = {**_cases(), **causal_cases, **window_cases}
+    latent_cases = _latent_cases()
+    cases = {**_cases(), **causal_cases, **window_cases, **latent_cases}
     unknown = [a for a in argv if a not in cases]
     if unknown:
         print(f"unknown case(s) {unknown}; known: {sorted(cases)}",
@@ -330,8 +358,10 @@ def main(argv) -> int:
             finally:
                 fa.FUSED_BWD_MAX = module_max
         else:
-            results.append(run_case(name, *cases[name], two_pass_cache,
-                                    causal=name in causal_cases))
+            results.append(run_case(
+                name, *cases[name], two_pass_cache,
+                causal=name in causal_cases or name in latent_cases,
+                latent=name in latent_cases))
     ok = all(r["ok"] for r in results)
     if not ok:
         print("FLASH KERNEL MISMATCH — do not trust these kernels on this "

@@ -217,3 +217,55 @@ def test_a_share_of_heads_by_kind_and_of_both_unit_runs_is_legal():
     assert share["attention_window"]["held"] == [3, 4, 5]
     assert share["dense_ffn"] == {"units": 96, "held": [48, 96]}
     assert share["shared_expert"] == {"units": 32, "held": [16, 32]}
+
+
+# --- decoder family: latent attention and interleaved rotary pairs ---------
+def _kanana_yaml():
+    import os
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "configs", "kanana_2_30b_a3b.yaml")
+
+
+def _kanana_overrides(*more):
+    return ["model.num_layers=2",
+            "model.layer_types=[latent_attention,latent_attention]",
+            "model.hidden_size=64", "model.num_heads=4",
+            "model.num_kv_heads=4", "model.mla_kv_rank=32",
+            "model.mla_nope_dim=16", "model.mla_rope_dim=8",
+            "model.mla_v_dim=16", "model.mlp_dim=96", "model.moe_mlp_dim=24",
+            "model.moe_shared_dim=32", "model.num_experts=16",
+            "model.expert_topk=3", "model.vocab_size=256",
+            "data.vocab_size=256", "data.seq_len=128", *more]
+
+
+def test_latent_attention_settings_parse_from_overrides():
+    from distributed_tensorflow_framework_tpu.models import get_model
+
+    m = load_config(_kanana_yaml(), _kanana_overrides(
+        "model.tensor_groups=2", "model.tensor_group=1")).model
+    assert (m.mla_kv_rank, m.mla_nope_dim, m.mla_rope_dim, m.mla_v_dim,
+            m.rope_pairs) == (32, 16, 8, 16, "interleaved")
+    model = get_model(m)
+    assert model.latent == (32, 16, 8, 16)
+    assert model.rope_rule.pairs == "interleaved"
+    assert model.tensor_share()["attention"]["held"] == [2, 3]
+    # every other configuration keeps the half rule and no latent
+    plain = load_config().model
+    assert (plain.rope_pairs, plain.mla_kv_rank, plain.mla_v_dim) == (
+        "half", 0, 0)
+
+
+@pytest.mark.parametrize("bad,says", [
+    (["model.mla_kv_rank=0"], "mla_kv_rank"),
+    (["model.mla_v_dim=0"], "mla_v_dim"),
+    (["model.mla_rope_dim=5"], "mla_rope_dim"),
+    (["model.qk_norm=true"], "no q/k norm"),
+    (["model.rope_layout=[1,0]"], "always rotates"),
+    (["model.rope_pairs=adjacent"], "rope_pairs"),
+])
+def test_a_latent_layer_without_its_settings_is_refused(bad, says):
+    from distributed_tensorflow_framework_tpu.models import get_model
+
+    cfg = load_config(_kanana_yaml(), _kanana_overrides(*bad))
+    with pytest.raises(ValueError, match=says):
+        get_model(cfg.model)
