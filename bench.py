@@ -48,11 +48,9 @@ import sys
 
 TARGET_PER_CHIP = 10_000 / 64  # BASELINE.json north star on v5e-64
 
-# Chip peaks and the roofline math moved to core/roofline.py so the
-# autotuner's analytic pruner (tools/autotune) and this bench judge
-# candidates against the SAME ridge. Re-exported here because the bench
-# is the historical home of these names (tests + PERF_NOTES refer to
-# bench.CHIP_PEAKS et al.).
+# Chip peaks and the roofline math live in core/roofline.py. Re-exported
+# here because the bench is the historical home of these names (tests +
+# PERF_NOTES refer to bench.CHIP_PEAKS et al.).
 from distributed_tensorflow_framework_tpu.core.roofline import (  # noqa: E402,F401
     CHIP_PEAKS,
     GIB,
@@ -64,10 +62,10 @@ from distributed_tensorflow_framework_tpu.core.roofline import (  # noqa: E402,F
 def _emit_json_line(payload: dict) -> None:
     """The ONE driver-contract JSON line: always stdout, and additionally
     written (whole-file, not append) to the BENCH_OUT=<path> file when
-    set. Supervisors (tools/autotune, run_tier1.sh) read the file instead
-    of regexing the tail out of warning-polluted stdout. Failure lines land in
-    the file too: an empty/missing BENCH_OUT after exit means the process
-    died before producing a verdict, which is itself a classification."""
+    set. A supervisor reads the file instead of regexing the tail out of
+    warning-polluted stdout. Failure lines land in the file too: an
+    empty/missing BENCH_OUT after exit means the process died before
+    producing a verdict, which is itself a classification."""
     line = json.dumps(payload)
     print(line)
     out_path = os.environ.get("BENCH_OUT", "").strip()
@@ -77,49 +75,6 @@ def _emit_json_line(payload: dict) -> None:
                 fh.write(line + "\n")
         except OSError as e:
             print(f"bench: BENCH_OUT write failed ({e})", file=sys.stderr)
-
-
-def _check_leaderboard(out: dict, workload: str) -> None:
-    """Regression pin against configs/leaderboard.json (dtf-leaderboard/1,
-    written by scripts/autotune.py). When the board has an entry for this
-    workload, annotate the result row with the pinned incumbent: its
-    config digest (re-verified — a board whose digest doesn't match its
-    own config dict has been hand-edited and can't be trusted as a pin),
-    the score ratio, and a regression flag when this run undershoots the
-    incumbent by more than the pinned margin. Annotation only — the exit
-    code stays the driver's; the flag is for the queue/tuner to read."""
-    board_path = os.environ.get("BENCH_LEADERBOARD", "").strip() or \
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "configs", "leaderboard.json")
-    try:
-        with open(board_path) as fh:
-            board = json.load(fh)
-    except (OSError, ValueError):
-        return
-    entry = (board.get("entries") or {}).get(workload)
-    if not isinstance(entry, dict) or not out.get("value"):
-        return
-    from tools.autotune.leaderboard import config_digest
-
-    digest = entry.get("config_digest")
-    digest_ok = (digest == config_digest(entry.get("config") or {}))
-    score = float(entry.get("score") or 0.0)
-    margin = float(entry.get("regression_margin") or 0.05)
-    note = {"incumbent_score": score, "config_digest": digest,
-            "digest_ok": digest_ok}
-    if score > 0:
-        ratio = float(out["value"]) / score
-        note["vs_incumbent"] = round(ratio, 4)
-        note["regression"] = bool(ratio < 1.0 - margin)
-        if note["regression"]:
-            print(f"bench: REGRESSION vs leaderboard incumbent for "
-                  f"{workload}: {out['value']} vs pinned {score} "
-                  f"(margin {margin})", file=sys.stderr)
-    if not digest_ok:
-        print(f"bench: leaderboard digest mismatch for {workload} — "
-              f"the pin was edited outside scripts/autotune.py",
-              file=sys.stderr)
-    out["leaderboard"] = note
 
 
 def _compile_and_time(builder, state, batch, steps: int, warmup: int) -> dict:
@@ -824,7 +779,7 @@ _PRECISION_MODES = {
 
 def _run_precision_ab(writer, mode: str, n_chips: int, chip: str) -> int:
     """BENCH_PRECISION=f32|bf16|bf16_fused|bf16_int8 — the precision
-    ladder A/B (ISSUE 13 / chip_window_queue.sh §13).
+    ladder A/B.
 
     Runs the ResNet-50 workload TWICE on the same batch ladder under
     ``train.spmd_mode=shard_map`` + ZeRO weight-update sharding (the
@@ -1053,7 +1008,6 @@ def _run(writer) -> int:
         _annotate_roofline(out, result, chip, n_chips,
                            accum_scaled=accum > 1)
         _annotate_memory(out, result, chip, n_chips)
-        _check_leaderboard(out, workload)
         _emit_bench_result(writer, workload, out, result)
         _emit_json_line(out)
         return 0
@@ -1081,7 +1035,6 @@ def _run(writer) -> int:
         }
         _annotate_roofline(out, result, chip, n_chips)
         _annotate_memory(out, result, chip, n_chips)
-        _check_leaderboard(out, workload)
         _emit_bench_result(writer, workload, out, result)
         _emit_json_line(out)
         return 0
@@ -1112,7 +1065,6 @@ def _run(writer) -> int:
     }
     _annotate_roofline(out, result, chip, n_chips)
     _annotate_memory(out, result, chip, n_chips)
-    _check_leaderboard(out, workload)
     _emit_bench_result(writer, workload, out, result)
     _emit_json_line(out)
     return 0

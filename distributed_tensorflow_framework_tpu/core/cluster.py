@@ -203,8 +203,7 @@ def probe_gang(
     """Can this host run a REAL ``procs``-process ``jax.distributed``
     gang with a cross-process computation?  Returns ``(ok, detail)``.
 
-    The gang drills (tests/test_cluster_drill.py) and the two-host-sim
-    bench arm (scripts/chip_window_queue.sh §15) gate on this: stub-level
+    The gang drills (tests/test_cluster_drill.py) gate on this: stub-level
     supervisor behavior is tier-1-tested without JAX, but end-to-end
     drills need a backend whose compiler accepts multi-process programs,
     which stock CPU jaxlib does not (see GANG_UNSUPPORTED_SIGNS).

@@ -918,30 +918,6 @@ class TraceConfig:
 
 
 @config_dataclass
-class AutotuneConfig:
-    """Goodput-driven autotuner (scripts/autotune.py, tools/autotune,
-    docs/PERFORMANCE.md "Autotuning")."""
-
-    # Roofline pruning tolerance: a candidate whose PREDICTED rate is
-    # more than this fraction below the incumbent's on the binding
-    # resource is skipped without spending a run (the prediction is
-    # logged + journaled either way). 0 disables the tolerance (any
-    # predicted loss prunes); keep it wide enough to absorb model error.
-    prune_margin: float = 0.05
-    # Cap on RUN (not pruned/resumed) trials per window; 0 = unbounded.
-    max_trials: int = 0
-    # Trial journal path (dtf-autotune-journal/1 JSONL). "" =
-    # <out_dir>/autotune_journal.jsonl. The journal is the resume
-    # contract: settled trials never re-run after a killed window.
-    journal_path: str = ""
-    # Where best_<workload>.yaml + leaderboard.json land.
-    out_dir: str = "configs"
-    # Regression tolerance written into the leaderboard entry: bench.py
-    # flags a headline run this fraction below the pinned incumbent.
-    regression_margin: float = 0.05
-
-
-@config_dataclass
 class ExperimentConfig:
     name: str = "experiment"
     mesh: MeshConfig = field(default_factory=MeshConfig)
@@ -958,7 +934,6 @@ class ExperimentConfig:
     serve: ServeConfig = field(default_factory=ServeConfig)
     decode: DecodeConfig = field(default_factory=DecodeConfig)
     trace: TraceConfig = field(default_factory=TraceConfig)
-    autotune: AutotuneConfig = field(default_factory=AutotuneConfig)
 
     def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
@@ -1295,21 +1270,4 @@ def load_config(
                 f"poison the loss metric with NaN; widen the model head or "
                 f"fix {role}.num_classes"
             )
-    tune = cfg.autotune
-    if not (0.0 <= tune.prune_margin < 1.0):
-        raise ValueError(
-            f"autotune.prune_margin must be in [0, 1), got "
-            f"{tune.prune_margin} — it is the fraction of predicted loss "
-            f"the pruner tolerates before skipping a candidate"
-        )
-    if tune.max_trials < 0:
-        raise ValueError(
-            f"autotune.max_trials must be >= 0 (0 = unbounded), got "
-            f"{tune.max_trials}"
-        )
-    if not (0.0 <= tune.regression_margin < 1.0):
-        raise ValueError(
-            f"autotune.regression_margin must be in [0, 1), got "
-            f"{tune.regression_margin}"
-        )
     return cfg

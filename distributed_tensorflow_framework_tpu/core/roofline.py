@@ -1,10 +1,8 @@
 """Roofline model: chip peaks, ridge points, and the step-time predictor.
 
 Factored out of bench.py so the bound verdict and the analytic step-time
-lower bound are one implementation shared by the bench (annotating
-measured rows) and the autotuner (pruning candidate configs BEFORE
-spending a chip run — tools/autotune). Stdlib-only: nothing here touches
-jax, so the CPU-side tuner harness can import it without a backend.
+lower bound are one implementation. Stdlib-only: nothing here touches
+jax, so it imports without a backend.
 
 The model is the classic two-resource roofline. A step that must move
 ``B`` bytes through HBM and execute ``F`` flops cannot finish faster
@@ -17,8 +15,7 @@ ResNet-50 step at 78.7 FLOP/byte, firmly HBM-bound).
 Traffic inputs come from the artifacts the repo already measures
 (docs/PERFORMANCE.md "The bench as the measurement instrument"):
 the compiled step's ``memory_analysis`` footprint (argument + output +
-temp bytes), the CollectiveTally's wire bytes, and
-``opt_state_bytes_per_chip`` — see :func:`traffic_bytes`.
+temp bytes) and the CollectiveTally's wire bytes.
 """
 
 from __future__ import annotations
@@ -48,25 +45,6 @@ def chip_hbm_capacity(chip: str) -> float | None:
     return peak[2] if peak else None
 
 
-def traffic_bytes(memory_analysis: dict | None, wire_bytes: float = 0.0,
-                  opt_state_bytes: float = 0.0) -> float:
-    """HBM + interconnect bytes/step from the measured artifacts.
-
-    ``memory_analysis`` is the compiled step's cost breakdown
-    (core/memstats.compiled_memory_analysis): argument + output + temp
-    bytes is the compiled footprint one execution streams. ``wire_bytes``
-    is the CollectiveTally grand total for the step. ``opt_state_bytes``
-    (bench's opt_state_bytes_per_chip) covers callers whose footprint was
-    taken on a forward/backward program only — a compiled WHOLE step
-    already carries the optimizer state in its argument bytes, so pass 0
-    there or the state is counted twice.
-    """
-    analysis = memory_analysis or {}
-    footprint = sum(int(analysis.get(f) or 0) for f in
-                    ("argument_bytes", "output_bytes", "temp_bytes"))
-    return float(footprint) + float(wire_bytes) + float(opt_state_bytes)
-
-
 @dataclasses.dataclass
 class RooflinePrediction:
     """Analytic step-time lower bound and the binding-resource verdict.
@@ -91,8 +69,7 @@ def predict(chip: str, flops_per_step: float, bytes_per_step: float,
             n_chips: int = 1) -> RooflinePrediction:
     """Predict the per-step time floor for a program on ``chip``.
 
-    Inputs are WHOLE-program flops and bytes (use :func:`traffic_bytes`
-    to assemble bytes from footprint + wire + opt state); the work is
+    Inputs are WHOLE-program flops and bytes; the work is
     assumed evenly divided across ``n_chips``. A chip that is not in
     CHIP_PEAKS raises: there is no roofline to predict against.
     """
@@ -122,7 +99,7 @@ def annotate_roofline(out: dict, result: dict, chip: str, n_chips: int,
                       *, accum_scaled: bool = False) -> None:
     """Achieved TFLOP/s, MFU, arithmetic intensity and the bottleneck
     verdict from the XLA cost model + public chip peaks (the bench row
-    annotator, moved here from bench.py so the tuner's predictor and the
+    annotator, moved here from bench.py so the predictor and the
     bench's measured verdict share one ridge). A device that is not in
     CHIP_PEAKS gets the program's own numbers (TFLOP/s, intensities) and
     no ``mfu`` / ``bound`` / ``hbm_bw_util``: those are statements about
@@ -157,8 +134,10 @@ def annotate_roofline(out: dict, result: dict, chip: str, n_chips: int,
         out["arith_intensity"] = round(intensity, 1)
     wire = (result.get("collectives") or {}).get("total_bytes") or 0
     ai = None
-    footprint_plus_wire = traffic_bytes(
-        (result.get("memory") or {}).get("analysis"), wire)
+    analysis = (result.get("memory") or {}).get("analysis") or {}
+    footprint_plus_wire = float(wire) + sum(
+        int(analysis.get(f) or 0)
+        for f in ("argument_bytes", "output_bytes", "temp_bytes"))
     if footprint_plus_wire > wire:  # a footprint was actually present
         ai = result["flops_per_step"] / footprint_plus_wire
         out["ai_flops_per_byte"] = round(ai, 1)

@@ -186,14 +186,6 @@ KIND_KV_CACHE = "kv_cache"
 KIND_DATA_SHARD = "data_shard"
 KIND_DATA_PACKING = "data_packing"
 KIND_DATA_STATE = "data_state"
-# Goodput-driven autotuner (scripts/autotune.py, tools/autotune,
-# docs/PERFORMANCE.md "Autotuning"): one event per trial decision.
-# ``extra.status`` is started|done|skipped|failed, keyed by
-# ``extra.trial`` (the candidate's config digest in space mode,
-# §section:label in plan mode), carrying the roofline prediction for
-# pruned candidates and the goodput-weighted score for completed ones —
-# the telemetry mirror of the dtf-autotune-journal/1 trial journal.
-KIND_AUTOTUNE_TRIAL = "autotune_trial"
 
 
 def make_run_id() -> str:
@@ -535,11 +527,6 @@ def summarize_events(path: str) -> dict:
         "count": 0, "traces": set(), "services": {}, "names": {},
         "errors": 0, "dur_ms_total": 0.0,
     }
-    # KIND_AUTOTUNE_TRIAL ledger: trial decisions by status plus the
-    # best goodput-weighted score the window produced.
-    autotune = {
-        "events": 0, "ran": 0, "pruned": 0, "failed": 0, "best": None,
-    }
     for ev in read_events(path, strict=False):
         kind = ev["kind"]
         kinds[kind] = kinds.get(kind, 0) + 1
@@ -773,23 +760,6 @@ def summarize_events(path: str) -> dict:
                 "to_processes": plan.get("to_processes"),
                 "watermark": plan.get("watermark"),
             })
-        elif kind == KIND_AUTOTUNE_TRIAL:
-            autotune["events"] += 1
-            status = str(extra.get("status", ""))
-            if status == "done":
-                autotune["ran"] += 1
-                score = extra.get("score")
-                if isinstance(score, (int, float)) and (
-                        autotune["best"] is None
-                        or score > autotune["best"]["score"]):
-                    autotune["best"] = {
-                        "trial": extra.get("trial"), "score": score,
-                        "unit": extra.get("unit"),
-                    }
-            elif status == "skipped":
-                autotune["pruned"] += 1
-            elif status == "failed":
-                autotune["failed"] += 1
         elif kind == KIND_GOODPUT:
             m = ev.get("metrics") or {}
             snap = {
@@ -922,7 +892,6 @@ def summarize_events(path: str) -> dict:
                             or fleet["reloads"] or fleet["tenants"]
                             or fleet["scaling"]["events"]) else None),
         "goodput": goodput,
-        "autotune": (autotune if autotune["events"] else None),
         "data": ({"shard": data_shard, "packing": data_packing}
                  if (data_shard or data_packing) else None),
         "memory": (memory if memory["samples"] else None),
@@ -1229,18 +1198,6 @@ def format_run_summary(summary: dict) -> str:
             + (f" [{svcs}]" if svcs else "")
             + (f", {spans['errors']} error(s)" if spans.get("errors") else "")
         )
-    at = summary.get("autotune")
-    if at:  # KIND_AUTOTUNE_TRIAL rollup (the autotuner's trial ledger)
-        lines.append(
-            f"  autotune: {at['ran']} ran / {at['pruned']} pruned / "
-            f"{at['failed']} failed"
-        )
-        best = at.get("best")
-        if best:
-            lines.append(
-                f"    best: {best.get('trial')} score {best.get('score')}"
-                + (f" {best['unit']}" if best.get("unit") else "")
-            )
     mem = summary.get("memory")
     if mem:  # KIND_MEMORY rollup
         srcs = ", ".join(

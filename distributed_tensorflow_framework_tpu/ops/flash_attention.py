@@ -108,7 +108,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import NamedTuple
 
 import jax
@@ -128,11 +127,8 @@ BLOCK_Q = 128
 # matmuls: at S=512 55k programs a step at 10 ps a pair forward and 30
 # backward where 512 rows take 4.7 and 9.4 (PERF.md §6, PR 25), and
 # 512×1024 is the tile every attention cell's ledger line was taken on.
-# The four FLASH_* variables here and below exist for trial runs
-# (scripts/bench_flash_tiles.py, the autotune plan); the shipped
-# selection is taken with all of them unset.
-BLOCK_Q_KB = int(os.environ.get("FLASH_BLOCK_Q_KB", "512"))
-BLOCK_K_KB = int(os.environ.get("FLASH_BLOCK_K_KB", "1024"))
+BLOCK_Q_KB = 512
+BLOCK_K_KB = 1024
 # Where the whole-K forward ends (no silent fallback above it):
 #   s_k ≤ MAX_SEQ_VMEM → each program holds the full opposing sequence
 #     in VMEM at INPUT dtype (the kernels dot in input dtype, no f32
@@ -144,8 +140,7 @@ BLOCK_K_KB = int(os.environ.get("FLASH_BLOCK_K_KB", "1024"))
 #     BLOCK_K_KB) regardless of sequence length. No fallback to the
 #     O(S²)-materializing XLA chain exists above the threshold — long
 #     chunks stay fused (tests/test_attention.py pins 8192).
-# FLASH_MAX_SEQ_VMEM=0 forces the streaming forward everywhere.
-MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
+MAX_SEQ_VMEM = 4096
 # Fused one-pass backward: one kernel over grid (B,H,nq,nk) produces dq
 # AND dk/dv/dbias, computing each (q-block, k-block) probability block
 # ONCE — the two-pass pair forms QKᵀ, the mask and the exp in both of
@@ -160,7 +155,7 @@ MAX_SEQ_VMEM = int(os.environ.get("FLASH_MAX_SEQ_VMEM", "4096"))
 # v5e to 6144 keys and overflows at 7168, bf16 compiles at 8192 (PERF.md
 # §6). Past it the fused kernel runs under a limit sized from its
 # footprint (``fused_bwd_vmem_limit``; PERF.md §6).
-FUSED_BWD_MAX = int(os.environ.get("FLASH_FUSED_BWD_MAX", "8192"))
+FUSED_BWD_MAX = 8192
 # The head size FUSED_BWD_MAX is stated for: 8192 keys of 64 dims are
 # 8 MiB of lane-padded dk/dv scratch, and 128-wide heads take the same
 # bytes at that length (under the default they compile for v5e at 8192

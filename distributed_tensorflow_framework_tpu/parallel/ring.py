@@ -71,8 +71,7 @@ def _chunk_attention(q, k, v, bias, q_seg=None, kv_seg=None):
     c = q.shape[1]
     # Flash kernels take any supported chunk at/above the crossover AND
     # any chunk above the VMEM threshold (the latter matters when
-    # MAX_SEQ_VMEM is tuned below FLASH_CHUNK_MIN, e.g. the
-    # FLASH_MAX_SEQ_VMEM=0 force-streaming knob — without it those
+    # MAX_SEQ_VMEM is set below FLASH_CHUNK_MIN — without it those
     # chunks would fall through to the misleading raise below).
     if (c >= FLASH_CHUNK_MIN or c > _fa.MAX_SEQ_VMEM) and chunk_supported(c):
         o, lse = flash_attention_chunk(q, k, v, bias, q_seg, kv_seg)

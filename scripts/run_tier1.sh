@@ -40,13 +40,9 @@ echo "=== tier-1 pytest (log → $ART/pytest.log) ==="
 # (tests/test_data_drill.py) archives its per-attempt telemetry —
 # supervisor events plus the worker event streams whose data_state /
 # data_shard records prove the multiset claim.
-# DTF_AUTOTUNE_DIR: the autotune smoke drill (tests/test_autotune.py)
-# archives its fake-runner search journal + leaderboard — the
-# dtf-autotune-journal/1 resume record and the dtf-leaderboard/1 pin.
 timeout -k 10 870 env JAX_PLATFORMS=cpu DTF_SERVE_BENCH_DIR="$ART" \
     DTF_GANG_DRILL_DIR="$ART" DTF_TRACE_DIR="$ART" \
     DTF_DECODE_BENCH_DIR="$ART" DTF_DATA_DRILL_DIR="$ART" \
-    DTF_AUTOTUNE_DIR="$ART" \
     python -m pytest tests/ -q \
     -m "$MARKERS" --continue-on-collection-errors \
     -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 \
@@ -83,12 +79,6 @@ done
 # telemetry that backs its consumed-sample multiset comparison.
 for ev in "$ART"/DATA_DRILL_*.jsonl; do
   [ -f "$ev" ] && echo "=== data drill events archived: $ev ==="
-done
-# The autotune smoke drill (tests/test_autotune.py) archives its search
-# journal and winner pin so a tier-1 run leaves a worked example of the
-# journal/leaderboard contracts next to the pytest log.
-for art in "$ART"/AUTOTUNE_*.json "$ART"/AUTOTUNE_*.jsonl; do
-  [ -f "$art" ] && echo "=== autotune artifact archived: $art ==="
 done
 
 echo "=== tier-1 summary: graftcheck rc=$gc_rc pytest rc=$py_rc ==="

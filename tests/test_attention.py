@@ -632,6 +632,37 @@ def test_selection_off_the_chip_is_the_verified_chips(monkeypatch, shape):
     assert fa.select_dispatch(*shape) == here
 
 
+def test_the_environment_does_not_change_the_selection():
+    """The tiles and thresholds are the module's constants: a process
+    started with the former FLASH_* tuning variables set selects what
+    this one does at every shape of the table."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
+
+    code = (
+        "import json, sys\n"
+        "from distributed_tensorflow_framework_tpu.ops import "
+        "flash_attention as fa\n"
+        "shapes = json.loads(sys.argv[1])\n"
+        "print(json.dumps([list(fa.select_dispatch(*s)) for s in shapes]))\n")
+    # Each variable was named FLASH_ and the constant it set.
+    env = dict(os.environ, **{
+        f"FLASH_{name}": value for name, value in (
+            ("BLOCK_Q_KB", "128"), ("BLOCK_K_KB", "128"),
+            ("MAX_SEQ_VMEM", "0"), ("FUSED_BWD_MAX", "0"))})
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code, json.dumps(_TABLE_SHAPES)], env=env,
+        cwd=root, capture_output=True, text=True, timeout=300, check=True)
+    child = json.loads(out.stdout.strip().splitlines()[-1])
+    here = [list(fa.select_dispatch(*s)) for s in _TABLE_SHAPES]
+    assert child == here
+
+
 def test_no_backward_holds_the_whole_opposing_sequence(monkeypatch):
     """Every backward streams: whatever the lengths, the dtype and the
     platform's answer, its tile is within the streaming targets."""
@@ -875,11 +906,9 @@ def test_kernel_mode_off_the_chip_is_interpret(devices):
 
 def test_kernel_check_matrix_follows_the_module_thresholds():
     """scripts/verify_flash_kernels.py (the smoke's kernel leg) reaches
-    every regime at the shipped thresholds, and the autotune plan's
-    verify trials name cases it has."""
+    every regime at the shipped thresholds."""
     from distributed_tensorflow_framework_tpu.ops import flash_attention as fa
     from scripts import verify_flash_kernels as vfk
-    from tools.autotune.plan import compile_chip_window_plan
 
     bf16, f32 = jnp.bfloat16, jnp.float32
     cases = vfk._cases()
@@ -903,7 +932,3 @@ def test_kernel_check_matrix_follows_the_module_thresholds():
         "causal_gqa_s512": (512, None, bf16),
         "causal_gqa_s8192": (8192, None, bf16)}
     assert 12 % vfk.KV_GROUP == 0
-    named = {a for t in compile_chip_window_plan()
-             if "scripts/verify_flash_kernels.py" in t.argv
-             for a in t.argv[2:]}
-    assert named and named <= set(cases)

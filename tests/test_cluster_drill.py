@@ -22,8 +22,7 @@ drill's serve bench.
 Both drills gate on ``cluster.probe_gang()``: stock CPU jaxlib forms the
 gang but rejects multi-process computations at compile time ("Multiprocess
 computations aren't implemented on the CPU backend"), so on such hosts the
-drills SKIP with the probe's evidence instead of failing — the same
-preflight contract as chip_window_queue.sh §0b/§15.  The supervisor's
+drills SKIP with the probe's evidence instead of failing.  The supervisor's
 decision logic itself is covered without JAX in tests/test_cluster.py.
 """
 

@@ -62,6 +62,17 @@ def test_unknown_key_rejected(tmp_path):
         load_config(p)
 
 
+def test_an_autotune_section_is_an_unknown_key(tmp_path):
+    """No config section chooses kernels or configurations by search: a
+    YAML that still carries one is refused, not silently ignored."""
+    p = tmp_path / "c.yaml"
+    p.write_text("autotune: {prune_margin: 0.05, max_trials: 4}\n")
+    with pytest.raises(ValueError,
+                       match=r"Unknown key\(s\) \['autotune'\] for "
+                             r"ExperimentConfig"):
+        load_config(p)
+
+
 def test_print_config_roundtrips(capsys):
     """--print-config dumps the resolved config as YAML and exits before
     any device/Trainer work; the dump must parse and carry overrides."""

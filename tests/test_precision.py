@@ -1,7 +1,7 @@
 """The precision policy layer + fused donated optimizer update (ISSUE 13).
 
-Four contracts, CPU-verifiable (the throughput claims live on the §13
-chip ladder, scripts/chip_window_queue.sh):
+Four contracts, CPU-verifiable (the throughput claims live on the
+chip ladder, bench.py's BENCH_PRECISION):
 
   * bf16 policy parity — ``precision.activation_dtype=bf16`` over an f32
     model config tracks the f32 run's loss within a pinned tolerance for

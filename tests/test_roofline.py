@@ -1,4 +1,4 @@
-"""core/roofline.py — the shared roofline predictor (bench + autotuner).
+"""core/roofline.py — the shared roofline predictor used by the bench.
 
 The fixture is PERF_NOTES.md round 2, the last good chip measurement
 (BENCH_r02): ResNet-50 on one TPU v5 lite at 6.26 TFLOP/step, measured
@@ -37,18 +37,6 @@ class TestChipHbmCapacity:
 
     def test_unknown_device_has_no_hbm(self):
         assert roofline.chip_hbm_capacity("cpu") is None
-
-
-class TestTrafficBytes:
-    def test_footprint_plus_wire_plus_opt(self):
-        analysis = {"argument_bytes": 100, "output_bytes": 10,
-                    "temp_bytes": 5, "generated_code_bytes": 999}
-        # generated_code_bytes is NOT streamed per step — excluded.
-        assert roofline.traffic_bytes(analysis, 7, 3) == 125.0
-
-    def test_tolerates_missing_pieces(self):
-        assert roofline.traffic_bytes(None) == 0.0
-        assert roofline.traffic_bytes({"argument_bytes": None}, 5) == 5.0
 
 
 class TestPredict:

@@ -1,7 +1,6 @@
 """graftcheck CLI — run passes, apply suppressions, report, exit.
 
-Exit codes (scripts consume these — scripts/chip_window_queue.sh gates the
-chip window on 0):
+Exit codes (scripts consume these; a caller gates on 0):
 
   * ``0`` — clean (every finding suppressed or none at all)
   * ``1`` — unsuppressed findings
