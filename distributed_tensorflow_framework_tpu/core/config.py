@@ -257,7 +257,7 @@ class ModelConfig:
     #                  the activation bytes. ResNet only.
     remat_policy: str = "full"
     # Decoder family (models/lfm2.py; names "lfm2*", "smallthinker*",
-    # "nemotron*", "laguna*" and "kanana*").
+    # "nemotron*", "laguna*", "kanana*" and "kimi*").
     # Knobs it shares with the BERT family keep their names: vocab_size,
     # hidden_size, num_layers, num_heads, mlp_dim (the dense SwiGLU
     # width), num_experts (the router's width), expert_topk,
@@ -267,7 +267,8 @@ class ModelConfig:
     # "sliding_attention" (the same inside a window of sliding_window
     # keys, over sliding_num_heads query heads where that is set) or
     # "latent_attention" (multi-head latent attention, the mla_*
-    # settings), each followed by a feed-forward; or a layer of ONE
+    # settings) or "kda" (Kimi Delta Attention, the kda_* settings), each
+    # followed by a feed-forward; or a layer of ONE
     # sublayer,
     # x + sublayer(RMSNorm(x)): "mamba2_only" (the Mamba-2 mixer),
     # "attention_only" (causal grouped-query attention) or "experts_only"
@@ -355,7 +356,8 @@ class ModelConfig:
     # heads / tensor_groups (num_heads, or sliding_num_heads in a window
     # layer) with the key/value heads they read, a Mamba-2 layer
     # mamba_num_heads / tensor_groups heads with mamba_groups /
-    # tensor_groups B/C groups, a dense feed-forward mlp_dim /
+    # tensor_groups B/C groups, a kda layer kda_heads / tensor_groups
+    # heads, a dense feed-forward mlp_dim /
     # tensor_groups and a shared expert moe_shared_dim / tensor_groups of
     # their hidden units (a unit, gated or not, is elementwise in them);
     # each adds its heads' (units') part of the out-projection's sum and
@@ -402,6 +404,14 @@ class ModelConfig:
     mla_nope_dim: int = 0
     mla_rope_dim: int = 0
     mla_v_dim: int = 0
+    # A "kda" layer (Kimi Delta Attention, the gated delta rule with a
+    # decay per key channel): kda_heads heads of kda_head_dim channels
+    # for q, k and v, each through a depthwise causal convolution of
+    # kda_conv_kernel taps, the recurrence computed as a chunked scan
+    # (ops/delta_rule.py). 0 heads: unset, and a kda layer is refused.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_kernel: int = 4
 
 
 @config_dataclass

@@ -41,14 +41,14 @@ def _is_builtin_model_name(name: str) -> bool:
 def _is_lfm2_name(name: str) -> bool:
     """The causal decoder family of models/lfm2.py: ``lfm2``,
     ``lfm2_moe``, ``lfm2-8b-a1b``, ``smallthinker``,
-    ``smallthinker_moe``, ``nemotron_h``, ``laguna``, ``kanana`` — the
-    whole ``lfm2``, ``smallthinker``, ``nemotron``, ``laguna`` and
-    ``kanana`` prefixes are reserved (the models differ by ModelConfig
-    settings, not by class).
+    ``smallthinker_moe``, ``nemotron_h``, ``laguna``, ``kanana``,
+    ``kimi_linear`` — the whole ``lfm2``, ``smallthinker``, ``nemotron``,
+    ``laguna``, ``kanana`` and ``kimi`` prefixes are reserved (the models
+    differ by ModelConfig settings, not by class).
     One test for
     get_model, the task and the decode refusal."""
     return name.lower().startswith(
-        ("lfm2", "smallthinker", "nemotron", "laguna", "kanana"))
+        ("lfm2", "smallthinker", "nemotron", "laguna", "kanana", "kimi"))
 
 
 def builtin_task(name: str) -> str:

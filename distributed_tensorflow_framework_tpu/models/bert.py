@@ -429,7 +429,9 @@ def decode_support_reason(model_config) -> str | None:
                 f"window of them for its sliding layers, a latent and one "
                 f"rotated key a token for its latent attention layers, the last "
                 f"conv_kernel-1 gated inputs for its short convolutions, "
-                f"the recurrent state of its Mamba-2 layers) "
+                f"the recurrent state of its Mamba-2 and kda layers, and "
+                f"the last kda_conv_kernel-1 inputs of a kda layer's "
+                f"convolutions) "
                 f"that serve/decode.py does not have")
     if name not in ("bert", "bert_base", "bert-base"):
         return (f"model {model_config.name!r} has no causal decode head "

@@ -1,5 +1,5 @@
 """The causal decoder family: a stack whose mixer is chosen per layer,
-over dropless experts. Five published models run through it, told apart
+over dropless experts. Six published models run through it, told apart
 by ``ModelConfig`` settings alone: LFM2-MoE (LiquidAI LFM2-8B-A1B,
 ``model_type: lfm2_moe``: gated short convolutions, grouped-query
 attention, bias-routed SwiGLU experts, a tied head; every default below
@@ -16,7 +16,11 @@ layer's head, a per-head output gate, softmax-routed SwiGLU experts with
 scaled weights beside a gated shared expert) and kanana (kakaocorp
 kanana-2-30b-a3b, ``model_type: deepseek_v3``: multi-head latent
 attention with interleaved rotary pairs in every layer, bias-routed
-SwiGLU experts with scaled weights beside an unscaled shared expert).
+SwiGLU experts with scaled weights beside an unscaled shared expert) and
+Kimi Linear (moonshotai Kimi-Linear-48B-A3B, ``model_type:
+kimi_linear``: three Kimi Delta Attention layers to one latent attention
+layer without positions, bias-routed SwiGLU experts with scaled weights
+beside an unscaled shared expert).
 
 Pre-norm blocks, ``h = x + mixer(RMSNorm(x))``, ``y = h + ffn(RMSNorm(h))``,
 one mixer kind per layer (``ModelConfig.layer_types``):
@@ -58,7 +62,23 @@ one mixer kind per layer (``ModelConfig.layer_types``):
                      softmax over keys of ``mla_nope_dim + mla_rope_dim``
                      and values of ``mla_v_dim`` (the kernels take value
                      heads of their own width); ``W_o``. No q/k norm, no
-                     gate.
+                     gate. Where ``rope_layout`` gives the layer 0
+                     nothing turns: the ``mla_rope_dim`` dims of q and of
+                     the shared key pass as they are (no positions in the
+                     layer).
+  kda                Kimi Delta Attention (``KimiDeltaAttention``):
+                     ``q, k, v = silu(conv(u W_q))`` etc., each a
+                     depthwise causal convolution of ``kda_conv_kernel``
+                     taps without bias, over ``kda_heads`` heads of
+                     ``kda_head_dim``; q and k L2-normed per head; a
+                     decay per key channel ``g = -exp(A_log) ·
+                     softplus(u W_fa W_fb + dt_bias)`` and a write rate
+                     ``β = sigmoid(u W_b)`` a head; the gated delta rule
+                     ``S_t = (I - β_t k_t k_tᵀ) Diag(exp g_t) S_{t-1} +
+                     β_t k_t v_tᵀ``, ``o_t = S_tᵀ q_t / sqrt(kda_head_dim)``
+                     as a chunked scan (ops/delta_rule.py, chunks of
+                     64 tokens); ``RMSNorm(o) ⊙ w ⊙ sigmoid(u W_ga
+                     W_gb)`` per head; ``W_o``.
 
 Or a layer is ONE sublayer, ``y = x + sublayer(RMSNorm(x))``
 (``SoloBlock``):
@@ -83,7 +103,11 @@ has something to split: an attention layer of any kind holds its query
 heads ``/ tensor_groups`` (``num_heads``, or ``sliding_num_heads`` in a
 window layer) with the key/value heads they read and the gate's columns
 of those heads (a latent attention layer ``W_q``'s and ``W_kvb``'s
-columns of its heads and ``W_o``'s rows of their values), a Mamba-2 layer ``mamba_num_heads / tensor_groups`` heads
+columns of its heads and ``W_o``'s rows of their values), a ``kda``
+layer ``kda_heads / tensor_groups`` heads (their columns of ``W_q``,
+``W_k``, ``W_v``, their taps, ``W_fb``, ``dt_bias``, ``A_log``, ``W_b``
+and ``W_gb``, and ``W_o``'s rows; ``W_fa``, ``W_ga`` and the output
+norm's scale whole), a Mamba-2 layer ``mamba_num_heads / tensor_groups`` heads
 with ``mamba_groups / tensor_groups`` B/C groups (its gated norm is over
 its own groups), a dense feed-forward ``mlp_dim / tensor_groups`` and a
 shared expert ``moe_shared_dim / tensor_groups`` of their hidden units
@@ -107,7 +131,7 @@ a matrix of its own.
 Packed rows (``segment_ids``, 0 on padding): attention stays inside a
 document, ``positions`` restart at each document, a convolution tap
 that would reach across a document boundary reads zero, and a Mamba-2
-layer's state starts from zero at each document.
+or ``kda`` layer's state starts from zero at each document.
 
 What ``model.remat`` keeps: ``remat`` checkpoints each layer
 (``nn.remat``). With no policy handed down (``precision.remat_policy:
@@ -146,11 +170,16 @@ and the shared key's broadcast into every head),
 ``layerN/{mlp_in,mlp_up,mlp_out}``,
 ``layerN/moe/{router,dispatch,experts,combine}`` and, around them,
 ``layerN/moe/{latent_in,latent_out,shared}``,
-``layerN/mamba/{in_proj,conv,scan,gate_norm,out_proj}``, ``lm_head``.
-Counters beside the logits: ``moe_*`` (the mean over the layers that
-have experts), ``attn_window_block_share``, ``attn_window_grid_share``,
-``attn_causal_grid_share``, ``ssm_resets`` and ``attn_gate_mean`` (the
-gate's mean over heads, tokens and gated layers).
+``layerN/mamba/{in_proj,conv,scan,gate_norm,out_proj}``,
+``layerN/kda/{qkv_proj,conv,gates,delta_scan,o_norm_gate,o_proj}``,
+``lm_head``. Counters beside the logits: ``moe_*`` (the mean over the
+layers that have experts), ``attn_window_block_share``,
+``attn_window_grid_share``, ``attn_causal_grid_share``, ``ssm_resets``,
+``attn_gate_mean`` (the gate's mean over heads, tokens and gated layers),
+``kda_resets`` (document starts a ``kda`` layer's scan resets at),
+``kda_decay_mean`` and ``kda_beta_mean`` (the means of ``exp g`` over held
+channels and of ``β`` over held heads, on real tokens, over the ``kda``
+layers).
 """
 
 from __future__ import annotations
@@ -169,7 +198,7 @@ from distributed_tensorflow_framework_tpu.models.moe import (
 
 # A layer is a mixer and then a feed-forward ...
 LAYER_KINDS = ("conv", "full_attention", "sliding_attention",
-               "latent_attention")
+               "latent_attention", "kda")
 # ... or ONE sublayer, ``x + sublayer(RMSNorm(x))``: a mixer alone or the
 # expert feed-forward alone.
 SOLO_KINDS = ("mamba2_only", "attention_only", "experts_only")
@@ -188,6 +217,12 @@ MOE_COUNTERS = ("local_assignments", "load_max_mean", "dropped",
 # over heads and tokens, averaged over the gated layers and named
 # ``attn_gate_mean`` in the step's metrics.
 GATE_COUNTER = "attn_gate"
+# What a kda layer reports beside them: the means of its decay exp(g) and
+# of its write rate β, averaged over the kda layers and named
+# ``kda_decay_mean`` and ``kda_beta_mean``.
+KDA_COUNTERS = ("kda_decay", "kda_beta")
+# Epsilon of the L2 norm of a kda layer's q and k, per head.
+KDA_L2_EPS = 1e-6
 # How Mamba-2 draws the step size a head starts from (``dt_bias`` is its
 # inverse softplus): log-uniform between the two, floored. The published
 # ``time_step_min/max/floor``; they shape this init and nothing else.
@@ -583,7 +618,11 @@ class LatentAttention(nn.Module):
     token; ``[k_n, v] = RMSNorm(c) W_kvb`` per head; ``q_h = [q_n ;
     rot(q_r)]``, ``k_h = [k_n ; rot(k_r)]`` with the same ``rot(k_r)`` in
     every head; ``o = softmax(q kᵀ / sqrt(nope_dim + rope_dim)) v``, heads
-    of ``v_dim``; ``o W_o``. No bias, no q/k norm."""
+    of ``v_dim``; ``o W_o``. No bias, no q/k norm. ``rope`` False: ``rot``
+    is the identity, the layer has no positions (Kimi Linear's
+    ``mla_use_nope``), and nothing runs under ``qk_norm_rope``; the key's
+    ``rope_dim`` dims are still one a token, shared by every head, and
+    the scale still counts them."""
 
     num_heads: int
     dims: LatentDims
@@ -594,6 +633,7 @@ class LatentAttention(nn.Module):
     mesh: Any = None
     out_init_std: float = 0.0    # of o_proj; 0: the fan-in rule
     rope_rule: Any = None        # a RotaryRule; None: the plain rule
+    rope: bool = True            # the rope_dim dims of q and k rotate
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
@@ -609,12 +649,15 @@ class LatentAttention(nn.Module):
             k_n, v = jnp.split(
                 _dense(n * (d_n + d_v), self.dtype, "kv_b_proj")(c).reshape(
                     b, s, n, d_n + d_v), [d_n], axis=-1)
-        with jax.named_scope("qk_norm_rope"):
-            q_n, q_r = jnp.split(q, [d_n], axis=-1)
-            q_r = rotary(q_r, positions, self.rope_theta, self.rope_rule)
-            q = jnp.concatenate([q_n, q_r.astype(self.dtype)], axis=-1)
-            k_r = rotary(k_r[:, :, None, :], positions, self.rope_theta,
-                         self.rope_rule).astype(self.dtype)
+        if self.rope:
+            with jax.named_scope("qk_norm_rope"):
+                q_n, q_r = jnp.split(q, [d_n], axis=-1)
+                q_r = rotary(q_r, positions, self.rope_theta, self.rope_rule)
+                q = jnp.concatenate([q_n, q_r.astype(self.dtype)], axis=-1)
+                k_r = rotary(k_r[:, :, None, :], positions, self.rope_theta,
+                             self.rope_rule).astype(self.dtype)
+        else:
+            k_r = k_r[:, :, None, :]
         with jax.named_scope("mla_latent"):
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(k_r, (b, s, n, d_r))], axis=-1)
@@ -629,6 +672,93 @@ class LatentAttention(nn.Module):
             out = causal_attention_xla(q, k, v, segment_ids, self.dtype)
         return projection(h, self.dtype, "o_proj", self.out_init_std)(
             out.reshape(b, s, n * d_v))
+
+
+class KdaDims(NamedTuple):
+    """The settings of a ``kda`` layer: the heads held here, each head's
+    channels (q, k and v alike) and the convolutions' taps."""
+    heads: int
+    head_dim: int
+    conv_kernel: int
+
+
+def l2_normed(x, eps: float = KDA_L2_EPS):
+    """``x / sqrt(Σ x² + eps)`` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                             + eps)
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692) over the heads it is given,
+    ``u`` the normed input, ``n`` heads of ``d``: ``q, k, v =
+    silu(conv(u W_q))``, ``silu(conv(u W_k))``, ``silu(conv(u W_v))``,
+    each a depthwise causal convolution of ``conv_kernel`` taps without
+    bias that reads zero across a document's start; q and k L2-normed per
+    head; the log decay of each key channel ``g = -exp(A_log_h) ·
+    softplus(u W_fa W_fb + dt_bias)`` (``W_fa`` hidden -> ``d``, whole on
+    every chip) and the write rate ``β = sigmoid(u W_b)``, one a head;
+    the gated delta rule ``S_t = (I - β_t k_t k_tᵀ) Diag(exp g_t) S_{t-1}
+    + β_t k_t v_tᵀ`` from 0 at each document, ``o_t = S_tᵀ q_t /
+    sqrt(d)`` (ops/delta_rule.py); ``y = RMSNorm_d(o) ⊙ w ⊙ sigmoid(u W_ga
+    W_gb)`` per head (``W_ga`` hidden -> ``d`` and ``w`` whole), ``y
+    W_o``. No bias anywhere. Returns ``(result, counters)``: the means
+    of ``exp g`` and of ``β`` on real tokens (``KDA_COUNTERS``)."""
+
+    dims: KdaDims
+    norm_eps: float = 1e-5
+    out_init_std: float = 0.0    # of o_proj; 0: the fan-in rule
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, segment_ids):
+        from distributed_tensorflow_framework_tpu.ops.delta_rule import (
+            chunked_delta_rule,
+        )
+
+        b, s, hidden = x.shape
+        n, d, taps_n = self.dims
+        with jax.named_scope("qkv_proj"):
+            qkv = [_dense(n * d, self.dtype, f"{name}_proj")(x)
+                   for name in "qkv"]
+        taps = [self.param(f"{name}_conv1d", _conv_init(taps_n),
+                           (taps_n, n * d), jnp.float32) for name in "qkv"]
+        with jax.named_scope("conv"):
+            q, k, v = (nn.silu(causal_depthwise_conv(
+                t, w.astype(self.dtype), segment_ids)).reshape(b, s, n, d)
+                for t, w in zip(qkv, taps))
+            q = l2_normed(q).astype(self.dtype)
+            k = l2_normed(k).astype(self.dtype)
+        dt_bias = self.param("dt_bias", _mamba_dt_bias_init, (n * d,),
+                             jnp.float32)
+        a_log = self.param("A_log", _mamba_a_log_init, (n,), jnp.float32)
+        with jax.named_scope("gates"):
+            f = _dense(n * d, self.dtype, "f_b_proj")(
+                _dense(d, self.dtype, "f_a_proj")(x))
+            g = -jnp.exp(a_log)[:, None] * nn.softplus(
+                f.astype(jnp.float32).reshape(b, s, n, d)
+                + dt_bias.reshape(n, d))
+            beta = jax.nn.sigmoid(
+                _dense(n, self.dtype, "b_proj")(x).astype(jnp.float32))
+            real = (segment_ids > 0).astype(jnp.float32)
+            tokens = jnp.maximum(jnp.sum(real), 1.0)
+            counters = {
+                "kda_decay": jnp.sum(jnp.mean(jnp.exp(g), axis=(2, 3))
+                                     * real) / tokens,
+                "kda_beta": jnp.sum(jnp.mean(beta, axis=2) * real) / tokens}
+        with jax.named_scope("delta_scan"):
+            o = chunked_delta_rule(q, k, v, g, beta, segment_ids)
+        scale = self.param("o_norm", nn.initializers.ones, (d,), jnp.float32)
+        with jax.named_scope("o_norm_gate"):
+            gate = _dense(n * d, self.dtype, "g_b_proj")(
+                _dense(d, self.dtype, "g_a_proj")(x))
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + self.norm_eps)
+            o = (o * scale * jax.nn.sigmoid(
+                gate.astype(jnp.float32).reshape(b, s, n, d))).astype(
+                    self.dtype)
+        return projection(hidden, self.dtype, "o_proj", self.out_init_std)(
+            o.reshape(b, s, n * d)), counters
 
 
 class Lfm2Block(nn.Module):
@@ -661,20 +791,26 @@ class Lfm2Block(nn.Module):
     routed_scaling: float = 1.0
     out_init_std: float = 0.0    # of attn_out, mlp_out, the shared down
     latent: Any = None           # LatentDims of a latent_attention layer
+    kda: Any = None              # KdaDims of a kda layer
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
         stream = x               # what ``router_input: stream`` reads
         normed = RMSNorm(self.norm_eps, name="mixer_norm")(x)
+        mixer_counters = {}
         if self.kind == "conv":
             mixed = ShortConv(self.conv_kernel, self.dtype,
                               name="short_conv")(normed, segment_ids)
+        elif self.kind == "kda":
+            mixed, mixer_counters = KimiDeltaAttention(
+                self.kda, self.norm_eps, self.out_init_std, self.dtype,
+                name="kda")(normed, segment_ids)
         elif self.kind == "latent_attention":
             mixed = LatentAttention(
                 self.num_heads, self.latent, self.rope_theta, self.norm_eps,
                 self.dtype, self.attention_impl, self.mesh,
                 out_init_std=self.out_init_std, rope_rule=self.rope_rule,
-                name=ATTENTION_SCOPES[self.kind],
+                rope=self.rope, name=ATTENTION_SCOPES[self.kind],
             )(normed, segment_ids, positions)
         else:
             sliding = self.kind == "sliding_attention"
@@ -689,7 +825,7 @@ class Lfm2Block(nn.Module):
                 name=ATTENTION_SCOPES[self.kind],
             )(normed, segment_ids, positions)
         gate_mean = None
-        if self.kind != "conv" and self.attention_gate != "none":
+        if self.kind not in ("conv", "kda") and self.attention_gate != "none":
             mixed, gate_mean = mixed
         x = x + mixed
         normed = RMSNorm(self.norm_eps, name="ffn_norm")(x)
@@ -717,7 +853,7 @@ class Lfm2Block(nn.Module):
             )(normed, *route_from)
         if gate_mean is not None:
             counters = {**counters, GATE_COUNTER: gate_mean}
-        return x + y.astype(x.dtype), counters
+        return x + y.astype(x.dtype), {**counters, **mixer_counters}
 
 
 class SoloBlock(nn.Module):
@@ -815,6 +951,7 @@ class Lfm2ForCausalLM(nn.Module):
     sliding_rope_rule: Any = None    # ... which is this one (None: plain)
     attention_gate: str = "none"
     latent: Any = None           # LatentDims of the latent_attention layers
+    kda: Any = None              # KdaDims of the kda layers, heads uncut
 
     def has_experts(self, i: int) -> bool:
         return layer_has_experts(self.layer_types, self.num_dense_layers, i)
@@ -845,8 +982,11 @@ class Lfm2ForCausalLM(nn.Module):
         # the window layers apart where their head count is their own
         own_window = {"sliding_attention"} & kinds \
             if self.sliding_num_heads else set()
-        if kinds - {"mamba2_only", "experts_only"} - own_window:
+        if kinds - {"mamba2_only", "experts_only", "kda"} - own_window:
             share["attention"] = attention("full_attention")
+        if "kda" in kinds:
+            share["kda"] = {"heads": self.kda.heads,
+                            "held": held(self.kda.heads)}
         if own_window:
             share["attention_window"] = attention("sliding_attention")
         if "mamba2_only" in kinds:
@@ -1041,6 +1181,7 @@ class Lfm2ForCausalLM(nn.Module):
         totals = {key: jnp.zeros((), jnp.float32) for key in MOE_COUNTERS}
         n_moe = 0
         gates = []               # the gated attention layers' gate means
+        kda = []                 # the kda layers' decay and rate means
         for i, kind in enumerate(self.layer_types):
             if kind in SOLO_KINDS:
                 block = solo_cls(
@@ -1072,6 +1213,9 @@ class Lfm2ForCausalLM(nn.Module):
                     out_init_std=self.out_proj_init_std,
                     latent=self.latent if kind == "latent_attention"
                     else None,
+                    kda=self.kda._replace(
+                        heads=self.kda.heads // self.tensor_groups)
+                    if kind == "kda" else None,
                     name=f"layer{i}")
             x, counters = block(x, segment_ids, positions)
             if self.has_experts(i):
@@ -1080,6 +1224,8 @@ class Lfm2ForCausalLM(nn.Module):
                 n_moe += 1
             if GATE_COUNTER in counters:
                 gates.append(counters[GATE_COUNTER])
+            if KDA_COUNTERS[0] in counters:
+                kda.append([counters[key] for key in KDA_COUNTERS])
         x = RMSNorm(self.norm_eps, name="final_norm")(x)
         if self.tie_embeddings:
             head = embed.embedding
@@ -1103,12 +1249,17 @@ class Lfm2ForCausalLM(nn.Module):
             counters["attn_causal_grid_share"] = jnp.float32(share)
         if gates:
             counters["attn_gate_mean"] = sum(gates) / len(gates)
-        if "mamba2_only" in self.layer_types:
-            # Document starts the scan resets at in these rows (the
-            # row's first token is one).
-            counters["ssm_resets"] = jnp.sum(
-                document_starts(segment_ids) & (segment_ids > 0)
-            ).astype(jnp.float32)
+        for kind, name in (("mamba2_only", "ssm_resets"),
+                           ("kda", "kda_resets")):
+            if kind in self.layer_types:
+                # Document starts the scan resets at in these rows (the
+                # row's first token is one).
+                counters[name] = jnp.sum(
+                    document_starts(segment_ids) & (segment_ids > 0)
+                ).astype(jnp.float32)
+        if kda:
+            for key, values in zip(KDA_COUNTERS, zip(*kda)):
+                counters[f"{key}_mean"] = sum(values) / len(values)
         if not counters:
             return logits
         return {"logits": logits, **counters}
@@ -1149,9 +1300,9 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
                         or set(rope_layout) - {0, 1}):
         raise ValueError(
             f"model.rope_layout must give 0 or 1 for each of "
-            f"model.num_layers={config.num_layers} layers (1: a "
-            f"full_attention or sliding_attention layer rotates its "
-            f"queries and keys), or be empty for all of them, got "
+            f"model.num_layers={config.num_layers} layers (1: an "
+            f"attention layer of any kind rotates its queries and keys), "
+            f"or be empty for all of them, got "
             f"{rope_layout}")
     if config.router_input not in ROUTER_INPUTS:
         raise ValueError(f"model.router_input must be one of "
@@ -1201,7 +1352,22 @@ def build(config, *, mesh=None, dtype=jnp.bfloat16, ckpt_policy=None):
         sliding_num_heads=config.sliding_num_heads,
         rope_rule=rope_rule, sliding_rope_theta=config.sliding_rope_theta,
         sliding_rope_rule=sliding_rope_rule,
-        attention_gate=config.attention_gate, latent=latent)
+        attention_gate=config.attention_gate, latent=latent,
+        kda=_kda_dims(config, kinds))
+
+
+def _kda_dims(config, kinds) -> KdaDims | None:
+    """The kda layers' settings, heads uncut, refused where one is unset;
+    None for a stack without them."""
+    if "kda" not in kinds:
+        return None
+    dims = KdaDims(config.kda_heads, config.kda_head_dim,
+                   config.kda_conv_kernel)
+    if min(dims) < 1:
+        raise ValueError(
+            "a kda layer needs model.kda_heads, model.kda_head_dim and "
+            f"model.kda_conv_kernel >= 1, got {dims}")
+    return dims
 
 
 def _latent_dims(config, kinds) -> LatentDims | None:
@@ -1217,12 +1383,10 @@ def _latent_dims(config, kinds) -> LatentDims | None:
             "a latent_attention layer needs model.mla_kv_rank, "
             "model.mla_nope_dim, model.mla_v_dim and an even "
             f"model.mla_rope_dim, each >= 1, got {dims}")
-    if (config.qk_norm or config.attention_gate != "none"
-            or 0 in config.rope_layout):
+    if config.qk_norm or config.attention_gate != "none":
         raise ValueError(
-            "a latent_attention layer has no q/k norm and no gate and "
-            "always rotates: set model.qk_norm=false, "
-            "model.attention_gate=none and no 0 in model.rope_layout")
+            "a latent_attention layer has no q/k norm and no gate: set "
+            "model.qk_norm=false and model.attention_gate=none")
     return dims
 
 
@@ -1321,6 +1485,10 @@ def _check_tensor_share(config, kinds, heads: int, kv_heads: int) -> None:
         raise ValueError(
             f"model.tensor_groups={groups} does not divide the dense "
             f"feed-forward's {config.mlp_dim} units (model.mlp_dim)")
+    if "kda" in kinds and config.kda_heads % groups:
+        raise ValueError(
+            f"model.tensor_groups={groups} does not divide the kda layers' "
+            f"{config.kda_heads} heads (model.kda_heads) into whole shares")
     if "mamba2_only" in kinds and config.mamba_groups % groups:
         raise ValueError(
             f"model.tensor_groups={groups} splits a B/C group of the Mamba-2 "

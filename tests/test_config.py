@@ -271,7 +271,7 @@ def test_latent_attention_settings_parse_from_overrides():
     (["model.mla_v_dim=0"], "mla_v_dim"),
     (["model.mla_rope_dim=5"], "mla_rope_dim"),
     (["model.qk_norm=true"], "no q/k norm"),
-    (["model.rope_layout=[1,0]"], "always rotates"),
+    (["model.attention_gate=per_head"], "no gate"),
     (["model.rope_pairs=adjacent"], "rope_pairs"),
 ])
 def test_a_latent_layer_without_its_settings_is_refused(bad, says):
